@@ -19,34 +19,3 @@ re-designed TPU-first:
 
 __version__ = "0.1.0"
 
-
-def _install_jax_compat():
-    """Gate the package's jax surface onto older installs: the parallel
-    modules call ``jax.shard_map(..., check_vma=...)`` (the stable API);
-    on a jax that predates it (<= 0.4.x) the same primitive lives at
-    ``jax.experimental.shard_map`` with the flag named ``check_rep``.
-    Installed once at package import so every submodule (they all
-    ``import jax`` and call ``jax.shard_map`` at trace time) sees one
-    consistent callable; a no-op on current jax."""
-    import jax
-    from jax import lax
-
-    if not hasattr(lax, "axis_size"):
-        # psum of the literal 1 constant-folds to the (concrete) axis
-        # size at trace time — the pre-axis_size idiom, so callers can
-        # keep doing static math (capacity ceil, 1/P seeds) on it
-        lax.axis_size = lambda axis_name: lax.psum(1, axis_name)
-    if hasattr(jax, "shard_map"):
-        return
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, mesh=None, in_specs=None, out_specs=None,
-                  check_vma=True, **kwargs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma,
-                          **kwargs)
-
-    jax.shard_map = shard_map
-
-
-_install_jax_compat()

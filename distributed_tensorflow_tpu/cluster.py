@@ -252,10 +252,7 @@ def maybe_initialize_distributed(cluster: ClusterSpec, task_index: int,
     # "Multiprocess computations aren't implemented on the CPU backend".
     # Opt into gloo BEFORE backend init; real TPU platforms are untouched.
     if (jax.config.jax_platforms or "").startswith("cpu"):
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # noqa: BLE001 — older jax: no such flag, no need
-            pass
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
     coordinator = _epoch_coordinator(workers[0],
                                      int(membership_epoch or 0))
@@ -268,12 +265,7 @@ def maybe_initialize_distributed(cluster: ClusterSpec, task_index: int,
         kwargs["initialization_timeout"] = int(init_timeout_s)
 
     def _init():
-        try:
-            jax.distributed.initialize(**kwargs)
-        except TypeError:
-            # older jax without initialization_timeout: library default
-            kwargs.pop("initialization_timeout", None)
-            jax.distributed.initialize(**kwargs)
+        jax.distributed.initialize(**kwargs)
 
     def _cleanup():
         # a failed connect leaves global_state.client set; a bare retry

@@ -153,8 +153,8 @@ class DataSet:
         Host->device traffic per example drops from 3176 B (f32 pixels +
         one-hot f32) to 788 B; models normalize on device (uint8 inputs are
         recognized in ``apply``) and the loss/accuracy ops accept integer
-        labels. On tunneled or PCIe-attached accelerators the input link is
-        the throughput ceiling, so this is the fast path ``bench.py`` and
+        labels. Where the host-to-device input link is the throughput
+        ceiling this is the fast path ``bench.py`` and
         ``--raw_input`` use. Same shuffled-epoch index stream as
         ``next_batch``.
         """

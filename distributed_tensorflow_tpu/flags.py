@@ -130,6 +130,11 @@ def run(main: Callable | None = None, argv=None):
     except ValueError as e:
         print(f"error: {e}", file=sys.stderr)
         sys.exit(2)
+    from distributed_tensorflow_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()  # before main: nothing has compiled yet
     main = main or sys.modules["__main__"].main
     sys.exit(main([sys.argv[0]] + extra))
 

@@ -256,8 +256,8 @@ def _ring_forward(q, k, v, axis_name, causal):
     qf = q.astype(jnp.float32)
     perm = [(i, (i + 1) % p_size) for i in range(p_size)]
     # axis_index only when the causal mask needs global positions: a
-    # non-causal ring never reads it, and a dead PartitionId in the
-    # lowered module breaks CPU SPMD partitioning on older jaxlibs
+    # non-causal ring never reads it, so it stays out of the lowered
+    # module
     me = lax.axis_index(axis_name) if causal else jnp.int32(0)
     row_global = me * sq + jnp.arange(sq)  # my queries' global positions
 

@@ -21,21 +21,20 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend may be absent on CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-
-    _MEMSPACE = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _MEMSPACE = None
+from jax.experimental.pallas import tpu as pltpu
 
 TILE_M = 128
 TILE_N = 128
 
 
 def _kernel(x_ref, w_ref, b_ref, o_ref):
-    acc = jnp.dot(x_ref[:], w_ref[:], preferred_element_type=jnp.float32)
+    # bf16 operands take the MXU's native pass whatever
+    # jax_default_matmul_precision says (the tests set "highest"): Mosaic
+    # refuses an fp32-precision contraction of bf16 vectors ("Bad lhs type")
+    precision = (jax.lax.Precision.DEFAULT
+                 if x_ref.dtype == jnp.bfloat16 else None)
+    acc = jnp.dot(x_ref[:], w_ref[:], precision=precision,
+                  preferred_element_type=jnp.float32)
     acc = acc + b_ref[:].astype(jnp.float32)  # b block is (1, TILE_N)
     o_ref[:] = jnp.maximum(acc, 0.0).astype(o_ref.dtype)
 
@@ -55,16 +54,10 @@ def _forward(x, w, b, interpret: bool = False):
     # bias as (1, Np): 1-D operands trip Mosaic/XLA layout mismatches
     bp = jnp.pad(b, (0, Np - N)).reshape(1, Np)
 
-    kwargs = {}
-    if _MEMSPACE is not None and not interpret:
-        in_space = _MEMSPACE
-    else:
-        in_space = None
-
     def spec(shape, index_map):
-        if in_space is not None:
-            return pl.BlockSpec(shape, index_map, memory_space=in_space)
-        return pl.BlockSpec(shape, index_map)
+        if interpret:
+            return pl.BlockSpec(shape, index_map)
+        return pl.BlockSpec(shape, index_map, memory_space=pltpu.VMEM)
 
     out = pl.pallas_call(
         _kernel,
@@ -77,7 +70,6 @@ def _forward(x, w, b, interpret: bool = False):
         out_specs=spec((TILE_M, TILE_N), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), x.dtype),
         interpret=interpret,
-        **kwargs,
     )(xp, wp, bp)
     return out[:M, :N]
 

@@ -1091,6 +1091,24 @@ def _mirror_train_loop(client, FLAGS, train_data, grad_fn, eval_fn,
     return cyc.step
 
 
+def _worker_devices(task_index: int) -> list:
+    """This worker's accelerator devices, or an exit that says why there
+    are none. An accelerator belongs to ONE process at a time and a
+    worker takes every chip of its host, so a second worker on the host
+    fails in backend start-up (a lock-file error on a TPU, seconds in)
+    — say that, instead of the runtime's advice to delete the lock."""
+    try:
+        return jax.local_devices()
+    except RuntimeError as e:
+        raise SystemExit(
+            f"worker/{task_index}: JAX could not start its backend: {e}\n"
+            f"worker/{task_index}: an accelerator belongs to one process "
+            f"at a time and a worker takes every chip of its host, so a "
+            f"host runs ONE worker (ps tasks are host-only and do not "
+            f"count). If another process on this host holds the chips, "
+            f"stop it — do not remove the runtime's lock file.") from e
+
+
 def run_worker(cluster, FLAGS) -> int:
     """The worker role: async stale-gradient SGD against the ps tasks —
     the reference's hot loop (MNISTDist.py:172-188) with XLA compute."""
@@ -1103,6 +1121,9 @@ def run_worker(cluster, FLAGS) -> int:
     err = ps_unsupported_flag_error(FLAGS)
     if err is not None:
         raise ValueError(err)
+    # before any wait on the ps tasks: a worker with no chip must not sit
+    # in wait_ready()/wait_initialized() looking alive
+    n_local = len(_worker_devices(FLAGS.task_index))
     ds = read_data_sets(FLAGS.data_dir, one_hot=True, dataset=FLAGS.dataset,
                         seed=FLAGS.seed + FLAGS.task_index,
                         seq_len=getattr(FLAGS, "seq_len", 256),
@@ -1146,7 +1167,6 @@ def run_worker(cluster, FLAGS) -> int:
     else:
         client.wait_initialized()
 
-    n_local = len(jax.local_devices())
     use_local_mesh = n_local > 1 and FLAGS.batch_size % n_local == 0
     if n_local > 1 and not use_local_mesh:
         print(f"worker/{FLAGS.task_index}: --batch_size={FLAGS.batch_size} is "

@@ -80,7 +80,13 @@ def build_serving_stack(FLAGS):
         mesh = make_mesh(MeshSpec(data=-1, model=int(FLAGS.serve_tp)))
     # continuous mode serves one replica per device: no mesh, pools
     # live on the default device (the flag validator already rejects
-    # --serve_tp > 1 with it)
+    # --serve_tp > 1 with it) — said aloud, so that a host with more
+    # devices is not taken to be serving on all of them
+    if continuous and len(jax.devices()) > 1:
+        print(f"continuous scheduler: this replica runs on "
+              f"{jax.devices()[0]} alone; {len(jax.devices()) - 1} other "
+              f"visible device(s) stay idle (one replica per device "
+              f"behind serving/router.py uses them)")
     engine = InferenceEngine(model, FLAGS.logdir, mesh=mesh, tp=tp,
                              max_batch=FLAGS.serve_max_batch)
     # resource plane (r13): the replica's memory meter + compile sentry

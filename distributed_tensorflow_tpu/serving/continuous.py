@@ -680,6 +680,12 @@ class ContinuousBatcher:
                 for r, res in finished:
                     if self.latency is not None:
                         self.latency.record((now - r.t_submit) * 1e3)
+                    if r.trace is not None:
+                        # every resident decodes on the backend's pinned
+                        # params (drain-to-swap), so the step at retire
+                        # is the step that served the whole request
+                        r.trace.served_step = getattr(
+                            sched.backend, "params_step", None)
                     # meta BEFORE the result, like the whole-batch path
                     r.future.meta = reqtrace.finish(r.trace, "ok")
                     r.future.set_result(res)

@@ -47,23 +47,26 @@ import time
 # accounting (Kaplan et al. 2020; Megatron-LM's 6ND has the same factor)
 TRAIN_FLOPS_MULTIPLIER = 3
 
-# bf16 peak FLOP/s per chip by device_kind substring (public TPU specs).
-# Checked in order; first match wins. "v5lite" covers the bare
-# "TPU v5 lite" device_kind this repo's flagship chip reports (which
-# contains neither "v5e" nor "v5litepod" once normalized).
-TPU_PEAK_FLOPS = (
-    ("v5p", 459e12),
-    ("v5litepod", 197e12),
-    ("v5lite", 197e12),
-    ("v5e", 197e12),
-    ("v4", 275e12),
-    ("v3", 123e12),
-    ("v2", 45e12),
-)
+# bf16 peak FLOP/s per chip, keyed by the exact ``device_kind`` string
+# JAX reports (Google Cloud TPU documentation, per-generation system
+# architecture pages). "TPU v5 lite" is what a v5e chip reports —
+# chip_smoke.py prints the string, tests/test_efficiency.py pins it. A
+# ``tpu`` platform whose kind is not a key here is an error, not a
+# default: an MFU over a guessed peak is not a measurement.
+TPU_PEAK_FLOPS = {
+    "TPU v5 lite": 197e12,
+    "TPU v5e": 197e12,
+    "TPU v5p": 459e12,
+    "TPU v5": 459e12,
+    "TPU v4": 275e12,
+    "TPU v3": 123e12,
+    "TPU v2": 45e12,
+}
 
-# matmul calibration (unknown backends, e.g. the CPU test mesh): one
-# square f32 matmul timed best-of-reps; achieved FLOP/s stands in for
-# peak. Cached per process — the loops must not pay it per run.
+# matmul calibration (the CPU test mesh only — never a ``tpu`` platform):
+# one square f32 matmul timed best-of-reps; achieved FLOP/s stands in
+# for peak, labeled ``matmul_calibration``. Cached per process — the
+# loops must not pay it per run.
 CALIBRATE_DIM = 1536
 CALIBRATE_REPS = 3
 
@@ -202,8 +205,6 @@ def xla_cost_flops(model, batch_size: int) -> float | None:
         params = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), params)
         step = jax.jit(jax.grad(loss_fn))
         cost = step.lower(params).compile().cost_analysis()
-        if isinstance(cost, (list, tuple)):  # older jax: one entry per device
-            cost = cost[0] if cost else {}
         flops = float(cost.get("flops", 0.0)) if cost else 0.0
         return flops if flops > 0 else None
     except Exception:  # noqa: BLE001 — absence of the stat, not an error
@@ -246,8 +247,8 @@ def flops_budget(model, batch_size: int = 1, *, xla: bool = False) -> dict:
 
 def _calibrate_matmul_peak() -> float:
     """Achieved FLOP/s of a square f32 matmul on the default backend —
-    the measured-achievable peak that stands in where no spec table
-    applies (the CPU test mesh, unknown accelerators)."""
+    the measured-achievable peak that stands in on the CPU test mesh,
+    where no spec table applies."""
     import jax
     import jax.numpy as jnp
 
@@ -265,27 +266,28 @@ def _calibrate_matmul_peak() -> float:
 
 def peak_flops_per_sec(override: float = 0.0) -> tuple[float, str]:
     """(peak FLOP/s per chip, source). Resolution order: an explicit
-    ``override`` (--mfu_peak_flops), the TPU spec table by device_kind,
-    else the cached matmul calibration."""
+    ``override`` (--mfu_peak_flops); on a ``tpu`` platform the spec
+    table by exact device_kind (an unknown kind raises); elsewhere (the
+    CPU test mesh) the cached matmul calibration."""
     if override and override > 0:
         return float(override), "flag_override"
     with _PEAK_LOCK:
-        if "peak" in _PEAK_CACHE:
-            return _PEAK_CACHE["peak"]
-        try:
+        if "peak" not in _PEAK_CACHE:
             import jax
 
-            kind = jax.devices()[0].device_kind.lower()
-            for tag, peak in TPU_PEAK_FLOPS:
-                if tag in kind.replace(" ", "").replace("tpu", ""):
-                    _PEAK_CACHE["peak"] = (peak, f"device_table:{tag}")
-                    return _PEAK_CACHE["peak"]
-            _PEAK_CACHE["peak"] = (_calibrate_matmul_peak(),
-                                   "matmul_calibration")
-        except Exception as e:  # noqa: BLE001 — accounting never kills a run
-            # no backend at all: a conservative 1 GFLOP/s floor keeps the
-            # ratio defined (and obviously-wrong enough to investigate)
-            _PEAK_CACHE["peak"] = (1e9, f"fallback:{type(e).__name__}")
+            device = jax.devices()[0]
+            kind = device.device_kind
+            if kind in TPU_PEAK_FLOPS:
+                _PEAK_CACHE["peak"] = (TPU_PEAK_FLOPS[kind],
+                                       f"device_table:{kind}")
+            elif device.platform == "tpu":
+                raise ValueError(
+                    f"no peak FLOP/s for device_kind {kind!r}: add its "
+                    f"published bf16 peak to utils.efficiency."
+                    f"TPU_PEAK_FLOPS or pass --mfu_peak_flops")
+            else:
+                _PEAK_CACHE["peak"] = (_calibrate_matmul_peak(),
+                                       "matmul_calibration")
         return _PEAK_CACHE["peak"]
 
 

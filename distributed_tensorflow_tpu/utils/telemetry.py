@@ -248,7 +248,7 @@ def get_tracer() -> Tracer:
 def record_span(name: str, *, ts: float, dur_s: float, **attrs) -> None:
     """Emit a retroactively-timed completed span to the global tracer
     (see ``Tracer.record_complete``) — the serving request plane's
-    emission entry point. Subject to the span taxonomy like
+    emission entry point. Subject to the span catalog like
     ``trace_span``/``record_instant`` (dttlint DTT005)."""
     _TRACER.record_complete(name, ts, dur_s, attrs or None)
 

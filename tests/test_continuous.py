@@ -378,6 +378,25 @@ def test_engine_parity_bitwise_mixed_lengths_one_signature(tmp_path):
     assert cs.site_signatures("serve_continuous_step") == 1
 
 
+def test_served_step_names_the_params_under_continuous(tmp_path, plane):
+    """The wire meta names the checkpoint step that served a request
+    under the continuous scheduler too (chip_smoke.py holds every
+    /v1/generate answer to the trainer's last step; the whole-batch path
+    stamps it through the engine, this one at retire)."""
+    model = TransformerLM(vocab_size=VOCAB, seq_len=SEQ, d_model=DM,
+                          num_heads=HEADS, num_blocks=BLOCKS)
+    save_checkpoint(str(tmp_path),
+                    create_train_state(model, sgd(0.1), seed=0), 10)
+    eng = InferenceEngine(model, str(tmp_path), max_batch=4)
+    b = _batcher(EngineSlotBackend(eng, n_slots=2, page_size=8))
+    try:
+        f = b.submit(np.array([1, 2, 3], np.int32), max_new_tokens=4)
+        assert len(f.result(timeout=120)) == 7
+        assert f.meta["served_step"] == 10
+    finally:
+        b.close()
+
+
 # ------------------------------------------------- bench + loadgen glue
 
 

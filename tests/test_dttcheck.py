@@ -9,7 +9,7 @@ CPU mesh; tracing is Python time, no chip anywhere).
 
 Fixture step functions mirror the builders' idiom: ``jax.shard_map``
 (the package shim) with ``check_vma=False`` and a ``jax.jit`` wrapper,
-so the fixtures exercise the same pjit/shard_map jaxpr shapes the real
+so the fixtures exercise the same jit/shard_map jaxpr shapes the real
 scenarios produce.
 """
 
@@ -102,9 +102,9 @@ def test_inventory_multiplies_scan_trips():
         ("ppermute", 5, 5 * 16)]
 
 
-def test_inventory_sees_checked_shard_map_psum2():
-    """A check_vma=True caller's psum stages as ``psum2`` — the walker
-    maps it to the psum family instead of going blind."""
+def test_inventory_sees_checked_shard_map_psum():
+    """A check_vma=True caller's psum stages as ``psum_invariant`` — the
+    walker maps it to the psum family instead of going blind."""
     mesh = _mesh8()
     fn = jax.shard_map(lambda v: jax.lax.psum(v, DATA_AXIS), mesh=mesh,
                        in_specs=P(DATA_AXIS), out_specs=P())
@@ -243,17 +243,17 @@ def test_collective_under_while_is_unprovable_finding():
 
 
 def test_unparseable_hlo_collective_fails_loudly():
-    """A collective line the HLO parser cannot read (variadic/tuple
-    result, async -start form) must become a finding, never a silent
-    skip — uncounted traffic breaks the whole proof."""
+    """A collective line the HLO parser cannot read (a variadic
+    all-gather, an async -start form) must become a finding, never a
+    silent skip — uncounted traffic breaks the whole proof."""
     from tools.dttcheck.inventory import hlo_inventory
 
     mesh = _mesh8()
-    hlo = ('  %ar = (f32[10]{0}, f32[128]{0}) all-reduce(%a, %b), '
-           'replica_groups={{0,1,2,3,4,5,6,7}}, to_apply=%add\n')
+    hlo = ('  %ag = (f32[80]{0}, f32[128]{0}) all-gather(%a, %b), '
+           'replica_groups={{0,1,2,3,4,5,6,7}}, dimensions={0}\n')
     inv = hlo_inventory(hlo, mesh)
     assert inv.entries == []
-    assert [op for op, _ in inv.unparsed] == ["all-reduce"]
+    assert [op for op, _ in inv.unparsed] == ["all-gather"]
     found = dtc_passes.pass_deadlock(_target(None, (), mesh), inv, None)
     assert [f.rule for f in found] == ["DTC002"]
     assert "could not read" in found[0].message
@@ -262,6 +262,24 @@ def test_unparseable_hlo_collective_fails_loudly():
           'replica_groups={{0,1,2,3,4,5,6,7}}, dimensions={0}\n')
     inv2 = hlo_inventory(ok, mesh)
     assert inv2.unparsed == [] and len(inv2.entries) == 1
+
+
+def test_variadic_all_reduce_is_priced_per_element():
+    """The collective combiner's merged all-reduce (tuple result,
+    operands by name) is one entry per element: arrays priced at 2x
+    their bytes, rank-0 elements exempt as control."""
+    from tools.dttcheck.inventory import hlo_inventory
+
+    mesh = _mesh8()
+    hlo = ('  %all-reduce.12 = (f32[32]{0}, f32[10,64]{1,0}, '
+           '/*index=2*/f32[]) all-reduce(%a, %b, /*index=2*/%c), '
+           'channel_id=3, replica_groups={{0,1,2,3,4,5,6,7}}, '
+           'use_global_device_ids=true, to_apply=%add\n')
+    inv = hlo_inventory(hlo, mesh)
+    assert inv.unparsed == []
+    assert [(e.family, e.wire_bytes, e.control) for e in inv.entries] == [
+        ("psum", 2 * 32 * 4, False), ("psum", 2 * 640 * 4, False),
+        ("psum", 2 * 4, True)]
 
 
 # -------------------------------------------- DTC003 donation audit pair

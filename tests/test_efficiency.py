@@ -110,6 +110,38 @@ def test_peak_resolution_and_cache():
     assert po == 123.0 and so == "flag_override"
 
 
+class _FakeDevice:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+@pytest.mark.parametrize("kind,want", [
+    ("TPU v5 lite", 197e12),  # the string a v5e chip reports
+    ("TPU v4", 275e12),
+])
+def test_peak_resolves_the_exact_device_kind(monkeypatch, kind, want):
+    import jax
+
+    efficiency._reset_peak_cache()
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: [_FakeDevice("tpu", kind)])
+    assert peak_flops_per_sec() == (want, f"device_table:{kind}")
+    efficiency._reset_peak_cache()
+
+
+def test_unknown_tpu_kind_raises_instead_of_guessing(monkeypatch):
+    """A TPU the table does not know is an error: no calibration, no
+    floor — an MFU over a guessed peak would read as a measurement."""
+    import jax
+
+    efficiency._reset_peak_cache()
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: [_FakeDevice("tpu", "TPU v9 ultra")])
+    with pytest.raises(ValueError, match="TPU v9 ultra"):
+        peak_flops_per_sec()
+    efficiency._reset_peak_cache()
+
+
 # -------------------------------------------------------------- meters
 
 

@@ -639,8 +639,7 @@ def test_bench_elastic_phase_nonnull():
 def test_bench_degraded_record_keeps_elastic_fields():
     import bench
 
-    rec = bench.degraded_record("forced outage", {"attempts": 1},
-                                cpu_smoke=False)
+    rec = bench.degraded_record("forced outage")
     assert rec["elastic_world"] == "2->1"
     assert rec["elastic_restore_fallback_depth"] == 1
     assert rec["elastic_resize_s"] is not None

@@ -613,8 +613,7 @@ def test_bench_recovery_phase_nonnull():
 def test_bench_degraded_record_keeps_recovery_fields():
     import bench
 
-    rec = bench.degraded_record("forced outage", {"attempts": 1},
-                                cpu_smoke=False)
+    rec = bench.degraded_record("forced outage")
     assert rec["recovery_restore_step"] == 10
     assert rec["recovery_fallback_depth"] == 1
     assert rec["recovery_time_s"] is not None

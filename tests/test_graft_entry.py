@@ -28,3 +28,13 @@ def test_dryrun_multichip_8():
 
 def test_dryrun_multichip_2():
     graft.dryrun_multichip(2)
+
+
+def test_dryrun_multichip_raises_without_the_devices():
+    """No fallback to a made-up platform: more devices than JAX reports
+    is an error that says how many there are."""
+    import pytest
+
+    with pytest.raises(RuntimeError, match="needs 16 devices and JAX "
+                                           "reports 8"):
+        graft.dryrun_multichip(16)

@@ -30,7 +30,7 @@ from tools.dttlint.rules import (  # noqa: E402
     rule_ledger_coverage,
     rule_perf_coverage,
     rule_scalar_contract,
-    rule_span_taxonomy,
+    rule_span_catalog,
     rule_trace_purity,
     rule_traced_coverage,
 )
@@ -68,7 +68,7 @@ FIXTURE_MATRIX = [
      "DTT003", 3),
     (rule_fault_registry, "", ("dtt004_bad.py",), ("dtt004_good.py",),
      "DTT004", 2),
-    (rule_span_taxonomy, "dtt005_bad", ("code.py",), None, "DTT005", 2),
+    (rule_span_catalog, "dtt005_bad", ("code.py",), None, "DTT005", 2),
     (rule_flag_validator, "dtt006_bad", ("flags.py",), None, "DTT006", 1),
     (rule_trace_purity, "", ("dtt007_bad.py",), ("dtt007_good.py",),
      "DTT007", 5),
@@ -117,7 +117,7 @@ def test_dtt004_names_both_directions():
 
 
 def test_dtt005_flags_both_directions():
-    res = _lint(rule_span_taxonomy, "dtt005_bad", "code.py")
+    res = _lint(rule_span_catalog, "dtt005_bad", "code.py")
     msgs = "\n".join(f.message for f in res.findings)
     assert "rogue_span" in msgs  # code -> docs drift
     assert "ghost_span" in msgs  # docs -> code drift

@@ -742,3 +742,26 @@ def test_concurrent_mirror_workers_stay_live_and_converge_steps():
     finally:
         init_client.close()
         server.close()
+
+
+def test_worker_without_a_chip_exits_saying_so(monkeypatch):
+    """What a second worker on a one-chip host sees (measured on a v5e
+    in PR 21: the backend fails start-up in ~3 s over the runtime's lock
+    file). The worker exits before any wait on the ps tasks, names the
+    rule — one process per accelerator, one worker per host — and does
+    not pass on the runtime's advice to delete the lock."""
+    import jax
+
+    from distributed_tensorflow_tpu.parallel import ps_emulation
+
+    def no_backend():
+        raise RuntimeError(
+            "Unable to initialize backend 'tpu': ABORTED: Internal error "
+            "when accessing libtpu multi-process lockfile.")
+
+    monkeypatch.setattr(jax, "local_devices", no_backend)
+    with pytest.raises(SystemExit) as exc:
+        ps_emulation._worker_devices(1)
+    msg = str(exc.value)
+    assert msg.startswith("worker/1: JAX could not start its backend")
+    assert "host runs ONE worker" in msg and "do not remove" in msg

@@ -704,8 +704,7 @@ def test_bench_reqtrace_phase_fields_non_null():
 def test_bench_degraded_record_keeps_reqtrace_fields():
     import bench
 
-    rec = bench.degraded_record("UNAVAILABLE: forced", {},
-                                cpu_smoke=False)
+    rec = bench.degraded_record("UNAVAILABLE: forced")
     for k in ("reqtrace_requests_total", "reqtrace_complete_pct",
               "reqtrace_p99_phase", "reqtrace_slo_compliant_pct",
               "reqtrace_overhead_pct"):

@@ -870,9 +870,7 @@ def test_bench_resources_phase_fields():
 def test_bench_degraded_record_resources_non_null():
     import bench
 
-    rec = bench.degraded_record("UNAVAILABLE: socket closed",
-                                {"attempts": 1, "waited_s": 0.0},
-                                cpu_smoke=False)
+    rec = bench.degraded_record("UNAVAILABLE: socket closed")
     assert rec["resources_hbm_live_bytes"] is not None
     assert rec["resources_comm_bytes_dp"] is not None
     assert rec["resources_compiles_distinct_shapes"] == 2

@@ -657,7 +657,7 @@ def test_bench_serving_phase_fields_non_null():
 def test_bench_degraded_record_keeps_serving_fields(monkeypatch):
     import bench
 
-    rec = bench.degraded_record("UNAVAILABLE: forced", {}, cpu_smoke=False)
+    rec = bench.degraded_record("UNAVAILABLE: forced")
     assert rec["serving_p50_ms"] is not None
     assert rec["serving_reload_blip_ms"] is not None
     assert rec["serving_throughput_rps"] is not None

@@ -584,8 +584,7 @@ def test_degraded_record_keeps_telemetry_facts_non_null():
     overhead_pct stays null."""
     import bench
 
-    rec = bench.degraded_record("UNAVAILABLE: tunnel down", {},
-                                cpu_smoke=False)
+    rec = bench.degraded_record("UNAVAILABLE: no device")
     assert rec["telemetry_span_overhead_ns"] is not None
     assert rec["telemetry_step_dispatch_s"] is not None
     assert rec["telemetry_breakdown_source"] == "synthetic"

@@ -15,7 +15,8 @@ merges the four reports into one object keyed by analyzer.
 
 dttcheck needs an 8-device mesh that must exist BEFORE jax initializes;
 like bench's jaxprcheck_phase it runs in a subprocess with a forced CPU
-mesh, so this command is chip-free end to end. dttperf is chip-free by
+mesh (started first, collected last — the other three run meanwhile),
+so this command is chip-free end to end. dttperf is chip-free by
 construction (pure Python + ``jax.eval_shape``). The acceptance budget
 is < 45 s for all four (DTP003 budget ``analyze_umbrella_wall_s``).
 
@@ -59,23 +60,36 @@ def _run_dttperf() -> dict:
     return run_perf().to_json()
 
 
-def _run_dttcheck() -> dict:
+def _start_dttcheck() -> subprocess.Popen:
     """Subprocess with its own forced 8-device CPU mesh (the bench
     jaxprcheck_phase pattern): this process's jax may already be bound
-    to real chips or a 1-device CPU fallback, and the verifier's mesh
-    must exist before jax initializes."""
+    to real chips, and the verifier's mesh must exist before jax
+    initializes. ``JAX_PLATFORMS=cpu`` keeps the child off the TPU
+    library altogether (tests/test_chip_smoke.py), so it cannot contend
+    for a chip its parent holds. Started FIRST and collected last: it
+    is the longest of the four and shares nothing with the others."""
     env = {**os.environ, "JAX_PLATFORMS": "cpu",
            "XLA_FLAGS": "--xla_force_host_platform_device_count=8"}
-    proc = subprocess.run(
+    return subprocess.Popen(
         [sys.executable, "-m", "tools.dttcheck", "--json"],
-        capture_output=True, text=True, cwd=REPO_ROOT, env=env,
-        timeout=300)
+        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
+        cwd=REPO_ROOT, env=env)
+
+
+def _finish_dttcheck(proc: subprocess.Popen) -> dict:
     try:
-        return json.loads(proc.stdout.strip().splitlines()[-1])
+        out, err = proc.communicate(timeout=300)
+    except subprocess.TimeoutExpired:
+        proc.kill()
+        proc.communicate()
+        return {"ok": False, "error": "dttcheck subprocess: no end "
+                                      "after 300s"}
+    try:
+        return json.loads(out.strip().splitlines()[-1])
     except (ValueError, IndexError):
         return {"ok": False,
                 "error": f"dttcheck subprocess failed (rc={proc.returncode}): "
-                         f"{proc.stderr.strip()[-400:]}"}
+                         f"{err.strip()[-400:]}"}
 
 
 def main(argv=None) -> int:
@@ -91,14 +105,18 @@ def main(argv=None) -> int:
                          "ergonomics)")
     args = ap.parse_args(argv)
 
-    runners = {"dttlint": _run_dttlint, "dttcheck": _run_dttcheck,
+    t_start = time.perf_counter()
+    check = (_start_dttcheck() if "dttcheck" not in args.skip else None)
+    runners = {"dttlint": _run_dttlint,
+               "dttcheck": lambda: _finish_dttcheck(check),
                "dttsan": _run_dttsan, "dttperf": _run_dttperf}
     merged: dict = {}
     ok = True
-    for name in ANALYZERS:
+    # the in-process three first, dttcheck's wait last
+    for name in ("dttlint", "dttsan", "dttperf", "dttcheck"):
         if name in args.skip:
             continue
-        t0 = time.perf_counter()
+        t0 = t_start if name == "dttcheck" else time.perf_counter()
         try:
             res = runners[name]()
         except Exception as e:  # a crashed analyzer is a failed gate

@@ -2,7 +2,7 @@
 
 ``trace_inventory`` runs ``jax.make_jaxpr`` on a step function (trace
 only — no XLA compile, no chip) and walks every equation, recursing
-into ``pjit`` / ``shard_map`` / ``scan`` / ``cond`` / ``while`` /
+into ``jit`` / ``shard_map`` / ``scan`` / ``cond`` / ``while`` /
 ``remat`` / custom-vjp bodies, to produce a :class:`Inventory`: one
 entry per collective equation with its primitive FAMILY, mesh AXES,
 and analytic WIRE BYTES (trip-count-multiplied — a ppermute inside a
@@ -49,11 +49,11 @@ CONTROL_FAMILIES = ("axis_index",)  # index reads move nothing
 #: jaxpr primitive name -> inventory family
 PRIM_FAMILY = {
     "psum": "psum",
-    "psum2": "psum",   # the check_rep/check_vma=True rewrite's name for
-                       # psum inside a shard_map body (jax 0.4.x); the
-                       # repo's builders trace check_vma=False but the
-                       # walker must not go blind on a checked caller
-
+    # what psum / all_gather stage as inside a check_vma=True shard_map
+    # body; the repo's builders trace check_vma=False but the walker
+    # must not go blind on a checked caller
+    "psum_invariant": "psum",
+    "all_gather_invariant": "all_gather",
     "reduce_scatter": "reduce_scatter",   # lax.psum_scatter lowers here
     "all_gather": "all_gather",
     "ppermute": "ppermute",
@@ -257,7 +257,7 @@ def walk_jaxpr(jaxpr, inv: Inventory, *, trips: int = 1,
                 walk_jaxpr(branches[0].jaxpr, inv, trips=trips, env=env,
                            site=here)
             continue
-        # generic recursion: pjit, remat/checkpoint, custom_vjp/jvp,
+        # generic recursion: jit, remat/checkpoint, custom_vjp/jvp,
         # closed_call, ... — anything carrying sub-jaxprs in params
         for v in eqn.params.values():
             for sub in _sub_jaxprs(v):
@@ -291,6 +291,14 @@ _HLO_OP = re.compile(
     r"=\s*(\w+)\[([\d,]*)\][^ ]*\s+"
     r"(all-reduce|all-gather|reduce-scatter|collective-permute|"
     r"all-to-all)\(")
+#: the combined form: XLA's collective combiner merges neighbouring
+#: all-reduces into ONE variadic op whose result is a tuple — every
+#: element is its own payload (and its own control/priced verdict).
+#: all-reduce only: there an element's operand has its result's shape
+#: (optimized HLO prints operands by name); any other variadic
+#: collective stays UNPARSED, loudly
+_HLO_TUPLE_OP = re.compile(r"=\s*\(([^()]*)\)\s+all-reduce\(")
+_HLO_SHAPE = re.compile(r"(\w+)\[([\d,]*)\]")
 #: loose probe: any instruction CALLING a collective op (hyphenated
 #: names with an open paren only occur at instruction position — jax
 #: metadata op_names use underscores). A line this hits that _HLO_OP
@@ -399,34 +407,41 @@ def hlo_inventory(hlo_text: str, mesh) -> Inventory:
         n_dev *= mesh.shape[n]
     for line in hlo_text.splitlines():
         m = _HLO_OP.search(line)
-        if not m:
+        tm = None if m else _HLO_TUPLE_OP.search(line)
+        if not m and not tm:
             probe = _HLO_COLLECTIVE_CALL.search(line)
             if probe:
                 inv.unparsed.append(
                     (probe.group(1), line.strip()[:160]))
             continue
-        dtype, dims, op = m.group(1), m.group(2), m.group(3)
+        if m:
+            op = m.group(3)
+            results = [(m.group(1), m.group(2))]
+            om = _HLO_OPERAND.search(line[m.end() - 1:])
+            operands = [(om.group(1), om.group(2))] if om else results
+        else:
+            op = "all-reduce"
+            results = operands = _HLO_SHAPE.findall(tm.group(1))
         family = HLO_FAMILY[op]
-        out_bytes, out_shape = _shape_bytes(dtype, dims)
-        om = _HLO_OPERAND.search(line[m.end() - 1:])
-        in_bytes, in_shape = ((_shape_bytes(om.group(1), om.group(2)))
-                              if om else (out_bytes, out_shape))
         if family == "ppermute":
             axes = _classify_pairs(line, mesh)
         else:
             axes = _classify_groups(_parse_groups(line, n_dev),
                                     axis_groups)
-        is_float = dtype in ("f64", "f32", "bf16", "f16")
-        control = (not is_float) or (not out_shape and not in_shape)
-        payload = in_bytes
-        if family == "psum":
-            wire = 2 * payload
-        elif family == "all_gather":
-            wire = out_bytes
-        else:
-            wire = payload
-        inv.entries.append(Entry(
-            family=family, axes=axes, wire_bytes=wire,
-            payload_bytes=payload, trips=1,
-            site=f"hlo/{op}", control=control))
+        for (dtype, dims), (in_dtype, in_dims) in zip(results, operands):
+            out_bytes, out_shape = _shape_bytes(dtype, dims)
+            in_bytes, in_shape = _shape_bytes(in_dtype, in_dims)
+            is_float = dtype in ("f64", "f32", "bf16", "f16")
+            control = (not is_float) or (not out_shape and not in_shape)
+            payload = in_bytes
+            if family == "psum":
+                wire = 2 * payload
+            elif family == "all_gather":
+                wire = out_bytes
+            else:
+                wire = payload
+            inv.entries.append(Entry(
+                family=family, axes=axes, wire_bytes=wire,
+                payload_bytes=payload, trips=1,
+                site=f"hlo/{op}", control=control))
     return inv

@@ -163,9 +163,9 @@ def pass_deadlock(target, inv: Inventory, ledger: dict | None) -> list:
     return out
 
 
-def _pjit_eqns(jaxpr):
+def _jit_eqns(jaxpr):
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pjit":
+        if eqn.primitive.name == "jit":
             yield eqn
 
 
@@ -181,7 +181,7 @@ def pass_donation(target, closed) -> list:
     if not target.donate:
         return out
     donated_any = False
-    for eqn in _pjit_eqns(closed.jaxpr):
+    for eqn in _jit_eqns(closed.jaxpr):
         donated = eqn.params.get("donated_invars", ())
         if not any(donated):
             continue
@@ -216,7 +216,7 @@ def pass_donation(target, closed) -> list:
 def pass_replication(target, closed) -> list:
     """DTC004: the declared plan vs the lowered split. For shard_map
     modes the jaxpr records, per input, exactly which dims split over
-    which axes (``in_names``); a leaf the plan declares sharded but the
+    which axes (``in_specs``); a leaf the plan declares sharded but the
     jaxpr replicates (or vice versa) is layout drift the memory budget
     and checkpoint layouts silently inherit. GSPMD (TP) targets carry
     no plan here — their commitment check is placement-based
@@ -225,13 +225,13 @@ def pass_replication(target, closed) -> list:
     path = MODE_PATH.get(target.mode, "tools/dttcheck")
     if target.plan is None:
         return out
-    for eqn in _pjit_eqns(closed.jaxpr):
+    for eqn in _jit_eqns(closed.jaxpr):
         inner = eqn.params["jaxpr"].jaxpr
         sm = next((e for e in inner.eqns
                    if e.primitive.name == "shard_map"), None)
         if sm is None:
             continue
-        in_names = sm.params.get("in_names", ())
+        in_specs = sm.params.get("in_specs", ())
         pos_of = {id(v): j for j, v in enumerate(sm.invars)}
         import jax
 
@@ -245,10 +245,10 @@ def pass_replication(target, closed) -> list:
             if i >= len(inner.invars):
                 break
             j = pos_of.get(id(inner.invars[i]))
-            if j is None or j >= len(in_names):
+            if j is None or j >= len(in_specs):
                 continue  # leaf transformed before entering shard_map
             actual = tuple(
-                a for axes in in_names[j].values()
+                a for axes in in_specs[j] if axes is not None
                 for a in (axes if isinstance(axes, tuple) else (axes,)))
             leaf = flat_paths[i] if i < len(flat_paths) else f"leaf{i}"
             if set(expected) - set(actual):
@@ -257,8 +257,8 @@ def pass_replication(target, closed) -> list:
                     0,
                     f"[{target.name}] plan declares leaf {leaf!r} "
                     f"sharded over {tuple(expected)} but the lowered "
-                    f"shard_map replicates it (in_names="
-                    f"{dict(in_names[j])}) — a full copy per device "
+                    f"shard_map replicates it (in_specs="
+                    f"{in_specs[j]}) — a full copy per device "
                     f"where the budget prices a shard"))
             elif set(actual) - set(expected):
                 out.append(Finding(

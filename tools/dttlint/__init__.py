@@ -5,7 +5,7 @@ catch topology mistakes before a step ran; the JAX port has no such
 graph pass, so the load-bearing invariants this tree has learned the
 hard way (replicated-leaf divergence from a mis-axed collective, loop
 variants forgetting the scalar contract, flags without parse-time
-validators, span names drifting from the ARCHITECTURE taxonomy) were
+validators, span names drifting from the ARCHITECTURE catalog) were
 enforced by memory and runtime tests alone. dttlint turns each of those
 hand-fixed bug classes into a named, machine-checked rule — the same
 move XLA makes with its static shape/layout verification, and the
@@ -25,8 +25,8 @@ docs/ARCHITECTURE.md "Static analysis"):
   DTT004 fault-registry    fired point names exist in
                            ``INJECTION_POINTS``; no registered point is
                            orphaned
-  DTT005 span-taxonomy     ``trace_span``/instant names match the
-                           ARCHITECTURE span-taxonomy table, both ways
+  DTT005 span-catalog     ``trace_span``/instant names match the
+                           ARCHITECTURE span-catalog table, both ways
   DTT006 flag-validator    every ``DEFINE_*`` flag is covered by a
                            registered parse-time validator (or an
                            explicit baseline entry)
@@ -86,7 +86,7 @@ DEFAULT_BASELINE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
 # code too and have grown collectives of their own)
 LINT_TARGETS = ("distributed_tensorflow_tpu", "tools",
                 "bench.py", "__graft_entry__.py", "mnist_dist.py")
-SPAN_TAXONOMY_DOC = os.path.join("docs", "ARCHITECTURE.md")
+SPAN_CATALOG_DOC = os.path.join("docs", "ARCHITECTURE.md")
 
 
 class RepoIndex:
@@ -112,7 +112,7 @@ class RepoIndex:
                             rel = os.path.relpath(
                                 os.path.join(dirpath, name), root)
                             self._load(rel)
-        doc = os.path.join(root, SPAN_TAXONOMY_DOC)
+        doc = os.path.join(root, SPAN_CATALOG_DOC)
         self.doc_text = (open(doc, encoding="utf-8").read()
                          if os.path.exists(doc) else "")
 

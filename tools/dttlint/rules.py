@@ -287,11 +287,11 @@ def rule_fault_registry(index) -> list:
 rule_fault_registry.rule_id = "DTT004"
 
 
-# -------------------------------------------------- DTT005 span-taxonomy
+# -------------------------------------------------- DTT005 span-catalog
 
 
 def _doc_span_names(doc_text: str) -> tuple[set, set]:
-    """Parse the ARCHITECTURE span-taxonomy table: -> (exact names,
+    """Parse the ARCHITECTURE span-catalog table: -> (exact names,
     parameterized prefixes like "fault:")."""
     exact, prefixes = set(), set()
     in_table = False
@@ -365,12 +365,12 @@ def _has_span_sites(index) -> bool:
         for tree in index.trees.values() for n, _ in _walk_scoped(tree))
 
 
-def rule_span_taxonomy(index) -> list:
+def rule_span_catalog(index) -> list:
     """DTT005: every ``trace_span``/``record_instant``/``record_span``
-    name literal appears in the ARCHITECTURE span-taxonomy table, and
+    name literal appears in the ARCHITECTURE span-catalog table, and
     every table row has a live call site — docs drift flags in BOTH
     directions.
-    A walk set WITH span sites but WITHOUT a parseable taxonomy table
+    A walk set WITH span sites but WITHOUT a parseable catalog table
     is itself a finding: the rule must never self-disable silently
     (a reworded table header would otherwise green every invariant
     this rule exists to enforce)."""
@@ -379,7 +379,7 @@ def rule_span_taxonomy(index) -> list:
         if _has_span_sites(index):
             return [Finding(
                 "DTT005", "docs::span-table", "docs/ARCHITECTURE.md", 0,
-                "the walk set emits spans but no span-taxonomy table "
+                "the walk set emits spans but no span-catalog table "
                 "parses from docs/ARCHITECTURE.md (header must be "
                 "'| span | where |') — the rule would silently "
                 "self-disable")]
@@ -413,7 +413,7 @@ def rule_span_taxonomy(index) -> list:
                 out.append(Finding(
                     "DTT005", f"{rel}::span::{name}", rel, node.lineno,
                     f"span name {name!r} is not in the ARCHITECTURE "
-                    f"span-taxonomy table (docs/ARCHITECTURE.md) — add "
+                    f"span-catalog table (docs/ARCHITECTURE.md) — add "
                     f"the row or rename the span"))
             for p in prefixes:
                 seen_prefix.add(p)
@@ -422,21 +422,21 @@ def rule_span_taxonomy(index) -> list:
                         "DTT005", f"{rel}::span::{p}<...>", rel,
                         node.lineno,
                         f"parameterized span family {p!r}<...> is not "
-                        f"in the span-taxonomy table"))
+                        f"in the span-catalog table"))
     for name in sorted(exact_doc - seen_exact):
         out.append(Finding(
             "DTT005", f"docs::span::{name}", "docs/ARCHITECTURE.md", 0,
-            f"taxonomy table documents span {name!r} but no "
+            f"catalog table documents span {name!r} but no "
             f"trace_span/record_instant site emits it — stale docs row"))
     for p in sorted(prefix_doc - seen_prefix):
         out.append(Finding(
             "DTT005", f"docs::span::{p}<...>", "docs/ARCHITECTURE.md", 0,
-            f"taxonomy table documents span family {p!r}<...> but no "
+            f"catalog table documents span family {p!r}<...> but no "
             f"site emits it — stale docs row"))
     return out
 
 
-rule_span_taxonomy.rule_id = "DTT005"
+rule_span_catalog.rule_id = "DTT005"
 
 
 # -------------------------------------------------- DTT006 flag-validator
@@ -1064,7 +1064,7 @@ ALL_RULES = (
     rule_ledger_coverage,
     rule_scalar_contract,
     rule_fault_registry,
-    rule_span_taxonomy,
+    rule_span_catalog,
     rule_flag_validator,
     rule_trace_purity,
     rule_donation_safety,

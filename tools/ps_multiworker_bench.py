@@ -5,10 +5,12 @@ The reference's deployment is N worker PROCESSES hammering the ps
 behaves as worker count grows — with real processes (r5: the r4
 version used threads, which confounded per-worker rates with the GIL
 and host compute contention; worker processes isolate what the ps
-actually serializes). Compute runs on CPU (forced — the object of
-measurement is the ps fan-in, dedup table, and the mirror
-desync/resync protocol under contention, not chip throughput; CPU also
-keeps the shared TPU chip clean). Each worker process owns a PSClient
+actually serializes). Compute runs on the CPU platform, by design and
+stated here, not as a fallback: the object of measurement is the ps
+fan-in, dedup table, and the mirror desync/resync protocol under
+contention, not chip throughput — and an accelerator belongs to one
+process at a time, so N worker processes on one host cannot each have
+the chip. Each worker process owns a PSClient
 (own sockets + client id) driving MirrorCycle in the documented
 multi-worker degraded mode: every foreign push desyncs the mirror,
 forcing a resync pull — the reference's staleness model.
@@ -48,9 +50,8 @@ BATCH = 64
 
 def worker_main(widx: int, n_workers: int, address: str, cycles: int,
                 gofile: str) -> None:
+    os.environ["JAX_PLATFORMS"] = "cpu"  # by design: see the docstring
     import jax
-
-    jax.config.update("jax_platforms", "cpu")
 
     from distributed_tensorflow_tpu.data import read_data_sets
     from distributed_tensorflow_tpu.models import get_model
@@ -122,9 +123,8 @@ def _err_tail(p, limit: int = 500) -> str:
 
 
 def main(cycles: int = 60):
+    os.environ["JAX_PLATFORMS"] = "cpu"  # by design: see the docstring
     import jax
-
-    jax.config.update("jax_platforms", "cpu")
     import numpy as np
 
     from distributed_tensorflow_tpu.models import get_model
