@@ -1262,6 +1262,14 @@ def continuous_batching_phase(measured: bool = True) -> dict:
 # drill are HOST-ONLY (stdlib telemetry, no chip), like the recovery
 # and serving drills; the A/B (telemetry on vs off around the flagship
 # device-resident chunk loop) needs the chip and stays null without it.
+
+#: The clock of the two host-cost budgets (ns/span here, ms/request-record
+#: in reqtrace_phase): the measuring thread's CPU time. Both loops are pure
+#: host work whose budget assertion ends the run, and on a shared host the
+#: wall counts whoever else ran: with every core oversubscribed one span
+#: read 19-41 us on the wall and 3.0-3.2 us of CPU time, against 2.3-2.7 us
+#: on either clock with the host idle (PR 21, this sandbox's CPU).
+_host_cost_clock = time.thread_time
 TELEMETRY_SPAN_SAMPLES = 20000
 TELEMETRY_SPAN_BUDGET_NS = 5000  # < 5 us/span, asserted
 TELEMETRY_AB_CHUNKS = 4
@@ -1287,11 +1295,11 @@ def telemetry_phase() -> dict:
         tracer.enabled = True
         best = math.inf
         for _ in range(3):  # best-of-3: absorb host scheduling noise
-            t0 = time.perf_counter()
+            t0 = _host_cost_clock()
             for _ in range(TELEMETRY_SPAN_SAMPLES):
                 with telemetry.trace_span("bench_span"):
                     pass
-            best = min(best, (time.perf_counter() - t0)
+            best = min(best, (_host_cost_clock() - t0)
                        / TELEMETRY_SPAN_SAMPLES * 1e9)
         assert best < TELEMETRY_SPAN_BUDGET_NS, (
             f"span overhead {best:.0f} ns/span blows the "
@@ -1480,6 +1488,7 @@ REQTRACE_COST_SAMPLES = 2000
 
 
 def reqtrace_phase() -> dict:
+    import math
     import shutil
     import tempfile
 
@@ -1556,22 +1565,20 @@ def reqtrace_phase() -> dict:
         # audit facts above), over the drill's measured mean latency
         cost_plane = reqtrace.RequestPlane(
             ring=64, slo_p99_ms=REQTRACE_SLO_P99_MS)
-        # this thread's CPU time, not the wall: the loop is pure host
-        # work, and on a shared host the wall counts whoever else ran.
-        # The budget assertion below ends the run when it fails, so it
-        # must not be a reading of the neighbours' load
-        t0 = time.thread_time()
-        for _ in range(REQTRACE_COST_SAMPLES):
-            tr = cost_plane.begin(reqtrace.new_request_id(),
-                                  "predict", x)
-            tr.admitted()
-            tr.taken()
-            tr.run_start()
-            tr.note("prefill", 0.0)
-            tr.run_end()
-            cost_plane.finish(tr, "ok")
-        cost_ms = ((time.thread_time() - t0)
-                   / REQTRACE_COST_SAMPLES * 1e3)
+        cost_ms = math.inf
+        for _ in range(3):  # best-of-3, as telemetry_phase's span cost
+            t0 = _host_cost_clock()
+            for _ in range(REQTRACE_COST_SAMPLES):
+                tr = cost_plane.begin(reqtrace.new_request_id(),
+                                      "predict", x)
+                tr.admitted()
+                tr.taken()
+                tr.run_start()
+                tr.note("prefill", 0.0)
+                tr.run_end()
+                cost_plane.finish(tr, "ok")
+            cost_ms = min(cost_ms, (_host_cost_clock() - t0)
+                          / REQTRACE_COST_SAMPLES * 1e3)
         mean_ms = rep["latency_ms_mean"]
         overhead = (100.0 * cost_ms / mean_ms if mean_ms > 0 else None)
         assert overhead is not None and overhead < 2.0, (
@@ -1602,13 +1609,15 @@ def reqtrace_phase() -> dict:
 # (utils/efficiency.py) measured on whatever backend is alive. The
 # FLOPs budget is ANALYTIC (per-layer, no chip); the rate measurement
 # is a short real train loop on the default backend — the chip in a
-# healthy record, the CPU fallback in the host-only record (degraded_record
-# runs this AFTER _cpu_smoke has flipped the platform) — so the mfu /
-# flops_per_step / goodput facts stay non-null in EVERY record. MFU is
-# asserted in (0, 1]: the number must be a real utilization, not a
-# unit-error artifact.
+# record, the CPU test mesh under degraded_record. MFU is asserted in
+# (0, 1] against the spec table's peak: the number must be a real
+# utilization, not a unit-error artifact. The CPU mesh's peak is one f32
+# matmul's ACHIEVED rate (matmul_calibration), which is no bound: the
+# conv step beats it (MFU 1.07-1.17 on XLA:CPU, PR 21), so there the
+# assertion only rules out a unit error.
 EFFICIENCY_BATCH = 128
 EFFICIENCY_STEPS = 6
+EFFICIENCY_CALIBRATED_MFU_LIMIT = 10.0
 
 
 _EFFICIENCY_CACHE: dict = {}
@@ -1657,9 +1666,12 @@ def efficiency_phase() -> dict:
     dt = time.perf_counter() - t0
     rate = EFFICIENCY_STEPS * EFFICIENCY_BATCH / dt
     s = eff.scalars(rate)
-    assert 0.0 < s["mfu"] <= 1.0, (
-        f"flagship-CNN MFU {s['mfu']} outside (0, 1] — the "
-        f"accounting (flops budget x rate / peak) is broken")
+    mfu_limit = (EFFICIENCY_CALIBRATED_MFU_LIMIT
+                 if eff.peak_source == "matmul_calibration" else 1.0)
+    assert 0.0 < s["mfu"] <= mfu_limit, (
+        f"flagship-CNN MFU {s['mfu']} outside (0, {mfu_limit}] against "
+        f"the {eff.peak_source} peak — the accounting (flops budget x "
+        f"rate / peak) is broken")
     assert 0.0 < s["goodput"] <= 1.0, s
     _EFFICIENCY_CACHE["out"] = {
         "mfu": s["mfu"],
@@ -1676,10 +1688,9 @@ def efficiency_phase() -> dict:
 # r13: the resources phase — the resource plane's evidence
 # (utils/resources.py) on whatever backend is alive. The budget and
 # comm-ledger facts are ANALYTIC (jax.eval_shape, no chip); the live
-# HBM sample and the compile drill run on the default backend — chip in
-# a healthy record, CPU in the host-only record (degraded_record runs this
-# AFTER _cpu_smoke has flipped the platform; the CPU fallback samples
-# live-array bytes) — so every field stays non-null in EVERY record.
+# HBM sample and the compile drill run on the default backend — the chip
+# in a record, the CPU test mesh under degraded_record (where the sample
+# is live-array bytes).
 # The compile assertion is the bench contract's recompile pin: exactly
 # ONE compile per distinct chunk shape, ZERO on repeats.
 RESOURCES_BATCH = 128
@@ -2409,7 +2420,7 @@ def degraded_record(error, partial: dict | None = None,
     # step rate over the calibrated peak; non-null in the host-only record
     out.update(efficiency_phase())
     # r13: resource-plane facts — the budget/ledger halves are analytic
-    # and the live sample/compile drill run on the CPU fallback, so
+    # and the live sample/compile drill run on the CPU test mesh, so
     # every resources_* field stays non-null in the host-only record too
     out.update(resources_phase())
     # r15: the elastic-resize drill is host-only like the recovery

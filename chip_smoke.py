@@ -61,20 +61,40 @@ SERVE_REQUESTS = 3
 SERVE_PROMPT_LEN = 8
 SERVE_NEW_TOKENS = 16
 PHASE_TIMEOUT_S = 600
-#: the CNN trainer's programs take ~20 s to compile on the chip and under
-#: a second to load from the persistent cache; below this they were loaded
+#: the CNN trainer's chunk takes ~20 s to compile on the chip; a process
+#: that loaded its programs from the persistent cache spends under a second
 WARM_COMPILE_S = 5.0
-#: DP on four chips vs one of them, f32, dropout off, identical batches:
-#: the products are the same, only the order of the f32 sums differs — and
-#: the CNN's first steps at this rate amplify that (on a v5e 2x2 the losses
-#: agree to 5e-7 for seven steps, then 4e-5, then 1.7e-4 at the tenth)
-DP_LOSS_RTOL = 1e-3
 DP_STEPS = 10
-#: pairs the docs hold equal (ZeRO-1 vs replicated DP, the pipeline
-#: schedules), in f32: largest |difference| of any parameter over
-#: the largest magnitude of its leaf. The CPU tests hold zero-bubble to
-#: 1e-6 and the rest to bitwise; the chip's own figures are printed
-SCHEDULE_RTOL = 1e-4
+#: The four-chip comparisons run twice: in f32
+#: (``jax.default_matmul_precision("highest")``), where two programs for
+#: one function differ only in the order of their f32 sums, and at the
+#: TPU's default precision, where an f32 matmul multiplies in one bf16
+#: pass. Every figure below is (bound in f32, bound at default precision);
+#: the chips' own figures (v5e 2x2, PR 21) stand beside each.
+#:
+#: Sync DP on four chips vs make_train_step on one of them, same ten
+#: batches, dropout off: largest relative difference of a loss. The run
+#: climbs from 4.8 to 6.3 before it falls and amplifies whatever it is
+#: given: 5e-7 for seven steps, 1.7e-4 at the tenth in f32; 2.7e-3 at
+#: default precision. A DP step that sums where it should average, or
+#: loses a shard, moves the loss by tens of percent within a few steps.
+DP_LOSS_RTOL = (1e-3, 1e-2)
+#: Pairs the docs hold equal: largest |difference| of any parameter over
+#: the largest magnitude of its leaf. In f32 the pipeline schedules are
+#: held to the 1e-6 of tests/test_pp_zb.py (chips: 2.8e-7, interleaved vs
+#: gpipe 0) and ZeRO-1 vs replicated DP to 1e-5 (chips: 2.1e-6; bitwise
+#: only on XLA:CPU). At default precision one bound for all: bf16 keeps 8
+#: bits, so each product is rounded by up to 2**-9 = 2e-3, and a leaf that
+#: starts at zero is after two sgd steps nothing but its gradient, which
+#: passed ~100 such matmuls forward and back through 8 blocks: 2e-2 if the
+#: roundings are independent, 2e-1 if they line up (chips: 4.4e-2 for
+#: zero-bubble, 8.8e-7 for ZeRO-1, 0 for interleaved). A schedule that
+#: drops, doubles or misplaces one of the four microbatches moves such a
+#: leaf by 2.5e-1. The child also prints what the precision alone does to
+#: ONE schedule (gpipe at default precision vs gpipe in f32), so that the
+#: bf16 explanation is a reading and not a claim.
+PP_RTOL = (1e-6, 1e-1)
+ZERO_RTOL = (1e-5, 1e-1)
 
 
 class SmokeError(Exception):
@@ -242,48 +262,30 @@ def train(name: str, flags: list, steps: int,
            "test_accuracy": test_acc, "test_loss": test_loss,
            "compile_seconds": _last(rows, "compile_time_s"),
            "compiles": _last(rows, "compiles_total"),
+           "compile_cache_hits": _last(rows, "compile_cache_hits"),
            "images_per_sec": _last(rows, "images_per_sec"),
            "hbm_peak_bytes": _last(rows, "hbm_peak_bytes")}
     _say(f"  {name}: compile {res['compile_seconds']}s over "
-         f"{res['compiles']:.0f} programs, {res['images_per_sec']} "
+         f"{res['compiles']:.0f} programs "
+         f"({res['compile_cache_hits']:.0f} loaded from the persistent "
+         f"cache), {res['images_per_sec']} "
          f"examples/s at the last display (smoke output, not a benchmark)")
     return res
 
 
-def _parse_train_flags(argv: list):
-    """The trainer's own flag parsing and PRNG choice (mnist_dist.main),
-    for a child that rebuilds the program those flags make."""
-    import jax
-
-    from distributed_tensorflow_tpu import flags
+def _trainer_flags(argv: list):
+    """``argv`` parsed as ``mnist_dist.py`` parses it — its flag table, its
+    cache placement (flags.run), its PRNG choice — for a child that
+    compiles the trainer's program again to look inside it."""
+    import mnist_dist
     from distributed_tensorflow_tpu.utils.compile_cache import (
         enable_compile_cache,
     )
 
-    flags.define_reference_flags()
-    flags.FLAGS._parse(["chip_smoke"] + list(argv))
+    mnist_dist.FLAGS._parse(["chip_smoke"] + list(argv))
     enable_compile_cache()
-    if flags.FLAGS.prng != "threefry":
-        jax.config.update("jax_default_prng_impl", flags.FLAGS.prng)
-    return flags.FLAGS
-
-
-def _model_opt_state(FLAGS, meta: dict):
-    """(model, optimizer, fresh state) exactly as training/loop.py makes
-    them from the flags."""
-    from distributed_tensorflow_tpu.training import (
-        create_train_state,
-        get_optimizer,
-    )
-    from distributed_tensorflow_tpu.training.loop import build_model_for
-    from distributed_tensorflow_tpu.training.schedules import (
-        schedule_from_flags,
-    )
-
-    model = build_model_for(FLAGS, meta)
-    opt = get_optimizer(FLAGS.optimizer, schedule_from_flags(FLAGS),
-                        weight_decay=FLAGS.weight_decay)
-    return model, opt, create_train_state(model, opt, seed=FLAGS.seed)
+    mnist_dist.set_prng_impl()
+    return mnist_dist.FLAGS
 
 
 def _timed_compile(lowered):
@@ -300,22 +302,23 @@ def _timed_compile(lowered):
 
 
 def _child_cnn_program(*argv: str) -> None:
-    """Rebuild the device-resident chunk ``mnist_dist.py <argv>`` trains
-    with — same flags, same builders as training/loop.py — and look at
-    what the compiler made of it."""
+    """The device-resident chunk ``mnist_dist.py <argv>`` trains with —
+    model, optimizer and state from the loop's own builder, which is
+    where ``--pallas`` is decided — and what the compiler made of it."""
     import jax
 
-    FLAGS = _parse_train_flags(argv)
+    FLAGS = _trainer_flags(argv)
     from distributed_tensorflow_tpu.data import read_data_sets
     from distributed_tensorflow_tpu.data.device_data import put_device_data
     from distributed_tensorflow_tpu.training.device_step import (
         make_device_train_step,
     )
+    from distributed_tensorflow_tpu.training.loop import build_training_for
 
     ds = read_data_sets(FLAGS.data_dir, one_hot=True, dataset=FLAGS.dataset,
                         seed=FLAGS.seed,
                         validation_size=FLAGS.validation_size)
-    model, opt, state = _model_opt_state(FLAGS, ds.meta)
+    model, opt, state = build_training_for(FLAGS, ds.meta)
     step = make_device_train_step(model, opt, FLAGS.batch_size,
                                   keep_prob=FLAGS.keep_prob,
                                   chunk=FLAGS.device_chunk)
@@ -335,19 +338,20 @@ def _child_lm_program(*argv: str) -> None:
     and the device's own peak."""
     import jax
 
-    FLAGS = _parse_train_flags(argv)
+    FLAGS = _trainer_flags(argv)
     from distributed_tensorflow_tpu.data.lm import LMDataSet
     from distributed_tensorflow_tpu.data.pipeline import (
         batch_iterator,
         prefetch_to_device,
     )
     from distributed_tensorflow_tpu.training import make_train_step
+    from distributed_tensorflow_tpu.training.loop import build_training_for
 
     # the shapes and dtypes of the trainer's batches; a few sequences are
     # enough to make one
     split = LMDataSet(2 * FLAGS.batch_size, FLAGS.seq_len, FLAGS.vocab_size,
                       seed=FLAGS.seed)
-    model, opt, state = _model_opt_state(
+    model, opt, state = build_training_for(
         FLAGS, {"kind": "lm", "seq_len": FLAGS.seq_len,
                 "vocab_size": FLAGS.vocab_size})
     step = make_train_step(model, opt, keep_prob=FLAGS.keep_prob)
@@ -479,15 +483,18 @@ def cnn_phases(report: dict) -> None:
          f"{cnn_p['test_accuracy']:.4f} | {cnn_x['test_accuracy']:.4f}")
     _say("== the same --pallas trainer again, a later process ==")
     again = train("cnn_pallas_again", pallas_flags, CNN_STEPS // 2)
-    _say(f"  compile seconds of the run's programs (the chunk and the "
-         f"display eval): {cnn_p['compile_seconds']} in the first "
-         f"process | {again['compile_seconds']} in this later one")
-    # a machine whose cache directory came with entries serves the first
-    # process from it too: then both figures are small
-    _check(again["compile_seconds"]
-           < max(0.5 * cnn_p["compile_seconds"], WARM_COMPILE_S),
-           "the later process found the compiled programs in the "
-           "persistent cache")
+    # the trainer's own count of /jax/compilation_cache/cache_hits. A
+    # machine whose cache directory came with entries serves the first
+    # process from it too, and its count says so
+    _say(f"  the run's programs (the chunk and the display eval): "
+         f"{cnn_p['compile_seconds']}s, {cnn_p['compile_cache_hits']:.0f} "
+         f"loaded from the persistent cache in the first process | "
+         f"{again['compile_seconds']}s, "
+         f"{again['compile_cache_hits']:.0f} loaded in this later one")
+    _check(again["compile_cache_hits"] >= 1
+           and again["compile_seconds"] < WARM_COMPILE_S,
+           "the later process loaded its programs from the persistent "
+           "cache and compiled none that takes seconds")
     _say("== the compiled CNN chunks, looked into ==")
     prog_p = _child("cnn_pallas_program", "_child_cnn_program",
                     *pallas_flags)
@@ -553,27 +560,26 @@ def multichip(report: dict) -> None:
     _say("== the same math on four chips: DP, the mode sweep, ZeRO, PP ==")
     res = _child("multichip", "_child_multichip", timeout=1200)
     report.update(dp_cli=dp, multichip=res)
-    held, shown = res["f32"], res["default_precision"]
-    _check(held["dp_vs_one_chip_max_rel_loss_diff"] <= DP_LOSS_RTOL,
-           f"sync DP on {res['n_devices']} chips follows one chip over "
-           f"{DP_STEPS} sgd steps in f32: largest relative loss difference "
-           f"{held['dp_vs_one_chip_max_rel_loss_diff']:.3e} <= "
-           f"{DP_LOSS_RTOL} (at the default matmul precision: "
-           f"{shown['dp_vs_one_chip_max_rel_loss_diff']:.3e})")
-    # the DP parameters ride the same amplification as the DP losses and
-    # are printed by the child, not held to the schedules' bound
-    for pair, d in held["param_diffs"].items():
-        if pair == "sync DP vs one chip":
-            continue
-        dflt = shown["param_diffs"][pair]
-        _check(d["max_rel"] <= SCHEDULE_RTOL,
-               f"{pair}, f32: largest parameter difference "
-               f"{d['max_abs']:.3e} absolute, {d['max_rel']:.3e} of its "
-               f"leaf's scale (<= {SCHEDULE_RTOL}; bitwise: "
-               f"{d['max_abs'] == 0.0}) | at the default matmul "
-               f"precision, reported only: {dflt['max_abs']:.3e} absolute, "
-               f"{dflt['max_rel']:.3e} of scale (bitwise: "
-               f"{dflt['max_abs'] == 0.0})")
+    for i, tag in enumerate(("f32", "default_precision")):
+        got = res[tag]
+        _check(got["dp_vs_one_chip_max_rel_loss_diff"] <= DP_LOSS_RTOL[i],
+               f"{tag}: sync DP on {res['n_devices']} chips follows one "
+               f"chip over {DP_STEPS} sgd steps: largest relative loss "
+               f"difference {got['dp_vs_one_chip_max_rel_loss_diff']:.3e} "
+               f"<= {DP_LOSS_RTOL[i]}")
+        # the DP parameters ride the same amplification as the DP losses
+        # and are printed by the child, not held to a bound of their own
+        for pair, d in got["param_diffs"].items():
+            if pair == "sync DP vs one chip":
+                continue
+            bound = (ZERO_RTOL if pair.startswith("ZeRO") else PP_RTOL)[i]
+            _check(d["max_rel"] <= bound,
+                   f"{tag}: {pair}: largest parameter difference "
+                   f"{d['max_abs']:.3e} absolute, {d['max_rel']:.3e} of "
+                   f"its leaf's scale <= {bound} (bitwise: "
+                   f"{d['max_abs'] == 0.0})")
+    for what, d in res["precision_alone"].items():
+        _say(f"  default precision vs f32, {what}: {d}")
     _check(all(b > 0 for b in res["bytes_in_use"]),
            f"memory in use on every device while the states were live: "
            f"{res['bytes_in_use']}")
@@ -674,7 +680,7 @@ def _child_multichip() -> None:
         one_step = make_train_step(cnn, sgd, keep_prob=1.0, donate=False)
         s_dp = replicate_state(mesh, state0)
         s_one = jax.device_put(state0, devices[-1])
-        worst = 0.0
+        worst, one_losses = 0.0, []
         for i in range(DP_STEPS):
             b = (xs[i * 256:(i + 1) * 256], ys[i * 256:(i + 1) * 256])
             s_dp, m_dp = dp_step(s_dp, shard_batch(mesh, b))
@@ -682,6 +688,7 @@ def _child_multichip() -> None:
             placed(s_dp.params, mesh, f"sync DP step {i}")
             l_dp, l_one = float(m_dp["loss"]), float(m_one["loss"])
             worst = max(worst, abs(l_dp - l_one) / abs(l_one))
+            one_losses.append(l_one)
             print(f"[{tag}] dp step {i}: loss on {n} chips {l_dp:.7f} | "
                   f"on {devices[-1]} {l_one:.7f}")
         sample_memory()
@@ -733,22 +740,28 @@ def _child_multichip() -> None:
             run_pp(2, "zb"), inter)
         for pair, d in diffs.items():
             print(f"[{tag}] {pair}: {d}")
-        return {"dp_vs_one_chip_max_rel_loss_diff": worst,
-                "param_diffs": diffs}
+        return ({"dp_vs_one_chip_max_rel_loss_diff": worst,
+                 "param_diffs": diffs}, one_losses, gpipe)
 
-    # held to the bounds in f32 (a TPU multiplies f32 operands in bf16
-    # passes unless asked: two programs for one function then differ by
-    # bf16 roundings wherever XLA fuses them differently); what users run
-    # by default is reported beside it
+    # in f32, then as users run it (a TPU multiplies f32 operands in one
+    # bf16 pass unless asked: two programs for one function then differ
+    # by bf16 roundings wherever XLA fuses them differently)
     with jax.default_matmul_precision("highest"):
-        f32 = compare("f32")
-    default = compare("default precision")
+        f32, one_f32, gpipe_f32 = compare("f32")
+    default, one_default, gpipe_default = compare("default precision")
+    # what the precision alone does to ONE program: the scale the
+    # default-precision differences above are read against
+    precision_alone = {
+        "one chip, largest relative loss difference": max(
+            abs(a - b) / abs(b) for a, b in zip(one_default, one_f32)),
+        "PP gpipe parameters": diff(gpipe_default, gpipe_f32)}
 
     # the mode sweep on the real devices
     graft.dryrun_multichip(n)
 
     print(json.dumps({"n_devices": n, "f32": f32,
                       "default_precision": default,
+                      "precision_alone": precision_alone,
                       "bytes_in_use": in_use}))
 
 

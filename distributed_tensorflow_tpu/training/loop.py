@@ -122,6 +122,16 @@ def build_model_for(FLAGS, meta: dict):
     )
 
 
+def build_training_for(FLAGS, meta: dict):
+    """(model, optimizer, fresh train state) as the flags describe them:
+    what every loop variant trains, and what ``chip_smoke.py`` compiles
+    again to look inside the trainer's program."""
+    model = build_model_for(FLAGS, meta)
+    opt = get_optimizer(FLAGS.optimizer, schedule_from_flags(FLAGS),
+                        weight_decay=getattr(FLAGS, "weight_decay", 0.0))
+    return model, opt, create_train_state(model, opt, seed=FLAGS.seed)
+
+
 def _log_recovery(sv, logger, step: int, eff=None) -> None:
     """Recovery observability: where this run's state came from
     (restore source step, fallback depth, quarantine count, time-to-
@@ -319,11 +329,8 @@ def _train_once(FLAGS, mode: str = "local") -> TrainResult:
                         seed=data_seed, validation_size=FLAGS.validation_size,
                         seq_len=getattr(FLAGS, "seq_len", 256),
                         vocab_size=getattr(FLAGS, "vocab_size", 64))
-    model = build_model_for(FLAGS, ds.meta)
+    model, opt, state = build_training_for(FLAGS, ds.meta)
     is_lm = ds.meta.get("kind") == "lm"
-    opt = get_optimizer(FLAGS.optimizer, schedule_from_flags(FLAGS),
-                        weight_decay=getattr(FLAGS, "weight_decay", 0.0))
-    state = create_train_state(model, opt, seed=FLAGS.seed)
 
     n_chips = 1
     mesh = None

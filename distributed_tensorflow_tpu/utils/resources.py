@@ -709,8 +709,11 @@ class CompileSentry:
 
     Two sources, one ledger: the ``jax.monitoring`` backend-compile
     listener (installed once per process, forwarding to the ACTIVE
-    sentry) supplies ``compiles_total`` / ``compile_time_s`` — real
-    compiles only, cache hits don't fire. ``observe(site, signature)``
+    sentry) supplies ``compiles_total`` / ``compile_time_s`` — every
+    program the backend was asked for, jit's in-memory cache hits don't
+    fire; ``compile_cache_hits`` counts those of them that were LOADED
+    from the persistent compilation cache (utils/compile_cache.py), not
+    compiled. ``observe(site, signature)``
     — called by the loops at each dispatch and by the serving engine
     per bucket — supplies the recompile story: the first signature a
     site ever shows is its expected first compile; a NEW signature
@@ -728,6 +731,7 @@ class CompileSentry:
         self._lock = threading.Lock()
         self.compiles_total = 0
         self.compile_time_s = 0.0
+        self.compile_cache_hits = 0
         self.recompiles_total = 0
         self.storms = 0
         self._sites: dict = {}       # site -> {sig: hits}
@@ -747,6 +751,12 @@ class CompileSentry:
         with self._lock:
             self.compiles_total += 1
             self.compile_time_s += float(dur)
+
+    def on_cache_event(self, event: str) -> None:
+        if event != "/jax/compilation_cache/cache_hits":
+            return
+        with self._lock:
+            self.compile_cache_hits += 1
 
     def site_signatures(self, site: str) -> int:
         with self._lock:
@@ -809,6 +819,7 @@ class CompileSentry:
             return {
                 "compiles_total": float(self.compiles_total),
                 "compile_time_s": round(self.compile_time_s, 4),
+                "compile_cache_hits": float(self.compile_cache_hits),
                 "recompiles_total": float(self.recompiles_total),
             }
 
@@ -834,7 +845,13 @@ def _install_compile_listener() -> None:
             if s is not None:
                 s.on_compile_event(event, duration)
 
+        def _on_event(event, **kw):
+            s = _ACTIVE.get("sentry")
+            if s is not None:
+                s.on_cache_event(event)
+
         jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        jax.monitoring.register_event_listener(_on_event)
     except Exception as e:  # noqa: BLE001 — no jax, no compile events
         print(f"resources: compile listener unavailable: {e}")
 

@@ -33,6 +33,15 @@ flags.define_reference_flags()
 FLAGS = flags.FLAGS
 
 
+def set_prng_impl():
+    """--prng: must land before any PRNG key is created; affects dropout
+    masks and --device_data's on-device batch sampling."""
+    if FLAGS.prng != "threefry":
+        import jax
+
+        jax.config.update("jax_default_prng_impl", FLAGS.prng)
+
+
 def main(_):
     from distributed_tensorflow_tpu.utils import faults
 
@@ -46,12 +55,7 @@ def main(_):
 
         evaluate_only(FLAGS)
         return 0
-    if FLAGS.prng != "threefry":
-        # must land before any PRNG key is created; affects dropout masks
-        # and --device_data's on-device batch sampling
-        import jax
-
-        jax.config.update("jax_default_prng_impl", FLAGS.prng)
+    set_prng_impl()
     mode = resolve_mode(FLAGS)
 
     if mode == "ps":

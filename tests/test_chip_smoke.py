@@ -137,6 +137,34 @@ def test_enable_sets_no_directory_when_the_environment_places_it(
         assert got["calls"] == [["jax_compilation_cache_dir", want]]
 
 
+def test_a_later_process_counts_its_persistent_cache_hits(tmp_path):
+    """What chip_smoke holds the later trainer to: ``compile_cache_hits``
+    of the compile sentry is 0 in the process that stores a program and
+    counts the load in the next one (the cache is off for the tests, so
+    the children turn it on and store every program, however small)."""
+    code = ("import jax, jax.numpy as jnp, json\n"
+            "from distributed_tensorflow_tpu.utils import resources\n"
+            "sentry = resources.CompileSentry()\n"
+            "resources._install_compile_listener()\n"
+            "resources.activate(sentry=sentry)\n"
+            "jax.jit(lambda x: (x @ x).sum())(jnp.ones((8, 8)))"
+            ".block_until_ready()\n"
+            "print(json.dumps(sentry.scalars()))\n")
+    env = _cpu_env(**{compile_cache.ENV_VAR: str(tmp_path),
+                      "JAX_ENABLE_COMPILATION_CACHE": "true",
+                      "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
+                      "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES": "-1"})
+    hits = []
+    for _ in range(2):
+        p = subprocess.run([sys.executable, "-c", code], cwd=REPO, env=env,
+                           capture_output=True, text=True, timeout=120)
+        assert p.returncode == 0, p.stderr
+        got = json.loads(p.stdout.strip().splitlines()[-1])
+        assert got["compiles_total"] >= 1
+        hits.append(got["compile_cache_hits"])
+    assert hits[0] == 0 and hits[1] >= 1, hits
+
+
 # ------------------------------------- children that must stay off the chip
 
 

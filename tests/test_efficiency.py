@@ -211,7 +211,10 @@ def test_bench_efficiency_phase_fields():
 
     out = bench.efficiency_phase()
     assert out.get("efficiency_error") is None, out
-    assert 0.0 < out["mfu"] <= 1.0
+    # the CPU mesh's peak is a matmul's achieved rate, not a bound: the
+    # conv step beats it, so only a unit error is ruled out here
+    assert out["mfu_peak_source"] == "matmul_calibration"
+    assert 0.0 < out["mfu"] <= bench.EFFICIENCY_CALIBRATED_MFU_LIMIT
     assert 0.0 < out["goodput"] <= 1.0
     assert out["flops_per_step"] == 3 * 27767808 * bench.EFFICIENCY_BATCH
     assert out["model_flops_per_sec"] > 0

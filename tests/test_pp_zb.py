@@ -220,7 +220,8 @@ def _assert_params_equal(a, b):
 #: difference of any parameter, over the largest magnitude of its leaf:
 #: 1.6e-7 on XLA:CPU and 2.7e-7 in chip_smoke.py's case (one or two f32
 #: ulps at the leaf's scale) — held to 1e-6, the bound
-#: ``chip_smoke.py --multichip`` holds the chip to as well.
+#: ``chip_smoke.py --multichip`` holds four chips to in f32 as well
+#: (``PP_RTOL[0]``; a v5e 2x2 showed 2.8e-7).
 ZB_RTOL = 1e-6
 
 

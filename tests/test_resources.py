@@ -384,7 +384,7 @@ def test_scalars_shape():
     cs = resources.CompileSentry()
     out = cs.scalars()
     assert set(out) == {"compiles_total", "compile_time_s",
-                        "recompiles_total"}
+                        "compile_cache_hits", "recompiles_total"}
 
 
 # ------------------------------------------------------- OOM postmortem
@@ -491,7 +491,8 @@ STANDARD_SCALARS = (
     "step_host_wait_s", "step_dispatch_s", "step_device_s",
     "mfu", "model_flops_per_sec", "goodput", "resize_s",
     "hbm_in_use_bytes", "hbm_peak_bytes", "hbm_headroom_pct",
-    "compiles_total", "compile_time_s", "recompiles_total",
+    "compiles_total", "compile_time_s", "compile_cache_hits",
+    "recompiles_total",
     "comm_bytes_per_step", "comm_exposed_bytes_per_step",
 )
 

@@ -133,12 +133,18 @@ PHASE_FACTS: dict = {
     "telemetry_phase": dict(
         # telemetry_overhead_pct needs the chip A/B and is legitimately
         # null in host-only/degraded records — it is DTP003's budget
-        # when measured, not a coverage fact here
+        # when measured, not a coverage fact here.
+        # telemetry_span_overhead_ns: CPU time of the measuring thread
+        # per span, best of 3 passes (PR 21; the wall before)
         keys=("telemetry_span_overhead_ns", "telemetry_span_budget_ns",
               "telemetry_step_host_wait_s", "telemetry_step_dispatch_s",
               "telemetry_step_device_s", "telemetry_breakdown_source"),
         error_key="telemetry_error"),
     "reqtrace_phase": dict(
+        # reqtrace_record_cost_ms: CPU time of the measuring thread per
+        # request record, best of 3 passes (PR 21; one pass on the wall
+        # before); reqtrace_overhead_pct is that cost over the drill's
+        # mean request latency on the wall
         keys=("reqtrace_requests_total", "reqtrace_complete_pct",
               "reqtrace_p99_phase", "reqtrace_slo_compliant_pct",
               "reqtrace_record_cost_ms", "reqtrace_overhead_pct"),

@@ -35,7 +35,7 @@ if _REPO_ROOT not in sys.path:
 
 HBM_KEYS = ("hbm_in_use_bytes", "hbm_peak_bytes", "hbm_headroom_pct",
             "hbm_analytic_bytes", "compiles_total", "compile_time_s",
-            "recompiles_total", "comm_bytes_per_step")
+            "compile_cache_hits", "recompiles_total", "comm_bytes_per_step")
 
 
 def _fmt_bytes(n) -> str:
