@@ -35,6 +35,7 @@ from distributed_tensorflow_tpu.ops.attention import (
     multi_head_attention,
     ring_attention,
 )
+from distributed_tensorflow_tpu.utils.profiling import scope, scoped
 
 
 def _layernorm(x, gain, bias, eps=1e-5):
@@ -78,6 +79,7 @@ def _transformer_block(h, blk, attn_fn, cd):
     return _mlp_half(_attn_half(h, blk, attn_fn, cd), blk, cd)
 
 
+@scoped("mlp")
 def _mlp_half(h, blk, cd):
     """LN -> relu MLP -> residual — the dense block's second half,
     shared with serving/decode.py's incremental step so the two code
@@ -96,6 +98,7 @@ def _attn_half(h, blk, attn_fn, cd):
     return _attn_half_kv(h, blk, attn_fn, cd)[0]
 
 
+@scoped("attn_proj")
 def _attn_half_kv(h, blk, attn_fn, cd):
     """``_attn_half`` that also hands back this block's (k, v) — the
     serving prefill captures them into the decode cache, computed by the
@@ -130,10 +133,11 @@ def _transformer_block_moe(h, blk, attn_fn, cd, capacity_factor,
     from distributed_tensorflow_tpu.ops.moe import switch_moe
 
     h = _attn_half(h, blk, attn_fn, cd)
-    y = _layernorm(h, blk["ln2_g"], blk["ln2_b"])
-    y, aux = switch_moe(y, blk["moe"], capacity_factor=capacity_factor,
-                        axis_name=moe_axis, compute_dtype=cd)
-    return h + y, aux["lb_loss"]
+    with scope("mlp"):
+        y = _layernorm(h, blk["ln2_g"], blk["ln2_b"])
+        y, aux = switch_moe(y, blk["moe"], capacity_factor=capacity_factor,
+                            axis_name=moe_axis, compute_dtype=cd)
+        return h + y, aux["lb_loss"]
 
 
 @register_model("transformer")
@@ -382,15 +386,16 @@ class TransformerLM:
         cd = self.compute_dtype
         # x: integer ids (B, S) — or the LOCAL token block (B, S/P) when
         # called inside the SP shard_map step
-        h = jnp.take(params["tok"], x, axis=0)
-        pos = params["pos"]
-        if self.seq_axis is not None:
-            s_local = x.shape[1]
-            start = lax.axis_index(self.seq_axis) * s_local
-            pos = lax.dynamic_slice_in_dim(pos, start, s_local, axis=0)
-        h = h + pos.astype(h.dtype)
-        if cd is not None:
-            h = h.astype(cd)
+        with scope("embed"):
+            h = jnp.take(params["tok"], x, axis=0)
+            pos = params["pos"]
+            if self.seq_axis is not None:
+                s_local = x.shape[1]
+                start = lax.axis_index(self.seq_axis) * s_local
+                pos = lax.dynamic_slice_in_dim(pos, start, s_local, axis=0)
+            h = h + pos.astype(h.dtype)
+            if cd is not None:
+                h = h.astype(cd)
 
         if self.seq_axis is not None:
             attn = lambda q, k, v: ring_attention(
@@ -419,21 +424,24 @@ class TransformerLM:
             for blk in params["blocks"]:
                 h = blk_fn(h, blk, attn, cd)
 
-        h = _layernorm(h, params["ln_f"]["g"], params["ln_f"]["b"])
-        if rng is not None and self.seq_axis is not None:
-            # per-token dropout: decorrelate the mask across sequence
-            # shards (each shard holds DIFFERENT tokens — unlike the
-            # classifier's post-pool dropout, which must be identical)
-            rng = jax.random.fold_in(rng, lax.axis_index(self.seq_axis))
-        return (nn.dropout(h, keep_prob, rng, deterministic=not train),
-                lb_total)
+        with scope("lm_head"):
+            h = _layernorm(h, params["ln_f"]["g"], params["ln_f"]["b"])
+            if rng is not None and self.seq_axis is not None:
+                # per-token dropout: decorrelate the mask across sequence
+                # shards (each shard holds DIFFERENT tokens — unlike the
+                # classifier's post-pool dropout, which must be identical)
+                rng = jax.random.fold_in(rng,
+                                         lax.axis_index(self.seq_axis))
+            return (nn.dropout(h, keep_prob, rng, deterministic=not train),
+                    lb_total)
 
     def apply(self, params, x, *, keep_prob=1.0, rng=None, train: bool = False):
         h = self.apply_hidden(params, x, keep_prob=keep_prob, rng=rng,
                               train=train)
-        logits = nn.dense(h, params["head"]["w"], params["head"]["b"],
-                          compute_dtype=self.compute_dtype)
-        return logits.astype(jnp.float32)
+        with scope("lm_head"):
+            logits = nn.dense(h, params["head"]["w"], params["head"]["b"],
+                              compute_dtype=self.compute_dtype)
+            return logits.astype(jnp.float32)
 
     @property
     def wants_loss_hook(self) -> bool:
@@ -457,11 +465,13 @@ class TransformerLM:
                 h, params["head"]["w"], params["head"]["b"], y,
                 block=self.ce_block, compute_dtype=self.compute_dtype)
         else:
-            logits = nn.dense(h, params["head"]["w"], params["head"]["b"],
-                              compute_dtype=self.compute_dtype)
-            logits = logits.astype(jnp.float32)
-            ce = nn.softmax_cross_entropy(logits, y)
-            acc = nn.accuracy(logits, y)
+            with scope("lm_head"):
+                logits = nn.dense(h, params["head"]["w"],
+                                  params["head"]["b"],
+                                  compute_dtype=self.compute_dtype)
+                logits = logits.astype(jnp.float32)
+                ce = nn.softmax_cross_entropy(logits, y)
+                acc = nn.accuracy(logits, y)
         metrics = {"loss": ce, "accuracy": acc}
         loss = ce
         if self.moe_experts:

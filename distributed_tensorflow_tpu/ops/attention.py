@@ -25,7 +25,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from distributed_tensorflow_tpu.utils.profiling import scoped
 
+
+@scoped("attention")
 def multi_head_attention(q, k, v, causal: bool = False):
     """Dense (all-to-all) multi-head attention.
 
@@ -118,6 +121,7 @@ def blockwise_attention(q, k, v, block_size: int, causal: bool = False):
     return _blockwise(q, k, v, int(block_size), bool(causal))
 
 
+@scoped("attention")
 def _blockwise_forward(q, k, v, block_size, causal):
     """Forward scan; returns (out BQHD in q.dtype, o_f32 BHQD, lse BHQ)."""
     b, sq, h, dh = q.shape
@@ -161,6 +165,7 @@ def _blockwise_fwd(q, k, v, block_size, causal):
     return out, (q, k, v, o, lse)
 
 
+@scoped("attention")
 def _blockwise_bwd(block_size, causal, res, g):
     """The flash backward: one scan over k/v blocks, each block's
     probability panel recomputed from (q, lse) — never all at once.
@@ -245,6 +250,7 @@ def _ring_mask(causal, owner, sk_blk, row_global):
     return (col_global[None, :] <= row_global[:, None])[None, None]
 
 
+@scoped("attention")
 def _ring_forward(q, k, v, axis_name, causal):
     """Forward ring; returns (out BQHD q.dtype, o_f32 BHQD, lse BHQ)."""
     p_size = lax.axis_size(axis_name)
@@ -307,6 +313,7 @@ def _ring_fwd(q, k, v, axis_name, causal):
     return out, (q, k, v, o, lse)
 
 
+@scoped("attention")
 def _ring_bwd(axis_name, causal, res, g):
     """Distributed flash backward.
 

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from distributed_tensorflow_tpu.utils.profiling import scoped
+
 # dimension_numbers matching the reference's NHWC/HWIO convention
 # (tf.nn.conv2d default, MNISTDist.py:54)
 _CONV_DIMS = ("NHWC", "HWIO", "NHWC")
@@ -197,6 +199,7 @@ def _streamed_ce_fwd(h2, w, b, labels2, block, cd, n_valid):
     return out, (h2, w, b, labels2, lses)
 
 
+@scoped("lm_head")
 def _streamed_ce_bwd(block, cd, n_valid, res, ct):
     """The streamed backward: recompute each block's logits from
     (h, w, b) and its saved row logsumexps — dL/dlogits = softmax -
@@ -246,6 +249,7 @@ def _streamed_ce_bwd(block, cd, n_valid, res, ct):
 _streamed_ce.defvjp(_streamed_ce_fwd, _streamed_ce_bwd)
 
 
+@scoped("lm_head")
 def streamed_softmax_ce_head(h, w, b, labels, block: int,
                              compute_dtype=None):
     """Fused dense head + softmax-CE + accuracy, streamed over row

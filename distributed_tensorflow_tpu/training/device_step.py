@@ -30,10 +30,12 @@ from distributed_tensorflow_tpu.training.train_state import (
     apply_updates,
     loss_and_metrics,
 )
+from distributed_tensorflow_tpu.utils.profiling import scoped
 
 _SAMPLE_SALT = 0x5EED  # folds the sampling stream away from the dropout stream
 
 
+@scoped("sample_batch")
 def _split_and_sample(state: TrainState, data, batch_size: int,
                       axis: str | None, augment_fn):
     """The ONE rng-evolution + on-device batch-draw rule every sampled

@@ -189,6 +189,48 @@ def _display_scalars(meter, stimer, eff, rmon=None) -> dict:
     return out
 
 
+def _display_eval(step, eval_fn, params, model_state, eff, stimer, *,
+                  batch=None, draw=None) -> dict:
+    """One display's evaluation, as three or four host spans that change
+    no device work: ``display_stage`` (only where the loop draws and
+    stages a fresh batch for it: ``draw`` given; its duration is the
+    display's share of ``step_host_wait_s``; the host-fed loops pass the
+    upcoming training ``batch`` instead); ``display_wait``, in which
+    the eval is enqueued (at the first display: traced and compiled) and
+    the host waits for the steps enqueued before it (the eval is queued
+    behind them by then, so the device sees no gap); and ``display_eval``,
+    the readback: the eval's own device time. Returns the display
+    metrics as floats."""
+    if draw is not None:
+        with trace_span("display_stage", step=step) as staged:
+            batch = draw()
+        stimer.add("host_wait", staged.dur_s)
+    with telemetry.armed("display_eval", step=step), _charged(eff, "eval"):
+        with trace_span("display_wait", step=step):
+            m = eval_fn(params, batch, model_state)
+            jax.block_until_ready(params)
+        with trace_span("display_eval", step=step):
+            return {k: float(v) for k, v in m.items()}
+
+
+def _display_log(step, display, logger, scalars, eff, snt,
+                 snt_state) -> None:
+    """The rest of a display, all host work with the device's queue as
+    the loop left it (``display_log``): the sentinel's look, the synced
+    row (written first: its wall time is the (step, time) pair every
+    rate is read from), the scalars row (``scalars()``: the loop's own
+    ``_display_scalars`` call, made in here so that its HBM sample is
+    inside the span), and both flushes."""
+    with trace_span("display_log", step=step):
+        if snt is not None:
+            snt.observe(step, display, state=snt_state,
+                        stall_s=_booked_stall(eff))
+        logger.log_display(step, display["loss"], display["accuracy"])
+        logger.scalars(step, scalars())
+        logger.flush()
+        telemetry.get_tracer().flush()
+
+
 def _booked_stall(eff) -> float:
     """The cumulative stall seconds the goodput ledger has booked —
     handed to Sentinel.observe so known stalls (ckpt/eval/restore/
@@ -302,6 +344,12 @@ def _train_once(FLAGS, mode: str = "local") -> TrainResult:
     # Every loop variant below inherits it (the dispatched _train_*
     # helpers run in this process)
     telemetry.configure_from_flags(FLAGS)
+    # one clock with the profiler: while a jax.profiler session is live
+    # every span also lies on the host plane of its trace
+    telemetry.set_annotator(jax.profiler.TraceAnnotation)
+    # everything the process did before this (imports, flags, the
+    # compile cache's placement, the backend's start) is launch
+    telemetry.get_tracer().record_instant("train_start", mode=mode)
     if int(getattr(FLAGS, "zero", 0) or 0) and mode != "sync":
         # fail BEFORE dataset/model setup: the parse-time validator can
         # only catch an EXPLICIT --mode=local/ps (--mode=auto resolves
@@ -325,11 +373,17 @@ def _train_once(FLAGS, mode: str = "local") -> TrainResult:
     # the shared seed, not a per-process seed discarded later
     data_seed = FLAGS.seed + (
         jax.process_index() if (n_procs > 1 and not span) else 0)
-    ds = read_data_sets(FLAGS.data_dir, one_hot=True, dataset=FLAGS.dataset,
-                        seed=data_seed, validation_size=FLAGS.validation_size,
-                        seq_len=getattr(FLAGS, "seq_len", 256),
-                        vocab_size=getattr(FLAGS, "vocab_size", 64))
-    model, opt, state = build_training_for(FLAGS, ds.meta)
+    with trace_span("data_build", dataset=FLAGS.dataset):
+        ds = read_data_sets(FLAGS.data_dir, one_hot=True,
+                            dataset=FLAGS.dataset, seed=data_seed,
+                            validation_size=FLAGS.validation_size,
+                            seq_len=getattr(FLAGS, "seq_len", 256),
+                            vocab_size=getattr(FLAGS, "vocab_size", 64))
+    with trace_span("state_init"):
+        model, opt, state = build_training_for(FLAGS, ds.meta)
+        # init is dispatched asynchronously: the span ends when the
+        # parameters and the optimizer's state are on the device
+        jax.block_until_ready(state)
     is_lm = ds.meta.get("kind") == "lm"
 
     n_chips = 1
@@ -797,24 +851,14 @@ def _train_once(FLAGS, mode: str = "local") -> TrainResult:
                 batch = next(batches)
                 stimer.add("host_wait", time.perf_counter() - t0)
                 if step % FLAGS.display_step == 0:
-                    with trace_span("display_eval", step=step), \
-                            telemetry.armed("display_eval", step=step), \
-                            _charged(eff, "eval"):
-                        m = eval_fn(state.params, batch, state.model_state)
-                        # the float() readback is where this actually blocks
-                        last_display = {k: float(v) for k, v in m.items()}
-                    if snt is not None:
-                        snt.observe(step, last_display,
-                                    state=lambda: _sentinel_host_state(
-                                        state),
-                                    stall_s=_booked_stall(eff))
-                    logger.log_display(step, last_display["loss"],
-                                       last_display["accuracy"])
-                    logger.scalars(step,
-                                   _display_scalars(meter, stimer, eff,
-                                                    rmon))
-                    logger.flush()
-                    telemetry.get_tracer().flush()
+                    # the upcoming training batch, before the update
+                    last_display = _display_eval(
+                        step, eval_fn, state.params, state.model_state,
+                        eff, stimer, batch=batch)
+                    _display_log(
+                        step, last_display, logger,
+                        lambda: _display_scalars(meter, stimer, eff, rmon),
+                        eff, snt, lambda: _sentinel_host_state(state))
                 if compile_done and not profile_done and not profiling:
                     jax.profiler.start_trace(FLAGS.profile_dir)
                     profiling = True
@@ -851,7 +895,8 @@ def _train_once(FLAGS, mode: str = "local") -> TrainResult:
                     # accumulated work plus this block's wait
                     if eff is not None:
                         eff.charge(stimer.cumulative_work()[0], "init")
-                    with _charged(eff, "init"):
+                    with trace_span("device_sync", step=step), \
+                            _charged(eff, "init"):
                         jax.block_until_ready(state.params)
                     meter.reset()
                     stimer.reset()  # compile stays out of the breakdown too
@@ -1446,7 +1491,8 @@ def _train_pipeline(FLAGS, ds, model, opt, state, mode,
                 # pre-compile window's work + this wait as an init stall
                 if eff is not None:
                     eff.charge(stimer.cumulative_work()[0], "init")
-                with _charged(eff, "init"):
+                with trace_span("device_sync", step=step), \
+                        _charged(eff, "init"):
                     jax.block_until_ready(pp_state.params)
                 meter.reset()
                 stimer.reset()  # compile stays out of the breakdown too
@@ -1474,16 +1520,10 @@ def _train_pipeline(FLAGS, ds, model, opt, state, mode,
                 box.update(host, step)
                 if step % FLAGS.display_step == 0:
                     last_display = {k: float(v) for k, v in m.items()}
-                    if snt is not None:
-                        snt.observe(step, last_display, state=host,
-                                    stall_s=_booked_stall(eff))
-                    logger.log_display(step, last_display["loss"],
-                                       last_display["accuracy"])
-                    logger.scalars(step,
-                                   _display_scalars(meter, stimer, eff,
-                                                    rmon))
-                    logger.flush()
-                    telemetry.get_tracer().flush()
+                    _display_log(
+                        step, last_display, logger,
+                        lambda: _display_scalars(meter, stimer, eff, rmon),
+                        eff, snt, host)
                 periodic_eval(host, step)
                 with _charged(eff, "ckpt"):
                     sv.maybe_checkpoint(host, step)
@@ -1537,7 +1577,8 @@ def _train_pipeline_device(FLAGS, ds, model, opt, state, mesh, n_chips,
     )
 
     k_stages = mesh.shape[MODEL_AXIS]
-    data = put_device_data(ds.train, mesh, data_sharded=True)
+    with trace_span("data_put"):
+        data = put_device_data(ds.train, mesh, data_sharded=True)
     chunk = max(1, math.gcd(FLAGS.display_step, max(1, FLAGS.device_chunk)))
     if chunk != FLAGS.device_chunk:
         print(f"--device_chunk={FLAGS.device_chunk} clamped to {chunk} so "
@@ -1622,7 +1663,8 @@ def _train_pipeline_device(FLAGS, ds, model, opt, state, mesh, n_chips,
                 # pre-compile window's work + this wait as an init stall
                 if eff is not None:
                     eff.charge(stimer.cumulative_work()[0], "init")
-                with _charged(eff, "init"):
+                with trace_span("device_sync", step=step), \
+                        _charged(eff, "init"):
                     jax.block_until_ready(pp_state.params)
                 meter.reset()
                 stimer.reset()  # compile stays out of the breakdown too
@@ -1653,16 +1695,10 @@ def _train_pipeline_device(FLAGS, ds, model, opt, state, mesh, n_chips,
                 box.update(host, step)
                 if step % FLAGS.display_step == 0:
                     last_display = {k: float(v) for k, v in m.items()}
-                    if snt is not None:
-                        snt.observe(step, last_display, state=host,
-                                    stall_s=_booked_stall(eff))
-                    logger.log_display(step, last_display["loss"],
-                                       last_display["accuracy"])
-                    logger.scalars(step,
-                                   _display_scalars(meter, stimer, eff,
-                                                    rmon))
-                    logger.flush()
-                    telemetry.get_tracer().flush()
+                    _display_log(
+                        step, last_display, logger,
+                        lambda: _display_scalars(meter, stimer, eff, rmon),
+                        eff, snt, host)
                 periodic_eval(host, step)
                 with _charged(eff, "ckpt"):
                     sv.maybe_checkpoint(host, step)
@@ -1836,25 +1872,15 @@ def _train_zero(FLAGS, ds, model, opt, state, mode, accum, augment_fn,
                     # the upcoming batch before the update
                     # (MNISTDist.py:179-182) — level 3 gathers the
                     # param chunks inside the sharded eval step
-                    with trace_span("display_eval", step=step), \
-                            telemetry.armed("display_eval", step=step), \
-                            _charged(eff, "eval"):
-                        m = eval_fn(z_state.params, batch,
-                                    z_state.model_state)
-                        # the float() readback is where this actually blocks
-                        last_display = {k: float(v) for k, v in m.items()}
-                    if snt is not None:
-                        # `host` is this displayed step's state in the
-                        # standard layout (fetched at the same boundary)
-                        snt.observe(step, last_display, state=host,
-                                    stall_s=_booked_stall(eff))
-                    logger.log_display(step, last_display["loss"],
-                                       last_display["accuracy"])
-                    logger.scalars(step,
-                                   _display_scalars(meter, stimer, eff,
-                                                    rmon))
-                    logger.flush()
-                    telemetry.get_tracer().flush()
+                    last_display = _display_eval(
+                        step, eval_fn, z_state.params, z_state.model_state,
+                        eff, stimer, batch=batch)
+                    # `host` is this displayed step's state in the
+                    # standard layout (fetched at the same boundary)
+                    _display_log(
+                        step, last_display, logger,
+                        lambda: _display_scalars(meter, stimer, eff, rmon),
+                        eff, snt, host)
                 if compile_done and not profile_done and not profiling:
                     jax.profiler.start_trace(FLAGS.profile_dir)
                     profiling = True
@@ -1883,7 +1909,8 @@ def _train_zero(FLAGS, ds, model, opt, state, mode, accum, augment_fn,
                     # the pre-compile work + this wait as an init stall
                     if eff is not None:
                         eff.charge(stimer.cumulative_work()[0], "init")
-                    with _charged(eff, "init"):
+                    with trace_span("device_sync", step=step), \
+                            _charged(eff, "init"):
                         jax.block_until_ready(z_state.params)
                     meter.reset()
                     stimer.reset()  # compile stays out of the breakdown too
@@ -1959,7 +1986,8 @@ def _train_zero_device(FLAGS, ds, model, opt, state, mesh, n_chips,
         make_zero_device_train_step,
     )
 
-    data = put_device_data(ds.train, mesh)
+    with trace_span("data_put"):
+        data = put_device_data(ds.train, mesh)
     eval_fn = make_zero_eval_step(model, mesh, level)
     chunk = max(1, math.gcd(FLAGS.display_step, max(1, FLAGS.device_chunk)))
     if chunk != FLAGS.device_chunk:
@@ -2020,28 +2048,17 @@ def _train_zero_device(FLAGS, ds, model, opt, state, mesh, n_chips,
                 # reference display semantics, same as the DP device
                 # loop: dropout-off eval of a fresh host batch before
                 # training continues
-                t0 = time.perf_counter()
-                b = ds.train.next_batch(FLAGS.batch_size)
-                staged = shard_batch(mesh, b)
-                stimer.add("host_wait", time.perf_counter() - t0)
-                with trace_span("display_eval", step=step), \
-                        telemetry.armed("display_eval", step=step), \
-                        _charged(eff, "eval"):
-                    m = eval_fn(z_state.params, staged,
-                                z_state.model_state)
-                    # the float() readback is where this actually blocks
-                    last_display = {k: float(v) for k, v in m.items()}
-                if snt is not None:
-                    # `host` is this displayed step's state in the
-                    # standard layout (fetched at the same boundary)
-                    snt.observe(step, last_display, state=host,
-                                stall_s=_booked_stall(eff))
-                logger.log_display(step, last_display["loss"],
-                                   last_display["accuracy"])
-                logger.scalars(step,
-                               _display_scalars(meter, stimer, eff, rmon))
-                logger.flush()
-                telemetry.get_tracer().flush()
+                last_display = _display_eval(
+                    step, eval_fn, z_state.params, z_state.model_state,
+                    eff, stimer,
+                    draw=lambda: shard_batch(
+                        mesh, ds.train.next_batch(FLAGS.batch_size)))
+                # `host` is this displayed step's state in the standard
+                # layout (fetched at the same boundary)
+                _display_log(
+                    step, last_display, logger,
+                    lambda: _display_scalars(meter, stimer, eff, rmon),
+                    eff, snt, host)
             if compile_done and not profile_done and not profiling:
                 jax.profiler.start_trace(FLAGS.profile_dir)
                 profiling = True
@@ -2076,7 +2093,8 @@ def _train_zero_device(FLAGS, ds, model, opt, state, mesh, n_chips,
                 # pre-compile window's work + this wait as an init stall
                 if eff is not None:
                     eff.charge(stimer.cumulative_work()[0], "init")
-                with _charged(eff, "init"):
+                with trace_span("device_sync", step=step), \
+                        _charged(eff, "init"):
                     jax.block_until_ready(z_state.params)
                 meter.reset()
                 stimer.reset()  # compile stays out of the breakdown too
@@ -2157,15 +2175,19 @@ def _train_device_resident(FLAGS, ds, model, opt, state, mesh, n_chips,
         make_ep_device_train_step,
     )
 
-    if sp_model is not None:
-        token_shape = (None if per_token_targets
-                       else (sp_model.seq_len, sp_model.token_dim))
-        data = put_device_data_sp(ds.train, mesh, per_token_targets,
-                                  token_shape=token_shape)
-    elif ep_model is not None:
-        data = put_device_data(ds.train, mesh, data_sharded=True)
-    else:
-        data = put_device_data(ds.train, mesh)
+    with trace_span("data_put"):
+        if sp_model is not None:
+            token_shape = (None if per_token_targets
+                           else (sp_model.seq_len, sp_model.token_dim))
+            data = put_device_data_sp(ds.train, mesh, per_token_targets,
+                                      token_shape=token_shape)
+        elif ep_model is not None:
+            data = put_device_data(ds.train, mesh, data_sharded=True)
+        else:
+            data = put_device_data(ds.train, mesh)
+        # the transfer is asynchronous: the span ends with the split
+        # resident on the device
+        jax.block_until_ready(data)
     chunk = max(1, math.gcd(FLAGS.display_step, max(1, FLAGS.device_chunk)))
     if chunk != FLAGS.device_chunk:
         print(f"--device_chunk={FLAGS.device_chunk} clamped to {chunk} so "
@@ -2240,6 +2262,10 @@ def _train_device_resident(FLAGS, ds, model, opt, state, mesh, n_chips,
              if jax.process_count() > 1 else None)
     should_stop = coord.should_stop if coord is not None else sv.should_stop
 
+    def draw_display_batch():
+        b = ds.train.next_batch(local_batch_size(FLAGS.batch_size))
+        return stage(b) if stage is not None else jax.device_put(b)
+
     with sv.managed(state) as box:
         state, step = box.state, box.step
         _log_recovery(sv, logger, step, eff)
@@ -2258,26 +2284,13 @@ def _train_device_resident(FLAGS, ds, model, opt, state, mesh, n_chips,
                 # minibatch before training continues (MNISTDist.py:179-182).
                 # Multi-process: each host draws its SLICE of the global
                 # batch — stage() assembles slices into the global array
-                t0 = time.perf_counter()
-                b = ds.train.next_batch(local_batch_size(FLAGS.batch_size))
-                staged = stage(b) if stage is not None else jax.device_put(b)
-                stimer.add("host_wait", time.perf_counter() - t0)
-                with trace_span("display_eval", step=step), \
-                        telemetry.armed("display_eval", step=step), \
-                        _charged(eff, "eval"):
-                    m = eval_fn(state.params, staged, state.model_state)
-                    # the float() readback is where this actually blocks
-                    last_display = {k: float(v) for k, v in m.items()}
-                if snt is not None:
-                    snt.observe(step, last_display,
-                                state=lambda: _sentinel_host_state(state),
-                                stall_s=_booked_stall(eff))
-                logger.log_display(step, last_display["loss"],
-                                   last_display["accuracy"])
-                logger.scalars(step,
-                               _display_scalars(meter, stimer, eff, rmon))
-                logger.flush()
-                telemetry.get_tracer().flush()
+                last_display = _display_eval(
+                    step, eval_fn, state.params, state.model_state, eff,
+                    stimer, draw=draw_display_batch)
+                _display_log(
+                    step, last_display, logger,
+                    lambda: _display_scalars(meter, stimer, eff, rmon),
+                    eff, snt, lambda: _sentinel_host_state(state))
             if compile_done and not profile_done and not profiling:
                 jax.profiler.start_trace(FLAGS.profile_dir)
                 profiling = True
@@ -2312,7 +2325,8 @@ def _train_device_resident(FLAGS, ds, model, opt, state, mesh, n_chips,
                 # pre-compile window's work + this wait as an init stall
                 if eff is not None:
                     eff.charge(stimer.cumulative_work()[0], "init")
-                with _charged(eff, "init"):
+                with trace_span("device_sync", step=step), \
+                        _charged(eff, "init"):
                     jax.block_until_ready(state.params)
                 meter.reset()
                 stimer.reset()  # compile stays out of the breakdown too

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from distributed_tensorflow_tpu.ops import nn
+from distributed_tensorflow_tpu.utils.profiling import scoped
 
 
 class TrainState(NamedTuple):
@@ -87,6 +88,7 @@ def sgd(learning_rate, weight_decay: float = 0.0) -> Optimizer:
     def init(params):
         return ()
 
+    @scoped("optimizer")
     def update(grads, opt_state, params, step=None):
         lr = _lr_at(learning_rate, step)
         if wd:
@@ -110,6 +112,7 @@ def momentum(learning_rate, beta: float = 0.9,
     def init(params):
         return jax.tree.map(jnp.zeros_like, params)
 
+    @scoped("optimizer")
     def update(grads, vel, params, step=None):
         lr = _lr_at(learning_rate, step)
         vel = jax.tree.map(lambda v, g: beta * v + g, vel, grads)
@@ -137,6 +140,7 @@ def adam(learning_rate, b1: float = 0.9, b2: float = 0.999, eps: float = 1e-8,
         zeros = lambda: jax.tree.map(jnp.zeros_like, params)
         return {"m": zeros(), "v": zeros(), "t": jnp.zeros((), jnp.int32)}
 
+    @scoped("optimizer")
     def update(grads, st, params, step=None):
         lr = _lr_at(learning_rate, step)
         t = st["t"] + 1
@@ -168,6 +172,7 @@ def get_optimizer(name: str, learning_rate, weight_decay: float = 0.0) -> Optimi
     return factory(learning_rate, weight_decay=weight_decay)
 
 
+@scoped("optimizer")
 def apply_updates(params, updates):
     return jax.tree.map(lambda p, u: p + u.astype(p.dtype), params, updates)
 
@@ -197,6 +202,7 @@ def clip_by_global_norm(max_norm: float, *, axis: str | None = None,
     stage-local-norm divergence the plain form had under PP/EP."""
     max_norm = float(max_norm)
 
+    @scoped("optimizer")
     def transform(grads):
         if axis is None:
             sq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)))

@@ -129,9 +129,14 @@ def _resnet_rows(model) -> list[dict]:
 
 def _transformer_rows(model) -> list[dict]:
     """MiniTransformer / TransformerLM (MoE included): per-EXAMPLE
-    forward FLOPs. Attention is the full causal score matrix (2*S^2*d
-    each for scores and values — what the dense/blockwise/ring forms
-    all compute); a top-1 switch MoE MLP moves each token through
+    forward FLOPs. Attention is what the MODEL needs, not what a form
+    of it computes: 2*S^2*d each for scores and values over the whole
+    score matrix in the classifier, over its causal half in the LM (a
+    token attends to S/2 keys on average — the count of
+    ``benchmark/harness/flops.py``, so the ``mfu`` scalar of a display
+    row agrees with the benchmark's ``step_mfu``; the blockwise and
+    ring forms still compute the masked half, which is no model
+    work); a top-1 switch MoE MLP moves each token through
     exactly one expert, so its per-token compute equals the dense MLP
     (capacity-dropped tokens make this a slight over-count, the
     standard convention)."""
@@ -145,9 +150,10 @@ def _transformer_rows(model) -> list[dict]:
         rows.append({"layer": "embed_proj",
                      "flops": s * _dense_flops(model.token_dim, d)})
         head = {"layer": "cls_head", "flops": _dense_flops(d, model.num_classes)}
+    causal = hasattr(model, "vocab_size")
     per_block = (
         4 * s * _dense_flops(d, d)        # q, k, v, out projections
-        + 2 * (2 * s * s * d)             # scores QK^T + attn*V
+        + 2 * (2 * s * s * d) // (2 if causal else 1)  # QK^T + attn*V
         + 2 * s * _dense_flops(d, mlp)    # MLP (or one switch expert) up+down
     )
     for b in range(model.num_blocks):

@@ -8,10 +8,46 @@ by the training loop via ``--profile_dir`` (training/loop.py).
 
 from __future__ import annotations
 
+import functools
 import threading
 import time
 
 import jax
+
+from distributed_tensorflow_tpu.utils.telemetry import SCOPES
+
+
+def _catalogued(name: str) -> str:
+    if name not in SCOPES:
+        raise ValueError(f"scope {name!r} is not in the scope catalog "
+                         f"{SCOPES} (utils/telemetry.py)")
+    return name
+
+
+def scope(name: str):
+    """``jax.named_scope(name)`` for a name of the scope catalog
+    (``telemetry.SCOPES``): every operation traced inside carries the
+    name in its ``op_name`` path, through jvp, transpose and remat, and
+    the profiler's trace gives each device operation that path. Only HLO
+    metadata changes; the arithmetic and the programs do not."""
+    return jax.named_scope(_catalogued(name))
+
+
+def scoped(name: str):
+    """Decorator form of ``scope``: the whole function runs under it.
+    The scope is opened at every call (not once at decoration), which is
+    what a custom-VJP rule that is traced long after its definition
+    needs; a name outside the catalog fails at import, not at trace."""
+    _catalogued(name)
+
+    def decorate(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return inner
+
+    return decorate
 
 
 class Throughput:

@@ -15,8 +15,14 @@ Four pieces, one shared ring of recent events:
   thread-safe context manager; completed spans land in a fixed-size
   ring (always, ~1-2 µs each — bench asserts < 5 µs) and, when a logdir
   is configured, batch-flush to ``<logdir>/spans-<host>.jsonl``.
+  Every record has an ``id`` and the ``parent`` that was open on its
+  thread (self time = duration less the children's), the file starts
+  with a header line (run id, pid, one epoch/``perf_counter`` pair),
+  and ``set_annotator`` mirrors spans into a second tracer (the loop's
+  ``jax.profiler.TraceAnnotation``: one clock with the device trace).
   ``chrome_trace`` converts any record set to Chrome-trace/Perfetto
-  JSON (``tools/trace_view.py`` is the CLI).
+  JSON (``tools/trace_view.py`` is the CLI). ``SCOPES`` is the catalog
+  of the compiled programs' ``jax.named_scope`` names.
 - **Step-time breakdown** — ``StepTimer`` accumulates host_wait /
   dispatch / device seconds per display window; the training loops emit
   the per-step means as ``step_host_wait_s`` / ``step_dispatch_s`` /
@@ -45,6 +51,7 @@ from __future__ import annotations
 
 import atexit
 import faulthandler
+import itertools
 import json
 import os
 import sys
@@ -55,6 +62,15 @@ from collections import deque
 SPAN_RING = 2048        # completed spans retained for dumps
 FLIGHT_EVENTS = 512     # flight-recorder ring length (--flightrec_events)
 WATCHDOG_LAST_SPANS = 32
+
+# The scope catalog: the ``jax.named_scope`` names the compiled programs
+# carry (docs/ARCHITECTURE.md, "scope catalog"). Each is put on once,
+# where the work is written, so every step, eval and decode program
+# inherits it; a trace reader gives an operation to the innermost of these
+# in its ``op_name`` path (``benchmark/harness/scopes.py`` imports this
+# tuple, tests/test_scopes.py holds the compiled step to it).
+SCOPES = ("attention", "attn_proj", "mlp", "lm_head", "embed", "optimizer",
+          "sample_batch")
 
 
 def _json_safe(v):
@@ -69,6 +85,7 @@ def _json_safe(v):
 
 class _NoopSpan:
     __slots__ = ()
+    dur_s = 0.0
 
     def __enter__(self):
         return self
@@ -83,9 +100,13 @@ _NOOP = _NoopSpan()
 class _Span:
     """One active span. Cheap by construction: two perf_counter reads,
     one wall-clock read, a thread-local stack push/pop, one deque
-    append."""
+    append. ``id`` is unique in the process; ``parent`` is the id of the
+    span that was open on this thread when this one started (None at
+    the top), so a span's self time is its duration less its children's.
+    ``dur_s`` is readable once the span has closed."""
 
-    __slots__ = ("_tracer", "_name", "_attrs", "_t0", "_wall", "_depth")
+    __slots__ = ("_tracer", "_name", "_attrs", "_t0", "_wall", "_depth",
+                 "_id", "_parent", "_annotation", "dur_s")
 
     def __init__(self, tracer, name, attrs):
         self._tracer = tracer
@@ -95,17 +116,29 @@ class _Span:
     def __enter__(self):
         stack = self._tracer._stack()
         self._depth = len(stack)
-        stack.append(self._name)
+        self._parent = stack[-1] if stack else None
+        self._id = next(_SPAN_IDS)
+        stack.append(self._id)
+        annotate = _ANNOTATOR
+        self._annotation = None
+        if annotate is not None:
+            self._annotation = annotate(self._name)
+            self._annotation.__enter__()
         self._wall = time.time()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        dur = time.perf_counter() - self._t0
+        self.dur_s = dur = time.perf_counter() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         self._tracer._stack().pop()
         rec = dict(self._attrs) if self._attrs else {}
         rec["name"] = self._name
+        rec["id"] = self._id
+        rec["parent"] = self._parent
         rec["ts"] = self._wall
+        rec["pc"] = self._t0
         rec["dur_s"] = dur
         rec["tid"] = threading.get_ident()
         rec["thread"] = threading.current_thread().name
@@ -114,6 +147,26 @@ class _Span:
             rec["error"] = exc_type.__name__
         self._tracer._finish(rec)
         return False
+
+
+_SPAN_IDS = itertools.count(1)  # next() is atomic under the GIL
+
+# ``set_annotator``'s factory: name -> context manager entered and left
+# with every live span. None (the default): a span costs what it did.
+_ANNOTATOR = None
+
+
+def set_annotator(factory) -> None:
+    """Mirror every span into a second tracer: ``factory(name)`` must
+    return a context manager. The training loop installs
+    ``jax.profiler.TraceAnnotation``, so that while a ``jax.profiler``
+    session is live each span also lies on the host plane of the same
+    ``.xplane.pb`` as the device's operations, on the trace's own clock
+    (with no session live a TraceAnnotation records nothing). This module
+    stays stdlib-only: it never imports the factory's package. ``None``
+    removes the hook."""
+    global _ANNOTATOR
+    _ANNOTATOR = factory
 
 
 class Tracer:
@@ -131,6 +184,7 @@ class Tracer:
         self._path: str | None = None
         self._file = None
         self._file_path: str | None = None  # path _file was opened for
+        self._header: dict | None = None  # owed to the sink's next flush
 
     def _stack(self) -> list:
         st = getattr(self._local, "stack", None)
@@ -142,6 +196,17 @@ class Tracer:
         if not self.enabled:
             return _NOOP
         return _Span(self, name, attrs)
+
+    def _mark(self, rec: dict) -> dict:
+        """Identity and clocks of a record that is no ``with`` block (an
+        instant, a retroactively-timed span): its own id, the open span
+        of this thread as its parent."""
+        stack = self._stack()
+        rec.update(id=next(_SPAN_IDS), parent=stack[-1] if stack else None,
+                   tid=threading.get_ident(),
+                   thread=threading.current_thread().name,
+                   depth=len(stack))
+        return rec
 
     def _finish(self, rec: dict) -> None:
         with self._lock:
@@ -155,11 +220,9 @@ class Tracer:
         if not self.enabled:
             return
         rec = {k: _json_safe(v) for k, v in attrs.items()}
-        rec.update(name=name, ts=time.time(), dur_s=0.0,
-                   tid=threading.get_ident(),
-                   thread=threading.current_thread().name,
-                   depth=len(self._stack()), instant=True)
-        self._finish(rec)
+        rec.update(name=name, ts=time.time(), pc=time.perf_counter(),
+                   dur_s=0.0, instant=True)
+        self._finish(self._mark(rec))
 
     def record_complete(self, name: str, ts: float, dur_s: float,
                         attrs=None) -> None:
@@ -170,20 +233,27 @@ class Tracer:
         if not self.enabled:
             return
         rec = {k: _json_safe(v) for k, v in (attrs or {}).items()}
-        rec.update(name=name, ts=float(ts), dur_s=float(dur_s),
-                   tid=threading.get_ident(),
-                   thread=threading.current_thread().name,
-                   depth=len(self._stack()))
-        self._finish(rec)
+        rec.update(name=name, ts=float(ts), dur_s=float(dur_s))
+        self._finish(self._mark(rec))
 
     def configure_sink(self, path: str | None) -> None:
         """Set (or clear) the spans JSONL file; flushes are batched —
         the loops call ``flush()`` at the display cadence and every
-        flight-recorder dump flushes too."""
+        flight-recorder dump flushes too. Each configuration owes the
+        file one header line, written ahead of its first spans: the
+        run's id, the pid, and one reading of the epoch and of
+        ``perf_counter`` taken together, which places every record's
+        ``pc`` (a monotonic clock) on the epoch of its ``ts``. It has a
+        ``kind`` and neither ``ts`` nor ``dur_s``: no span."""
         with self._lock:
             # _path reads/writes stay under _lock (the writers' lock);
             # the file handle swap alone rides _io_lock
             self._path = path
+            self._header = None if path is None else {
+                "kind": "header", "name": "spans_header",
+                "run": os.urandom(6).hex(),
+                "pid": os.getpid(), "epoch": time.time(),
+                "perf_counter": time.perf_counter()}
         with self._io_lock:
             if self._file is not None and path != self._file_path:
                 self._file.close()
@@ -197,6 +267,9 @@ class Tracer:
             if self._path is None or not self._pending:
                 return
             pending, self._pending = self._pending, []
+            if self._header is not None:
+                pending.insert(0, self._header)
+                self._header = None
             path = self._path
         with self._io_lock:
             try:
@@ -265,7 +338,8 @@ def chrome_trace(records=None) -> dict:
     if records is None:
         records = _TRACER.last(10 ** 9)
     pid = os.getpid()
-    core = ("name", "ts", "dur_s", "tid", "thread", "depth", "instant")
+    core = ("name", "ts", "pc", "dur_s", "tid", "thread", "depth",
+            "instant")
     events = []
     for r in records:
         args = {k: _json_safe(v) for k, v in r.items() if k not in core}
@@ -464,8 +538,8 @@ class Watchdog:
         print(f"last {len(spans)} spans (oldest first):", file=out)
         for r in spans:
             extras = {k: v for k, v in r.items()
-                      if k not in ("name", "ts", "dur_s", "tid", "thread",
-                                   "depth")}
+                      if k not in ("name", "ts", "pc", "dur_s", "tid",
+                                   "thread", "depth", "id", "parent")}
             print(f"  {r.get('ts', 0):.6f} {r.get('dur_s', 0) * 1e3:9.3f}ms "
                   f"[{r.get('thread', '?')}] "
                   f"{'  ' * r.get('depth', 0)}{r.get('name', '?')} "

@@ -137,6 +137,44 @@ def test_enable_sets_no_directory_when_the_environment_places_it(
         assert got["calls"] == [["jax_compilation_cache_dir", want]]
 
 
+def test_the_cache_key_covers_the_scope_catalog_and_not_the_source():
+    """An executable compiled before the program named its blocks must not
+    be loaded for the program that names them (the trace would show the
+    old paths): JAX's key leaves metadata out, so the catalog goes in
+    through ``cache_key.custom_hook``. A scope alone, or a moved line,
+    still changes no key: checkouts that share the catalog share entries."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax._src import cache_key, compiler
+
+    from distributed_tensorflow_tpu.utils.telemetry import SCOPES
+
+    def key(scoped):
+        def f(x):
+            if scoped:
+                with jax.named_scope("mlp"):
+                    return jnp.tanh(x @ x).sum()
+            return jnp.tanh(x @ x).sum()
+
+        module = jax.jit(f).lower(jnp.ones((8, 8))).compiler_ir()
+        options = compiler.get_compile_options(num_replicas=1,
+                                               num_partitions=1)
+        return cache_key.get(module, np.array(jax.devices()[:1]), options,
+                             jax.devices()[0].client)
+
+    before = cache_key.custom_hook
+    try:
+        cache_key.custom_hook = lambda: ""
+        bare = key(False)
+        assert key(True) == bare  # metadata is not in JAX's key
+        compile_cache.enable_compile_cache()
+        assert cache_key.custom_hook() == "scopes=" + ",".join(SCOPES)
+        assert key(True) == key(False) != bare
+    finally:
+        cache_key.custom_hook = before
+
+
 def test_a_later_process_counts_its_persistent_cache_hits(tmp_path):
     """What chip_smoke holds the later trainer to: ``compile_cache_hits``
     of the compile sentry is 0 in the process that stores a program and

@@ -54,8 +54,15 @@ def test_lm_budget_scales_with_blocks_and_counts_head():
     b1, b2 = flops_budget(mk(1)), flops_budget(mk(2))
     per_block = b2["fwd_flops_per_example"] - b1["fwd_flops_per_example"]
     s, d, mlp = 32, 32, 4 * 32
-    assert per_block == (4 * s * 2 * d * d + 2 * (2 * s * s * d)
+    # the causal half of the score matrix, as benchmark/harness/flops.py
+    # counts it: a token attends to S/2 keys on average
+    assert per_block == (4 * s * 2 * d * d + 2 * (2 * s * s * d) // 2
                          + 2 * s * 2 * d * mlp)
+    from benchmark.harness import flops as bench_flops
+
+    sizes = dict(d_model=d, num_blocks=1, ffn_dim=mlp, vocab_size=64)
+    assert b1["train_flops_per_example"] == pytest.approx(
+        s * bench_flops.train_flops_per_token(sizes, s))
     head = [r for r in b1["rows"] if r["layer"] == "lm_head"]
     assert head and head[0]["flops"] == s * 2 * d * 64
 

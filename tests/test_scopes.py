@@ -1,0 +1,121 @@
+"""The scope catalog (``telemetry.SCOPES``) on the compiled step: every
+catalogued ``jax.named_scope`` reaches the ``op_name`` of some operation of
+one ``TransformerLM`` train step, with ``--remat`` and without, for dense and
+blockwise attention; a scope changes metadata only, so the step's outputs
+are bit-equal with ``jax.named_scope`` patched to a no-op; and the program
+opens no scope that the catalog does not hold."""
+
+import ast
+import contextlib
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from distributed_tensorflow_tpu.data.device_data import DeviceData
+from distributed_tensorflow_tpu.models import get_model
+from distributed_tensorflow_tpu.training import (
+    create_train_state,
+    get_optimizer,
+)
+from distributed_tensorflow_tpu.training.device_step import (
+    make_device_train_step,
+)
+from distributed_tensorflow_tpu.utils import profiling
+from distributed_tensorflow_tpu.utils.telemetry import SCOPES
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+FORMS = [(remat, block) for remat in (False, True) for block in (None, 16)]
+
+
+def build(remat, attn_block):
+    model = get_model("lm", vocab_size=300, seq_len=64, d_model=32,
+                      num_heads=2, num_blocks=2, compute_dtype=jnp.bfloat16,
+                      attn_block=attn_block, remat=remat, ce_block=16)
+    opt = get_optimizer("adam", 1e-3)
+    state = create_train_state(model, opt, seed=0)
+    tokens = np.random.default_rng(0).integers(0, 300, (32, 65))
+    data = DeviceData(jnp.asarray(tokens[:, :-1], jnp.int32),
+                      jnp.asarray(tokens[:, 1:], jnp.int32))
+    step = make_device_train_step(model, opt, 4, chunk=2, donate=False)
+    return step, state, data
+
+
+def scopes_in(op_name: str) -> set:
+    """Catalogued scopes among the elements of a path, seen through the
+    transform wrappers (``transpose(jvp(mlp))`` holds ``mlp``)."""
+    return {e for e in re.findall(r"[A-Za-z_]\w*", op_name) if e in SCOPES}
+
+
+@pytest.mark.parametrize("remat,attn_block", FORMS)
+def test_every_scope_of_the_catalog_is_in_the_compiled_step(remat,
+                                                            attn_block):
+    step, state, data = build(remat, attn_block)
+    text = step.lower(state, data).compile().as_text()
+    paths = set(re.findall(r'op_name="([^"]*)"', text))
+    found = set().union(*(scopes_in(p) for p in paths))
+    assert found == set(SCOPES)
+    # the backward pass carries the names too, through jvp and transpose
+    assert any("transpose(" in p and "attention" in scopes_in(p)
+               for p in paths)
+    assert any("transpose(" in p and "lm_head" in scopes_in(p)
+               for p in paths)
+    # attention is opened inside attn_proj and is the innermost there
+    assert any(re.search(r"attn_proj\)?/attention", p) for p in paths)
+    assert any("rematted_computation" in p for p in paths) == remat
+
+
+@pytest.mark.parametrize("remat,attn_block", FORMS)
+def test_a_scope_changes_no_number(remat, attn_block, monkeypatch):
+    def outputs():
+        step, state, data = build(remat, attn_block)
+        new_state, metrics = step(state, data)
+        return jax.device_get((new_state.params, new_state.opt_state,
+                               metrics))
+
+    with_scopes = outputs()
+    opened = []
+
+    @contextlib.contextmanager
+    def no_scope(name):
+        opened.append(name)
+        yield
+
+    monkeypatch.setattr(jax, "named_scope", no_scope)
+    without = outputs()
+    assert set(opened) == set(SCOPES)  # the patch was what the step opened
+    for a, b in zip(jax.tree.leaves(with_scopes), jax.tree.leaves(without)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_the_program_opens_only_catalogued_scopes():
+    """Every ``scope("x")`` / ``scoped("x")`` literal in the package is in
+    the catalog (a name outside it raises as the module is imported), the
+    package calls ``jax.named_scope`` nowhere else, and each catalogued
+    name has a site."""
+    with pytest.raises(ValueError, match="scope catalog"):
+        profiling.scope("not_in_the_catalog")
+    with pytest.raises(ValueError, match="scope catalog"):
+        profiling.scoped("not_in_the_catalog")
+    used = set()
+    package = os.path.join(REPO, "distributed_tensorflow_tpu")
+    for dirpath, _, files in os.walk(package):
+        for f in files:
+            if not f.endswith(".py"):
+                continue
+            path = os.path.join(dirpath, f)
+            tree = ast.parse(open(path).read())
+            for node in ast.walk(tree):
+                if not isinstance(node, ast.Call):
+                    continue
+                callee = getattr(node.func, "attr",
+                                 getattr(node.func, "id", None))
+                if callee == "named_scope":
+                    assert path.endswith(os.path.join("utils", "profiling.py"))
+                if callee in ("scope", "scoped") and node.args and \
+                        isinstance(node.args[0], ast.Constant):
+                    used.add(node.args[0].value)
+    assert used == set(SCOPES)

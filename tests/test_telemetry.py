@@ -135,6 +135,121 @@ def test_tracer_jsonl_sink_batched_flush(tmp_path):
     assert any(r["name"] == "sunk" and r["step"] == 3 for r in recs)
 
 
+def test_span_ids_and_parents_nest_across_threads():
+    """Every record has an id of its own; ``parent`` is the id of the span
+    open on the SAME thread (None at the top), whatever other threads have
+    open — so self time needs neither ``depth`` nor the thread id."""
+    inside = threading.Event()
+    release = threading.Event()
+
+    def other():
+        with trace_span("other_outer"):
+            inside.set()
+            release.wait(5)
+            with trace_span("other_inner"):
+                telemetry.get_tracer().record_instant("fault:other")
+
+    t = threading.Thread(target=other)
+    with trace_span("main_outer"):
+        t.start()
+        assert inside.wait(5)
+        with trace_span("main_inner"):  # opened while other_outer is open
+            pass
+        release.set()
+        t.join(5)
+        assert not t.is_alive()
+    by = {r["name"]: r for r in telemetry.last_spans(10)}
+    assert len({r["id"] for r in by.values()}) == 5
+    assert by["main_outer"]["parent"] is None
+    assert by["other_outer"]["parent"] is None
+    assert by["main_inner"]["parent"] == by["main_outer"]["id"]
+    assert by["other_inner"]["parent"] == by["other_outer"]["id"]
+    assert by["fault:other"]["parent"] == by["other_inner"]["id"]
+    # self time of a parent: its duration less its children's
+    kids = [r for r in by.values() if r["parent"] == by["main_outer"]["id"]]
+    assert [k["name"] for k in kids] == ["main_inner"]
+    assert by["main_outer"]["dur_s"] - kids[0]["dur_s"] > 0
+    # one monotonic clock beside the epoch: a child starts after its parent
+    assert by["main_inner"]["pc"] >= by["main_outer"]["pc"]
+
+
+def test_spans_file_starts_with_a_header_line(tmp_path):
+    """Each configuration owes the file one header: the run's id, the pid
+    and one reading of the epoch and of perf_counter taken together; it is
+    no span (neither ``ts`` nor ``dur_s``), and a second configuration of
+    the same file (a restart into one logdir) writes a second one."""
+    path = tmp_path / "spans-worker-0.jsonl"
+    runs = []
+    for _ in range(2):
+        before = (time.time(), time.perf_counter())
+        telemetry.configure(logdir=str(tmp_path), host="worker-0")
+        with trace_span("a"):
+            pass
+        telemetry.get_tracer().flush()
+        telemetry.get_tracer().flush()  # nothing pending: no second header
+        recs = [json.loads(l) for l in path.read_text().splitlines()]
+        head = [r for r in recs if r.get("kind") == "header"]
+        runs.append(head[-1])
+        assert head[-1]["pid"] == os.getpid()
+        assert before[0] <= head[-1]["epoch"] <= time.time()
+        assert before[1] <= head[-1]["perf_counter"] <= time.perf_counter()
+        assert "ts" not in head[-1] and "dur_s" not in head[-1]
+    assert recs[0]["kind"] == "header" and recs[1]["name"] == "a"
+    assert [r.get("kind") for r in recs] == ["header", None, "header", None]
+    assert runs[0]["run"] != runs[1]["run"]
+    # the pair places a record's monotonic ``pc`` on the epoch of its ``ts``
+    span = recs[-1]
+    on_epoch = runs[1]["epoch"] + span["pc"] - runs[1]["perf_counter"]
+    assert on_epoch == pytest.approx(span["ts"], abs=0.05)
+
+
+def test_annotator_hook_once_a_span_and_never_without_one():
+    calls = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            calls.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            calls.append(("exit", self.name))
+
+    with trace_span("unhooked"):
+        pass
+    assert calls == []
+    telemetry.set_annotator(Annotation)
+    try:
+        with trace_span("outer"):
+            with trace_span("inner"):
+                pass
+        telemetry.get_tracer().record_instant("fault:x")  # no interval
+        assert calls == [("enter", "outer"), ("enter", "inner"),
+                         ("exit", "inner"), ("exit", "outer")]
+        telemetry.configure(logdir=None, enabled=False)
+        with trace_span("disabled"):
+            pass
+        assert len(calls) == 4
+    finally:
+        telemetry.set_annotator(None)
+    telemetry.configure(logdir=None, enabled=True)
+    with trace_span("unhooked_again"):
+        pass
+    assert len(calls) == 4
+
+
+def test_chrome_trace_carries_ids_and_still_loads():
+    with trace_span("outer"):
+        with trace_span("inner"):
+            pass
+    ct = json.loads(json.dumps(chrome_trace()))
+    by = {e["name"]: e for e in ct["traceEvents"]}
+    assert by["inner"]["args"]["parent"] == by["outer"]["args"]["id"]
+    assert by["outer"]["args"]["parent"] is None
+    assert by["outer"]["ph"] == "X" and by["outer"]["dur"] >= by["inner"]["dur"]
+
+
 # ----------------------------------------------------- step breakdown
 
 
@@ -366,6 +481,61 @@ def test_step_breakdown_scalars_in_every_loop_variant(
                       "device_resident": "device_chunk",
                       "pp": "pp_step", "zero": "zero_step"}
     assert dispatch_spans[variant] in names, (variant, names)
+
+
+DISPLAY_VARIANTS = {
+    "host_fed": [],
+    "device_resident": ["--device_data", "--device_chunk=5"],
+    "zero": ["--zero=1"],
+    "zero_device": ["--zero=1", "--device_data", "--device_chunk=5"],
+}
+
+
+@pytest.mark.parametrize("variant", sorted(DISPLAY_VARIANTS))
+def test_display_spans_and_the_synced_row_between_them(
+        tmp_path, fresh_flags, variant):
+    """One display is ``display_stage`` (only where the loop stages a fresh
+    batch for it), ``display_wait``, ``display_eval``, ``display_log``, in
+    that order at the top of the main thread, and the synced row is written
+    right after the readback: its time lies between ``display_eval``'s end
+    and ``display_log``'s end."""
+    from distributed_tensorflow_tpu.training.loop import train
+
+    flags.FLAGS._parse([
+        f"--logdir={tmp_path}/logs", f"--data_dir={tmp_path}/no-data",
+        "--training_iter=15", "--batch_size=16", "--display_step=5",
+        "--save_model_secs=100000", "--test_eval=false",
+        *DISPLAY_VARIANTS[variant]])
+    train(flags.FLAGS, mode="sync")
+    rows = [json.loads(l) for l in open(f"{tmp_path}/logs/metrics.jsonl")]
+    synced = {r["step"]: r["time"] for r in rows if "mini_batch_loss" in r}
+    spans = [json.loads(l) for l in
+             open(glob.glob(f"{tmp_path}/logs/spans-*.jsonl")[0])]
+    staged = "device" in variant
+    parts = (["display_stage"] if staged else []) + [
+        "display_wait", "display_eval", "display_log"]
+    for step in (0, 5, 10):
+        mine = [s for s in spans if s["name"].startswith("display_")
+                and s.get("step") == step]
+        assert [s["name"] for s in mine] == parts, (variant, step, mine)
+        assert all(s["parent"] is None for s in mine)
+        assert [s["id"] for s in mine] == sorted(s["id"] for s in mine)
+        by = {s["name"]: s for s in mine}
+        end = lambda s: s["ts"] + s["dur_s"]
+        slack = 1e-3  # ``ts`` is the epoch clock, ``dur_s`` the monotonic
+        assert end(by["display_eval"]) - slack <= synced[step] \
+            <= end(by["display_log"]) + slack
+        assert by["display_log"]["ts"] <= synced[step] + slack
+    if staged:
+        # the staging's seconds are the display's share of host_wait (a
+        # mean over the five steps since the last display), read off the
+        # span and not timed a second time
+        scalars = [r for r in rows if "step_host_wait_s" in r
+                   and r["step"] == 10][0]
+        stage = [s for s in spans if s["name"] == "display_stage"
+                 and s.get("step") == 10][0]
+        assert scalars["step_host_wait_s"] == pytest.approx(
+            stage["dur_s"] / 5, rel=1e-3, abs=1e-9)
 
 
 # ------------------------------------------- serving /healthz /metrics

@@ -120,8 +120,8 @@ def render_timeline(records: list[dict], out=None) -> None:
     records = sorted(records, key=lambda r: float(r.get("ts", 0.0)))
     multi_host = len({r.get("host") for r in records}) > 1
     last_step = object()
-    core = ("name", "ts", "dur_s", "tid", "thread", "depth", "instant",
-            "host")
+    core = ("name", "ts", "pc", "dur_s", "tid", "thread", "depth",
+            "instant", "host", "id", "parent")
     for r in records:
         step = r.get("step")
         if step != last_step and step is not None:
