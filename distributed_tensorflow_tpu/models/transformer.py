@@ -378,6 +378,19 @@ class TransformerLM:
         return self._hidden_and_aux(params, x, keep_prob=keep_prob,
                                     rng=rng, train=train)[0]
 
+    def attention_fn(self):
+        """``(q, k, v) -> out``, all (B, S, H, Dh): this model's causal
+        attention flavor (ring / blockwise / dense). The one place the
+        choice is made: the pipeline stages call it, and the TP step
+        wraps it per head shard (parallel/tensor_parallel.py)."""
+        if self.seq_axis is not None:
+            return lambda q, k, v: ring_attention(
+                q, k, v, self.seq_axis, causal=True)
+        if self.attn_block is not None:
+            return lambda q, k, v: blockwise_attention(
+                q, k, v, self.attn_block, causal=True)
+        return lambda q, k, v: multi_head_attention(q, k, v, causal=True)
+
     def _hidden_and_aux(self, params, x, *, keep_prob=1.0, rng=None,
                         train: bool = False):
         """(hidden, moe load-balance loss total) — the aux term is 0.0
@@ -397,14 +410,7 @@ class TransformerLM:
             if cd is not None:
                 h = h.astype(cd)
 
-        if self.seq_axis is not None:
-            attn = lambda q, k, v: ring_attention(
-                q, k, v, self.seq_axis, causal=True)
-        elif self.attn_block is not None:
-            attn = lambda q, k, v: blockwise_attention(
-                q, k, v, self.attn_block, causal=True)
-        else:
-            attn = lambda q, k, v: multi_head_attention(q, k, v, causal=True)
+        attn = self.attention_fn()
 
         lb_total = jnp.float32(0.0)
         if self.moe_experts:

@@ -1,20 +1,37 @@
-"""Attention ops: dense multi-head attention and RING attention for
-sequence/context parallelism.
+"""Attention ops: dense multi-head attention, single-chip blockwise
+(flash) attention, and RING attention for sequence/context parallelism.
 
 The reference framework predates attention entirely — this module is the
-build's long-context extension, designed TPU-first: the sequence axis is
-sharded over a mesh axis and the key/value blocks ROTATE around the ring
-with ``lax.ppermute`` (one ICI hop per step) while each device's queries
-accumulate the streaming-softmax statistics blockwise (the flash/online
-softmax recurrence). Peak activation memory per device is one (q, k, v)
-block regardless of total sequence length, and the collective traffic
-rides neighbor-to-neighbor ICI links — the layout "How to Scale Your
-Model"-style context parallelism wants.
+build's long-context extension, designed TPU-first. All three are the same
+mathematics; the blockwise and ring forms share the flash/online-softmax
+recurrence (``_online_softmax_step``) and its backward
+(``_flash_bwd_block``).
 
-Everything is expressed with ``lax.scan`` + differentiable collectives
-(``ppermute`` has a transpose rule), so ``jax.grad`` through a ring step
-is exact — no custom VJP required. Equivalence with dense attention (fwd
-and grads) is pinned by tests/test_attention.py.
+- ``blockwise_attention`` is the single-chip path (``--attn_block``). On a
+  TPU, for bf16 causal self-attention at 128-aligned shapes, each (query
+  tile, key tile) pair is computed inside one fused Pallas kernel
+  (``ops/flash_attention.py``): scores, probabilities, ``dp`` and ``ds``
+  live in VMEM, the tiles above the diagonal are skipped, and only q, k,
+  v, o, the row statistics and the three gradients cross HBM. Everything
+  else — other backends, f32 operands, tiny or ragged shapes — runs the
+  same recurrence as a ``lax.scan`` over key blocks with all query rows at
+  once, whose (B, H, Sq, block) panels XLA writes to memory (on a v5e that
+  scan ran at the memory roofline of its panels, PERF.md §6 PR 26). The
+  choice is made from the shapes and the lowering platform, never by a
+  flag; the scan is the form the tests hold the kernels to.
+- ``ring_attention``: the sequence axis is sharded over a mesh axis and
+  the key/value blocks ROTATE around the ring with ``lax.ppermute`` (one
+  ICI hop per step) while each device's queries accumulate the
+  streaming-softmax statistics blockwise. Peak activation memory per
+  device is one (q, k, v) block regardless of total sequence length, and
+  the collective traffic rides neighbor-to-neighbor ICI links — the layout
+  "How to Scale Your Model"-style context parallelism wants. It runs the
+  scan form of the block step (no benchmark cell runs it).
+
+Both flash forms carry a custom VJP (plain autodiff of the forward scan
+would save every block's probability panel). Equivalence with dense
+attention (fwd and grads) is pinned by tests/test_attention.py,
+tests/test_lm.py and, for the kernels, tests/test_flash_kernel.py.
 """
 
 from __future__ import annotations
@@ -25,7 +42,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from distributed_tensorflow_tpu.utils.profiling import scoped
+from distributed_tensorflow_tpu.utils.profiling import lowering_instant, scoped
 
 
 @scoped("attention")
@@ -97,22 +114,39 @@ def blockwise_attention(q, k, v, block_size: int, causal: bool = False):
     """Single-device FLASH attention with O(S * block) peak memory —
     forward AND backward.
 
-    Same math as ``multi_head_attention`` (pinned by tests), computed as
-    a ``lax.scan`` over k/v blocks with the online-softmax recurrence —
-    the full (Sq, Sk) score matrix never materializes. The backward pass
-    is a CUSTOM VJP (the flash backward): plain autodiff of the forward
-    scan would save each step's (B, H, Sq, block) probability panel as a
-    residual — O(Sq * Sk) total, no better than dense (measured: WORSE,
-    round-4 sweep) — so instead only (q, k, v, o, logsumexp) are saved
-    and each block's probabilities are RECOMPUTED from them during a
-    second scan that accumulates dq and emits per-block dk/dv. Peak
-    activation is one (B, H, Sq, block) panel in both passes. This is
-    the single-chip half of the long-context story; ``ring_attention``
-    is the same recurrence with blocks arriving over the mesh.
+    Same math as ``multi_head_attention`` (pinned by tests), computed
+    one key block at a time with the online-softmax recurrence — the
+    full (Sq, Sk) score matrix never materializes. The backward pass is
+    a CUSTOM VJP (the flash backward): plain autodiff of the forward
+    would save each block's probability panel as a residual — O(Sq * Sk)
+    total, no better than dense (measured: WORSE, round-4 sweep) — so
+    instead only (q, k, v, out, logsumexp) are saved and each block's
+    probabilities are RECOMPUTED from them while dq, dk and dv
+    accumulate. This is the single-chip half of the long-context story;
+    ``ring_attention`` is the same recurrence with blocks arriving over
+    the mesh.
+
+    Two implementations of that one algorithm, chosen by what the code
+    can observe (``_pick``):
+
+    - **fused** — causal bf16 self-attention with S and ``block_size``
+      multiples of 128 (``fusable``), in a program
+      lowered for a TPU: the Pallas kernels of ``ops/flash_attention.py``.
+      A (query tile, ``block_size`` keys) panel lives in VMEM only,
+      the tiles above the diagonal are never visited and the mask is
+      applied only in the tiles the diagonal crosses. On a TPU at such
+      shapes the kernel compiles or the run fails: there is no fallback.
+    - **scan** — everything else (f32 operands, tiny or ragged shapes,
+      non-causal, any backend but the TPU): a ``lax.scan`` over key
+      blocks with all Sq query rows at once, so each step makes one
+      (B, H, Sq, block) panel that XLA writes to memory. Blocks
+      entirely above the diagonal still run here (static scan length)
+      and contribute exact zeros. It is the form the tests hold the
+      kernels to.
 
     ``causal=True`` masks by absolute position, identical to the dense
-    triangle. Blocks entirely above the diagonal still run (static scan
-    length — XLA needs static shapes) but contribute exact zeros.
+    triangle. Each pass records which implementation its program was
+    lowered with (the ``attention_path`` telemetry instant).
     """
     sk = k.shape[1]
     if sk % block_size:
@@ -121,9 +155,57 @@ def blockwise_attention(q, k, v, block_size: int, causal: bool = False):
     return _blockwise(q, k, v, int(block_size), bool(causal))
 
 
-@scoped("attention")
-def _blockwise_forward(q, k, v, block_size, causal):
-    """Forward scan; returns (out BQHD in q.dtype, o_f32 BHQD, lse BHQ)."""
+def _by_platform(fused, scan, *args):
+    """``fused(*args)`` in a program lowered for a TPU, ``scan(*args)``
+    in one lowered for anything else (both are traced, one is lowered:
+    a described-TPU compile on a CPU host gets the kernel)."""
+    return lax.platform_dependent(*args, tpu=fused, default=scan)
+
+
+def fusable(q, k, v, block_size: int, causal: bool = True) -> bool:
+    """Whether these operands are the fused kernels' to take: causal bf16
+    self-attention (Sq = Sk) with S and the key tile multiples of the
+    128-lane tile and a head width of 64, 128 or 256."""
+    return (causal and q.shape == k.shape == v.shape
+            and q.dtype == k.dtype == v.dtype == jnp.bfloat16
+            and q.shape[1] % 128 == 0 and block_size % 128 == 0
+            and q.shape[-1] in (64, 128, 256))
+
+
+def _pick(pass_name, scan, q, k, v, block_size, causal, *rest):
+    """Run one pass (``forward`` / ``backward``) of blockwise attention
+    through the implementation its shapes and lowering platform select,
+    marked with the ``attention_path`` instant."""
+    s = q.shape[1]
+    note = {"pass": pass_name, "seq_len": s, "k_tile": block_size,
+            "dtype": q.dtype.name}
+
+    def run_scan(q, *xs):
+        q = lowering_instant("attention_path", q, path="scan", q_tile=s,
+                             **note)
+        return scan(q, *xs, block_size, causal)
+
+    if not fusable(q, k, v, block_size, causal):
+        return run_scan(q, k, v, *rest)
+    # imports Pallas: only a program that can take the kernels pays for it
+    from distributed_tensorflow_tpu.ops import flash_attention
+
+    fused = {"forward": flash_attention.flash_forward,
+             "backward": flash_attention.flash_backward}[pass_name]
+
+    def run_fused(q, *xs):
+        q = lowering_instant("attention_path", q, path="fused",
+                             q_tile=flash_attention.query_tile(s), **note)
+        return fused(q, *xs, block_size)
+
+    return _by_platform(run_fused, run_scan, q, k, v, *rest)
+
+
+# jitted (here and the backward): every layer of a model traces both
+# implementations, and shares one trace of each this way
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _scan_forward(q, k, v, block_size, causal):
+    """Forward scan; returns (out BQHD in q.dtype, lse BHQ f32)."""
     b, sq, h, dh = q.shape
     sk = k.shape[1]
     n_blocks = sk // block_size
@@ -151,22 +233,27 @@ def _blockwise_forward(q, k, v, block_size, causal):
                             (jnp.arange(n_blocks), kb, vb))
     o = o / l[..., None]
     lse = m + jnp.log(l)  # logsumexp per row: p_ij = exp(s_ij - lse_i)
-    out = jnp.einsum("bhqd->bqhd", o).astype(q.dtype)
-    return out, o, lse
+    return jnp.einsum("bhqd->bqhd", o).astype(q.dtype), lse
+
+
+@scoped("attention")
+def _forward(q, k, v, block_size, causal):
+    """(out, lse) by the fused kernel or the scan."""
+    return _pick("forward", _scan_forward, q, k, v, block_size, causal)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _blockwise(q, k, v, block_size, causal):
-    return _blockwise_forward(q, k, v, block_size, causal)[0]
+    return _forward(q, k, v, block_size, causal)[0]
 
 
 def _blockwise_fwd(q, k, v, block_size, causal):
-    out, o, lse = _blockwise_forward(q, k, v, block_size, causal)
-    return out, (q, k, v, o, lse)
+    out, lse = _forward(q, k, v, block_size, causal)
+    return out, (q, k, v, out, lse)
 
 
-@scoped("attention")
-def _blockwise_bwd(block_size, causal, res, g):
+@functools.partial(jax.jit, static_argnums=(6, 7))
+def _scan_backward(q, k, v, out, lse, g, block_size, causal):
     """The flash backward: one scan over k/v blocks, each block's
     probability panel recomputed from (q, lse) — never all at once.
 
@@ -176,7 +263,6 @@ def _blockwise_bwd(block_size, causal, res, g):
     softmax-through-attention transpose, evaluated blockwise. Exactness
     vs dense autodiff is pinned by tests/test_lm.py (values AND grads).
     """
-    q, k, v, o, lse = res
     b, sq, h, dh = q.shape
     sk = k.shape[1]
     n_blocks = sk // block_size
@@ -184,7 +270,7 @@ def _blockwise_bwd(block_size, causal, res, g):
     qf = q.astype(jnp.float32)
     gf = jnp.einsum("bqhd->bhqd", g.astype(jnp.float32))
     rows = jnp.arange(sq)
-    dD = jnp.sum(gf * o, axis=-1)  # (B, H, Sq)
+    dD = jnp.einsum("bhqd,bqhd->bhq", gf, out.astype(jnp.float32))
     kb = jnp.moveaxis(k.reshape(b, n_blocks, block_size, h, dh), 1, 0)
     vb = jnp.moveaxis(v.reshape(b, n_blocks, block_size, h, dh), 1, 0)
 
@@ -203,6 +289,15 @@ def _blockwise_bwd(block_size, causal, res, g):
     dk = jnp.moveaxis(dkb, 0, 1).reshape(b, sk, h, dh)
     dv = jnp.moveaxis(dvb, 0, 1).reshape(b, sk, h, dh)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+
+
+@scoped("attention")
+def _blockwise_bwd(block_size, causal, res, g):
+    """(dq, dk, dv) by the fused kernel or the scan, from the residuals
+    both forwards save in one form: (q, k, v, out, logsumexp)."""
+    q, k, v, out, lse = res
+    return _pick("backward", _scan_backward, q, k, v, block_size, causal,
+                 out, lse, g)
 
 
 _blockwise.defvjp(_blockwise_fwd, _blockwise_bwd)

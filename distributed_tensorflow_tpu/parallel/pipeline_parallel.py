@@ -272,16 +272,8 @@ def fetch_state_pp(state: TrainState, model, k_stages: int | None = None,
 def _attn_for(model):
     """The model's single-device attention flavor (causal; dense or
     blockwise) — PP stages run the SAME block math the plain model
-    runs, so the flavor selection must match apply_hidden's."""
-    from distributed_tensorflow_tpu.ops.attention import (
-        blockwise_attention,
-        multi_head_attention,
-    )
-
-    if model.attn_block is not None:
-        return lambda q, k, v: blockwise_attention(
-            q, k, v, model.attn_block, causal=True)
-    return lambda q, k, v: multi_head_attention(q, k, v, causal=True)
+    runs, so the flavor selection IS the model's."""
+    return model.attention_fn()
 
 
 def _pp_step_fn(model, optimizer, mesh, microbatches: int,

@@ -23,10 +23,12 @@ collective traffic at these shapes).
 
 from __future__ import annotations
 
+import copy
+
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from distributed_tensorflow_tpu.parallel.mesh import MODEL_AXIS
+from distributed_tensorflow_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 from distributed_tensorflow_tpu.training.train_state import TrainState
 
 # FC-stack split for the reference CNN's parameter names (models/cnn.py):
@@ -205,6 +207,28 @@ def shard_state_tp(state: TrainState, mesh: Mesh) -> TrainState:
     return jax.device_put(state, shardings)
 
 
+def shard_attention(model, mesh: Mesh):
+    """``model`` with its blockwise attention run per (batch, head) shard.
+
+    Everything else of a TP step is global-view and XLA partitions it, but
+    the fused attention is a Mosaic kernel, which the partitioner refuses
+    ("cannot be automatically partitioned"). Attention is independent
+    across batch rows and heads — the two dims this layout shards, batch
+    over "data" and heads over "model" — so a ``shard_map`` over exactly
+    those is the same mathematics with local shapes, and each shard
+    chooses kernel or scan from ITS shapes as a single chip would. Models
+    without ``attn_block`` (dense attention is plain XLA) pass through."""
+    if getattr(model, "attn_block", None) is None:
+        return model
+    spec = P(DATA_AXIS, None, MODEL_AXIS, None)
+    per_shard = jax.shard_map(model.attention_fn(), mesh=mesh,
+                              in_specs=(spec, spec, spec), out_specs=spec,
+                              check_vma=False)
+    model = copy.copy(model)
+    model.attention_fn = lambda: per_shard
+    return model
+
+
 def make_tp_train_step(model, optimizer, mesh: Mesh, keep_prob: float = 1.0,
                        donate: bool = True, grad_transform=None,
                        accum_steps: int = 1, augment_fn=None):
@@ -214,24 +238,25 @@ def make_tp_train_step(model, optimizer, mesh: Mesh, keep_prob: float = 1.0,
     parallelism comes entirely from the layouts committed on the input
     arrays (``shard_state_tp`` / ``stage_batch_tp``) — XLA's SPMD
     partitioner derives every collective (grad psum over "data", activation
-    psum over "model") from those. ``mesh`` is accepted for API symmetry
-    with ``make_dp_train_step`` and to document which mesh the caller
-    placed the state on; the compiled code never reads it.
+    psum over "model") from those. ``mesh`` is the mesh the caller placed
+    the state on; the one thing built on it here is the blockwise
+    attention's ``shard_map`` (``shard_attention``).
     """
-    del mesh
     from distributed_tensorflow_tpu.training.train_state import make_train_step
 
-    return make_train_step(model, optimizer, keep_prob=keep_prob,
+    return make_train_step(shard_attention(model, mesh), optimizer,
+                           keep_prob=keep_prob,
                            grad_transform=grad_transform, donate=donate,
                            accum_steps=accum_steps, augment_fn=augment_fn)
 
 
-def make_tp_eval_step(model):
+def make_tp_eval_step(model, mesh: Mesh):
     """Global-view eval: shardings propagate from the committed params —
-    the plain eval step unchanged."""
+    the plain eval step, its blockwise attention per shard as in the
+    train step."""
     from distributed_tensorflow_tpu.training.train_state import make_eval_step
 
-    return make_eval_step(model)
+    return make_eval_step(shard_attention(model, mesh))
 
 
 def stage_batch_tp(mesh: Mesh, batch):

@@ -448,11 +448,16 @@ def make_device_tp_train_step(model, optimizer, mesh, batch_size: int, *,
     beyond-parity modes (--device_data + --model_axis)."""
     from jax.sharding import NamedSharding
 
+    from distributed_tensorflow_tpu.parallel.tensor_parallel import (
+        shard_attention,
+    )
+
     batch_sharding = (
         NamedSharding(mesh, P(DATA_AXIS, None)),  # images [B, P]
         NamedSharding(mesh, P(DATA_AXIS)),        # int labels [B]
     )
-    body = _sampled_step_body(model, optimizer, batch_size, keep_prob,
+    body = _sampled_step_body(shard_attention(model, mesh), optimizer,
+                              batch_size, keep_prob,
                               None, grad_transform, batch_sharding,
                               augment_fn=augment_fn)
     fn = _scan_chunk(body, chunk)

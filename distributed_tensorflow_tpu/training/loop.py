@@ -749,7 +749,7 @@ def _train_once(FLAGS, mode: str = "local") -> TrainResult:
         step_fn = make_tp_train_step(model, opt, mesh, keep_prob=FLAGS.keep_prob,
                                      grad_transform=clip, accum_steps=accum,
                                      augment_fn=augment)
-        eval_fn = make_tp_eval_step(model)
+        eval_fn = make_tp_eval_step(model, mesh)
         stage = lambda b: stage_batch_tp(mesh, b)
         restage = lambda s: shard_state_tp(s, mesh)
     elif mode == "sync":

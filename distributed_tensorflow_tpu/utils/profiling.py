@@ -13,7 +13,10 @@ import threading
 import time
 
 import jax
+from jax.extend.core import Primitive
+from jax.interpreters import batching, mlir
 
+from distributed_tensorflow_tpu.utils import telemetry
 from distributed_tensorflow_tpu.utils.telemetry import SCOPES
 
 
@@ -48,6 +51,29 @@ def scoped(name: str):
         return inner
 
     return decorate
+
+
+def _record(x, *, name, attrs):
+    telemetry.get_tracer().record_instant(name, **dict(attrs))
+    return x
+
+
+_instant_p = Primitive("lowering_instant")
+_instant_p.def_impl(_record)  # called eagerly: there is no program
+_instant_p.def_abstract_eval(lambda x, **_: x)
+mlir.register_lowering(
+    _instant_p, lambda ctx, x, **params: [_record(x, **params)])
+batching.defvectorized(_instant_p)
+
+
+def lowering_instant(name: str, x, **attrs):
+    """``x``, unchanged — and one telemetry instant ``name`` whenever the
+    program that holds this call is LOWERED (no operation is emitted, the
+    program does not change). A branch of ``lax.platform_dependent`` is
+    traced for every platform and lowered for one, so a choice the
+    lowering makes can only be recorded from there. Use the returned
+    value, or the marker is dead code. ``attrs``: strings and numbers."""
+    return _instant_p.bind(x, name=name, attrs=tuple(sorted(attrs.items())))
 
 
 class Throughput:

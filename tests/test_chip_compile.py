@@ -14,6 +14,8 @@ compile cache is off around them — such a compile can be written to it
 but not read back without a chip.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -21,6 +23,7 @@ from jax.sharding import SingleDeviceSharding
 
 from distributed_tensorflow_tpu.models import DeepCNN
 from distributed_tensorflow_tpu.models.transformer import TransformerLM
+from distributed_tensorflow_tpu.ops.attention import blockwise_attention
 from distributed_tensorflow_tpu.ops.pallas_ops import fused_dense_relu
 from distributed_tensorflow_tpu.training import (
     adam,
@@ -113,3 +116,109 @@ def test_lm_train_step_compiles_at_4k_context(one_chip):
     step = make_train_step(model, opt, keep_prob=1.0)
     compiled = step.lower(*_on(one_chip, (state, batch))).compile()
     assert _device_bytes(compiled) < V5E_HBM_BYTES // 2
+
+
+# the benchmark's two cells (benchmark/configs/opt-*.json, traffic
+# train-s2048): heads, width, blocks, remat
+CELLS = {"opt-1.3b": (32, 2048, 8, True), "opt-125m": (12, 768, 12, False)}
+
+
+def _kernels_under_attention(hlo: str) -> list[str]:
+    """Names of the Mosaic kernels whose ``op_name`` path holds the
+    ``attention`` scope, bare or inside ``jvp(...)`` / ``transpose(...)``
+    (what ``attention_device_pct`` reads)."""
+    paths = re.findall(
+        r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', hlo)
+    return [re.search(r"flash_attention_\w+", p).group(0) for p in paths
+            if "attention" in re.findall(r"[A-Za-z_]\w*", p)]
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_fused_attention_compiles_at_the_cells_shapes(one_chip, cell):
+    """Forward and backward of the cells' attention (8 x 2,048 tokens,
+    head width 64, 512-key tile, bf16) lower to the two flash kernels
+    under the ``attention`` scope, and nothing panel-shaped is left."""
+    heads = CELLS[cell][0]
+    qkv = _on(one_chip, (jax.ShapeDtypeStruct((8, 2048, heads, 64),
+                                              jnp.bfloat16),) * 3)
+
+    def loss(q, k, v):
+        out = blockwise_attention(q, k, v, 512, causal=True)
+        return out.astype(jnp.float32).sum()
+
+    hlo = jax.jit(jax.grad(loss, (0, 1, 2))).lower(*qkv).compile().as_text()
+    assert sorted(_kernels_under_attention(hlo)) == [
+        "flash_attention_bwd", "flash_attention_fwd"]
+    assert f"[8,{heads},2048,512]" not in hlo
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_lm_device_step_compiles_with_the_fused_attention(one_chip, cell):
+    """The benchmark cells' whole step (``make_device_train_step``, batch
+    8, chunk 1, adam on f32 masters, streamed head) for the described
+    chip: the flash kernels are in it under the ``attention`` scope, no
+    (B, H, S, block) panel is, and it fits the chip."""
+    from distributed_tensorflow_tpu.data.device_data import DeviceData
+
+    heads, d_model, blocks, remat = CELLS[cell]
+    model = TransformerLM(vocab_size=50272, seq_len=2048, d_model=d_model,
+                          num_heads=heads, num_blocks=blocks, attn_block=512,
+                          ce_block=2048, remat=remat,
+                          compute_dtype=jnp.bfloat16)
+    opt = adam(1e-4)
+    state = jax.eval_shape(lambda: create_train_state(model, opt, seed=0))
+    data = DeviceData(jax.ShapeDtypeStruct((4096, 2048), jnp.uint16),
+                      jax.ShapeDtypeStruct((4096, 2048), jnp.uint16))
+    step = make_device_train_step(model, opt, 8, keep_prob=1.0, chunk=1)
+    compiled = step.lower(*_on(one_chip, (state, data))).compile()
+    hlo = compiled.as_text()
+    kernels = _kernels_under_attention(hlo)
+    # remat's second forward is a kernel call of its own
+    assert kernels.count("flash_attention_bwd") == blocks
+    assert kernels.count("flash_attention_fwd") == blocks * (2 if remat else 1)
+    assert f"[8,{heads},2048,512]" not in hlo
+    assert _device_bytes(compiled) < V5E_HBM_BYTES
+
+
+@pytest.mark.parametrize("mode", ["dp", "tp"])
+def test_sharded_steps_run_the_fused_attention_on_local_shapes(topo, mode):
+    """Across the described 2x2 chips the kernel runs on each shard's own
+    shapes: inside sync DP's ``shard_map`` as it stands, and in the
+    global-view TP step through ``tensor_parallel.shard_attention`` (batch
+    over "data", heads over "model") — GSPMD refuses to partition a Mosaic
+    kernel, so without it this compile raises."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from distributed_tensorflow_tpu.parallel.data_parallel import (
+        make_dp_train_step,
+    )
+    from distributed_tensorflow_tpu.parallel.mesh import MeshSpec, make_mesh
+    from distributed_tensorflow_tpu.parallel.tensor_parallel import (
+        make_tp_train_step,
+        tp_state_sharding,
+    )
+
+    model = TransformerLM(vocab_size=512, seq_len=512, d_model=256,
+                          num_heads=4, num_blocks=2, attn_block=128,
+                          ce_block=128, compute_dtype=jnp.bfloat16)
+    opt = adam(1e-3)
+    state = jax.eval_shape(lambda: create_train_state(model, opt, seed=0))
+    if mode == "dp":
+        mesh = make_mesh(MeshSpec(data=4, model=1), devices=topo.devices)
+        shardings = jax.tree.map(lambda _: NamedSharding(mesh, P()), state)
+        step = make_dp_train_step(model, opt, mesh, donate=False)
+        local = (8 // 4) * 4  # rows a shard x heads a shard
+    else:
+        mesh = make_mesh(MeshSpec(data=2, model=2), devices=topo.devices)
+        shardings = tp_state_sharding(state, mesh)
+        step = make_tp_train_step(model, opt, mesh, donate=False)
+        local = (8 // 2) * (4 // 2)
+    state = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        state, shardings)
+    rows = NamedSharding(mesh, P("data"))
+    batch = (jax.ShapeDtypeStruct((8, 512), jnp.int32, sharding=rows),) * 2
+    hlo = step.lower(state, batch).compile().as_text()
+    assert sorted(_kernels_under_attention(hlo)) == (
+        ["flash_attention_bwd"] * 2 + ["flash_attention_fwd"] * 2)
+    assert f"bf16[{local},64,512]" in hlo  # the kernels' (H*B, Dh, S)

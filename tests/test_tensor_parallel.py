@@ -117,7 +117,7 @@ def test_tp_eval_step():
     mesh = make_mesh(MeshSpec(data=4, model=2))
     model = DeepCNN()
     state = shard_state_tp(create_train_state(model, sgd(0.01), seed=0), mesh)
-    eval_fn = make_tp_eval_step(model)
+    eval_fn = make_tp_eval_step(model, mesh)
     m = eval_fn(state.params, stage_batch_tp(mesh, _batch(24)), ())
     assert np.isfinite(float(m["loss"]))
     assert 0.0 <= float(m["accuracy"]) <= 1.0
@@ -338,9 +338,10 @@ def test_lm_model_axis_cli(tmp_path):
 
 
 def test_transformer_tp_composes_with_blockwise_attention():
-    """TP head-sharding propagates through the blockwise flash scan
-    (its (B, H, S, block) panels shard on H): trajectory == the same
-    blockwise model on one device."""
+    """TP runs blockwise attention per (batch, head) shard
+    (``shard_attention``: here the scan on local shapes, on a TPU at
+    fusable shapes the kernel): trajectory == the same blockwise model on
+    one device."""
     from distributed_tensorflow_tpu.data.lm import LMDataSet
     from distributed_tensorflow_tpu.models.transformer import TransformerLM
 

@@ -352,10 +352,12 @@ def _value_constants(expr) -> list:
     return []
 
 
-# the three span-emitting entry points DTT005 audits: the live context
-# manager, the instant marker, and the request plane's retroactively-
-# timed completed span (utils/telemetry.record_span)
-_SPAN_CALLEES = ("trace_span", "record_instant", "record_span")
+# the span-emitting entry points DTT005 audits: the live context
+# manager, the instant marker, the request plane's retroactively-
+# timed completed span (utils/telemetry.record_span), and the instant a
+# program records as it is lowered (utils/profiling.lowering_instant)
+_SPAN_CALLEES = ("trace_span", "record_instant", "record_span",
+                 "lowering_instant")
 
 
 def _has_span_sites(index) -> bool:
