@@ -22,8 +22,6 @@ import threading
 import jax
 import jax.numpy as jnp
 
-from benchmark.reference.opt_lm import leaf_names
-
 ADAM_B1 = 0.9
 STEPS = 3
 
@@ -40,10 +38,13 @@ def _diff_norm(a, b):
 
 
 class FirstStepsProbe:
-    """Install before ``train()``; read ``result`` once ``done`` is set."""
+    """Install before ``train()``; read ``result`` once ``done`` is set.
+    ``leaf_names`` is the cell's family's: it names the parameters' leaves
+    as that family's reference names its own."""
 
-    def __init__(self, builders=("make_device_train_step",
-                                 "make_device_dp_train_step")):
+    def __init__(self, leaf_names, builders=("make_device_train_step",
+                                             "make_device_dp_train_step")):
+        self.leaf_names = leaf_names
         self.builders = builders
         self.calls = 0
         self.done = threading.Event()
@@ -108,7 +109,7 @@ class FirstStepsProbe:
         return call
 
     def _finish(self, state):
-        names = leaf_names(state.params)
+        names = self.leaf_names(state.params)
         change = {}
         starts = jax.tree.leaves(self._start)
         self._start = None

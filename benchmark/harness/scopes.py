@@ -28,6 +28,7 @@ as ``unscoped``, which is the guard of the whole reading.
 
 from __future__ import annotations
 
+import bisect
 import functools
 import os
 import re
@@ -122,11 +123,13 @@ def _map_entry(buf, span):
 
 
 def read_device_planes(path: str) -> dict:
-    """{plane name: {'ops': [(start ns, end ns, metadata id)], 'meta':
-    {metadata id: {'name', 'op_name', 'hlo_category', 'flops',
-    'bytes_accessed'}}}} of every ``/device:TPU:<i>`` plane. Times are
-    what ``harness/trace.py`` gets from ``ProfileData``, which cuts an
-    offset and a duration to whole nanoseconds each."""
+    """{plane name: {'ops': [(start ns, end ns, metadata id)], 'modules':
+    [(start ns, end ns, program name)], 'meta': {metadata id: {'name',
+    'op_name', 'hlo_category', 'flops', 'bytes_accessed'}}}} of every
+    ``/device:TPU:<i>`` plane: its operations and the runs of its programs
+    (the line ``XLA Modules``). Times are what ``harness/trace.py`` gets
+    from ``ProfileData``, which cuts an offset and a duration to whole
+    nanoseconds each."""
     with open(path, "rb") as f:
         buf = memoryview(f.read())
     planes = {}
@@ -144,14 +147,15 @@ def read_device_planes(path: str) -> dict:
                 for m, _, w in _fields(buf, *value):
                     if m == 2:
                         stat_names[key] = _text(buf, w)
-        ops = []
+        ops, runs = [], []
         for n, _, v in top:
             if n != 3:
                 continue
             line = list(_fields(buf, *v))
-            if next((_text(buf, w) for m, _, w in line if m == 2),
-                    "") != trace.OPS_LINE:
+            line_name = next((_text(buf, w) for m, _, w in line if m == 2), "")
+            if line_name not in (trace.OPS_LINE, trace.MODULES_LINE):
                 continue
+            events = ops if line_name == trace.OPS_LINE else runs
             line_ns = next((_signed(w) for m, _, w in line if m == 3), 0)
             for m, _, w in line:
                 if m != 4:
@@ -167,10 +171,10 @@ def read_device_planes(path: str) -> dict:
                     elif k == 3:
                         duration_ps = _signed(x)
                 start = line_ns + offset_ps // 1000
-                ops.append((start, start + duration_ps // 1000, meta_id))
+                events.append((start, start + duration_ps // 1000, meta_id))
         if not ops:
             continue
-        used = {o[2] for o in ops}
+        used = {e[2] for e in ops + runs}
         meta = {}
         for n, _, v in top:
             if n != 4:
@@ -191,7 +195,10 @@ def read_device_planes(path: str) -> dict:
                     elif stat in KEPT_STATS and x is not None:
                         entry[stat] = x
             meta[key] = entry
-        planes[name] = {"ops": ops, "meta": meta}
+        modules = [(s, e, meta[k]["name"]) for s, e, k in runs]
+        for k in {k for _, _, k in runs}:
+            del meta[k]
+        planes[name] = {"ops": ops, "modules": modules, "meta": meta}
     return planes
 
 
@@ -229,13 +236,30 @@ def scope_of(op_name: str, scopes: tuple) -> str:
     return UNSCOPED
 
 
+def whole_runs(plane: dict) -> list[tuple[int, int, str]]:
+    """(start, end, program) of the programs' runs that lie whole between
+    the plane's first operation and its last, in time order: a run cut by
+    an edge of the trace holds only part of its operations."""
+    t0 = min(s for s, _, _ in plane["ops"])
+    t1 = max(e for _, e, _ in plane["ops"])
+    return sorted(r for r in plane["modules"] if r[0] > t0 and r[1] < t1)
+
+
 def reduce_device(plane: dict, scopes: tuple) -> dict:
     """One device: {'busy_ns', 'scopes': {scope: {'own_ns', 'flops',
-    'bytes_accessed', 'by_category': {hlo category: own ns}}}, 'remat_ns',
+    'bytes_accessed', 'by_category': {hlo category: own ns}, 'by_run':
+    {program: [own ns in each of its whole runs]}}}, 'remat_ns',
     'has_remat'}. ``flops`` and ``bytes_accessed`` are summed over the
     events of operations that hold no other (a ``while`` or a call repeats
-    its body's)."""
+    its body's). ``by_run`` gives an operation to the whole run
+    (``whole_runs``) it starts in, and has an entry for every scope that
+    ran in one; what runs in a cut program is in ``own_ns`` alone."""
     meta = plane["meta"]
+    runs = whole_runs(plane)
+    run_starts = [r[0] for r in runs]
+    runs_of = defaultdict(list)
+    for i, (_, _, program) in enumerate(runs):
+        runs_of[program].append(i)
     per_scope = {}
     remat_ns, has_remat = 0, False
     which = {k: scope_of(m["op_name"], scopes) for k, m in meta.items()}
@@ -244,15 +268,20 @@ def reduce_device(plane: dict, scopes: tuple) -> dict:
     holders = {"while", "conditional", "call"}
     nameless = {"hlo_category": "", "flops": 0, "bytes_accessed": 0}
     busy = 0
-    for meta_id, ns in trace.self_times(plane["ops"]):
+    for (meta_id, start), ns in trace.self_times(
+            (s, e, (k, s)) for s, e, k in plane["ops"]):
         m = meta.get(meta_id, nameless)
         scope = which.get(meta_id, UNSCOPED)
         entry = per_scope.get(scope)
         if entry is None:
             entry = per_scope[scope] = {
                 "own_ns": 0, "flops": 0, "bytes_accessed": 0,
-                "by_category": defaultdict(int)}
+                "by_category": defaultdict(int),
+                "by_run": defaultdict(lambda: defaultdict(int))}
         entry["own_ns"] += ns
+        i = bisect.bisect_right(run_starts, start) - 1
+        if i >= 0 and start < runs[i][1]:
+            entry["by_run"][runs[i][2]][i] += ns
         entry["by_category"][m["hlo_category"]] += ns
         if m["hlo_category"] not in holders:
             entry["flops"] += m["flops"]
@@ -263,6 +292,9 @@ def reduce_device(plane: dict, scopes: tuple) -> dict:
             has_remat = True
     for entry in per_scope.values():
         entry["by_category"] = dict(entry["by_category"])
+        # every whole run of a program the scope ran in, 0 where it did not
+        entry["by_run"] = {program: [by_index[i] for i in runs_of[program]]
+                           for program, by_index in entry["by_run"].items()}
     return {"busy_ns": busy, "scopes": per_scope, "remat_ns": remat_ns,
             "has_remat": has_remat}
 

@@ -74,8 +74,9 @@ def reduce_window(rows: list[dict], seconds: float, open_step: int,
               and opened["step"] <= r["step"] <= closed["step"]]
     steps = closed["step"] - opened["step"]
     span = closed["time"] - opened["time"]
-    slowest = max((b["time"] - a["time"]) / (b["step"] - a["step"])
-                  for a, b in zip(inside, inside[1:]))
+    before, after = max(zip(inside, inside[1:]), key=lambda ab: (
+        ab[1]["time"] - ab[0]["time"]) / (ab[1]["step"] - ab[0]["step"]))
+    slowest = (after["time"] - before["time"]) / (after["step"] - before["step"])
     first, last = (scalars_at(rows, opened["step"]),
                    scalars_at(rows, closed["step"]))
     compiles = None
@@ -86,6 +87,12 @@ def reduce_window(rows: list[dict], seconds: float, open_step: int,
         "steps": steps, "seconds": span,
         "tokens_per_s_per_chip": steps * tokens_per_step / span / chips,
         "step_ms_slowest": slowest * 1e3,
+        # where it was: a stall is one interval, and the trainer's spans
+        # say what the host did in it (``spans.inside``)
+        "slowest_interval": {"from_step": before["step"],
+                             "to_step": after["step"],
+                             "start": before["time"],
+                             "seconds": after["time"] - before["time"]},
         "step_ms_mean": span / steps * 1e3,
         "losses": [r[SYNC_KEY] for r in inside],
         "compiles_in_window": compiles,

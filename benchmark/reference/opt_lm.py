@@ -1,8 +1,14 @@
-"""Plain reference for the OPT-shaped causal LM and its first training steps.
+"""The family ``opt_lm``: the OPT-shaped causal LM (pre-LN decoder, learned
+positions, full multi-head attention, ReLU MLP of 4 x d, untied head), as
+``models/transformer.py:TransformerLM`` runs it. A configuration file names
+it (``"family": "opt_lm"``) and the harness finds here, by the names of
+``harness/manifest.py:FAMILY_NAMES``: the sizes and the trainer's flags the
+configuration maps to, the plain reference for the first training steps, and
+the operation counts (at the end of the file).
 
-Everything the trainer does between ``--seed`` and the state after three
-steps, written out again in ``jax.numpy`` float32 with nothing taken from
-the program: the procedural token split, the parameters drawn from the
+The reference is everything the trainer does between ``--seed`` and the
+state after three steps, written out again in ``jax.numpy`` float32 with
+nothing taken from the program: the procedural token split, the parameters drawn from the
 seed, the batch rows sampled from the step's key, a pre-LN decoder with
 dense causal attention and a whole-logits mean cross-entropy, its gradient
 and the Adam update. Matrix products run under
@@ -38,6 +44,29 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+# ---- the configuration, as the counts, the reference and the trainer take it
+
+def sizes(config: dict, mix: dict) -> dict:
+    """The published ``config.json`` and the mix under the trainer's names."""
+    return {"d_model": config["hidden_size"],
+            "num_heads": config["num_attention_heads"],
+            "num_blocks": config["num_hidden_layers"],
+            "ffn_dim": config["ffn_dim"],
+            "vocab_size": config["vocab_size"],
+            "seq_len": mix["seq_len"]}
+
+
+def trainer_flags(config: dict, mix: dict) -> dict:
+    """The model's own flags of ``mnist_dist.py``."""
+    if config["ffn_dim"] != 4 * config["hidden_size"]:
+        raise ValueError("TransformerLM's MLP is 4 x d_model wide; "
+                         f"ffn_dim {config['ffn_dim']} is not")
+    return {"d_model": config["hidden_size"],
+            "num_heads": config["num_attention_heads"],
+            "num_blocks": config["num_hidden_layers"],
+            "vocab_size": config["vocab_size"]}
+
 
 LM_TRAIN_SEQUENCES = 4096
 SAMPLE_SALT = 0x5EED
@@ -104,12 +133,13 @@ def sampled_rows(seed: int, steps: int, rows_per_shard: int, shards: int,
     return out
 
 
-def first_batches(seed: int, steps: int, rows_per_shard: int, shards: int,
-                  seq_len: int, vocab_size: int,
+def first_batches(seed: int, steps: int, sizes: dict, rows_per_shard: int,
+                  shards: int,
                   prng: str = "threefry2x32") -> list[np.ndarray]:
     """(rows, seq_len + 1) int32 tokens of each of the first batches."""
     idx = sampled_rows(seed, steps, rows_per_shard, shards, prng)
-    table = token_rows(seed, np.concatenate(idx), seq_len, vocab_size)
+    table = token_rows(seed, np.concatenate(idx), sizes["seq_len"],
+                       sizes["vocab_size"])
     return [np.stack([table[int(r)] for r in step]).astype(np.int32)
             for step in idx]
 
@@ -256,11 +286,13 @@ def leaf_differences(grads, others) -> dict[str, float]:
 
 
 def first_steps(seed: int, sizes: dict, batches, learning_rate: float, *,
+                config: dict | None = None, mix: dict | None = None,
                 precision: str = "f32", keep_rows=None, row_block: int = 1,
                 prng: str = "threefry2x32", first_gradient_of_other=None,
                 keep_first_gradient: bool = False) -> dict:
     """Drive the reference through ``len(batches)`` Adam steps from the
-    seed. Returns each step's loss (before its update), the norm of every
+    seed (``config`` and ``mix`` are handed to every family; this one needs
+    no more than ``sizes``). Returns each step's loss (before its update), the norm of every
     leaf of the first gradient and the norm of every leaf's change over all
     the steps. ``first_gradient_of_other`` (host arrays, or a function that
     hands them over) is another run's first gradient: the norm of its
@@ -317,3 +349,54 @@ def first_steps(seed: int, sizes: dict, batches, learning_rate: float, *,
             change[name] = float(_norm(flat_new.pop(0) - flat_old.pop(0)))
     return {"losses": losses, "grad_norms": grad_norms,
             "change_norms": change, **extra}
+
+
+# ---- the counts -----------------------------------------------------------
+#
+# Operations and bytes a training step needs, from the sizes. A function of
+# the configuration's sizes and the mix's shapes, never of the
+# implementation: a product of an (m, k) by a (k, n) matrix is 2 m k n
+# operations, the backward pass twice the forward, attention is counted over
+# the causal half of the score matrix only, and nothing that is recomputed
+# (``--remat``, the flash backward, the streamed head) is counted twice.
+
+def scope_flops_per_token(sizes: dict) -> dict:
+    """``train_flops_per_token`` by the program's scope
+    (``telemetry.SCOPES``): 6 operations per matmul parameter and token (2
+    forward, 4 backward) for q, k, v and the output projection
+    (``attn_proj``), the two MLP matrices (``mlp``) and the output head
+    (``lm_head``); the token and position tables are looked up, not
+    multiplied. ``attention`` is QK^T and PV over the causal half: a token
+    attends to S/2 keys on average, 2 products of 2·d operations each,
+    times three for forward plus backward: 6·L·d·S."""
+    d, ffn, layers = sizes["d_model"], sizes["ffn_dim"], sizes["num_blocks"]
+    return {"attn_proj": 6.0 * layers * 4 * d * d,
+            "attention": 6.0 * layers * d * sizes["seq_len"],
+            "mlp": 6.0 * layers * 2 * d * ffn,
+            "lm_head": 6.0 * d * sizes["vocab_size"]}
+
+
+def train_flops_per_token(sizes: dict) -> float:
+    return sum(scope_flops_per_token(sizes).values())
+
+
+def total_params(sizes: dict) -> int:
+    d, ffn, vocab = sizes["d_model"], sizes["ffn_dim"], sizes["vocab_size"]
+    per_block = 4 * d * d + 2 * d * ffn + ffn + d + 4 * d
+    return (sizes["num_blocks"] * per_block + vocab * d
+            + sizes["seq_len"] * d + 2 * d + d * vocab + vocab)
+
+
+def adam_bytes_per_step(sizes: dict) -> int:
+    """f32 master, gradient, m and v read, master, m and v written."""
+    return 7 * 4 * total_params(sizes)
+
+
+def state_bytes(sizes: dict) -> int:
+    """f32 master, m and v resident between steps."""
+    return 3 * 4 * total_params(sizes)
+
+
+def allreduce_bytes_per_step(sizes: dict) -> int:
+    """f32 gradients of every parameter."""
+    return 4 * total_params(sizes)

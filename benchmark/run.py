@@ -166,27 +166,25 @@ def checkpoint_mismatch(logdir: str, state, seed: int) -> tuple[int, str]:
 
 
 @functools.lru_cache(maxsize=2)
-def _first_batches(seed, rows_per_shard, shards, seq_len, vocab_size, prng):
-    from benchmark.reference import opt_lm
-
-    return opt_lm.first_batches(seed, 3, rows_per_shard, shards, seq_len,
-                                vocab_size, prng)
+def _first_batches(family, seed, sizes, rows_per_shard, shards, prng):
+    return family.first_batches(seed, 3, dict(sizes), rows_per_shard, shards,
+                                prng)
 
 
 def reference_numbers(cell, seed: int, precision="f32", keep_rows=None,
                       learning_rate=None, **kw):
-    """The plain reference's first three steps for this cell and seed."""
-    from benchmark.reference import opt_lm
-
+    """The first three steps of the plain reference of the cell's family,
+    for this cell and seed."""
+    family = cell.family()
     prng = {"threefry": "threefry2x32"}.get(cell.config["trainer"]["prng"],
                                             cell.config["trainer"]["prng"])
-    batches = _first_batches(seed, cell.mix["batch_per_chip"], cell.chips,
-                             cell.mix["seq_len"], cell.config["vocab_size"],
-                             prng)
+    sizes = cell.sizes
+    batches = _first_batches(family, seed, tuple(sorted(sizes.items())),
+                             cell.mix["batch_per_chip"], cell.chips, prng)
     if learning_rate is None:
         learning_rate = cell.config["trainer"]["learning_rate"]
-    return opt_lm.first_steps(
-        seed, cell.sizes, batches, learning_rate,
+    return family.first_steps(
+        seed, sizes, batches, learning_rate, config=cell.config, mix=cell.mix,
         precision=precision, keep_rows=keep_rows, prng=prng, **kw)
 
 
@@ -227,7 +225,7 @@ def main(argv=None, *, require_tpu: bool = True, root: str = manifest.ROOT,
     log("trainer flags:", " ".join(argv_trainer))
     mnist_dist.FLAGS._parse(argv_trainer)
 
-    probe = FirstStepsProbe().install()
+    probe = FirstStepsProbe(cell.family().leaf_names).install()
     watcher = Watcher(os.path.join(logdir, "metrics.jsonl"), args.seconds,
                       cell.mix["display_step"], probe, trace_dir,
                       cell.mix.get("trace_rows", 2))
@@ -280,6 +278,14 @@ def reduce_run(cell, args, device, logdir, trace_dir, probe, watcher,
         f"{win['rows']} rows; compiles inside it: {win['compiles_in_window']}; "
         f"compile_cache_hits at open: "
         f"{(win['scalars_open'] or {}).get('compile_cache_hits')}")
+    slow = dict(win["slowest_interval"])
+    slow["host_s"] = spans.inside(span_rows, slow["start"],
+                                  slow["start"] + slow["seconds"])
+    log(f"slowest interval: steps {slow['from_step']}..{slow['to_step']} in "
+        f"{slow['seconds']:.3f} s, {slow['start'] - win['open']['time']:.1f} s "
+        f"into the window; the host spent it in "
+        + ", ".join(f"{n} {s:.3f}" for n, s in sorted(
+            slow["host_s"].items(), key=lambda kv: -kv[1])))
 
     # 1. what the drain wrote, against the state the last step left
     mismatch, looked = checkpoint_mismatch(logdir, probe.last_state,
@@ -331,7 +337,8 @@ def reduce_run(cell, args, device, logdir, trace_dir, probe, watcher,
                         "rows": win["rows"],
                         "compiles_in_window": win["compiles_in_window"],
                         "step_ms_mean": win["step_ms_mean"],
-                        "step_ms_slowest": win["step_ms_slowest"]}
+                        "step_ms_slowest": win["step_ms_slowest"],
+                        "slowest_interval": slow}
     result["checks"] = checks
     return result
 

@@ -2,8 +2,8 @@
 
     python3 benchmark/tools/calibrate.py --workload <cell> --seeds 1,2,3 [--out FILE]
 
-For every seed the plain reference runs the first three steps in float32
-(what the program is compared with), then once more in the control's
+For every seed the plain reference of the cell's family runs the first three
+steps in float32 (what the program is compared with), then once more in the control's
 precision (float8 operands in every linear layer, the step below the
 configuration's bfloat16) and once with the half-batch fault (the mean taken
 over the first half of the rows) and once with the state left unchanged

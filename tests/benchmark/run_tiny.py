@@ -1,7 +1,10 @@
 """Drives the whole of a run on the CPU at a tiny size, past the harness's
 look for a chip, optionally with the timed path broken underneath.
 
-    python tests/benchmark/run_tiny.py <root> <seed> <fault>
+    python tests/benchmark/run_tiny.py <root> <seed> <fault> [<cell>]
+
+``cell`` is ``tiny.CELL`` unless given (``tiny.SWITCH_CELL`` in a root that
+``tiny.add_switch_family`` has been at).
 
 ``fault``: ``none``; ``state_unchanged`` (the step returns its parameters as
 they were); ``half_batch`` (half of the batch left out, the mean taken over
@@ -46,8 +49,9 @@ if __name__ == "__main__":
     from benchmark import run
     from tests.benchmark import tiny
 
-    root, seed, fault = sys.argv[1], sys.argv[2], sys.argv[3]
-    sys.exit(run.main(["--workload", tiny.CELL, "--seed", seed,
+    root, seed, fault = sys.argv[1:4]
+    cell = sys.argv[4] if len(sys.argv) > 4 else tiny.CELL
+    sys.exit(run.main(["--workload", cell, "--seed", seed,
                        "--seconds", "1", "--trace", "0"],
                       require_tpu=False, root=root,
                       before_train=lambda probe: plant(fault)))

@@ -46,7 +46,7 @@ def program_first_steps(seed, compute_dtype=None, shards=1):
         data = put_device_data(split, mesh)
         fn = make_device_dp_train_step(model, opt, mesh, BATCH * shards,
                                        keep_prob=1.0, chunk=1)
-    p = probe.FirstStepsProbe()
+    p = probe.FirstStepsProbe(opt_lm.leaf_names)
     call = p.wrap(fn)
     for _ in range(4):
         state, _ = call(state, data)
@@ -55,7 +55,7 @@ def program_first_steps(seed, compute_dtype=None, shards=1):
 
 
 def reference_first_steps(seed, shards=1, **kw):
-    batches = opt_lm.first_batches(seed, 3, BATCH, shards, 64, 300)
+    batches = opt_lm.first_batches(seed, 3, SIZES, BATCH, shards)
     return opt_lm.first_steps(seed, SIZES, batches, LR, **kw)
 
 

@@ -76,6 +76,11 @@ def test_the_sound_program_is_correct_and_the_line_is_whole(runs):
     assert all(m["value"] > 0 for m in line["metrics"].values())
     assert line["device"]["platform"] == "cpu" and line["device"]["count"] == 1
     assert line["window"]["compiles_in_window"] == 0
+    # where the slowest interval lay and what the host did in it
+    slow = line["window"]["slowest_interval"]
+    assert slow["to_step"] - slow["from_step"] == 5
+    assert sum(slow["host_s"].values()) == pytest.approx(slow["seconds"], rel=0.05)
+    assert "slowest interval: steps " in stderr
     assert line["checks"]["ckpt_mismatch"]["value"] == 0
     # every number beside its limit, as the last lines of standard error
     tail = stderr.strip().splitlines()[-9:]

@@ -48,6 +48,30 @@ def test_a_stall_in_one_interval_moves_both():
     assert stalled["tokens_per_s_per_chip"] == pytest.approx(0.75 * steady["tokens_per_s_per_chip"])
     assert stalled["step_ms_slowest"] == pytest.approx(1000.0)
     assert stalled["step_ms_mean"] > steady["step_ms_mean"]
+    # and the reducer says which interval it was
+    assert stalled["slowest_interval"] == {
+        "from_step": 10, "to_step": 15, "start": pytest.approx(1062.5),
+        "seconds": pytest.approx(5.0)}
+
+
+def test_what_the_host_did_in_an_interval_by_its_top_level_spans():
+    from benchmark.harness import spans
+
+    def span(name, ts, dur, **kw):
+        return dict({"name": name, "ts": ts, "dur_s": dur, "parent": None,
+                     "thread": "MainThread"}, **kw)
+
+    recorded = [span("device_chunk", 99.0, 1.5),       # half of it inside
+                span("display_wait", 100.5, 4.0),
+                span("hbm_sample", 101.0, 0.5, parent=7),  # nested: its parent counts
+                span("ckpt_write", 100.0, 5.0, thread="ckpt-writer"),
+                span("display_eval", 104.5, 0.25),
+                span("display_log", 104.75, 1.0)]      # runs past the end
+    found = spans.inside(recorded, 100.0, 105.0)
+    assert found == {"device_chunk": pytest.approx(0.5), "display_wait": pytest.approx(4.0),
+                     "display_eval": pytest.approx(0.25), "display_log": pytest.approx(0.25),
+                     "no_span": pytest.approx(0.0)}
+    assert spans.inside(recorded[:1], 100.0, 105.0)["no_span"] == pytest.approx(4.5)
 
 
 def test_per_chip_rate_divides_by_the_chips():
