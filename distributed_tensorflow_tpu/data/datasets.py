@@ -249,19 +249,23 @@ def read_data_sets(
     validation_size: int = 0,
     seq_len: int = 256,
     vocab_size: int = 64,
+    reserved_ids: int = 0,
 ) -> Datasets:
     """API parity with the tutorial loader the reference imports
     (``MNISTDist.py:11,167``), extended with ``dataset`` selection:
     "mnist" | "fashion_mnist" (same IDX format) | "cifar10" | "lm"
     (procedural associative-recall token sequences for the causal-LM
-    family; ``seq_len``/``vocab_size`` apply only there).
+    family; ``seq_len``/``vocab_size`` apply only there, and
+    ``reserved_ids``: the vocabulary's last ids the tokens leave out).
     Falls back to procedural data when files are absent (offline envs)."""
     dataset = dataset.lower().replace("-", "_")
     if dataset == "lm":
         from distributed_tensorflow_tpu.data.lm import LMDataSet
 
-        train = LMDataSet(LM_TRAIN, seq_len, vocab_size, seed=seed)
-        test = LMDataSet(LM_TEST, seq_len, vocab_size, seed=seed + 10_000)
+        train = LMDataSet(LM_TRAIN, seq_len, vocab_size, seed=seed,
+                          reserved_ids=reserved_ids)
+        test = LMDataSet(LM_TEST, seq_len, vocab_size, seed=seed + 10_000,
+                         reserved_ids=reserved_ids)
         val = None
         if validation_size:
             # generated independently (own seed space), not carved from a
@@ -270,7 +274,7 @@ def read_data_sets(
                 raise ValueError(
                     f"validation_size={validation_size} must be >= 0")
             val = LMDataSet(validation_size, seq_len, vocab_size,
-                            seed=seed + 20_000)
+                            seed=seed + 20_000, reserved_ids=reserved_ids)
         return Datasets(
             train=train, test=test, validation=val, source="synthetic",
             meta={"kind": "lm", "seq_len": seq_len,

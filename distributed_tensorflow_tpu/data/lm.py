@@ -67,11 +67,17 @@ class LMDataSet:
     the shared ``evaluate`` path (the names are the tutorial API's)."""
 
     def __init__(self, n: int, seq_len: int, vocab_size: int = 64,
-                 seed: int = 0):
+                 seed: int = 0, reserved_ids: int = 0):
+        """``reserved_ids``: the vocabulary's last ids are kept out of the
+        data (the masked-diffusion objective's mask id is one: a token the
+        data could hold would be taken for a masked position)."""
         if vocab_size < 2 or vocab_size > 65535:
             raise ValueError(f"vocab_size={vocab_size} not in [2, 65535]")
+        if not 0 <= reserved_ids <= vocab_size - 2:
+            raise ValueError(f"reserved_ids={reserved_ids} leaves no two "
+                             f"ids of {vocab_size}")
         rng = np.random.default_rng(seed)
-        toks = _gen_sequences(n, seq_len, vocab_size, rng)
+        toks = _gen_sequences(n, seq_len, vocab_size - reserved_ids, rng)
         store = np.uint8 if vocab_size <= 256 else np.uint16
         self._tokens = toks.astype(store)
         self.seq_len = seq_len

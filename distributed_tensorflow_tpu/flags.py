@@ -353,6 +353,53 @@ def define_reference_flags():
                  "residual stream — Switch semantics)")
     DEFINE_float("moe_aux", 0.01, "Load-balance auxiliary loss "
                  "coefficient for --moe_experts")
+    DEFINE_integer("moe_top_k", 0, "If > 0, the --moe_experts layer is "
+                   "the DROPLESS routed one (ops/moe.py:routed_experts): "
+                   "this many experts a token from a softmax over all, "
+                   "weights renormalised, rows sorted by expert into a "
+                   "buffer of --moe_capacity times the expected rows and "
+                   "run through grouped products; no auxiliary loss. "
+                   "Needs --mlp_gated. 0 = the top-1 Switch layer")
+    DEFINE_integer("moe_ffn_dim", 0, "Width of a routed expert "
+                   "(--moe_top_k); 0 = 4 x --d_model")
+    DEFINE_integer("moe_first_expert", 0, "First of the experts this "
+                   "job HOLDS among the --moe_experts the router chooses "
+                   "between (--moe_top_k): the chip's share of an "
+                   "expert-parallel deployment; the others add nothing")
+    DEFINE_integer("moe_held_experts", 0, "How many experts are held, "
+                   "from --moe_first_expert on (0 = all of them)")
+    DEFINE_string("norm", "layernorm", "The LM's normalisation: layernorm "
+                  "or rmsnorm (no bias leaf)")
+    DEFINE_float("norm_eps", 1e-5, "Epsilon of --norm (and of --qk_norm)")
+    DEFINE_float("rope_theta", 0.0, "If > 0, rotary positions of this "
+                 "base on q and k (rotate-half form) in place of the "
+                 "learned position table")
+    DEFINE_integer("num_kv_heads", 0, "Key/value heads under --num_heads "
+                   "query heads (grouped-query attention); must divide "
+                   "--num_heads. 0 = as many as query heads")
+    DEFINE_integer("head_dim", 0, "Width of an attention head; 0 = "
+                   "--d_model / --num_heads")
+    DEFINE_boolean("qk_norm", False, "RMSNorm over the head width on q "
+                   "and k, before the rotary positions")
+    DEFINE_boolean("mlp_gated", False, "Gated feed-forward: silu(gate) * "
+                   "up in place of ReLU (the dense MLP and the routed "
+                   "experts alike)")
+    DEFINE_boolean("biases", True, "Biases on the feed-forward and the "
+                   "output head (the attention projections have none)")
+    DEFINE_string("objective", "next_token", "The LM's training "
+                  "objective: next_token (causal, shifted targets) or "
+                  "masked_diffusion (diffusion over blocks of "
+                  "--diffusion_block tokens: the step masks each block's "
+                  "positions with probability t ~ U(--diffusion_t_min, 1) "
+                  "drawn under its own key, the network sees [noised ; "
+                  "clean] under the block-diffusion mask, masked positions "
+                  "predict their own token weighted 1/t; the mask id is "
+                  "--vocab_size - 1, which the data leaves out). Needs "
+                  "--device_data")
+    DEFINE_integer("diffusion_block", 4, "Block length of --objective "
+                   "masked_diffusion; must divide --seq_len")
+    DEFINE_float("diffusion_t_min", 1e-3, "Smallest masking probability "
+                 "of --objective masked_diffusion, in (0, 1)")
     DEFINE_boolean("expert_parallel", False, "Shard the MoE experts "
                    "--model_axis ways over the mesh's 'model' axis "
                    "(expert parallelism: every device routes "
@@ -584,6 +631,7 @@ def define_reference_flags():
                    "telemetry is on")
     FLAGS._register_validator(_validate_core_flags)
     FLAGS._register_validator(_validate_model_data_flags)
+    FLAGS._register_validator(_validate_lm_arch_flags)
     FLAGS._register_validator(_validate_pairing_flags)
     FLAGS._register_validator(_validate_pipeline_flags)
     FLAGS._register_validator(_validate_elastic_flags)
@@ -884,6 +932,89 @@ def _validate_model_data_flags(values: dict):
              "must be > 0 (a per-expert capacity factor)")
     _require(values, "moe_aux", lambda v: float(v) >= 0,
              "must be >= 0 (the load-balance coefficient)")
+
+
+def _validate_lm_arch_flags(values: dict):
+    """The LM's further choices: each flag's own range, then the pairs
+    that would be silently inert or cannot be built."""
+    _require(values, "moe_top_k", lambda v: int(v) >= 0,
+             "must be >= 0 (0 = the top-1 Switch layer)")
+    _require(values, "moe_ffn_dim", lambda v: int(v) >= 0,
+             "must be >= 0 (0 = 4 x d_model)")
+    _require(values, "moe_first_expert", lambda v: int(v) >= 0,
+             "must be >= 0 (an index among --moe_experts)")
+    _require(values, "moe_held_experts", lambda v: int(v) >= 0,
+             "must be >= 0 (0 = every expert)")
+    _require(values, "norm", lambda v: v in ("layernorm", "rmsnorm"),
+             "must be layernorm or rmsnorm")
+    _require(values, "norm_eps", lambda v: float(v) > 0, "must be > 0")
+    _require(values, "rope_theta", lambda v: float(v) >= 0,
+             "must be >= 0 (0 = learned positions)")
+    _require(values, "num_kv_heads", lambda v: int(v) >= 0,
+             "must be >= 0 (0 = as many as query heads)")
+    _require(values, "head_dim", lambda v: int(v) >= 0 and int(v) % 2 == 0,
+             "must be even and >= 0 (0 = d_model / num_heads)")
+    _require(values, "qk_norm", lambda v: isinstance(v, bool),
+             "must be a boolean")
+    _require(values, "mlp_gated", lambda v: isinstance(v, bool),
+             "must be a boolean")
+    _require(values, "biases", lambda v: isinstance(v, bool),
+             "must be a boolean")
+    _require(values, "objective",
+             lambda v: v in ("next_token", "masked_diffusion"),
+             "must be next_token or masked_diffusion")
+    _require(values, "diffusion_block", lambda v: int(v) >= 1,
+             "must be >= 1")
+    _require(values, "diffusion_t_min", lambda v: 0 < float(v) < 1,
+             "must lie in (0, 1)")
+    heads, kv = values.get("num_heads"), values.get("num_kv_heads")
+    if heads and kv and int(heads) % int(kv):
+        raise ValueError(f"--num_kv_heads={kv} must divide --num_heads="
+                         f"{heads} (a group of query heads reads one "
+                         f"key/value head)")
+    top_k, experts = values.get("moe_top_k"), values.get("moe_experts")
+    if top_k:
+        held = int(values.get("moe_held_experts") or 0) or int(experts or 0)
+        first = int(values.get("moe_first_expert") or 0)
+        if not experts or int(top_k) > int(experts):
+            raise ValueError(f"--moe_top_k={top_k} needs --moe_experts >= "
+                             f"{top_k} to choose among")
+        if first + held > int(experts):
+            raise ValueError(
+                f"--moe_first_expert={first} + --moe_held_experts={held} "
+                f"reach past the router's --moe_experts={experts}")
+        if not values.get("mlp_gated"):
+            raise ValueError("--moe_top_k's experts are gated (silu(gate) "
+                             "* up): add --mlp_gated")
+        if values.get("expert_parallel"):
+            raise ValueError(
+                "--moe_top_k's layer is told which experts it holds "
+                "(--moe_first_expert, --moe_held_experts); "
+                "--expert_parallel shards the Switch layer over a mesh "
+                "axis — drop one")
+    elif any(values.get(n) for n in ("moe_ffn_dim", "moe_first_expert",
+                                     "moe_held_experts")):
+        raise ValueError("--moe_ffn_dim, --moe_first_expert and "
+                         "--moe_held_experts shape the routed layer: "
+                         "without --moe_top_k they would silently change "
+                         "nothing")
+    if values.get("objective") == "masked_diffusion":
+        seq, blk = values.get("seq_len"), values.get("diffusion_block")
+        if seq and blk and int(seq) % int(blk):
+            raise ValueError(f"--diffusion_block={blk} must divide "
+                             f"--seq_len={seq}")
+        if values.get("device_data") is False or values.get("dataset") \
+                not in (None, "lm"):
+            raise ValueError(
+                "--objective masked_diffusion draws its noise inside the "
+                "compiled step of --device_data on --dataset lm; the "
+                "host-fed steps have no key for it")
+        for other in ("seq_parallel", "pipeline", "zero", "expert_parallel"):
+            if values.get(other):
+                raise ValueError(
+                    f"--objective masked_diffusion runs in the local and "
+                    f"sync data-parallel device steps; --{other} builds "
+                    f"another step that draws no noise")
 
 
 def _validate_pairing_flags(values: dict):

@@ -21,7 +21,7 @@ parallel/sequence_parallel.py for the derivation).
 
 from __future__ import annotations
 
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +31,7 @@ from distributed_tensorflow_tpu.models.cnn import truncated_normal_init
 from distributed_tensorflow_tpu.models.registry import register_model
 from distributed_tensorflow_tpu.ops import nn
 from distributed_tensorflow_tpu.ops.attention import (
+    Mask,
     blockwise_attention,
     multi_head_attention,
     ring_attention,
@@ -46,70 +47,192 @@ def _layernorm(x, gain, bias, eps=1e-5):
     return (y * gain + bias).astype(x.dtype)
 
 
-def _attn_half_params(w, d, h, dh, dtype):
+class BlockArch(NamedTuple):
+    """The choices of a block beyond the first form's (LayerNorm, learned
+    positions added at the embedding, one head count, a ReLU MLP with
+    biases): read by the two halves and their parameter builders, so that
+    every caller of those (training, ``serving/decode.py``) takes the
+    same choices from one place. ``None`` stands for the first form."""
+
+    norm: str = "layernorm"     # or "rmsnorm" (no bias leaf)
+    norm_eps: float = 1e-5
+    rope_theta: float = 0.0     # > 0: rotary positions on q and k
+    kv_heads: int = 0           # 0: as many as query heads
+    qk_norm: bool = False       # RMSNorm over the head width on q and k
+    gated: bool = False         # feed-forward silu(gate) * up
+    biases: bool = True         # on the feed-forward
+
+
+def _rmsnorm(x, gain, eps):
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (y * gain).astype(x.dtype)
+
+
+def _norm(x, params, prefix, arch):
+    """The normalisation whose leaves are ``params[prefix + "g"]`` (and
+    ``"b"``), by ``arch``."""
+    if arch is None:
+        return _layernorm(x, params[prefix + "g"], params[prefix + "b"])
+    if arch.norm == "layernorm":
+        return _layernorm(x, params[prefix + "g"], params[prefix + "b"],
+                          arch.norm_eps)
+    return _rmsnorm(x, params[prefix + "g"], arch.norm_eps)
+
+
+def _norm_params(prefix, d, dtype, arch):
+    out = {prefix + "g": jnp.ones((d,), dtype)}
+    if arch is None or arch.norm == "layernorm":
+        out[prefix + "b"] = jnp.zeros((d,), dtype)
+    return out
+
+
+def rope(x, pos, theta):
+    """Rotary positions in the rotate-half form: x (B, S, H, Dh), pos (S,)
+    position ids. f32 inside, x's dtype out."""
+    dh = x.shape[-1]
+    inv = theta ** (-jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
+    ang = pos.astype(jnp.float32)[:, None] * inv[None, :]       # (S, Dh/2)
+    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], -1)[None, :, None]
+    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], -1)[None, :, None]
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., : dh // 2], xf[..., dh // 2:]
+    return (xf * cos + jnp.concatenate([-x2, x1], -1) * sin).astype(x.dtype)
+
+
+def _attn_half_params(w, d, h, dh, dtype, arch=None):
     """The attention half's parameters — ONE constructor for the dense
     and MoE block forms (like _attn_half on the compute side), so the
-    layouts cannot diverge."""
-    return {
-        "ln1_g": jnp.ones((d,), dtype),
-        "ln1_b": jnp.zeros((d,), dtype),
-        "qkv": w((d, 3, h, dh)),
-        "proj": w((h * dh, d)),
-        "ln2_g": jnp.ones((d,), dtype),
-        "ln2_b": jnp.zeros((d,), dtype),
-    }
+    layouts cannot diverge. Fewer key/value heads than query heads split
+    ``qkv`` into ``q`` and ``kv``; ``qk_norm`` adds a gain a head width."""
+    kv = h if arch is None or not arch.kv_heads else arch.kv_heads
+    out = _norm_params("ln1_", d, dtype, arch)
+    if kv == h:
+        out["qkv"] = w((d, 3, h, dh))
+    else:
+        out["q"] = w((d, h, dh))
+        out["kv"] = w((d, 2, kv, dh))
+    if arch is not None and arch.qk_norm:
+        out["q_norm_g"] = jnp.ones((dh,), dtype)
+        out["k_norm_g"] = jnp.ones((dh,), dtype)
+    out["proj"] = w((h * dh, d))
+    out.update(_norm_params("ln2_", d, dtype, arch))
+    return out
 
 
-def _block_params(w, d, h, dh, mlp_dim, dtype):
+def _mlp_params(w, d, mlp_dim, dtype, arch=None):
+    gated = arch is not None and arch.gated
+    shapes = {"mlp_in": (d, (2 if gated else 1) * mlp_dim),
+              "mlp_out": (mlp_dim, d)}
+    out = {}
+    for name, shape in shapes.items():
+        out[name] = {"w": w(shape)}
+        if arch is None or arch.biases:
+            out[name]["b"] = jnp.zeros((shape[1],), dtype)
+    return out
+
+
+def _block_params(w, d, h, dh, mlp_dim, dtype, arch=None):
     """One pre-LN block's parameter dict (shared by both transformer
     families so their checkpoints stay structurally interchangeable)."""
     return {
-        **_attn_half_params(w, d, h, dh, dtype),
-        "mlp_in": {"w": w((d, mlp_dim)), "b": jnp.zeros((mlp_dim,), dtype)},
-        "mlp_out": {"w": w((mlp_dim, d)), "b": jnp.zeros((d,), dtype)},
+        **_attn_half_params(w, d, h, dh, dtype, arch),
+        **_mlp_params(w, d, mlp_dim, dtype, arch),
     }
 
 
-def _transformer_block(h, blk, attn_fn, cd):
+def _transformer_block(h, blk, attn_fn, cd, arch=None, pos=None):
     """One pre-LN transformer block: LN -> attention -> residual ->
     LN -> MLP -> residual. ``attn_fn(q, k, v)`` supplies the attention
     flavor (dense / blockwise / ring, causal or not) so the block is the
     ONE implementation both model families and every parallelism mode
     run."""
-    return _mlp_half(_attn_half(h, blk, attn_fn, cd), blk, cd)
+    return _mlp_half(_attn_half(h, blk, attn_fn, cd, arch, pos), blk, cd,
+                     arch)
 
 
 @scoped("mlp")
-def _mlp_half(h, blk, cd):
+def _mlp_half(h, blk, cd, arch=None):
     """LN -> relu MLP -> residual — the dense block's second half,
     shared with serving/decode.py's incremental step so the two code
     paths cannot diverge (the KV-cache bitwise-parity contract rides on
-    this being the one implementation)."""
-    y = _layernorm(h, blk["ln2_g"], blk["ln2_b"])
-    y = jax.nn.relu(nn.dense(y, blk["mlp_in"]["w"], blk["mlp_in"]["b"],
-                             compute_dtype=cd))
-    return h + nn.dense(y, blk["mlp_out"]["w"], blk["mlp_out"]["b"],
+    this being the one implementation). ``arch.gated``: silu(gate) * up
+    from one (d, 2 m) matrix, the gate's columns first."""
+    y = _norm(h, blk, "ln2_", arch)
+    y = nn.dense(y, blk["mlp_in"]["w"], blk["mlp_in"].get("b"),
+                 compute_dtype=cd)
+    if arch is not None and arch.gated:
+        m = y.shape[-1] // 2
+        y = jax.nn.silu(y[..., :m]) * y[..., m:]
+    else:
+        y = jax.nn.relu(y)
+    return h + nn.dense(y, blk["mlp_out"]["w"], blk["mlp_out"].get("b"),
                         compute_dtype=cd)
 
 
-def _attn_half(h, blk, attn_fn, cd):
+def _attn_half(h, blk, attn_fn, cd, arch=None, pos=None):
     """LN -> attention -> residual (shared by the dense-MLP and MoE
     block forms)."""
-    return _attn_half_kv(h, blk, attn_fn, cd)[0]
+    return _attn_half_kv(h, blk, attn_fn, cd, arch, pos)[0]
 
 
 @scoped("attn_proj")
-def _attn_half_kv(h, blk, attn_fn, cd):
+def _attn_half_kv(h, blk, attn_fn, cd, arch=None, pos=None):
     """``_attn_half`` that also hands back this block's (k, v) — the
     serving prefill captures them into the decode cache, computed by the
     SAME projection the training forward runs (returns
-    ``(h_out, k, v)``; k/v are (B, S, H, Dh) in the attention input
-    dtype)."""
-    y = _layernorm(h, blk["ln1_g"], blk["ln1_b"])
-    qkv = jnp.einsum("bsd,dthe->tbshe", y, blk["qkv"].astype(y.dtype))
-    a = attn_fn(qkv[0], qkv[1], qkv[2])
+    ``(h_out, k, v)``; k/v are (B, S, Hkv, Dh) in the attention input
+    dtype, after the q/k norm and the rotary positions where ``arch``
+    has them; ``pos``: the rows' position ids, 0..S-1 if None)."""
+    y = _norm(h, blk, "ln1_", arch)
+    if "qkv" in blk:
+        qkv = jnp.einsum("bsd,dthe->tbshe", y, blk["qkv"].astype(y.dtype))
+        q, k, v = qkv[0], qkv[1], qkv[2]
+    else:
+        q = jnp.einsum("bsd,dhe->bshe", y, blk["q"].astype(y.dtype))
+        kv = jnp.einsum("bsd,dthe->tbshe", y, blk["kv"].astype(y.dtype))
+        k, v = kv[0], kv[1]
+    if arch is not None and arch.qk_norm:
+        q = _rmsnorm(q, blk["q_norm_g"], arch.norm_eps)
+        k = _rmsnorm(k, blk["k_norm_g"], arch.norm_eps)
+    if arch is not None and arch.rope_theta:
+        if pos is None:
+            pos = jnp.arange(q.shape[1])
+        q, k = rope(q, pos, arch.rope_theta), rope(k, pos, arch.rope_theta)
+    a = attn_fn(q, k, v)
     a = a.reshape(*a.shape[:2], -1)  # (B, S, H*Dh)
-    return h + nn.dense(a, blk["proj"], compute_dtype=cd), qkv[1], qkv[2]
+    return h + nn.dense(a, blk["proj"], compute_dtype=cd), k, v
+
+
+def _routed_block_params(w, d, h, dh, ffn_dim, num_experts, held, dtype,
+                         arch):
+    """Routed block: the attention half of ``_block_params``; the
+    feed-forward is ``held`` gated experts of width ``ffn_dim`` behind a
+    router over ``num_experts`` (``ops/moe.py:routed_experts``)."""
+    return {
+        **_attn_half_params(w, d, h, dh, dtype, arch),
+        "moe": {
+            "router": w((d, num_experts)),
+            "w1": w((held, d, 2 * ffn_dim)),
+            "w2": w((held, ffn_dim, d)),
+        },
+    }
+
+
+def _transformer_block_routed(h, blk, attn_fn, cd, arch, pos, top_k,
+                              first_expert, capacity_factor):
+    """Routed block form: returns (h, the layer's routing counters)."""
+    from distributed_tensorflow_tpu.ops.moe import routed_experts
+
+    h = _attn_half(h, blk, attn_fn, cd, arch, pos)
+    with scope("moe_router"):
+        y = _norm(h, blk, "ln2_", arch)
+    y, aux = routed_experts(y, blk["moe"], top_k=top_k,
+                            first_expert=first_expert,
+                            capacity_factor=capacity_factor,
+                            compute_dtype=cd)
+    with scope("moe_router"):
+        return h + y, aux
 
 
 def _moe_block_params(w, d, h, dh, mlp_dim, num_experts, dtype):
@@ -291,6 +414,28 @@ class TransformerLM:
     (the flash VJPs removed the O(S^2) one). ``apply`` still exists
     and still returns full logits (generation/inspection); training
     simply never calls it when ``ce_block`` is set.
+
+    Further choices, each off by default (the tree and the program are
+    then the first form's): ``norm`` / ``norm_eps`` (RMSNorm has no bias
+    leaf), ``rope_theta`` (rotary positions, no ``pos`` table),
+    ``num_kv_heads`` under ``num_heads`` query heads of ``head_dim``,
+    ``qk_norm``, ``mlp_gated``, ``biases`` (off: none on the feed-forward
+    nor the head); ``moe_top_k`` > 0 makes the feed-forward the DROPLESS
+    routed layer (``ops/moe.py:routed_experts``: ``moe_experts`` the
+    router's width, ``moe_top_k`` experts a token, ``moe_ffn_dim`` their
+    width, of which this model HOLDS ``moe_held_experts`` from
+    ``moe_first_expert`` on; ``moe_capacity`` sizes its sorted buffer,
+    which is what a step costs; an overflow makes the loss NaN).
+
+    ``objective="masked_diffusion"`` trains by diffusion over blocks of
+    ``diffusion_block`` tokens: ``noise_batch`` (called under the step's
+    key, and under a key folded from ``noise_seed`` by the eval) masks
+    each position of a block with that block's probability t ~
+    U(``diffusion_t_min``, 1) (the mask id is the vocabulary's last, which
+    the data leaves out), the network sees ``[noised ; clean]`` (2 S rows,
+    positions 0..S-1 twice, the block-diffusion ``Mask``), and the loss is
+    the cross-entropy of the masked positions' own tokens, weighted 1/t,
+    over B S.
     """
 
     stateful = False
@@ -312,10 +457,40 @@ class TransformerLM:
         moe_capacity: float = 1.25,
         moe_aux: float = 0.01,
         moe_axis: str | None = None,
+        norm: str = "layernorm",
+        norm_eps: float = 1e-5,
+        rope_theta: float = 0.0,
+        num_kv_heads: int = 0,
+        head_dim: int = 0,
+        qk_norm: bool = False,
+        mlp_gated: bool = False,
+        biases: bool = True,
+        moe_top_k: int = 0,
+        moe_ffn_dim: int = 0,
+        moe_first_expert: int = 0,
+        moe_held_experts: int = 0,
+        objective: str = "next_token",
+        diffusion_block: int = 4,
+        diffusion_t_min: float = 1e-3,
+        noise_seed: int = 0,
         **_unused,
     ):
-        if d_model % num_heads:
+        if d_model % num_heads and not head_dim:
             raise ValueError(f"d_model={d_model} % num_heads={num_heads} != 0")
+        if norm not in ("layernorm", "rmsnorm"):
+            raise ValueError(f"norm={norm!r} is neither layernorm nor rmsnorm")
+        if objective not in ("next_token", "masked_diffusion"):
+            raise ValueError(f"objective={objective!r} is neither next_token "
+                             f"nor masked_diffusion")
+        if num_kv_heads and num_heads % num_kv_heads:
+            raise ValueError(f"num_heads={num_heads} must divide over "
+                             f"num_kv_heads={num_kv_heads}")
+        if moe_top_k and not moe_experts:
+            raise ValueError("moe_top_k needs moe_experts > 0")
+        if objective == "masked_diffusion" and (
+                seq_axis is not None or seq_len % diffusion_block):
+            raise ValueError("masked diffusion needs blocks that divide "
+                             "seq_len and no sequence parallelism")
         if seq_axis is not None and attn_block is not None:
             raise ValueError("seq_axis (ring) and attn_block (local "
                              "blockwise) are mutually exclusive attention "
@@ -341,33 +516,82 @@ class TransformerLM:
         self.moe_capacity = float(moe_capacity)
         self.moe_aux = float(moe_aux)
         self.moe_axis = moe_axis
+        self.head_dim = int(head_dim) or d_model // num_heads
+        self.moe_top_k = int(moe_top_k)
+        self.moe_ffn_dim = int(moe_ffn_dim) or self.mlp_dim
+        self.moe_first_expert = int(moe_first_expert)
+        self.moe_held_experts = int(moe_held_experts) or self.moe_experts
+        self.objective = objective
+        self.diffusion_block = int(diffusion_block)
+        self.diffusion_t_min = float(diffusion_t_min)
+        self.noise_seed = int(noise_seed)
+        arch = BlockArch(norm, float(norm_eps), float(rope_theta),
+                         int(num_kv_heads), bool(qk_norm), bool(mlp_gated),
+                         bool(biases))
+        # None is the first form: its callers, its tree and its program
+        self.arch = None if arch == BlockArch() else arch
+        if self.moe_top_k and (moe_axis is not None or not (
+                0 <= self.moe_first_expert
+                <= self.moe_experts - self.moe_held_experts)):
+            raise ValueError(
+                f"the routed layer holds experts {self.moe_first_expert}.."
+                f"{self.moe_first_expert + self.moe_held_experts - 1} of "
+                f"{self.moe_experts} by its configuration, not by a mesh "
+                f"axis")
+        if objective == "masked_diffusion":
+            # found by the steps and the eval (getattr): absent otherwise
+            self.noise_batch = self._noise_batch
+
+    @property
+    def mask_token(self) -> int:
+        """The id that stands for a masked position: the vocabulary's
+        last, which the data never draws (data/lm.py)."""
+        return self.vocab_size - 1
 
     def init(self, key, dtype=jnp.float32):
-        d, h = self.d_model, self.num_heads
-        dh = d // h
+        d, h, dh = self.d_model, self.num_heads, self.head_dim
+        arch = self.arch
         keys = iter(jax.random.split(key, 4 + 8 * self.num_blocks))
 
         def w(shape, stddev=0.02):
             return truncated_normal_init(next(keys), shape, stddev, dtype)
 
-        params = {
-            "tok": w((self.vocab_size, d)),
-            "pos": w((self.seq_len, d)),
-            "blocks": [],
-            "ln_f": {"g": jnp.ones((d,), dtype), "b": jnp.zeros((d,), dtype)},
-            "head": {
-                "w": w((d, self.vocab_size)),
-                "b": jnp.zeros((self.vocab_size,), dtype),
-            },
-        }
+        params = {"tok": w((self.vocab_size, d))}
+        if arch is None or not arch.rope_theta:
+            params["pos"] = w((self.seq_len, d))
+        params["blocks"] = []
+        params["ln_f"] = _norm_params("", d, dtype, arch)
+        params["head"] = {"w": w((d, self.vocab_size))}
+        if arch is None or arch.biases:
+            params["head"]["b"] = jnp.zeros((self.vocab_size,), dtype)
         for _ in range(self.num_blocks):
-            if self.moe_experts:
+            if self.moe_top_k:
+                params["blocks"].append(_routed_block_params(
+                    w, d, h, dh, self.moe_ffn_dim, self.moe_experts,
+                    self.moe_held_experts, dtype, arch))
+            elif self.moe_experts:
                 params["blocks"].append(_moe_block_params(
                     w, d, h, dh, self.mlp_dim, self.moe_experts, dtype))
             else:
                 params["blocks"].append(
-                    _block_params(w, d, h, dh, self.mlp_dim, dtype))
+                    _block_params(w, d, h, dh, self.mlp_dim, dtype, arch))
         return params
+
+    def _noise_batch(self, batch, key):
+        """(tokens (B, S), _) -> ([noised ; clean] (B, 2 S) int32, the
+        loss weights (B, S) f32: 1/t at a masked position, else 0). A block
+        of ``diffusion_block`` positions shares t ~ U(t_min, 1); a
+        position is masked where its own u ~ U(0, 1) falls under it."""
+        x0 = batch[0].astype(jnp.int32)
+        b, s = x0.shape
+        k_t, k_u = jax.random.split(key)
+        t = jax.random.uniform(k_t, (b, s // self.diffusion_block),
+                               jnp.float32, self.diffusion_t_min, 1.0)
+        t = jnp.repeat(t, self.diffusion_block, axis=1)
+        masked = jax.random.uniform(k_u, (b, s), jnp.float32) < t
+        noised = jnp.where(masked, self.mask_token, x0)
+        return (jnp.concatenate([noised, x0], axis=1),
+                jnp.where(masked, 1.0 / t, 0.0))
 
     def apply_hidden(self, params, x, *, keep_prob=1.0, rng=None,
                      train: bool = False):
@@ -386,6 +610,12 @@ class TransformerLM:
         if self.seq_axis is not None:
             return lambda q, k, v: ring_attention(
                 q, k, v, self.seq_axis, causal=True)
+        if self.objective == "masked_diffusion":
+            mask = Mask("block_diffusion", self.seq_len, self.diffusion_block)
+            if self.attn_block is not None:
+                return lambda q, k, v: blockwise_attention(
+                    q, k, v, self.attn_block, mask=mask)
+            return lambda q, k, v: multi_head_attention(q, k, v, mask=mask)
         if self.attn_block is not None:
             return lambda q, k, v: blockwise_attention(
                 q, k, v, self.attn_block, causal=True)
@@ -395,25 +625,58 @@ class TransformerLM:
                         train: bool = False):
         """(hidden, moe load-balance loss total) — the aux term is 0.0
         for dense-MLP models; loss_with_metrics adds it to the training
-        loss scaled by ``moe_aux``."""
+        loss scaled by ``moe_aux``. With the routed layer the second is
+        the routing counters of the layers (a dict); under masked
+        diffusion x is ``[noised ; clean]`` and the hidden states are the
+        noised half's."""
         cd = self.compute_dtype
+        arch = self.arch
+        diffusion = self.objective == "masked_diffusion"
         # x: integer ids (B, S) — or the LOCAL token block (B, S/P) when
         # called inside the SP shard_map step
         with scope("embed"):
             h = jnp.take(params["tok"], x, axis=0)
-            pos = params["pos"]
-            if self.seq_axis is not None:
-                s_local = x.shape[1]
-                start = lax.axis_index(self.seq_axis) * s_local
-                pos = lax.dynamic_slice_in_dim(pos, start, s_local, axis=0)
-            h = h + pos.astype(h.dtype)
+            if "pos" in params:
+                pos = params["pos"]
+                if self.seq_axis is not None:
+                    s_local = x.shape[1]
+                    start = lax.axis_index(self.seq_axis) * s_local
+                    pos = lax.dynamic_slice_in_dim(pos, start, s_local,
+                                                   axis=0)
+                elif diffusion:
+                    pos = jnp.concatenate([pos, pos])
+                h = h + pos.astype(h.dtype)
             if cd is not None:
                 h = h.astype(cd)
+            # the rows' position ids, where a block wants them (rotary):
+            # both halves of the doubled sequence count from 0
+            ids = None
+            if diffusion:
+                ids = jnp.tile(jnp.arange(self.seq_len), 2)
 
         attn = self.attention_fn()
 
         lb_total = jnp.float32(0.0)
-        if self.moe_experts:
+        if self.moe_top_k:
+            routed = _transformer_block_routed
+            if self.remat:
+                routed = jax.checkpoint(routed,
+                                        static_argnums=(2, 3, 4, 6, 7, 8))
+            layers = []
+            for blk in params["blocks"]:
+                h, aux = routed(h, blk, attn, cd, arch, ids, self.moe_top_k,
+                                self.moe_first_expert, self.moe_capacity)
+                layers.append(aux)
+            by = {k: jnp.stack([a[k] for a in layers]) for k in layers[0]}
+            # the fullest expert and buffer of any layer, the layers' means
+            lb_total = {
+                "rows_per_expert_max": by["rows_per_expert_max"].max(),
+                "rows_per_expert_mean": by["rows_per_expert_mean"].mean(),
+                "overflow_rows": by["overflow_rows"].sum(),
+                "buffer_fill_max": by["buffer_fill"].max(),
+                "unrouted_frac": by["unrouted_frac"].mean(),
+            }
+        elif self.moe_experts:
             moe_fn = _transformer_block_moe
             if self.remat:
                 moe_fn = jax.checkpoint(_transformer_block_moe,
@@ -426,12 +689,14 @@ class TransformerLM:
             blk_fn = _transformer_block
             if self.remat:
                 blk_fn = jax.checkpoint(_transformer_block,
-                                        static_argnums=(2, 3))
+                                        static_argnums=(2, 3, 4))
             for blk in params["blocks"]:
-                h = blk_fn(h, blk, attn, cd)
+                h = blk_fn(h, blk, attn, cd, arch, ids)
 
         with scope("lm_head"):
-            h = _layernorm(h, params["ln_f"]["g"], params["ln_f"]["b"])
+            if diffusion:
+                h = h[:, : self.seq_len]  # the noised half alone is scored
+            h = _norm(h, params["ln_f"], "", arch)
             if rng is not None and self.seq_axis is not None:
                 # per-token dropout: decorrelate the mask across sequence
                 # shards (each shard holds DIFFERENT tokens — unlike the
@@ -445,7 +710,8 @@ class TransformerLM:
         h = self.apply_hidden(params, x, keep_prob=keep_prob, rng=rng,
                               train=train)
         with scope("lm_head"):
-            logits = nn.dense(h, params["head"]["w"], params["head"]["b"],
+            logits = nn.dense(h, params["head"]["w"],
+                              params["head"].get("b"),
                               compute_dtype=self.compute_dtype)
             return logits.astype(jnp.float32)
 
@@ -453,8 +719,10 @@ class TransformerLM:
     def wants_loss_hook(self) -> bool:
         """True when training/eval must route through
         ``loss_with_metrics`` (training.loss_and_metrics checks this):
-        the streamed CE head and/or the MoE auxiliary loss."""
-        return bool(self.ce_block or self.moe_experts)
+        the streamed CE head, the MoE auxiliary loss or counters, the
+        masked-diffusion loss."""
+        return bool(self.ce_block or self.moe_experts
+                    or self.objective == "masked_diffusion")
 
     def loss_with_metrics(self, params, x, y, *, keep_prob=1.0, rng=None,
                           train: bool = False):
@@ -466,21 +734,46 @@ class TransformerLM:
         loss stays the plain CE)."""
         h, lb = self._hidden_and_aux(params, x, keep_prob=keep_prob,
                                      rng=rng, train=train)
+        diffusion = self.objective == "masked_diffusion"
+        weights = denominator = None
+        if diffusion:
+            # x is [noised ; clean] and y the weights of ``noise_batch``: a
+            # masked position predicts its own token, no shift
+            weights, y = y, x[:, self.seq_len:]
+            denominator = float(y.shape[0] * y.shape[1])
         if self.ce_block:
             ce, acc = nn.streamed_softmax_ce_head(
-                h, params["head"]["w"], params["head"]["b"], y,
-                block=self.ce_block, compute_dtype=self.compute_dtype)
+                h, params["head"]["w"], params["head"].get("b"), y,
+                block=self.ce_block, compute_dtype=self.compute_dtype,
+                weights=weights, denominator=denominator)
         else:
             with scope("lm_head"):
                 logits = nn.dense(h, params["head"]["w"],
-                                  params["head"]["b"],
+                                  params["head"].get("b"),
                                   compute_dtype=self.compute_dtype)
                 logits = logits.astype(jnp.float32)
-                ce = nn.softmax_cross_entropy(logits, y)
-                acc = nn.accuracy(logits, y)
+                if diffusion:
+                    logp = jax.nn.log_softmax(logits, axis=-1)
+                    own = jnp.take_along_axis(logp, y[..., None], -1)[..., 0]
+                    ce = -jnp.sum(own * weights) / denominator
+                    acc = jnp.sum((jnp.argmax(logits, -1) == y)
+                                  * (weights > 0)) / denominator
+                else:
+                    ce = nn.softmax_cross_entropy(logits, y)
+                    acc = nn.accuracy(logits, y)
         metrics = {"loss": ce, "accuracy": acc}
+        if diffusion:
+            masked = jnp.mean((weights > 0).astype(jnp.float32))
+            # hits among the masked positions, not among all
+            metrics["accuracy"] = acc / jnp.maximum(masked, 1e-9)
+            metrics["diffusion_masked_frac"] = masked
         loss = ce
-        if self.moe_experts:
+        if self.moe_top_k:
+            metrics.update({f"moe_{k}": v for k, v in lb.items()})
+            # an overflow is a failed step, never a silent drop
+            loss = jnp.where(lb["overflow_rows"] > 0, jnp.nan, ce)
+            metrics["loss"] = loss
+        elif self.moe_experts:
             metrics["moe_lb"] = lb
             if train:
                 loss = ce + self.moe_aux * lb

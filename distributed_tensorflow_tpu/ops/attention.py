@@ -36,6 +36,7 @@ tests/test_lm.py and, for the kernels, tests/test_flash_kernel.py.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -44,38 +45,213 @@ from jax import lax
 
 from distributed_tensorflow_tpu.utils.profiling import lowering_instant, scoped
 
+# a finite stand-in for -inf in the masks that can leave a query row with
+# no visible key inside one key block: exp(MASK_VALUE - m) is an exact 0
+# and no inf - inf can arise (the causal scan keeps -inf: its first block
+# shows every row key 0)
+MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
+
+
+@dataclasses.dataclass(frozen=True)
+class Mask:
+    """Which (query, key) pairs attend, as a small static description from
+    which every form derives what it needs: the dense mask
+    (``allowed`` on index arrays), the scan's per-block mask (the same),
+    and for the fused kernels the three-way test of a tile (``tile``: runs
+    unmasked, masked, or not at all) and the index maps that keep a
+    skipped tile from moving bytes (``next_key_tile`` / ``next_query_tile``).
+
+    - ``Mask("causal")``: key j <= query i.
+    - ``Mask("block_diffusion", half=S, block=L)``: the sequence is
+      ``[noised ; clean]``, 2 S rows whose position is ``i mod S`` and whose
+      diffusion block is ``position // L``. Noised rows see the noised rows
+      of their own block and the clean rows of earlier blocks; clean rows
+      see the clean rows of their own and earlier blocks; no row sees a
+      noised row of another block. S^2 + S L of the (2 S)^2 pairs.
+    """
+
+    kind: str = "causal"
+    half: int = 0
+    block: int = 0
+
+    def __post_init__(self):
+        if self.kind not in ("causal", "block_diffusion"):
+            raise ValueError(f"unknown attention mask {self.kind!r}")
+        if self.kind == "block_diffusion" and (
+                self.half < 1 or self.block < 1 or self.half % self.block):
+            raise ValueError(f"block diffusion needs blocks of {self.block} "
+                             f"that divide the sequence of {self.half}")
+
+    @property
+    def fill(self) -> float:
+        return -jnp.inf if self.kind == "causal" else MASK_VALUE
+
+    def _block_start(self, pos):
+        """First position of the diffusion block that holds ``pos``."""
+        if self.block & (self.block - 1) == 0:
+            return pos & -self.block  # no vector division in a kernel
+        return pos - pos % self.block
+
+    def allowed(self, queries, keys):
+        """Boolean mask of broadcastable int index arrays."""
+        if self.kind == "causal":
+            return keys <= queries
+        s = self.half
+        q_clean, k_clean = queries >= s, keys >= s
+        start = self._block_start(jnp.where(q_clean, queries - s, queries))
+        kp = jnp.where(k_clean, keys - s, keys)
+        # noised -> noised: its own block; noised -> clean: earlier blocks;
+        # clean -> clean: its own and earlier blocks; clean -> noised: never
+        hi = start + jnp.where(q_clean == k_clean, self.block, 0)
+        lo = jnp.where(jnp.logical_or(q_clean, k_clean), 0, start)
+        seen = jnp.logical_and(kp >= lo, kp < hi)
+        return jnp.logical_and(
+            seen, jnp.logical_not(jnp.logical_and(q_clean,
+                                                  jnp.logical_not(k_clean))))
+
+    def tile(self, q0, q1, k0, k1):
+        """(visible, runs) of the tile of queries q0..q1 and keys k0..k1
+        (inclusive; scalars, traced or not): every pair attends / some pair
+        does. A block-diffusion tile lies within one half on each side
+        (``tiles_fit``)."""
+        if self.kind == "causal":
+            return k1 <= q0, k0 <= q1
+        s, lb = self.half, self.block
+        q_clean, k_clean = q0 >= s, k0 >= s
+        bq0 = jnp.where(q_clean, q0 - s, q0) // lb
+        bq1 = jnp.where(q_clean, q1 - s, q1) // lb
+        bk0 = jnp.where(k_clean, k0 - s, k0) // lb
+        bk1 = jnp.where(k_clean, k1 - s, k1) // lb
+        nn = jnp.logical_not(jnp.logical_or(q_clean, k_clean))
+        same = q_clean == k_clean
+        runs = jnp.where(nn, jnp.logical_and(bk0 <= bq1, bq0 <= bk1),
+                         jnp.where(same, bk0 <= bq1, bk0 < bq1))
+        visible = jnp.where(
+            nn, jnp.logical_and(jnp.logical_and(bq0 == bq1, bk0 == bk1),
+                                bq0 == bk0),
+            jnp.where(same, bk1 <= bq0, bk1 < bq0))
+        never = jnp.logical_and(q_clean, jnp.logical_not(k_clean))
+        return (jnp.logical_and(visible, jnp.logical_not(never)),
+                jnp.logical_and(runs, jnp.logical_not(never)))
+
+    def tiles_fit(self, seq_len: int, tq: int, tk: int) -> bool:
+        """Whether tiles of tq queries and tk keys suit the kernels."""
+        if self.kind == "causal":
+            return True
+        return (seq_len == 2 * self.half and self.half % tq == 0
+                and self.half % tk == 0
+                and self.block & (self.block - 1) == 0)
+
+    def next_key_tile(self, i, j, tq, tk):
+        """The key tile to hold at grid step (query tile i, key tile j): j
+        where the tile runs, else the next one that does, else the last
+        that did, so that a skipped step fetches nothing of its own."""
+        if self.kind == "causal":
+            return jnp.minimum(j, ((i + 1) * tq - 1) // tk)
+        s, lb = self.half, self.block
+        nk = s // tk
+        q0, q1 = i * tq, (i + 1) * tq - 1
+        q_clean = q0 >= s
+        b0 = jnp.where(q_clean, q0 - s, q0) // lb
+        b1 = jnp.where(q_clean, q1 - s, q1) // lb
+        noised = (b0 * lb // tk, ((b1 + 1) * lb - 1) // tk,
+                  jnp.logical_not(q_clean))
+        last_block = jnp.where(q_clean, b1, b1 - 1)  # clean keys' last block
+        clean = (nk, nk + ((last_block + 1) * lb - 1) // tk, last_block >= 0)
+        return _next_in_ranges(j, (noised, clean))
+
+    def next_query_tile(self, j, i, tq, tk):
+        """The query tile to hold at grid step (key tile j, query tile i)
+        of the backward kernel: as ``next_key_tile``."""
+        if self.kind == "causal":
+            return jnp.maximum(i, (j * tk) // tq)
+        s, lb = self.half, self.block
+        nq = s // tq
+        k0, k1 = j * tk, (j + 1) * tk - 1
+        k_clean = k0 >= s
+        b0 = jnp.where(k_clean, k0 - s, k0) // lb
+        b1 = jnp.where(k_clean, k1 - s, k1) // lb
+        # noised queries: of these blocks (noised keys), of later blocks
+        # (clean keys); clean queries: of these and later blocks
+        first = jnp.where(k_clean, (b0 + 1) * lb, b0 * lb)
+        noised = (first // tq,
+                  jnp.where(k_clean, nq - 1, ((b1 + 1) * lb - 1) // tq),
+                  first < s)
+        clean = (nq + b0 * lb // tq, 2 * nq - 1, k_clean)
+        return _next_in_ranges(i, (noised, clean))
+
+
+CAUSAL = Mask("causal")
+
+
+def _next_in_ranges(x, ranges):
+    """``x`` if it lies in one of the ascending inclusive ``(lo, hi,
+    nonempty)`` ranges, else the start of the next one, else the end of
+    the last."""
+    out = None
+    for lo, hi, ok in ranges:  # the end of the last range that is there
+        out = hi if out is None else jnp.where(ok, hi, out)
+    for lo, hi, ok in reversed(ranges):
+        out = jnp.where(jnp.logical_and(ok, x <= hi), jnp.maximum(x, lo), out)
+    return out
+
+
+def _as_mask(causal, mask):
+    """The ``mask`` argument, else the ``causal`` flag, as a ``Mask`` or
+    None (every pair attends)."""
+    if mask is not None:
+        return mask
+    return CAUSAL if causal else None
+
 
 @scoped("attention")
-def multi_head_attention(q, k, v, causal: bool = False):
+def multi_head_attention(q, k, v, causal: bool = False, mask=None):
     """Dense (all-to-all) multi-head attention.
 
     q, k, v: (B, S, H, Dh) -> (B, S, H, Dh). f32 softmax statistics
     regardless of input dtype (bf16-safe). ``causal`` masks j > i (the
-    autoregressive/LM form).
+    autoregressive/LM form); ``mask`` (a ``Mask``) overrides it. k and v
+    may have fewer heads than q (grouped-query attention): query head n
+    reads key/value head n // (H // Hkv).
     """
     dh = q.shape[-1]
+    k, v = _repeat_kv(q, k, v)
+    mask = _as_mask(causal, mask)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
     s = s / jnp.sqrt(jnp.float32(dh))
-    if causal:
+    if mask is not None:
         sq, sk = s.shape[-2], s.shape[-1]
-        mask = jnp.arange(sk)[None, :] <= jnp.arange(sq)[:, None]
-        s = jnp.where(mask, s, -jnp.inf)
+        s = jnp.where(mask.allowed(jnp.arange(sq)[:, None],
+                                   jnp.arange(sk)[None, :]), s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(q.dtype), v)
 
 
-def _online_softmax_step(qf, scale, o, m, l, k_blk, v_blk, mask):
+def _repeat_kv(q, k, v):
+    """k and v with every head repeated for the query heads that read it
+    (grouped-query attention); as they are where the head counts agree."""
+    h, hkv = q.shape[2], k.shape[2]
+    if h == hkv:
+        return k, v
+    if h % hkv:
+        raise ValueError(f"{h} query heads do not divide over {hkv} "
+                         f"key/value heads")
+    return (jnp.repeat(k, h // hkv, axis=2), jnp.repeat(v, h // hkv, axis=2))
+
+
+def _online_softmax_step(qf, scale, o, m, l, k_blk, v_blk, mask,
+                         fill=-jnp.inf):
     """Fold one k/v block into the streaming-softmax accumulators.
 
     The one implementation of the flash/online-softmax recurrence, shared
     by ``ring_attention`` (blocks arrive over ICI) and
     ``blockwise_attention`` (blocks are scanned locally): running max m,
     denominator l, unnormalized numerator o, all f32. ``mask`` (broadcast
-    to (B, H, Sq, Skb)) or None."""
+    to (B, H, Sq, Skb)) or None; ``fill`` stands where it is false."""
     s = jnp.einsum("bqhd,bkhd->bhqk", qf, k_blk.astype(jnp.float32))
     s = s * scale
     if mask is not None:
-        s = jnp.where(mask, s, -jnp.inf)
+        s = jnp.where(mask, s, fill)
     m_new = jnp.maximum(m, s.max(axis=-1))
     corr = jnp.exp(m - m_new)
     p = jnp.exp(s - m_new[..., None])
@@ -85,7 +261,8 @@ def _online_softmax_step(qf, scale, o, m, l, k_blk, v_blk, mask):
     return o, m_new, l
 
 
-def _flash_bwd_block(qf, gf, dD, lse, scale, k_blk, v_blk, mask):
+def _flash_bwd_block(qf, gf, dD, lse, scale, k_blk, v_blk, mask,
+                     fill=-jnp.inf):
     """One k/v block of the flash backward — the single implementation
     both ``_blockwise_bwd`` (local scan) and ``_ring_bwd`` (ring hops)
     run, mirroring how ``_online_softmax_step`` is the one forward.
@@ -99,7 +276,7 @@ def _flash_bwd_block(qf, gf, dD, lse, scale, k_blk, v_blk, mask):
     s = jnp.einsum("bqhd,bkhd->bhqk", qf, k_blk.astype(jnp.float32))
     s = s * scale
     if mask is not None:
-        s = jnp.where(mask, s, -jnp.inf)
+        s = jnp.where(mask, s, fill)
     p = jnp.exp(s - lse[..., None])  # masked entries: exp(-inf) = 0
     dv_blk = jnp.einsum("bhqk,bhqd->bkhd", p, gf)
     dp = jnp.einsum("bhqd,bkhd->bhqk", gf, v_blk.astype(jnp.float32))
@@ -110,7 +287,8 @@ def _flash_bwd_block(qf, gf, dD, lse, scale, k_blk, v_blk, mask):
     return dq_contrib, dk_blk, dv_blk
 
 
-def blockwise_attention(q, k, v, block_size: int, causal: bool = False):
+def blockwise_attention(q, k, v, block_size: int, causal: bool = False,
+                        mask=None):
     """Single-device FLASH attention with O(S * block) peak memory —
     forward AND backward.
 
@@ -145,14 +323,19 @@ def blockwise_attention(q, k, v, block_size: int, causal: bool = False):
       kernels to.
 
     ``causal=True`` masks by absolute position, identical to the dense
-    triangle. Each pass records which implementation its program was
+    triangle; ``mask`` (a ``Mask``) overrides it, and both forms take what
+    they need from that one description (block diffusion: 80 of the 256
+    tiles of 512 run at 2 S = 8,192). k and v may have fewer heads than q
+    (grouped-query attention): the kernels' index maps send a group's
+    query heads to its one key/value head, and dk, dv are summed over the
+    group. Each pass records which implementation its program was
     lowered with (the ``attention_path`` telemetry instant).
     """
     sk = k.shape[1]
     if sk % block_size:
         raise ValueError(f"key length {sk} must divide into blocks of "
                          f"{block_size}")
-    return _blockwise(q, k, v, int(block_size), bool(causal))
+    return _blockwise(q, k, v, int(block_size), _as_mask(causal, mask))
 
 
 def _by_platform(fused, scan, *args):
@@ -162,30 +345,45 @@ def _by_platform(fused, scan, *args):
     return lax.platform_dependent(*args, tpu=fused, default=scan)
 
 
-def fusable(q, k, v, block_size: int, causal: bool = True) -> bool:
-    """Whether these operands are the fused kernels' to take: causal bf16
-    self-attention (Sq = Sk) with S and the key tile multiples of the
-    128-lane tile and a head width of 64, 128 or 256."""
-    return (causal and q.shape == k.shape == v.shape
-            and q.dtype == k.dtype == v.dtype == jnp.bfloat16
+def fusable(q, k, v, block_size: int, causal=True) -> bool:
+    """Whether these operands are the fused kernels' to take: masked
+    (``causal``: True or a ``Mask``) bf16 self-attention (Sq = Sk) with S
+    and the key tile multiples of the 128-lane tile, a head width of 64,
+    128 or 256, key/value heads that divide the query heads, and tiles
+    the mask can be cut into."""
+    mask = _as_mask(causal, None) if isinstance(causal, bool) else causal
+    b, s, h, dh = q.shape
+    if mask is None or k.shape != v.shape or len(k.shape) != 4 \
+            or k.shape != (b, s, k.shape[2], dh) or h % max(k.shape[2], 1):
+        return False
+    if not (q.dtype == k.dtype == v.dtype == jnp.bfloat16
             and q.shape[1] % 128 == 0 and block_size % 128 == 0
-            and q.shape[-1] in (64, 128, 256))
+            and q.shape[-1] in (64, 128, 256)):
+        return False
+    from distributed_tensorflow_tpu.ops import flash_attention
+
+    return mask.tiles_fit(q.shape[1],
+                          flash_attention.query_tile(q.shape[1], mask),
+                          block_size)
 
 
-def _pick(pass_name, scan, q, k, v, block_size, causal, *rest):
+def _pick(pass_name, scan, q, k, v, block_size, mask, *rest):
     """Run one pass (``forward`` / ``backward``) of blockwise attention
     through the implementation its shapes and lowering platform select,
     marked with the ``attention_path`` instant."""
     s = q.shape[1]
     note = {"pass": pass_name, "seq_len": s, "k_tile": block_size,
             "dtype": q.dtype.name}
+    if mask is not None and mask.kind != "causal":
+        # told apart in the record only where there is something to tell
+        note.update(mask=mask.kind, kv_heads=k.shape[2])
 
     def run_scan(q, *xs):
         q = lowering_instant("attention_path", q, path="scan", q_tile=s,
                              **note)
-        return scan(q, *xs, block_size, causal)
+        return scan(q, *xs, block_size, mask)
 
-    if not fusable(q, k, v, block_size, causal):
+    if not fusable(q, k, v, block_size, mask):
         return run_scan(q, k, v, *rest)
     # imports Pallas: only a program that can take the kernels pays for it
     from distributed_tensorflow_tpu.ops import flash_attention
@@ -195,8 +393,11 @@ def _pick(pass_name, scan, q, k, v, block_size, causal, *rest):
 
     def run_fused(q, *xs):
         q = lowering_instant("attention_path", q, path="fused",
-                             q_tile=flash_attention.query_tile(s), **note)
-        return fused(q, *xs, block_size)
+                             q_tile=flash_attention.query_tile(s, mask),
+                             **note)
+        if mask.kind == "causal":  # today's call, and so today's trace
+            return fused(q, *xs, block_size)
+        return fused(q, *xs, block_size, mask)
 
     return _by_platform(run_fused, run_scan, q, k, v, *rest)
 
@@ -204,9 +405,10 @@ def _pick(pass_name, scan, q, k, v, block_size, causal, *rest):
 # jitted (here and the backward): every layer of a model traces both
 # implementations, and shares one trace of each this way
 @functools.partial(jax.jit, static_argnums=(3, 4))
-def _scan_forward(q, k, v, block_size, causal):
+def _scan_forward(q, k, v, block_size, mask):
     """Forward scan; returns (out BQHD in q.dtype, lse BHQ f32)."""
     b, sq, h, dh = q.shape
+    k, v = _repeat_kv(q, k, v)
     sk = k.shape[1]
     n_blocks = sk // block_size
     scale = 1.0 / jnp.sqrt(jnp.float32(dh))
@@ -218,12 +420,13 @@ def _scan_forward(q, k, v, block_size, causal):
     def step(carry, inp):
         o, m, l = carry
         t, k_blk, v_blk = inp
-        mask = None
-        if causal:
+        seen, fill = None, -jnp.inf
+        if mask is not None:
             cols = t * block_size + jnp.arange(block_size)
-            mask = (cols[None, :] <= rows[:, None])[None, None]
+            seen = mask.allowed(rows[:, None], cols[None, :])[None, None]
+            fill = mask.fill
         o, m, l = _online_softmax_step(qf, scale, o, m, l, k_blk, v_blk,
-                                       mask)
+                                       seen, fill)
         return (o, m, l), None
 
     o0 = jnp.zeros((b, h, sq, dh), jnp.float32)
@@ -237,23 +440,23 @@ def _scan_forward(q, k, v, block_size, causal):
 
 
 @scoped("attention")
-def _forward(q, k, v, block_size, causal):
+def _forward(q, k, v, block_size, mask):
     """(out, lse) by the fused kernel or the scan."""
-    return _pick("forward", _scan_forward, q, k, v, block_size, causal)
+    return _pick("forward", _scan_forward, q, k, v, block_size, mask)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _blockwise(q, k, v, block_size, causal):
-    return _forward(q, k, v, block_size, causal)[0]
+def _blockwise(q, k, v, block_size, mask):
+    return _forward(q, k, v, block_size, mask)[0]
 
 
-def _blockwise_fwd(q, k, v, block_size, causal):
-    out, lse = _forward(q, k, v, block_size, causal)
+def _blockwise_fwd(q, k, v, block_size, mask):
+    out, lse = _forward(q, k, v, block_size, mask)
     return out, (q, k, v, out, lse)
 
 
 @functools.partial(jax.jit, static_argnums=(6, 7))
-def _scan_backward(q, k, v, out, lse, g, block_size, causal):
+def _scan_backward(q, k, v, out, lse, g, block_size, mask):
     """The flash backward: one scan over k/v blocks, each block's
     probability panel recomputed from (q, lse) — never all at once.
 
@@ -264,6 +467,8 @@ def _scan_backward(q, k, v, out, lse, g, block_size, causal):
     vs dense autodiff is pinned by tests/test_lm.py (values AND grads).
     """
     b, sq, h, dh = q.shape
+    hkv = k.shape[2]
+    k, v = _repeat_kv(q, k, v)
     sk = k.shape[1]
     n_blocks = sk // block_size
     scale = 1.0 / jnp.sqrt(jnp.float32(dh))
@@ -276,27 +481,31 @@ def _scan_backward(q, k, v, out, lse, g, block_size, causal):
 
     def step(dq, inp):
         t, k_blk, v_blk = inp
-        mask = None
-        if causal:
+        seen, fill = None, -jnp.inf
+        if mask is not None:
             cols = t * block_size + jnp.arange(block_size)
-            mask = (cols[None, :] <= rows[:, None])[None, None]
+            seen = mask.allowed(rows[:, None], cols[None, :])[None, None]
+            fill = mask.fill
         dq_c, dk_blk, dv_blk = _flash_bwd_block(
-            qf, gf, dD, lse, scale, k_blk, v_blk, mask)
+            qf, gf, dD, lse, scale, k_blk, v_blk, seen, fill)
         return dq + dq_c, (dk_blk, dv_blk)
 
     dq0 = jnp.zeros((b, sq, h, dh), jnp.float32)
     dq, (dkb, dvb) = lax.scan(step, dq0, (jnp.arange(n_blocks), kb, vb))
     dk = jnp.moveaxis(dkb, 0, 1).reshape(b, sk, h, dh)
     dv = jnp.moveaxis(dvb, 0, 1).reshape(b, sk, h, dh)
+    if hkv != h:  # a key/value head's gradient: the sum over its group
+        dk = dk.reshape(b, sk, hkv, h // hkv, dh).sum(axis=3)
+        dv = dv.reshape(b, sk, hkv, h // hkv, dh).sum(axis=3)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
 @scoped("attention")
-def _blockwise_bwd(block_size, causal, res, g):
+def _blockwise_bwd(block_size, mask, res, g):
     """(dq, dk, dv) by the fused kernel or the scan, from the residuals
     both forwards save in one form: (q, k, v, out, logsumexp)."""
     q, k, v, out, lse = res
-    return _pick("backward", _scan_backward, q, k, v, block_size, causal,
+    return _pick("backward", _scan_backward, q, k, v, block_size, mask,
                  out, lse, g)
 
 

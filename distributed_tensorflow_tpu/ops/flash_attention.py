@@ -1,10 +1,18 @@
-"""Causal flash attention as two Pallas TPU kernels: the (key tile, query
+"""Masked flash attention as two Pallas TPU kernels: the (key tile, query
 tile) probability panel lives in VMEM and never crosses HBM.
 
 ``ops/attention.py:blockwise_attention`` runs these on a TPU where the
 shapes allow (its ``fusable``); its ``lax.scan`` form is the same mathematics
 and the reference the tests hold the kernels to. Only q, k, v, o, one f32
 logsumexp a row and the three gradients cross HBM.
+
+The mask is a static description (``ops/attention.py:Mask``: causal, or
+block diffusion over a doubled sequence) that gives the kernels the
+three-way test of a tile (it runs unmasked, masked, or not at all), the
+mask inside a tile, and the index maps that make a skipped tile move no
+bytes. Key/value heads may be fewer than query heads: the k/v index map
+sends a group's query heads to its one key/value head, and the backward
+writes dk, dv a query head, summed over the group outside the kernel.
 
 - **Layout.** The kernels take (heads * batch, Dh, S): the sequence on
   the 128 lanes, the head width on sublanes, heads outermost. That is the
@@ -59,11 +67,14 @@ _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 _TN = (((0,), (0,)), ((), ()))  # a.T @ b
 
 
-def query_tile(seq_len: int) -> int:
+def query_tile(seq_len: int, mask=None) -> int:
     """The query tile: the largest multiple of 128 that divides
-    ``seq_len`` and is at most ``MAX_QUERY_TILE``."""
+    ``seq_len`` (each half of it under a block-diffusion mask, whose tiles
+    lie within one half) and is at most ``MAX_QUERY_TILE``."""
+    if mask is not None and mask.kind == "block_diffusion":
+        seq_len = mask.half
     tq = min(seq_len, MAX_QUERY_TILE)
-    while seq_len % tq:
+    while tq > LANES and seq_len % tq:
         tq -= LANES
     return tq
 
@@ -76,7 +87,7 @@ def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
                            preferred_element_type=jnp.float32)
 
 
-def _scores(kt, qt, scale, i, j, tq, tk, masked):
+def _scores(kt, qt, scale, i, j, tq, tk, masked, mask):
     """(scaled scores (tk, tq) f32, the q tile for the dk product, the
     factor that product still owes). Where 1/sqrt(Dh) is a power of two
     (Dh = 64, 256) it is put on the small q tile, which is exact in bf16
@@ -91,23 +102,24 @@ def _scores(kt, qt, scale, i, j, tq, tk, masked):
     if masked:
         keys = j * tk + lax.broadcasted_iota(jnp.int32, st.shape, 0)
         queries = i * tq + lax.broadcasted_iota(jnp.int32, st.shape, 1)
-        st = jnp.where(keys <= queries, st, MASK)
+        st = jnp.where(mask.allowed(queries, keys), st, MASK)
     return st, qt, (None if exact else scale)
 
 
-def _when_tile_runs(i, j, tq, tk, fold):
+def _when_tile_runs(i, j, tq, tk, mask, fold):
     """``fold(masked)`` for tile (query tile i, key tile j): unmasked
-    where its last key <= its first query, masked where the diagonal
-    crosses it, not at all where its first key > its last query."""
-    visible = (j + 1) * tk - 1 <= i * tq
-    runs = j * tk <= (i + 1) * tq - 1
+    where every pair attends (causal: its last key <= its first query),
+    masked where only some do (the diagonal crosses it), not at all where
+    none does (its first key > its last query)."""
+    visible, runs = mask.tile(i * tq, (i + 1) * tq - 1,
+                              j * tk, (j + 1) * tk - 1)
     pl.when(visible)(lambda: fold(False))
     pl.when(jnp.logical_and(runs, jnp.logical_not(visible)))(
         lambda: fold(True))
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
-                scale, tq, tk):
+                scale, tq, tk, mask):
     i, j = pl.program_id(1), pl.program_id(2)
 
     @pl.when(j == 0)
@@ -119,7 +131,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
     def fold(masked):
         vt = v_ref[...]  # (Dh, tk)
         st, _, _ = _scores(k_ref[...], q_ref[...], scale, i, j, tq, tk,
-                           masked)
+                           masked, mask)
         m_prev = m_sc[...]  # (1, tq)
         m_next = jnp.maximum(m_prev, st.max(axis=0, keepdims=True))
         pt = jnp.exp(st - m_next)
@@ -128,7 +140,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
         acc_sc[...] = alpha * acc_sc[...] + _dot(vt, pt.astype(vt.dtype))
         m_sc[...] = m_next
 
-    _when_tile_runs(i, j, tq, tk, fold)
+    _when_tile_runs(i, j, tq, tk, mask, fold)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _():
@@ -149,23 +161,43 @@ def _heads_last(x, like):
     return jnp.einsum("hbds->bshd", x.reshape(h, b, dh, s))
 
 
+def _kv_head(g, batch: int, group: int):
+    """The row of the (Hkv * B, Dh, S) key/value operands that query row
+    ``g`` of (H * B, Dh, S) reads: head g // B of the queries is head
+    (g // B) // group of the keys."""
+    if group == 1:
+        return g
+    return (g // batch) // group * batch + g % batch
+
+
+def _causal():
+    from distributed_tensorflow_tpu.ops.attention import CAUSAL
+
+    return CAUSAL
+
+
 # jitted: a model's layers share one trace and one lowering of each kernel
-@functools.partial(jax.jit, static_argnames=("block_size",))
-def flash_forward(q, k, v, block_size: int):
-    """(out, lse) of causal attention. q, k, v: (B, S, H, Dh) bf16;
-    out like q; lse (B, H, S) f32, the logsumexp of each row's scores."""
+@functools.partial(jax.jit, static_argnames=("block_size", "mask"))
+def flash_forward(q, k, v, block_size: int, mask=None):
+    """(out, lse) of masked attention (``mask``: a ``Mask``, causal if
+    None). q: (B, S, H, Dh) bf16, k and v: (B, S, Hkv, Dh); out like q;
+    lse (B, H, S) f32, the logsumexp of each row's scores."""
     b, s, h, dh = q.shape
+    mask = _causal() if mask is None else mask
+    group = h // k.shape[2]
     tk = block_size
-    tq = query_tile(s)
+    tq = query_tile(s, mask)
 
     def kv_map(g, i, j):
-        # stop at the query tile's last key tile: a repeated block index
-        # is not fetched again, so the skipped steps move nothing
-        return g, 0, jnp.minimum(j, ((i + 1) * tq - 1) // tk)
+        # stop at the query tile's last key tile (causal), hold the next
+        # tile that runs: a repeated block index is not fetched again, so
+        # the skipped steps move nothing
+        return _kv_head(g, b, group), 0, mask.next_key_tile(i, j, tq, tk)
 
     row = pltpu.VMEM((1, tq), jnp.float32)
     out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=dh ** -0.5, tq=tq, tk=tk),
+        functools.partial(_fwd_kernel, scale=dh ** -0.5, tq=tq, tk=tk,
+                          mask=mask),
         grid=(h * b, s // tq, s // tk),
         in_specs=[pl.BlockSpec((None, dh, tq), lambda g, i, j: (g, 0, i)),
                   pl.BlockSpec((None, dh, tk), kv_map),
@@ -186,7 +218,7 @@ def flash_forward(q, k, v, block_size: int):
 
 def _bwd_kernel(q_ref, do_ref, lse_ref, d_ref, k_ref, v_ref,
                 dq_ref, dk_ref, dv_ref, dq_sc, dk_sc, dv_sc, *,
-                scale, tq, tk):
+                scale, tq, tk, mask):
     j, i = pl.program_id(1), pl.program_id(2)
     last_i = pl.num_programs(2) - 1
 
@@ -201,7 +233,8 @@ def _bwd_kernel(q_ref, do_ref, lse_ref, d_ref, k_ref, v_ref,
 
     def fold(masked):
         dot_, kt, vt = do_ref[...], k_ref[...], v_ref[...]  # (Dh, tile)
-        st, qt, owed = _scores(kt, q_ref[...], scale, i, j, tq, tk, masked)
+        st, qt, owed = _scores(kt, q_ref[...], scale, i, j, tq, tk, masked,
+                               mask)
         pt = jnp.exp(st - lse_ref[...])  # lse: (1, tq) down the sublanes
         dv_sc[...] += _dot(dot_, pt.astype(dot_.dtype), _NT)
         dpt = _dot(vt, dot_, _TN)
@@ -210,7 +243,7 @@ def _bwd_kernel(q_ref, do_ref, lse_ref, d_ref, k_ref, v_ref,
         dk_sc[...] += dk if owed is None else dk * owed
         dq_sc[i] += _dot(kt, dst)
 
-    _when_tile_runs(i, j, tq, tk, fold)
+    _when_tile_runs(i, j, tq, tk, mask, fold)
 
     @pl.when(i == last_i)
     def _():
@@ -224,34 +257,41 @@ def _bwd_kernel(q_ref, do_ref, lse_ref, d_ref, k_ref, v_ref,
                 dq_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("block_size",))
-def flash_backward(q, k, v, out, lse, g, block_size: int):
+@functools.partial(jax.jit, static_argnames=("block_size", "mask"))
+def flash_backward(q, k, v, out, lse, g, block_size: int, mask=None):
     """(dq, dk, dv) of ``flash_forward`` from its operands, its two
-    results and the cotangent ``g`` of ``out``; all (B, S, H, Dh)."""
+    results and the cotangent ``g`` of ``out``; dq like q, dk and dv like
+    k and v."""
     b, s, h, dh = q.shape
+    mask = _causal() if mask is None else mask
+    group = h // k.shape[2]
     tk = block_size
-    tq = query_tile(s)
+    tq = query_tile(s, mask)
     # D_i = sum_d do_i * o_i, f32, one row a (head, batch) like lse
     dd = jnp.einsum("bshd,bshd->hbs", g.astype(jnp.float32),
                     out.astype(jnp.float32))
 
     def q_map(g_, j, i):
         # start at the key tile's first query tile (see kv_map above)
-        return g_, 0, jnp.maximum(i, (j * tk) // tq)
+        return g_, 0, mask.next_query_tile(j, i, tq, tk)
 
     def kv_map(g_, j, i):
         return g_, 0, j
 
+    def kv_in_map(g_, j, i):
+        return _kv_head(g_, b, group), 0, j
+
     grads = jax.ShapeDtypeStruct((h * b, dh, s), q.dtype)
     dq, dk, dv = pl.pallas_call(
-        functools.partial(_bwd_kernel, scale=dh ** -0.5, tq=tq, tk=tk),
+        functools.partial(_bwd_kernel, scale=dh ** -0.5, tq=tq, tk=tk,
+                          mask=mask),
         grid=(h * b, s // tk, s // tq),
         in_specs=[pl.BlockSpec((None, dh, tq), q_map),
                   pl.BlockSpec((None, dh, tq), q_map),
                   pl.BlockSpec((None, 1, tq), q_map),
                   pl.BlockSpec((None, 1, tq), q_map),
-                  pl.BlockSpec((None, dh, tk), kv_map),
-                  pl.BlockSpec((None, dh, tk), kv_map)],
+                  pl.BlockSpec((None, dh, tk), kv_in_map),
+                  pl.BlockSpec((None, dh, tk), kv_in_map)],
         out_specs=[pl.BlockSpec((None, dh, s), lambda g_, j, i: (g_, 0, 0)),
                    pl.BlockSpec((None, dh, tk), kv_map),
                    pl.BlockSpec((None, dh, tk), kv_map)],
@@ -266,4 +306,10 @@ def flash_backward(q, k, v, out, lse, g, block_size: int):
     )(_heads_first(q), _heads_first(g.astype(q.dtype)),
       jnp.einsum("bhs->hbs", lse).reshape(h * b, 1, s),
       dd.reshape(h * b, 1, s), _heads_first(k), _heads_first(v))
-    return _heads_last(dq, q), _heads_last(dk, q), _heads_last(dv, q)
+    if group > 1:
+        # a key/value head's gradient is the sum over the query heads
+        # that read it; each was accumulated in f32 over its query tiles
+        dk, dv = (x.reshape(h // group, group, b, dh, s).astype(jnp.float32)
+                  .sum(axis=1).astype(q.dtype).reshape(-1, dh, s)
+                  for x in (dk, dv))
+    return _heads_last(dq, q), _heads_last(dk, k), _heads_last(dv, k)

@@ -113,3 +113,246 @@ def switch_moe(h, params, *, capacity_factor: float = 1.25,
         y = lax.psum(y, axis_name)
     return y.reshape(b, s, d), {"lb_loss": lb_loss,
                                 "dropped_frac": dropped}
+
+
+# ---------------------------------------------------------------------------
+# The dropless routed layer: top-k of a softmax, grouped products over the
+# experts this chip holds.
+
+# largest tiles (rows, contraction, columns) of the Pallas grouped product,
+# from the chip's sweep at 40,960 x 2,048 x 1,536 and x 768 x 2,048 (PERF.md
+# §6, PR 29: 1,024 rows a tile 25 % ahead of 512; a 1,536-wide column tile
+# does not fit VMEM)
+GROUPED_TILING = (1024, 1024, 1024)
+ROW_TILE = GROUPED_TILING[0]
+
+
+def _tiling(m: int, k: int, n: int) -> tuple[int, int, int]:
+    """Tiles for an (m, k) x (k, n) grouped product: the largest allowed,
+    the contraction and column tiles divisors of k and n in whole lanes
+    where they have one (a ragged last tile is masked in f32 and costs the
+    kernel its VMEM)."""
+    def divisor(size, most):
+        tile = min(most, size)
+        while tile > 128 and (size % tile or tile % 128):
+            tile -= 128
+        return tile
+
+    return (min(GROUPED_TILING[0], m), divisor(k, GROUPED_TILING[1]),
+            divisor(n, GROUPED_TILING[2]))
+
+
+def routed_capacity(tokens: int, top_k: int, held: int, experts: int,
+                    capacity_factor: float) -> int:
+    """Rows of the sorted buffer: ``capacity_factor`` times the (row,
+    expert) pairs that uniform routing sends to the ``held`` of
+    ``experts`` experts, rounded up to the grouped product's row tile, and
+    never more than every pair there is."""
+    expected = tokens * top_k * held / experts
+    rows = min(math.ceil(capacity_factor * expected), tokens * min(top_k, held))
+    return max(ROW_TILE, -(-rows // ROW_TILE) * ROW_TILE)
+
+
+def _megablox():
+    """The module of the Pallas grouped products (the package's ``gmm``
+    attribute is the function of that name, which shadows it)."""
+    import importlib
+
+    return importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+
+
+def _grouped_pallas(lhs, rhs, sizes, transpose_rhs=False):
+    backend = _megablox()
+
+    m, k = lhs.shape
+    n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    tiling = _tiling(m, k, n)
+    if transpose_rhs:  # the kernel turns the tile too: half the rows fit
+        tiling = (min(tiling[0], 512),) + tiling[1:]
+    # bf16 operands take the MXU's one native pass whatever the process-wide
+    # jax_default_matmul_precision says (Mosaic refuses an f32-precision
+    # contraction of bf16 vectors, as in ops/flash_attention.py)
+    with jax.default_matmul_precision("bfloat16"):
+        return backend.gmm(lhs, rhs, sizes, lhs.dtype, tiling,
+                           transpose_rhs=transpose_rhs)
+
+
+def _grouped_pallas_rhs_grad(lhs, grad, sizes, groups, dtype):
+    backend = _megablox()
+
+    m, k = lhs.shape
+    n = grad.shape[1]
+    with jax.default_matmul_precision("bfloat16"):
+        return backend.tgmm(lhs.swapaxes(0, 1), grad, sizes, dtype,
+                            _tiling(m, k, n), num_actual_groups=groups)
+
+
+def _ragged(lhs, rhs, sizes):
+    return lax.ragged_dot(lhs, rhs, sizes, preferred_element_type=lhs.dtype)
+
+
+def _path_marked(fn, path, pass_name):
+    from distributed_tensorflow_tpu.utils.profiling import lowering_instant
+
+    def run(lhs, *rest):
+        lhs = lowering_instant("moe_path", lhs, path=path, rows=lhs.shape[0],
+                               dtype=lhs.dtype.name, **{"pass": pass_name})
+        return fn(lhs, *rest)
+
+    return run
+
+
+def _pallas_takes(lhs, rhs) -> bool:
+    """Whether the Pallas grouped product takes these operands: bf16, a
+    row count its row tile divides, lane-aligned widths."""
+    return (lhs.dtype == rhs.dtype == jnp.bfloat16
+            and lhs.shape[0] % min(ROW_TILE, lhs.shape[0]) == 0
+            and lhs.shape[0] % 128 == 0
+            and rhs.shape[1] % 128 == 0 and rhs.shape[2] % 128 == 0)
+
+
+@jax.custom_vjp
+def grouped_matmul(lhs, rhs, sizes):
+    """(m, k) rows sorted by group times the (g, k, n) matrix of each
+    row's group: rows ``sum(sizes[:i]) .. sum(sizes[:i+1])`` meet
+    ``rhs[i]``. What the rows past ``sum(sizes)`` hold is not defined
+    (``routed_experts`` leaves none). On a TPU, for bf16 at aligned shapes, the
+    Pallas grouped product (``jax.experimental.pallas.ops.tpu.megablox``)
+    visits only the row tiles a group reaches into; elsewhere
+    ``lax.ragged_dot``. Which was lowered is the ``moe_path`` instant."""
+    return _grouped_forward(lhs, rhs, sizes)
+
+
+def _grouped_forward(lhs, rhs, sizes):
+    plain = _path_marked(_ragged, "ragged_dot", "forward")
+    if not _pallas_takes(lhs, rhs):
+        return plain(lhs, rhs, sizes)
+    # both are traced, one is lowered (as ops/attention.py picks its kernels)
+    return lax.platform_dependent(
+        lhs, rhs, sizes, default=plain,
+        tpu=_path_marked(_grouped_pallas, "pallas_gmm", "forward"))
+
+
+def _grouped_fwd(lhs, rhs, sizes):
+    return _grouped_forward(lhs, rhs, sizes), (lhs, rhs, sizes)
+
+
+def _grouped_bwd(res, g):
+    lhs, rhs, sizes = res
+    g = g.astype(lhs.dtype)
+
+    def plain(lhs, rhs, sizes, g):
+        return jax.vjp(lambda a, b: _ragged(a, b, sizes), lhs, rhs)[1](g)
+
+    def pallas(lhs, rhs, sizes, g):
+        return (_grouped_pallas(g, rhs, sizes, transpose_rhs=True),
+                _grouped_pallas_rhs_grad(lhs, g, sizes, rhs.shape[0],
+                                         rhs.dtype))
+
+    plain = _path_marked(plain, "ragged_dot", "backward")
+    if not _pallas_takes(lhs, rhs):
+        dl, dr = plain(lhs, rhs, sizes, g)
+    else:
+        dl, dr = lax.platform_dependent(
+            lhs, rhs, sizes, g, default=plain,
+            tpu=_path_marked(pallas, "pallas_gmm", "backward"))
+    import numpy as np
+
+    from jax.dtypes import float0
+
+    return dl, dr, np.zeros(sizes.shape, float0)
+
+
+grouped_matmul.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+def routed_experts(h, params, *, top_k: int, first_expert: int = 0,
+                   capacity_factor: float = 1.25, compute_dtype=None):
+    """(B, S, d) -> ((B, S, d) f32-accumulated in h's dtype, aux): the
+    dropless top-k mixture of gated experts, of which this chip holds
+    ``params["w1"].shape[0]``, numbered from ``first_expert`` among the
+    ``params["router"].shape[1]`` the router chooses between.
+
+    ``params``: ``router`` (d, E), ``w1`` (held, d, 2 f: the gate's
+    columns, then the up projection's), ``w2`` (held, f, d). A row's
+    weights are its top-k softmax probabilities renormalised to one; the
+    experts not held add nothing (their part is another chip's), and no
+    code stands in for them.
+
+    Rows are not dropped: the (row, expert) pairs whose expert is held
+    are sorted by expert into a buffer of ``routed_capacity`` rows (static
+    shapes), the experts run as two grouped products over it, and the
+    results are scatter-added back in f32. The buffer's rows past the last
+    pair hold pairs of experts not held, at weight nought, and ride with
+    the last held expert: every row of the buffer is written, nothing is
+    masked, and a step costs the same whatever the routing (the buffer's
+    size is the cost: ``capacity_factor``). ``aux``: the held experts'
+    rows (``rows_per_expert_max`` / ``_mean``), ``buffer_fill`` (pairs over
+    the buffer's rows), ``unrouted_frac``, and ``overflow_rows``, the pairs
+    the buffer could not take: the caller fails the step when it is not 0
+    (``TransformerLM`` makes the loss NaN), never a silent drop."""
+    from distributed_tensorflow_tpu.utils.profiling import scope
+
+    b, s, d = h.shape
+    t = b * s
+    hf = h.reshape(t, d)
+    cd = compute_dtype
+    e_total = params["router"].shape[1]
+    held, _, two_f = params["w1"].shape
+    f = two_f // 2
+    if not 0 <= first_expert <= e_total - held:
+        raise ValueError(f"experts {first_expert}..{first_expert + held - 1} "
+                         f"are not among the router's {e_total}")
+    cap = routed_capacity(t, top_k, held, e_total, capacity_factor)
+
+    with scope("moe_router"):
+        # f32 operands and products: the choice of experts is a
+        # discontinuous function of these logits
+        logits = jnp.dot(hf.astype(jnp.float32),
+                         params["router"].astype(jnp.float32),
+                         precision=lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_p, top_e = lax.top_k(probs, top_k)                  # (T, k)
+        gate = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        local = top_e - first_expert
+        is_held = jnp.logical_and(local >= 0, local < held)
+        # the pairs of held experts first, by expert, in row order
+        key = jnp.where(is_held, local, held).reshape(t * top_k)
+        order = jnp.argsort(key, stable=True)
+        # (a buffer with more room than there are pairs: the rest is
+        # never valid)
+        order = jnp.pad(order, (0, max(0, cap - t * top_k)))[:cap]
+        row = order // top_k
+        counts = jnp.sum(
+            jax.nn.one_hot(key, held, dtype=jnp.int32), axis=0)  # (held,)
+        total = jnp.sum(counts)
+        ends = jnp.minimum(jnp.cumsum(counts), cap)
+        # the rows past the last pair ride with the last expert
+        sizes = jnp.diff(ends.at[-1].set(cap), prepend=0).astype(jnp.int32)
+        weight = jnp.where(jnp.arange(cap) < ends[-1],
+                           gate.reshape(t * top_k)[order], 0.0)
+        xs = hf[row]                                            # (cap, d)
+        if cd is not None:
+            xs = xs.astype(cd)
+
+    with scope("moe_experts"):
+        w1, w2 = params["w1"], params["w2"]
+        if cd is not None:
+            w1, w2 = w1.astype(cd), w2.astype(cd)
+        up = grouped_matmul(xs, w1, sizes)                      # (cap, 2 f)
+        act = jax.nn.silu(up[:, :f]) * up[:, f:]
+        ys = grouped_matmul(act, w2, sizes)                     # (cap, d)
+
+    with scope("moe_router"):
+        y = jnp.zeros((t, d), jnp.float32).at[row].add(
+            ys.astype(jnp.float32) * weight[:, None])
+        aux = {
+            "rows_per_expert_max": jnp.max(counts).astype(jnp.float32),
+            "rows_per_expert_mean": jnp.mean(counts.astype(jnp.float32)),
+            "buffer_fill": total.astype(jnp.float32) / cap,
+            "overflow_rows": (total - ends[-1]).astype(jnp.float32),
+            "unrouted_frac": 1.0 - jnp.mean(
+                jnp.any(is_held, axis=-1).astype(jnp.float32)),
+        }
+    return y.astype(h.dtype).reshape(b, s, d), aux

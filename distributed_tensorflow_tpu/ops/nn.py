@@ -150,16 +150,37 @@ def _head_logits(h_blk, w, b, cd):
         y = jnp.dot(h_blk.astype(cd), w.astype(cd)).astype(h_blk.dtype)
     else:
         y = jnp.dot(h_blk, w)
-    y = y + b.astype(y.dtype)
+    if b is not None:
+        y = y + b.astype(y.dtype)
     return y.astype(jnp.float32)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _streamed_ce(h2, w, b, labels2, block, cd, n_valid):
-    return _streamed_ce_forward(h2, w, b, labels2, block, cd, n_valid)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 8))
+def _streamed_ce(h2, w, b, labels2, block, cd, n_valid, weights2=None,
+                 denom=None):
+    return _streamed_ce_forward(h2, w, b, labels2, block, cd, n_valid,
+                                weights2, denom)[0]
 
 
-def _streamed_ce_forward(h2, w, b, labels2, block, cd, n_valid):
+def _row_weights(vmask, wts):
+    """One block's f32 weight of each row in the loss and in the hit
+    count: the padding mask alone, or times the caller's weights (a row
+    of weight nought is no hit either)."""
+    vf = vmask.astype(jnp.float32)
+    if wts is None:
+        return vf, vf
+    wts = wts.astype(jnp.float32)
+    return vf * wts, vf * (wts > 0).astype(jnp.float32)
+
+
+def _weight_blocks(weights2, nb, block):
+    """The scan's operand for the rows' weights: () where there are none
+    (the scan is then the unweighted head's, to the operation)."""
+    return () if weights2 is None else (weights2.reshape(nb, block),)
+
+
+def _streamed_ce_forward(h2, w, b, labels2, block, cd, n_valid,
+                         weights2=None, denom=None):
     """Forward scan over row blocks; returns ((loss, acc), lse (N,))."""
     n_pad, d = h2.shape
     nb = n_pad // block
@@ -168,7 +189,7 @@ def _streamed_ce_forward(h2, w, b, labels2, block, cd, n_valid):
     valid = (jnp.arange(n_pad) < n_valid).reshape(nb, block)
 
     def step(carry, inp):
-        h_blk, lbl, vmask = inp
+        h_blk, lbl, vmask, *wts = inp
         logits = _head_logits(h_blk, w, b, cd)  # (R, V) f32 — the peak
         m = jnp.max(logits, axis=-1)
         lse = m + jnp.log(jnp.sum(jnp.exp(logits - m[:, None]), axis=-1))
@@ -176,7 +197,7 @@ def _streamed_ce_forward(h2, w, b, labels2, block, cd, n_valid):
         # where(), not onehot*logits — same -inf rationale as
         # softmax_cross_entropy above
         lab = jnp.sum(jnp.where(onehot != 0, logits, 0.0), axis=-1)
-        vf = vmask.astype(jnp.float32)
+        vf, hf = _row_weights(vmask, *wts or (None,))
         # out-of-range ids: zero loss AND zero gradient, matching
         # softmax_cross_entropy's one_hot semantics (all-zero row);
         # accuracy still counts the row in its denominator (a miss) —
@@ -185,28 +206,31 @@ def _streamed_ce_forward(h2, w, b, labels2, block, cd, n_valid):
         loss_sum, corr_sum = carry
         loss_sum = loss_sum + jnp.sum((lse - lab) * vf * ok)
         hit = (jnp.argmax(logits, axis=-1) == lbl).astype(jnp.float32)
-        corr_sum = corr_sum + jnp.sum(hit * vf)
+        corr_sum = corr_sum + jnp.sum(hit * hf)
         return (loss_sum, corr_sum), lse
 
     (loss_sum, corr_sum), lses = lax.scan(
-        step, (jnp.float32(0.0), jnp.float32(0.0)), (hb, lb, valid))
-    inv = jnp.float32(1.0 / n_valid)
+        step, (jnp.float32(0.0), jnp.float32(0.0)),
+        (hb, lb, valid) + _weight_blocks(weights2, nb, block))
+    inv = jnp.float32(1.0 / (n_valid if denom is None else denom))
     return (loss_sum * inv, corr_sum * inv), lses
 
 
-def _streamed_ce_fwd(h2, w, b, labels2, block, cd, n_valid):
-    out, lses = _streamed_ce_forward(h2, w, b, labels2, block, cd, n_valid)
-    return out, (h2, w, b, labels2, lses)
+def _streamed_ce_fwd(h2, w, b, labels2, block, cd, n_valid, weights2=None,
+                     denom=None):
+    out, lses = _streamed_ce_forward(h2, w, b, labels2, block, cd, n_valid,
+                                     weights2, denom)
+    return out, (h2, w, b, labels2, lses, weights2)
 
 
 @scoped("lm_head")
-def _streamed_ce_bwd(block, cd, n_valid, res, ct):
+def _streamed_ce_bwd(block, cd, n_valid, denom, res, ct):
     """The streamed backward: recompute each block's logits from
     (h, w, b) and its saved row logsumexps — dL/dlogits = softmax -
     onehot, never materialized beyond one (block, V) panel. dw/db
     accumulate in f32 across the scan; dh blocks stack. The accuracy
     output's cotangent is ignored (argmax has no gradient)."""
-    h2, w, b, labels2, lses = res
+    h2, w, b, labels2, lses, weights2 = res
     g_loss = ct[0]
     n_pad, d = h2.shape
     nb = n_pad // block
@@ -214,17 +238,17 @@ def _streamed_ce_bwd(block, cd, n_valid, res, ct):
     lb = labels2.reshape(nb, block)
     valid = (jnp.arange(n_pad) < n_valid).reshape(nb, block)
     lsb = lses.reshape(nb, block)
-    scale = g_loss.astype(jnp.float32) / n_valid
+    scale = g_loss.astype(jnp.float32) / (n_valid if denom is None else denom)
 
     def step(carry, inp):
         dw, db = carry
-        h_blk, lbl, vmask, lse_blk = inp
+        h_blk, lbl, vmask, lse_blk, *wts = inp
         logits = _head_logits(h_blk, w, b, cd)
         p = jnp.exp(logits - lse_blk[:, None])
         onehot = jax.nn.one_hot(lbl, logits.shape[-1], dtype=jnp.float32)
         ok = ((lbl >= 0) & (lbl < logits.shape[-1])).astype(jnp.float32)
-        g = (p - onehot) * (vmask.astype(jnp.float32) * ok
-                            * scale)[:, None]
+        vf = _row_weights(vmask, *wts or (None,))[0]
+        g = (p - onehot) * (vf * ok * scale)[:, None]
         if cd is not None:
             gc = g.astype(cd)
             dh_blk = jnp.dot(gc, w.astype(cd).T).astype(h2.dtype)
@@ -232,18 +256,23 @@ def _streamed_ce_bwd(block, cd, n_valid, res, ct):
         else:
             dh_blk = jnp.dot(g, w.T).astype(h2.dtype)
             dw = dw + jnp.dot(h_blk.T, g)
-        db = db + jnp.sum(g, axis=0)
+        if b is not None:
+            db = db + jnp.sum(g, axis=0)
         return (dw, db), dh_blk
 
     dw0 = jnp.zeros(w.shape, jnp.float32)
-    db0 = jnp.zeros(b.shape, jnp.float32)
-    (dw, db), dhb = lax.scan(step, (dw0, db0), (hb, lb, valid, lsb))
+    db0 = None if b is None else jnp.zeros(b.shape, jnp.float32)
+    (dw, db), dhb = lax.scan(
+        step, (dw0, db0),
+        (hb, lb, valid, lsb) + _weight_blocks(weights2, nb, block))
     import numpy as np
 
     from jax.dtypes import float0
 
-    return (dhb.reshape(n_pad, d), dw.astype(w.dtype), db.astype(b.dtype),
-            np.zeros(labels2.shape, float0))
+    return (dhb.reshape(n_pad, d), dw.astype(w.dtype),
+            None if b is None else db.astype(b.dtype),
+            np.zeros(labels2.shape, float0),
+            None if weights2 is None else jnp.zeros_like(weights2))
 
 
 _streamed_ce.defvjp(_streamed_ce_fwd, _streamed_ce_bwd)
@@ -251,7 +280,8 @@ _streamed_ce.defvjp(_streamed_ce_fwd, _streamed_ce_bwd)
 
 @scoped("lm_head")
 def streamed_softmax_ce_head(h, w, b, labels, block: int,
-                             compute_dtype=None):
+                             compute_dtype=None, weights=None,
+                             denominator=None):
     """Fused dense head + softmax-CE + accuracy, streamed over row
     blocks: the vocab-axis flash (the round-4 lesson applied to the
     loss). The unstreamed LM head materializes (B, S, V) f32 logits
@@ -269,6 +299,13 @@ def streamed_softmax_ce_head(h, w, b, labels, block: int,
     the head projection. Values and gradients match
     ``softmax_cross_entropy(dense(h, w, b, compute_dtype), labels)``
     + ``accuracy`` to fp tolerance (pinned by tests/test_lm.py).
+    ``b`` may be None (a head without a bias). ``weights`` (of the
+    labels' shape, f32) gives each row its weight in the loss, and
+    ``denominator`` what the weighted sum is divided by (the masked-
+    diffusion loss: a masked position weighs 1/t, the others nought, the
+    denominator is the row count whatever the mask); a row of weight
+    nought is left out of the hit count too. The weights carry no
+    gradient. With neither it is the mean over every row.
     Returns (mean loss f32, accuracy f32).
     """
     d = h.shape[-1]
@@ -287,8 +324,18 @@ def streamed_softmax_ce_head(h, w, b, labels, block: int,
         h2 = jnp.concatenate([h2, jnp.zeros((pad, d), h2.dtype)])
         labels2 = jnp.concatenate(
             [labels2, jnp.zeros((pad,), labels2.dtype)])
+    if weights is None and denominator is None:
+        return _streamed_ce(h2, w, b, labels2, int(block), compute_dtype,
+                            n_valid)
+    weights2 = None
+    if weights is not None:
+        weights2 = lax.stop_gradient(weights.reshape(n_valid))
+        if pad:
+            weights2 = jnp.concatenate(
+                [weights2, jnp.zeros((pad,), weights2.dtype)])
     return _streamed_ce(h2, w, b, labels2, int(block), compute_dtype,
-                        n_valid)
+                        n_valid, weights2,
+                        None if denominator is None else float(denominator))
 
 
 def accuracy(logits, labels):

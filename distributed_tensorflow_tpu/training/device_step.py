@@ -33,17 +33,21 @@ from distributed_tensorflow_tpu.training.train_state import (
 from distributed_tensorflow_tpu.utils.profiling import scoped
 
 _SAMPLE_SALT = 0x5EED  # folds the sampling stream away from the dropout stream
+_NOISE_SALT = 0xD1FF  # folds the objective's noise away from the sampling
 
 
 @scoped("sample_batch")
 def _split_and_sample(state: TrainState, data, batch_size: int,
-                      axis: str | None, augment_fn):
+                      axis: str | None, augment_fn, noise_fn=None):
     """The ONE rng-evolution + on-device batch-draw rule every sampled
     step body shares (``_sampled_step_body`` and the ZeRO device step —
     their bit-identity contract is this function being common, not two
     copies kept in lockstep): returns ``(next_rng, dropout_sub, batch)``.
     ``state.rng`` advances every step, so the sampling key (a salted
-    fold of it) yields a fresh batch each iteration of a scan."""
+    fold of it) yields a fresh batch each iteration of a scan.
+    ``noise_fn(batch, key)`` (a model's ``noise_batch``: the masked-
+    diffusion objective's mask and t) turns the sampled rows into what the
+    loss takes, under a key folded from the sampling key."""
     rng, sub = jax.random.split(state.rng)
     samp = jax.random.fold_in(state.rng, _SAMPLE_SALT)
     if axis is not None:
@@ -56,6 +60,8 @@ def _split_and_sample(state: TrainState, data, batch_size: int,
         # samp is already per-shard (axis fold above), so the salted
         # augment stream decorrelates across shards too
         batch = apply_augment(augment_fn, batch, samp)
+    if noise_fn is not None:
+        batch = noise_fn(batch, jax.random.fold_in(samp, _NOISE_SALT))
     return rng, sub, batch
 
 
@@ -69,8 +75,9 @@ def _sampled_step_body(model, optimizer, batch_size: int, keep_prob: float,
     partitioner splits the compute over the data axis."""
 
     def body(state: TrainState, data):
-        rng, sub, batch = _split_and_sample(state, data, batch_size, axis,
-                                            augment_fn)
+        rng, sub, batch = _split_and_sample(
+            state, data, batch_size, axis, augment_fn,
+            getattr(model, "noise_batch", None))
         if batch_sharding is not None:
             batch = tuple(
                 lax.with_sharding_constraint(b, s)

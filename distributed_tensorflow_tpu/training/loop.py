@@ -96,6 +96,7 @@ def build_model_for(FLAGS, meta: dict):
             moe_experts=int(getattr(FLAGS, "moe_experts", 0)),
             moe_capacity=float(getattr(FLAGS, "moe_capacity", 1.25)),
             moe_aux=float(getattr(FLAGS, "moe_aux", 0.01)),
+            **_lm_arch_kwargs(FLAGS),
         )
     if FLAGS.model == "lm":
         raise ValueError("--model lm consumes token sequences; use "
@@ -120,6 +121,24 @@ def build_model_for(FLAGS, meta: dict):
         compute_dtype=compute_dtype,
         **kwargs,
     )
+
+
+def _lm_arch_kwargs(FLAGS) -> dict:
+    """The LM's further choices, each named by its mechanism; a flag left
+    at its default is not passed, so a parse set without these flags
+    builds the first form."""
+    names = ("norm", "norm_eps", "rope_theta", "num_kv_heads", "head_dim",
+             "qk_norm", "mlp_gated", "biases", "moe_top_k", "moe_ffn_dim",
+             "moe_first_expert", "moe_held_experts", "objective",
+             "diffusion_block", "diffusion_t_min")
+    out = {n: getattr(FLAGS, n) for n in names if hasattr(FLAGS, n)}
+    out["noise_seed"] = int(FLAGS.seed)
+    return out
+
+
+def _reserved_ids(FLAGS) -> int:
+    """Ids the LM data leaves out: the masked-diffusion mask id."""
+    return int(getattr(FLAGS, "objective", "") == "masked_diffusion")
 
 
 def build_training_for(FLAGS, meta: dict):
@@ -225,7 +244,11 @@ def _display_log(step, display, logger, scalars, eff, snt,
         if snt is not None:
             snt.observe(step, display, state=snt_state,
                         stall_s=_booked_stall(eff))
-        logger.log_display(step, display["loss"], display["accuracy"])
+        logger.log_display(
+            step, display["loss"], display["accuracy"],
+            {k: v for k, v in display.items()
+             if k.startswith(("moe_rows", "moe_overflow", "moe_unrouted",
+                              "moe_buffer", "diffusion_"))})
         logger.scalars(step, scalars())
         logger.flush()
         telemetry.get_tracer().flush()
@@ -378,7 +401,8 @@ def _train_once(FLAGS, mode: str = "local") -> TrainResult:
                             dataset=FLAGS.dataset, seed=data_seed,
                             validation_size=FLAGS.validation_size,
                             seq_len=getattr(FLAGS, "seq_len", 256),
-                            vocab_size=getattr(FLAGS, "vocab_size", 64))
+                            vocab_size=getattr(FLAGS, "vocab_size", 64),
+                            reserved_ids=_reserved_ids(FLAGS))
     with trace_span("state_init"):
         model, opt, state = build_training_for(FLAGS, ds.meta)
         # init is dispatched asynchronously: the span ends when the

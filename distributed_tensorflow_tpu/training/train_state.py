@@ -405,14 +405,24 @@ def make_train_step(
     return jax.jit(step_fn)
 
 
+_EVAL_NOISE_SALT = 0xE7A1  # the eval's noise key: fold_in(key(seed), this)
+
+
 def make_eval_step(model):
     """(params, batch, model_state) -> metrics, dropout off — the
     reference's eval run (``MNISTDist.py:181-182``) but usable on the *test*
     set too (the reference never evaluates on test data; the build's
-    targets require it)."""
+    targets require it). A model whose objective noises its batch
+    (``noise_batch``: masked diffusion) is evaluated under a key folded
+    from its ``noise_seed``, the same at every call, so that two evals of
+    one state on one batch agree."""
+    noise_fn = getattr(model, "noise_batch", None)
 
     @jax.jit
     def eval_fn(params, batch, model_state=()):
+        if noise_fn is not None:
+            batch = noise_fn(batch, jax.random.fold_in(
+                jax.random.PRNGKey(model.noise_seed), _EVAL_NOISE_SALT))
         _, aux = loss_and_metrics(model, params, batch, train=False,
                                   model_state=model_state)
         return aux["metrics"]

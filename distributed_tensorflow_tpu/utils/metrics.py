@@ -62,9 +62,14 @@ class MetricsLogger:
 
             telemetry.register_flush(self.flush)
 
-    def log_display(self, step: int, loss, acc):
+    def log_display(self, step: int, loss, acc, counters=None):
+        """The reference's display line, and the display row; ``counters``
+        (a model's own display metrics: the routed layer's rows an expert,
+        the diffusion objective's masked share) ride in the same row."""
         print(reference_log_line(self.job_name, self.task_index, step, loss, acc))
-        self.scalars(step, {"mini_batch_loss": float(loss), "training_accuracy": float(acc)})
+        self.scalars(step, {"mini_batch_loss": float(loss),
+                            "training_accuracy": float(acc),
+                            **(counters or {})})
 
     def scalars(self, step: int, values: dict):
         from distributed_tensorflow_tpu.utils import telemetry

@@ -70,7 +70,7 @@ WATCHDOG_LAST_SPANS = 32
 # in its ``op_name`` path (``benchmark/harness/scopes.py`` imports this
 # tuple, tests/test_scopes.py holds the compiled step to it).
 SCOPES = ("attention", "attn_proj", "mlp", "lm_head", "embed", "optimizer",
-          "sample_batch")
+          "sample_batch", "moe_router", "moe_experts")
 
 
 def _json_safe(v):

@@ -222,3 +222,59 @@ def test_sharded_steps_run_the_fused_attention_on_local_shapes(topo, mode):
     assert sorted(_kernels_under_attention(hlo)) == (
         ["flash_attention_bwd"] * 2 + ["flash_attention_fwd"] * 2)
     assert f"bf16[{local},64,512]" in hlo  # the kernels' (H*B, Dh, S)
+
+
+def test_block_diffusion_attention_compiles_at_the_routed_cells_shapes(one_chip):
+    """``sdar-30b-a3b.train-s4096``: 4 doubled sequences of 8,192 rows, 32
+    query heads reading 4 key/value heads of width 128, the block-diffusion
+    mask at block 4, a 512-key tile: forward and backward lower to the two
+    flash kernels under the ``attention`` scope, dk and dv come back at the
+    key/value heads' shape, and no (B, H, S, block) panel is left."""
+    from distributed_tensorflow_tpu.ops.attention import Mask
+
+    q = jax.ShapeDtypeStruct((4, 8192, 32, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((4, 8192, 4, 128), jnp.bfloat16)
+    mask = Mask("block_diffusion", 4096, 4)
+
+    def loss(q, k, v):
+        out = blockwise_attention(q, k, v, 512, mask=mask)
+        return out.astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        *_on(one_chip, (q, kv, kv))).compile()
+    hlo = compiled.as_text()
+    assert sorted(_kernels_under_attention(hlo)) == [
+        "flash_attention_bwd", "flash_attention_fwd"]
+    assert "[4,32,8192,512]" not in hlo
+    assert [o.shape for o in compiled.out_info] == [
+        (4, 8192, 32, 128), (4, 8192, 4, 128), (4, 8192, 4, 128)]
+
+
+def test_routed_layer_compiles_at_the_cells_widths(one_chip):
+    """The routed cell's expert layer, forward and backward, at its
+    published widths and tiles, on a quarter of its rows (8,192, and a
+    buffer of 4 x: 32,768 rows; the whole takes 45 s to compile):
+    the four grouped products of a pass are Mosaic kernels under the
+    ``moe_experts`` scope (a tile that does not fit VMEM fails here, as
+    the transposed product's 1,024-row tile did), the routing, the gather
+    and the add-back are under ``moe_router``."""
+    from distributed_tensorflow_tpu.ops.moe import routed_experts
+
+    h = jax.ShapeDtypeStruct((1, 8192, 2048), jnp.bfloat16)
+    params = {"router": jax.ShapeDtypeStruct((2048, 128), jnp.float32),
+              "w1": jax.ShapeDtypeStruct((16, 2048, 1536), jnp.float32),
+              "w2": jax.ShapeDtypeStruct((16, 768, 2048), jnp.float32)}
+
+    def loss(h, params):
+        y, aux = routed_experts(h, params, top_k=8, capacity_factor=4.0,
+                                compute_dtype=jnp.bfloat16)
+        return y.astype(jnp.float32).sum() + aux["overflow_rows"]
+
+    hlo = jax.jit(jax.grad(loss, (0, 1))).lower(
+        *_on(one_chip, (h, params))).compile().as_text()
+    paths = re.findall(
+        r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', hlo)
+    # forward: 2 products; backward: 2 for the rows, 2 for the matrices
+    assert len(paths) == 6 and all("moe_experts" in p for p in paths)
+    assert sum("tgmm" in p for p in paths) == 2
+    assert "bf16[32768,2048]" in hlo and "moe_router" in hlo

@@ -1,0 +1,631 @@
+"""The block-diffusion mixture-of-experts configuration at a small size on the
+CPU: the program (``TransformerLM`` under ``--objective masked_diffusion
+--moe_top_k`` through ``make_device_train_step``) held to the plain reference
+of its family (``benchmark/reference/sdar_moe.py``), and the mechanisms it
+brought, each held to something written independently:
+
+- loss, every leaf's gradient and the change after three Adam steps, in f32
+  to 1e-5 and in bf16 within a band that the float8 control fails;
+- the share ties to the whole: the routed layer's output under the four
+  shares of 16 experts adds up to the reference's uncut layer;
+- the block-diffusion mask in scan and (interpreted) kernel form against the
+  dense mask of the family's equation 4, values and gradients; the causal
+  case bit-equal to the form it had before the mask became a description;
+- dropless routing: no row lost however the router piles them up, and the
+  overflow counter rises only past the stated capacity;
+- the streamed head's row weights, the noise's key, the flags' validators.
+
+d 64, 4 query / 2 key-value heads of width 16, 16 experts of width 32 with
+4 a token and 4 held, S 64, L_b 4, 2 layers.
+"""
+
+import contextlib
+import functools
+import json
+import os
+import statistics
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import lax
+
+from benchmark.harness import manifest
+from distributed_tensorflow_tpu import flags
+from distributed_tensorflow_tpu.data.device_data import DeviceData
+from distributed_tensorflow_tpu.data.lm import LMDataSet
+from distributed_tensorflow_tpu.models import get_model
+from distributed_tensorflow_tpu.ops import attention, flash_attention, moe, nn
+from distributed_tensorflow_tpu.ops.attention import (
+    CAUSAL,
+    Mask,
+    blockwise_attention,
+    multi_head_attention,
+)
+from distributed_tensorflow_tpu.training import (
+    create_train_state,
+    get_optimizer,
+)
+from distributed_tensorflow_tpu.training.device_step import (
+    make_device_train_step,
+)
+from distributed_tensorflow_tpu.training.train_state import make_eval_step
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+FAMILY = manifest.load_family(
+    os.path.join(REPO, "benchmark", "reference", "sdar_moe.py"))
+SIZES = {"d_model": 64, "num_heads": 4, "kv_heads": 2, "head_dim": 16,
+         "num_blocks": 2, "router_width": 16, "held_experts": 4,
+         "first_expert": 4, "top_k": 4, "expert_dim": 32, "vocab_size": 300,
+         "norm_eps": 1e-6, "rope_theta": 1e6, "block_length": 4,
+         "t_min": 1e-3, "seq_len": 64}
+SEED, ROWS, LR = 7, 4, 1e-3
+
+
+def small_model(compute_dtype=None, **over):
+    kw = dict(vocab_size=300, seq_len=64, d_model=64, num_heads=4,
+              num_blocks=2, norm="rmsnorm", norm_eps=1e-6, rope_theta=1e6,
+              num_kv_heads=2, head_dim=16, qk_norm=True, mlp_gated=True,
+              biases=False, moe_experts=16, moe_top_k=4, moe_ffn_dim=32,
+              moe_first_expert=4, moe_held_experts=4, moe_capacity=4.0,
+              objective="masked_diffusion", diffusion_block=4, attn_block=16,
+              ce_block=16, remat=True, compute_dtype=compute_dtype,
+              noise_seed=SEED)
+    kw.update(over)
+    return get_model("lm", **kw)
+
+
+def small_data():
+    ds = LMDataSet(4096, 64, 300, seed=SEED, reserved_ids=1)
+    return ds, DeviceData(jnp.asarray(ds.images), jnp.asarray(ds.labels))
+
+
+@functools.lru_cache(maxsize=None)
+def program(dtype_name):
+    """Three steps of the trainer's own compiled step from the seed: the
+    losses, the first gradient's leaves, the leaves' changes."""
+    cd = {"f32": None, "bf16": jnp.bfloat16}[dtype_name]
+    model = small_model(cd)
+    opt = get_optimizer("adam", LR)
+    state = create_train_state(model, opt, seed=SEED)
+    step = make_device_train_step(model, opt, ROWS, chunk=1, donate=False)
+    start, losses, first = state.params, [], None
+    with jax.default_matmul_precision("highest"):
+        for i in range(3):
+            state, metrics = step(state, small_data()[1])
+            losses.append(float(metrics["loss"]))
+            if i == 0:  # Adam's m after one step is (1 - b1) x the gradient
+                first = [np.asarray(m) / 0.1
+                         for m in jax.tree.leaves(state.opt_state["m"])]
+    names = FAMILY.leaf_names(state.params)
+    change = {n: float(jnp.linalg.norm(a - b)) for n, a, b in zip(
+        names, jax.tree.leaves(state.params), jax.tree.leaves(start))}
+    norms = {n: float(np.linalg.norm(g)) for n, g in zip(names, first)}
+    return {"losses": losses, "grad_norms": norms, "change_norms": change,
+            "first_gradient": first, "names": names}
+
+
+@functools.lru_cache(maxsize=None)
+def reference(precision="f32"):
+    batches = FAMILY.first_batches(SEED, 3, SIZES, ROWS, 1)
+    return FAMILY.first_steps(SEED, SIZES, batches, LR, precision=precision,
+                              keep_first_gradient=True)
+
+
+def gradient_shares(other, ref):
+    """|other's first gradient - the reference's| / |the reference's|, by
+    leaf, against the median leaf's norm where a leaf's is smaller."""
+    floor = statistics.median(ref["grad_norms"].values())
+    names = list(ref["grad_norms"])
+    return {n: float(np.linalg.norm(np.asarray(a, np.float32) - b))
+            / max(ref["grad_norms"][n], floor)
+            for n, a, b in zip(names, other, ref["first_gradient"])}
+
+
+# ---- the program against the reference --------------------------------------
+
+def test_f32_program_matches_the_reference_loss_gradients_and_change():
+    prog, ref = program("f32"), reference()
+    assert prog["names"] == list(ref["grad_norms"])  # the same leaves
+    for p, r in zip(prog["losses"], ref["losses"]):
+        assert abs(p - r) / r < 1e-5
+    for n, a, b in zip(prog["names"], prog["first_gradient"],
+                       ref["first_gradient"]):
+        assert np.linalg.norm(a - b) <= 1e-5 * np.linalg.norm(b) + 1e-12, n
+    for n in prog["names"]:
+        assert abs(prog["change_norms"][n] - ref["change_norms"][n]) \
+            <= 1e-4 * ref["change_norms"][n], n
+
+
+def test_bf16_program_keeps_a_band_that_the_float8_control_fails():
+    """bf16 rounds operands and results to 8 bits: the median leaf's
+    gradient differs by about 1 % from the f32 reference's, the worst (a
+    routed leaf: a row whose fourth and fifth expert swap under rounding
+    moves whole rows of its gradient) by 4 %; the float8 control, the
+    reference's own arithmetic three bits shorter, reads 10 % and 33 %."""
+    prog, ref, control = program("bf16"), reference(), reference("fp8")
+    for p, r in zip(prog["losses"], ref["losses"]):
+        assert abs(p - r) / r < 2e-4
+    shares = gradient_shares(prog["first_gradient"], ref)
+    assert statistics.median(shares.values()) < 0.03
+    assert max(shares.values()) < 0.12
+    assert max(abs(prog["change_norms"][n] - ref["change_norms"][n])
+               / ref["change_norms"][n] for n in shares) < 0.05
+    fp8 = gradient_shares(control["first_gradient"], ref)
+    assert statistics.median(fp8.values()) > 0.06
+    assert max(fp8.values()) > 0.12
+
+
+# ---- the share ties to the whole ---------------------------------------------
+
+def _uncut_layer(seed=0):
+    k = jax.random.split(jax.random.key(seed), 4)
+    b = jax.random.normal(k[0], (2, 64, 64))
+    full = {"router": jax.random.normal(k[1], (64, 16)) * 0.5,
+            "w1": jax.random.normal(k[2], (16, 64, 64)) * 0.1,
+            "w2": jax.random.normal(k[3], (16, 32, 64)) * 0.1}
+    return b, full
+
+
+def test_the_four_shares_add_up_to_the_uncut_reference_layer():
+    b, full = _uncut_layer()
+    with jax.default_matmul_precision("highest"):
+        whole = FAMILY.routed_layer(b.reshape(-1, 64), full, SIZES, first=0)
+        total, unrouted = 0.0, []
+        for first in (0, 4, 8, 12):
+            share = {"router": full["router"],
+                     "w1": full["w1"][first:first + 4],
+                     "w2": full["w2"][first:first + 4]}
+            y, aux = moe.routed_experts(b, share, top_k=4, first_expert=first,
+                                        capacity_factor=4.0)
+            assert float(aux["overflow_rows"]) == 0
+            # the reference's share is the program's share
+            mine = FAMILY.routed_layer(b.reshape(-1, 64), share, SIZES,
+                                       first=first)
+            np.testing.assert_allclose(y.reshape(-1, 64), mine, atol=2e-6)
+            total = total + y
+            unrouted.append(float(aux["unrouted_frac"]))
+    np.testing.assert_allclose(total.reshape(-1, 64), whole, atol=5e-6)
+    # every row chose 4 of 16: none is unrouted under all four shares
+    assert all(0.0 < u < 1.0 for u in unrouted)
+
+
+# ---- the mask ----------------------------------------------------------------
+
+@contextlib.contextmanager
+def kernels_interpreted():
+    """The dispatch as a TPU lowering would make it, the kernels run by
+    Pallas's generic interpreter (as ``tests/test_flash_kernel.py``)."""
+    def clear():
+        flash_attention.flash_forward.clear_cache()
+        flash_attention.flash_backward.clear_cache()
+
+    by_platform = attention._by_platform
+    pallas_call = flash_attention.pl.pallas_call
+    clear()
+    attention._by_platform = lambda fused, scan, *args: fused(*args)
+    flash_attention.pl.pallas_call = functools.partial(pallas_call,
+                                                       interpret=True)
+    try:
+        yield
+    finally:
+        attention._by_platform = by_platform
+        flash_attention.pl.pallas_call = pallas_call
+        clear()
+
+
+def _qkvg(s2, heads, kv_heads, dh, dtype, seed=0):
+    k = jax.random.split(jax.random.key(seed), 4)
+    shapes = [(2, s2, heads, dh), (2, s2, kv_heads, dh), (2, s2, kv_heads, dh),
+              (2, s2, heads, dh)]
+    return [jax.random.normal(key, shape).astype(dtype)
+            for key, shape in zip(k, shapes)]
+
+
+def _value_and_grads(fn, q, k, v, g):
+    out, vjp = jax.vjp(fn, q, k, v)
+    return (out,) + vjp(g.astype(out.dtype))
+
+
+def _dense_by_equation_4(q, k, v, g, seq, lb):
+    """Dense softmax attention under the reference's own mask, f32."""
+    mask = FAMILY.dense_mask(seq, lb)
+
+    def fn(q, k, v):
+        group = q.shape[2] // k.shape[2]
+        k, v = jnp.repeat(k, group, 2), jnp.repeat(v, group, 2)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+        p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+    return _value_and_grads(fn, *(x.astype(jnp.float32) for x in (q, k, v)),
+                            g)
+
+
+def _rel(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return np.linalg.norm(a - b) / np.linalg.norm(b)
+
+
+@pytest.mark.parametrize("seq,lb", [(64, 4), (128, 128), (96, 12)])
+def test_mask_description_is_equation_4(seq, lb):
+    mask = Mask("block_diffusion", seq, lb)
+    rows = jnp.arange(2 * seq)
+    got = mask.allowed(rows[:, None], rows[None, :])
+    assert np.array_equal(np.asarray(got), np.asarray(
+        FAMILY.dense_mask(seq, lb)))
+    assert int(got.sum()) == seq * seq + seq * lb  # a quarter, and L_b S
+
+
+@pytest.mark.parametrize("seq,lb,tq,tk", [
+    (256, 4, 128, 128), (256, 128, 128, 128), (512, 128, 256, 256),
+    (512, 4, 256, 128), (512, 64, 128, 256)])
+def test_tiles_and_index_maps_follow_the_dense_mask(seq, lb, tq, tk):
+    """The three-way test of a tile and the two index maps, against the
+    dense mask cut into the same tiles."""
+    mask = Mask("block_diffusion", seq, lb)
+    dense = np.asarray(FAMILY.dense_mask(seq, lb))
+    nq, nk = 2 * seq // tq, 2 * seq // tk
+    runs = np.zeros((nq, nk), bool)
+    for i in range(nq):
+        for j in range(nk):
+            tile = dense[i * tq:(i + 1) * tq, j * tk:(j + 1) * tk]
+            visible, some = mask.tile(i * tq, (i + 1) * tq - 1,
+                                      j * tk, (j + 1) * tk - 1)
+            assert (bool(visible), bool(some)) == (tile.all(), tile.any())
+            runs[i, j] = tile.any()
+
+    def held(x, running):
+        later = [r for r in running if r >= x]
+        return later[0] if later else running[-1]
+
+    for i in range(nq):
+        running = list(np.flatnonzero(runs[i]))
+        assert [int(mask.next_key_tile(i, j, tq, tk)) for j in range(nk)] \
+            == [held(j, running) for j in range(nk)]
+    for j in range(nk):
+        running = list(np.flatnonzero(runs[:, j]))
+        assert [int(mask.next_query_tile(j, i, tq, tk)) for i in range(nq)] \
+            == [held(i, running) for i in range(nq)]
+
+
+def test_eighty_of_the_cells_256_tiles_run():
+    mask = Mask("block_diffusion", 4096, 4)
+    i, j = np.arange(16)[:, None] * 512, np.arange(16)[None, :] * 512
+    visible, runs = mask.tile(i, i + 511, j, j + 511)
+    # 28 + 8 noised -> clean, 28 + 8 clean -> clean, 8 on the noised diagonal
+    assert (int(np.sum(runs)), int(np.sum(visible))) == (80, 56)
+
+
+@pytest.mark.parametrize("seq,lb,tile", [(256, 4, 128), (256, 128, 128),
+                                         (512, 128, 256), (192, 12, 64)])
+def test_scan_form_matches_the_dense_mask(seq, lb, tile):
+    mask = Mask("block_diffusion", seq, lb)
+    q, k, v, g = _qkvg(2 * seq, 4, 2, 16, jnp.float32)
+    want = _dense_by_equation_4(q, k, v, g, seq, lb)
+    got = _value_and_grads(
+        lambda q, k, v: blockwise_attention(q, k, v, tile, mask=mask),
+        q, k, v, g)
+    dense = _value_and_grads(
+        lambda q, k, v: multi_head_attention(q, k, v, mask=mask), q, k, v, g)
+    for a, b, c in zip(got, dense, want):
+        assert _rel(a, c) < 1e-5 and _rel(b, c) < 1e-5
+
+
+# L_b 4: every tile holds many blocks; L_b 128 at a 128 tile: the blocks are
+# the tiles; L_b 128 at a 256 tile: the block boundary crosses the tile
+@pytest.mark.parametrize("seq,lb,tile", [(256, 4, 128), (256, 128, 128),
+                                         (512, 128, 256)])
+def test_kernel_form_matches_the_dense_mask(seq, lb, tile):
+    mask = Mask("block_diffusion", seq, lb)
+    q, k, v, g = _qkvg(2 * seq, 4, 2, 64, jnp.bfloat16)
+    assert attention.fusable(q, k, v, tile, mask)
+    want = _dense_by_equation_4(q, k, v, g, seq, lb)
+    with kernels_interpreted():
+        got = _value_and_grads(
+            lambda q, k, v: blockwise_attention(q, k, v, tile, mask=mask),
+            q, k, v, g)
+    assert got[2].shape == k.shape and got[3].shape == v.shape
+    for a, b in zip(got, want):
+        assert _rel(a, b) < 1e-2  # bf16 keeps 8 bits
+
+
+@pytest.mark.parametrize("why,seq,lb,tile,dtype", [
+    ("a block that is no power of two", 384, 96, 128, jnp.bfloat16),
+    ("a tile that straddles the halves", 384, 4, 256, jnp.bfloat16),
+    ("f32 operands", 256, 4, 128, jnp.float32)])
+def test_what_the_kernels_cannot_cut_takes_the_scan(why, seq, lb, tile, dtype):
+    q, k, v, _ = _qkvg(2 * seq, 4, 2, 64, dtype)
+    assert not attention.fusable(q, k, v, tile,
+                                 Mask("block_diffusion", seq, lb)), why
+
+
+def _scan_as_it_was(q, k, v, block):
+    """The causal forward scan as the repository had it before the mask
+    became a description (PR 26), written out: the case the new form must
+    reproduce to the bit."""
+    b, sq, h, dh = q.shape
+    n = k.shape[1] // block
+    scale = 1.0 / jnp.sqrt(jnp.float32(dh))
+    qf, rows = q.astype(jnp.float32), jnp.arange(sq)
+    kb = jnp.moveaxis(k.reshape(b, n, block, h, dh), 1, 0)
+    vb = jnp.moveaxis(v.reshape(b, n, block, h, dh), 1, 0)
+
+    def step(carry, inp):
+        o, m, l = carry
+        t, k_blk, v_blk = inp
+        cols = t * block + jnp.arange(block)
+        s = jnp.einsum("bqhd,bkhd->bhqk", qf, k_blk.astype(jnp.float32))
+        s = jnp.where((cols[None, :] <= rows[:, None])[None, None],
+                      s * scale, -jnp.inf)
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        corr, p = jnp.exp(m - m_new), jnp.exp(s - m_new[..., None])
+        l = l * corr + p.sum(axis=-1)
+        o = o * corr[..., None] + jnp.einsum(
+            "bhqk,bkhd->bhqd", p, v_blk.astype(jnp.float32))
+        return (o, m_new, l), None
+
+    (o, m, l), _ = lax.scan(
+        step, (jnp.zeros((b, h, sq, dh), jnp.float32),
+               jnp.full((b, h, sq), -jnp.inf, jnp.float32),
+               jnp.zeros((b, h, sq), jnp.float32)), (jnp.arange(n), kb, vb))
+    return jnp.einsum("bhqd->bqhd", o / l[..., None]).astype(q.dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_the_causal_case_is_bit_equal_to_what_it_was(dtype):
+    q, k, v, g = _qkvg(128, 2, 2, 16, dtype)
+    as_flag = _value_and_grads(
+        lambda q, k, v: blockwise_attention(q, k, v, 32, causal=True),
+        q, k, v, g)
+    as_mask = _value_and_grads(
+        lambda q, k, v: blockwise_attention(q, k, v, 32, mask=CAUSAL),
+        q, k, v, g)
+    for a, b in zip(as_flag, as_mask):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    assert np.array_equal(np.asarray(as_flag[0]),
+                          np.asarray(jax.jit(_scan_as_it_was, static_argnums=3)(
+                              q, k, v, 32)))
+    # the kernels are handed the causal description with today's arguments
+    assert Mask("causal").next_key_tile(3, 9, 512, 512) == 3
+    assert Mask("causal").next_query_tile(5, 2, 512, 512) == 5
+
+
+# ---- dropless ----------------------------------------------------------------
+
+def test_a_router_forced_onto_one_expert_loses_no_row():
+    b, full = _uncut_layer(1)
+    # every row's largest probability is expert 5's, by a wide margin
+    router = full["router"].at[:, 5].set(0.0)
+    b = b.at[..., 0].set(50.0)
+    router = router.at[0, 5].set(10.0)
+    share = {"router": router, "w1": full["w1"][4:8], "w2": full["w2"][4:8]}
+    rows = b.shape[0] * b.shape[1]
+    with jax.default_matmul_precision("highest"):
+        want = FAMILY.routed_layer(b.reshape(-1, 64), share, SIZES, first=4)
+        y, aux = moe.routed_experts(b, share, top_k=4, first_expert=4,
+                                    capacity_factor=4.0)
+    assert float(aux["rows_per_expert_max"]) == rows  # all of them on one
+    assert float(aux["overflow_rows"]) == 0
+    np.testing.assert_allclose(y.reshape(-1, 64), want, rtol=2e-5, atol=2e-5)
+
+
+def test_the_overflow_counter_rises_only_past_the_stated_capacity(
+        monkeypatch):
+    b, full = _uncut_layer(2)
+    share = {"router": full["router"], "w1": full["w1"][:4],
+             "w2": full["w2"][:4]}
+    rows = b.shape[0] * b.shape[1]
+    _, roomy = moe.routed_experts(b, share, top_k=4, capacity_factor=4.0)
+    pairs = 4 * float(roomy["rows_per_expert_mean"])
+    assert float(roomy["overflow_rows"]) == 0
+    assert float(roomy["buffer_fill"]) == pytest.approx(
+        pairs / moe.routed_capacity(rows, 4, 4, 16, 4.0))
+    # the buffer is never smaller than one row tile: make the tile small
+    monkeypatch.setattr(moe, "ROW_TILE", 8)
+    cap = moe.routed_capacity(rows, 4, 4, 16, 0.5)
+    _, tight = moe.routed_experts(b, share, top_k=4, capacity_factor=0.5)
+    assert cap < pairs and float(tight["overflow_rows"]) == pairs - cap
+    assert float(tight["buffer_fill"]) == pytest.approx(pairs / cap)
+
+
+def test_an_overflow_is_a_failed_step_not_a_silent_drop(monkeypatch):
+    monkeypatch.setattr(moe, "ROW_TILE", 8)
+    model = small_model(moe_capacity=0.25)
+    params = model.init(jax.random.key(0))
+    x = jax.random.randint(jax.random.key(1), (4, 64), 0, 299)
+    batch = model.noise_batch((x, x), jax.random.key(2))
+    loss, metrics = jax.jit(functools.partial(
+        model.loss_with_metrics, train=True))(params, *batch)
+    assert float(metrics["moe_overflow_rows"]) > 0 and np.isnan(float(loss))
+    assert float(metrics["moe_buffer_fill_max"]) > 1
+    roomy = small_model()
+    loss, metrics = jax.jit(functools.partial(
+        roomy.loss_with_metrics, train=True))(params, *batch)
+    assert float(metrics["moe_overflow_rows"]) == 0 and np.isfinite(float(loss))
+    assert float(metrics["moe_buffer_fill_max"]) <= 1
+
+
+def test_capacity_is_a_multiple_of_the_row_tile_and_never_past_every_pair():
+    assert moe.routed_capacity(32768, 8, 16, 128, 1.25) == 40960
+    assert moe.routed_capacity(32768, 8, 16, 128, 100.0) == 32768 * 8
+    assert moe.routed_capacity(16, 2, 4, 8, 1.0) == moe.ROW_TILE
+
+
+# ---- the streamed head, the noise, the flags ---------------------------------
+
+def test_the_streamed_heads_row_weights_and_denominator():
+    k = jax.random.split(jax.random.key(0), 4)
+    h = jax.random.normal(k[0], (3, 20, 16))
+    w = jax.random.normal(k[1], (16, 50)) * 0.3
+    labels = jax.random.randint(k[2], (3, 20), 0, 50)
+    weights = jnp.where(jax.random.uniform(k[3], (3, 20)) < 0.5,
+                        jax.random.uniform(k[0], (3, 20)) * 5, 0.0)
+
+    def direct(h, w):
+        logp = jax.nn.log_softmax(h @ w, axis=-1)
+        own = jnp.take_along_axis(logp, labels[..., None], -1)[..., 0]
+        return -jnp.sum(own * weights) / 60.0
+
+    def streamed(h, w):
+        return nn.streamed_softmax_ce_head(h, w, None, labels, block=16,
+                                           weights=weights,
+                                           denominator=60.0)[0]
+
+    np.testing.assert_allclose(streamed(h, w), direct(h, w), rtol=1e-6)
+    for a, b in zip(jax.grad(streamed, (0, 1))(h, w),
+                    jax.grad(direct, (0, 1))(h, w)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
+    # weights of one and the row count: today's function, to the bit
+    bias = jnp.zeros((50,))
+    plain = nn.streamed_softmax_ce_head(h, w, bias, labels, block=16)
+    ones = nn.streamed_softmax_ce_head(h, w, bias, labels, block=16,
+                                       weights=jnp.ones((3, 20)),
+                                       denominator=60.0)
+    assert [float(x) for x in plain] == [float(x) for x in ones]
+
+
+def test_the_noise_comes_from_the_key_and_the_eval_agrees_with_itself():
+    model = small_model()
+    ds, _ = small_data()
+    assert ds.images.max() == 298  # the mask id, 299, is no data id
+    x = jnp.asarray(ds.images[:8])
+    a = model.noise_batch((x, x), jax.random.key(3))
+    b = model.noise_batch((x, x), jax.random.key(3))
+    c = model.noise_batch((x, x), jax.random.key(4))
+    assert np.array_equal(a[0], b[0]) and not np.array_equal(a[0], c[0])
+    noised, clean = np.asarray(a[0][:, :64]), np.asarray(a[0][:, 64:])
+    masked = np.asarray(a[1]) > 0
+    assert np.array_equal(clean, np.asarray(x))
+    assert np.array_equal(noised == model.mask_token, masked)
+    assert 0.3 < masked.mean() < 0.7  # t ~ U(0.001, 1): a half on average
+    t = 1.0 / np.asarray(a[1])[masked]
+    assert t.min() >= 1e-3 and t.max() <= 1.0
+    # a block shares its t: the weights of a block's masked positions agree
+    blocks = np.asarray(a[1]).reshape(8, 16, 4)
+    assert all(len(set(np.round(blk[blk > 0], 5))) <= 1
+               for row in blocks for blk in row)
+    eval_fn = make_eval_step(model)
+    params = model.init(jax.random.key(0))
+    y = jnp.asarray(ds.labels[:8])
+    one, two = eval_fn(params, (x, y)), eval_fn(params, (x, y))
+    assert {k: float(v) for k, v in one.items()} == \
+        {k: float(v) for k, v in two.items()}
+    assert set(one) >= {"loss", "accuracy", "diffusion_masked_frac",
+                        "moe_rows_per_expert_max", "moe_rows_per_expert_mean",
+                        "moe_overflow_rows", "moe_unrouted_frac",
+                        "moe_buffer_fill_max"}
+    # about (1 - 4/16)^4 = 0.32 of the rows choose none of the four held
+    assert 0.2 < float(one["moe_unrouted_frac"]) < 0.45
+
+
+def test_todays_flags_build_todays_tree():
+    first = get_model("lm", vocab_size=50, seq_len=16, d_model=32,
+                      num_heads=2, num_blocks=1)
+    assert first.arch is None and not hasattr(first, "noise_batch")
+    tree = jax.tree.map(lambda x: x.shape, first.init(jax.random.key(0)))
+    assert tree == {
+        "tok": (50, 32), "pos": (16, 32),
+        "blocks": [{"ln1_g": (32,), "ln1_b": (32,), "qkv": (32, 3, 2, 16),
+                    "proj": (32, 32), "ln2_g": (32,), "ln2_b": (32,),
+                    "mlp_in": {"w": (32, 128), "b": (128,)},
+                    "mlp_out": {"w": (128, 32), "b": (32,)}}],
+        "ln_f": {"g": (32,), "b": (32,)},
+        "head": {"w": (32, 50), "b": (50,)}}
+
+
+@pytest.fixture
+def fresh_flags():
+    flags.define_reference_flags()
+    flags.FLAGS._reset()
+    yield
+    flags.FLAGS._reset()
+
+
+@pytest.mark.parametrize("argv,needle", [
+    (["--norm=batchnorm"], "--norm"),
+    (["--objective=denoise"], "--objective"),
+    (["--num_heads=4", "--num_kv_heads=3"], "must divide --num_heads"),
+    (["--head_dim=15"], "--head_dim"),
+    (["--moe_top_k=2"], "needs --moe_experts"),
+    (["--moe_experts=8", "--moe_top_k=2"], "--mlp_gated"),
+    (["--moe_experts=8", "--moe_top_k=2", "--mlp_gated",
+      "--moe_first_expert=6", "--moe_held_experts=4"], "reach past"),
+    (["--moe_experts=8", "--moe_held_experts=4"], "silently change"),
+    (["--moe_experts=8", "--moe_top_k=2", "--mlp_gated", "--expert_parallel",
+      "--model_axis=2"], "--expert_parallel"),
+    (["--objective=masked_diffusion", "--dataset=lm"], "--device_data"),
+    (["--objective=masked_diffusion", "--dataset=lm", "--device_data",
+      "--seq_len=64", "--diffusion_block=5"], "must divide --seq_len"),
+    (["--objective=masked_diffusion", "--dataset=lm", "--device_data",
+      "--zero=1"], "--zero"),
+    (["--diffusion_t_min=0"], "--diffusion_t_min"),
+    (["--rope_theta=-1"], "--rope_theta"),
+])
+def test_the_new_flags_validators_reject_at_parse_time(fresh_flags, argv,
+                                                       needle):
+    with pytest.raises(ValueError) as e:
+        flags.FLAGS._parse(argv)
+    assert needle in str(e.value)
+
+
+def test_the_configurations_flags_parse(fresh_flags):
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "sdar-30b-a3b.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(REPO, "benchmark", "traffic",
+                           "train-s4096.json")) as f:
+        mix = json.load(f)
+    given = FAMILY.trainer_flags(config, mix)
+    flags.FLAGS._parse(
+        [f"--{k}={str(v).lower() if isinstance(v, bool) else v}"
+         for k, v in given.items()]
+        + ["--model=lm", "--dataset=lm", "--device_data", "--seq_len=4096"])
+    assert flags.FLAGS.moe_held_experts == 16 and flags.FLAGS.qk_norm is True
+    sizes = FAMILY.sizes(config, mix)
+    assert FAMILY.total_params(sizes) == 550_984_960
+    parts = FAMILY.scope_flops_per_token(sizes)
+    assert sum(parts.values()) == FAMILY.train_flops_per_token(sizes)
+    assert parts["attention"] == 12 * 5 * 32 * 128 * (4096 + 4)
+    assert round(FAMILY.train_flops_per_token(sizes) / 1e9, 2) == 2.67
+
+
+def test_the_trainer_runs_the_configuration_from_flags_alone(tmp_path):
+    """``mnist_dist.py`` -> ``training.loop.train`` ->
+    ``make_device_train_step``, every choice a flag named by its mechanism;
+    the display row carries the routed layer's and the objective's
+    counters."""
+    argv = ["--model=lm", "--dataset=lm", "--device_data", "--mode=local",
+            "--seq_len=64", "--vocab_size=300", "--d_model=64",
+            "--num_heads=4", "--num_blocks=2", "--batch_size=4",
+            "--norm=rmsnorm", "--norm_eps=1e-6", "--rope_theta=1000000",
+            "--num_kv_heads=2", "--head_dim=16", "--qk_norm", "--mlp_gated",
+            "--biases=false", "--moe_experts=16", "--moe_top_k=4",
+            "--moe_ffn_dim=32", "--moe_first_expert=4",
+            "--moe_held_experts=4", "--moe_capacity=4",
+            "--objective=masked_diffusion", "--diffusion_block=4",
+            "--attn_block=16", "--ce_block=16", "--remat",
+            "--optimizer=adam", "--learning_rate=0.001", "--training_iter=6",
+            "--display_step=3", "--device_chunk=1", "--test_eval=false",
+            f"--logdir={tmp_path}/logs", f"--data_dir={tmp_path}/data"]
+    p = subprocess.run(
+        [sys.executable, os.path.join(REPO, "mnist_dist.py"), *argv],
+        capture_output=True, text=True, cwd=REPO, timeout=300,
+        env=dict(os.environ, JAX_PLATFORMS="cpu",
+                 XLA_FLAGS="--xla_force_host_platform_device_count=1"))
+    assert p.returncode == 0, p.stderr[-3000:]
+    with open(tmp_path / "logs" / "metrics.jsonl") as f:
+        rows = [json.loads(line) for line in f]
+    display = [r for r in rows if "mini_batch_loss" in r]
+    assert [r["step"] for r in display] == [0, 3]
+    for r in display:
+        assert np.isfinite(r["mini_batch_loss"])
+        assert r["moe_overflow_rows"] == 0 and 0 < r["moe_buffer_fill_max"] <= 1
+        assert r["moe_rows_per_expert_max"] >= r["moe_rows_per_expert_mean"] > 0
+        assert 0.2 < r["moe_unrouted_frac"] < 0.45
+        assert 0.3 < r["diffusion_masked_frac"] < 0.7
+    # the eval's noise has one key: the display loss moves with the weights
+    assert display[0]["mini_batch_loss"] != display[1]["mini_batch_loss"]

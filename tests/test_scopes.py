@@ -1,7 +1,9 @@
 """The scope catalog (``telemetry.SCOPES``) on the compiled step: every
 catalogued ``jax.named_scope`` reaches the ``op_name`` of some operation of
 one ``TransformerLM`` train step, with ``--remat`` and without, for dense and
-blockwise attention; a scope changes metadata only, so the step's outputs
+blockwise attention, with the dense MLP (``mlp``) or the dropless routed
+layer in its place (``moe_router``, ``moe_experts``, under the
+masked-diffusion objective); a scope changes metadata only, so the step's outputs
 are bit-equal with ``jax.named_scope`` patched to a no-op; and the program
 opens no scope that the catalog does not hold."""
 
@@ -28,13 +30,28 @@ from distributed_tensorflow_tpu.utils import profiling
 from distributed_tensorflow_tpu.utils.telemetry import SCOPES
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-FORMS = [(remat, block) for remat in (False, True) for block in (None, 16)]
+ROUTED = {"moe_router", "moe_experts"}
+FORMS = [(remat, block, False) for remat in (False, True)
+         for block in (None, 16)] + [(False, 16, True), (True, 16, True)]
 
 
-def build(remat, attn_block):
+def scopes_of(routed) -> set:
+    """The catalog's names a form opens: the feed-forward is the dense
+    MLP or the routed layer, never both."""
+    return set(SCOPES) - ({"mlp"} if routed else ROUTED)
+
+
+def build(remat, attn_block, routed=False):
+    kw = {}
+    if routed:
+        kw = dict(norm="rmsnorm", rope_theta=1e4, num_kv_heads=1, head_dim=16,
+                  qk_norm=True, mlp_gated=True, biases=False, moe_experts=8,
+                  moe_top_k=2, moe_ffn_dim=32, moe_held_experts=4,
+                  moe_capacity=2.0, objective="masked_diffusion",
+                  diffusion_block=4)
     model = get_model("lm", vocab_size=300, seq_len=64, d_model=32,
                       num_heads=2, num_blocks=2, compute_dtype=jnp.bfloat16,
-                      attn_block=attn_block, remat=remat, ce_block=16)
+                      attn_block=attn_block, remat=remat, ce_block=16, **kw)
     opt = get_optimizer("adam", 1e-3)
     state = create_train_state(model, opt, seed=0)
     tokens = np.random.default_rng(0).integers(0, 300, (32, 65))
@@ -50,14 +67,18 @@ def scopes_in(op_name: str) -> set:
     return {e for e in re.findall(r"[A-Za-z_]\w*", op_name) if e in SCOPES}
 
 
-@pytest.mark.parametrize("remat,attn_block", FORMS)
+@pytest.mark.parametrize("remat,attn_block,routed", FORMS)
 def test_every_scope_of_the_catalog_is_in_the_compiled_step(remat,
-                                                            attn_block):
-    step, state, data = build(remat, attn_block)
+                                                            attn_block,
+                                                            routed):
+    step, state, data = build(remat, attn_block, routed)
     text = step.lower(state, data).compile().as_text()
     paths = set(re.findall(r'op_name="([^"]*)"', text))
     found = set().union(*(scopes_in(p) for p in paths))
-    assert found == set(SCOPES)
+    assert found == scopes_of(routed)
+    if routed:  # the grouped products' backward carries its name too
+        assert any("transpose(" in p and "moe_experts" in scopes_in(p)
+                   for p in paths)
     # the backward pass carries the names too, through jvp and transpose
     assert any("transpose(" in p and "attention" in scopes_in(p)
                for p in paths)
@@ -68,10 +89,10 @@ def test_every_scope_of_the_catalog_is_in_the_compiled_step(remat,
     assert any("rematted_computation" in p for p in paths) == remat
 
 
-@pytest.mark.parametrize("remat,attn_block", FORMS)
-def test_a_scope_changes_no_number(remat, attn_block, monkeypatch):
+@pytest.mark.parametrize("remat,attn_block,routed", FORMS)
+def test_a_scope_changes_no_number(remat, attn_block, routed, monkeypatch):
     def outputs():
-        step, state, data = build(remat, attn_block)
+        step, state, data = build(remat, attn_block, routed)
         new_state, metrics = step(state, data)
         return jax.device_get((new_state.params, new_state.opt_state,
                                metrics))
@@ -86,7 +107,7 @@ def test_a_scope_changes_no_number(remat, attn_block, monkeypatch):
 
     monkeypatch.setattr(jax, "named_scope", no_scope)
     without = outputs()
-    assert set(opened) == set(SCOPES)  # the patch was what the step opened
+    assert set(opened) == scopes_of(routed)  # the patch was what the step opened
     for a, b in zip(jax.tree.leaves(with_scopes), jax.tree.leaves(without)):
         assert np.array_equal(np.asarray(a), np.asarray(b))
 
