@@ -411,7 +411,13 @@ def define_reference_flags():
     DEFINE_boolean("remat", False, "Rematerialize each transformer block "
                    "in the backward pass (jax.checkpoint): activation "
                    "memory drops to one block's worth at the cost of "
-                   "one extra forward — the standard long-context trade")
+                   "one extra forward — the standard long-context trade. "
+                   "Kept besides each block's input: the output and the "
+                   "logsumexp of --attn_block's attention (batch x seq x "
+                   "d_model of the compute dtype + batch x heads x seq "
+                   "f32 a block), so that the extra forward does not run "
+                   "the attention again; dense and ring attention keep "
+                   "nothing")
     DEFINE_integer("zero", 0, "ZeRO-sharded data parallelism (sync DP "
                    "only, parallel/zero.py): 0 = replicated (default), "
                    "1 = shard the optimizer state 1/D per data rank "

@@ -31,11 +31,13 @@ from distributed_tensorflow_tpu.models.cnn import truncated_normal_init
 from distributed_tensorflow_tpu.models.registry import register_model
 from distributed_tensorflow_tpu.ops import nn
 from distributed_tensorflow_tpu.ops.attention import (
+    REMAT_KEPT,
     Mask,
     blockwise_attention,
     multi_head_attention,
     ring_attention,
 )
+from distributed_tensorflow_tpu.utils import telemetry
 from distributed_tensorflow_tpu.utils.profiling import scope, scoped
 
 
@@ -263,6 +265,34 @@ def _transformer_block_moe(h, blk, attn_fn, cd, capacity_factor,
         return h + y, aux["lb_loss"]
 
 
+def _remat(fn, static_argnums):
+    """``fn`` (a block) under ``--remat``: ``jax.checkpoint`` whose backward
+    pass recomputes the block from its input, all but the values that
+    ``ops/attention.py`` names (``REMAT_KEPT``: a blockwise attention's
+    ``out`` and logsumexp). Those are kept, because a whole kernel call (or
+    scan) stands behind them and they are small beside it; q, k and v come
+    back from the projections, matmuls that run near the peak. A block
+    whose attention is dense or the ring names nothing and keeps nothing.
+    The one wrapper of every site that rematerializes a block.
+
+    The first block that shows the policy every name records the
+    ``remat_saved`` instant: the names and the bytes a block they cost."""
+    named = jax.checkpoint_policies.save_only_these_names(*REMAT_KEPT)
+    kept = {}
+
+    def policy(prim, *avals, **params):
+        keep = named(prim, *avals, **params)
+        if keep and params["name"] not in kept:
+            kept[params["name"]] = avals[0].size * avals[0].dtype.itemsize
+            if len(kept) == len(REMAT_KEPT):
+                telemetry.get_tracer().record_instant(
+                    "remat_saved", names=sorted(kept),
+                    bytes_per_block=sum(kept.values()))
+        return keep
+
+    return jax.checkpoint(fn, static_argnums=static_argnums, policy=policy)
+
+
 @register_model("transformer")
 class MiniTransformer:
     """Row-sequence transformer classifier.
@@ -357,8 +387,7 @@ class MiniTransformer:
             attn = multi_head_attention
         blk_fn = _transformer_block
         if self.remat:
-            blk_fn = jax.checkpoint(_transformer_block,
-                                    static_argnums=(2, 3))
+            blk_fn = _remat(_transformer_block, (2, 3))
         for blk in params["blocks"]:
             h = blk_fn(h, blk, attn, cd)
 
@@ -402,9 +431,12 @@ class TransformerLM:
     - ``seq_axis="model"``: RING attention over the mesh axis; tokens
       sharded, k/v blocks rotating on ICI — the multi-chip long-context
       path (must run inside the SP shard_map step).
-    ``remat=True`` wraps each block in ``jax.checkpoint`` — activation
-    memory drops from O(num_blocks * S * d) to O(S * d) + one block's
-    recompute, the standard trade for long sequences.
+    ``remat=True`` wraps each block in ``jax.checkpoint`` (``_remat``) —
+    activation memory drops from O(num_blocks * S * d) to O(S * d) + one
+    block's recompute, the standard trade for long sequences. Of a block
+    with ``attn_block`` the attention's out and logsumexp are kept too
+    (B S H Dh of the compute dtype + B H S f32 a block), so the recompute
+    runs every part of the block but the attention's forward.
 
     ``ce_block=N`` streams the LOSS head the same way ``attn_block``
     streams attention: the train/eval steps route through
@@ -660,8 +692,7 @@ class TransformerLM:
         if self.moe_top_k:
             routed = _transformer_block_routed
             if self.remat:
-                routed = jax.checkpoint(routed,
-                                        static_argnums=(2, 3, 4, 6, 7, 8))
+                routed = _remat(routed, (2, 3, 4, 6, 7, 8))
             layers = []
             for blk in params["blocks"]:
                 h, aux = routed(h, blk, attn, cd, arch, ids, self.moe_top_k,
@@ -679,8 +710,7 @@ class TransformerLM:
         elif self.moe_experts:
             moe_fn = _transformer_block_moe
             if self.remat:
-                moe_fn = jax.checkpoint(_transformer_block_moe,
-                                        static_argnums=(2, 3, 4, 5))
+                moe_fn = _remat(_transformer_block_moe, (2, 3, 4, 5))
             for blk in params["blocks"]:
                 h, lb = moe_fn(h, blk, attn, cd, self.moe_capacity,
                                self.moe_axis)
@@ -688,8 +718,7 @@ class TransformerLM:
         else:
             blk_fn = _transformer_block
             if self.remat:
-                blk_fn = jax.checkpoint(_transformer_block,
-                                        static_argnums=(2, 3, 4))
+                blk_fn = _remat(_transformer_block, (2, 3, 4))
             for blk in params["blocks"]:
                 h = blk_fn(h, blk, attn, cd, arch, ids)
 

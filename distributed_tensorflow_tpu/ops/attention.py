@@ -42,6 +42,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from distributed_tensorflow_tpu.utils.profiling import lowering_instant, scoped
 
@@ -300,9 +301,12 @@ def blockwise_attention(q, k, v, block_size: int, causal: bool = False,
     total, no better than dense (measured: WORSE, round-4 sweep) — so
     instead only (q, k, v, out, logsumexp) are saved and each block's
     probabilities are RECOMPUTED from them while dq, dk and dv
-    accumulate. This is the single-chip half of the long-context story;
-    ``ring_attention`` is the same recurrence with blocks arriving over
-    the mesh.
+    accumulate. Of the five, ``out`` and the logsumexp carry names
+    (``REMAT_KEPT``) for a checkpoint policy: a rematerialized block
+    (``--remat``) keeps those two and makes q, k, v again, so its backward
+    pass does not run this forward a second time. This is the single-chip
+    half of the long-context story; ``ring_attention`` is the same
+    recurrence with blocks arriving over the mesh.
 
     Two implementations of that one algorithm, chosen by what the code
     can observe (``_pick``):
@@ -450,8 +454,18 @@ def _blockwise(q, k, v, block_size, mask):
     return _forward(q, k, v, block_size, mask)[0]
 
 
+# what a checkpoint policy may keep of a blockwise attention call, so that
+# the backward pass of a rematerialized block does not run the forward
+# (a whole kernel call) again: models/transformer.py:_remat keeps these
+REMAT_KEPT = ("attention_out", "attention_lse")
+
+
 def _blockwise_fwd(q, k, v, block_size, mask):
     out, lse = _forward(q, k, v, block_size, mask)
+    # named once, in the form the backward reads ((B, S, H, Dh) and
+    # (B, H, S)): not also the kernel's (H B, Dh, S) results under the
+    # copies, or a layer would keep two buffers
+    out, lse = map(checkpoint_name, (out, lse), REMAT_KEPT)
     return out, (q, k, v, out, lse)
 
 

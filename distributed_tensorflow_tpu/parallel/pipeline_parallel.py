@@ -66,6 +66,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from distributed_tensorflow_tpu.models.transformer import (
     _layernorm,
+    _remat,
     _transformer_block,
 )
 from distributed_tensorflow_tpu.ops import nn
@@ -461,7 +462,8 @@ def _pp_loss(model, params, x, y, sub, m, k_stages, s_idx, keep_prob, cd,
     if getattr(model, "remat", False):
         # same remat the plain model applies (apply_hidden): one
         # block's activations live at a time, recompute in the backward
-        blk_fn = jax.checkpoint(_transformer_block, static_argnums=(2, 3))
+        # (all but a blockwise attention's out and logsumexp)
+        blk_fn = _remat(_transformer_block, (2, 3))
 
     sched = build_pp_schedule(k_stages, m, v_stages)
     chunk_tbl = jnp.asarray(sched.chunk_index)  # [T, K]
@@ -570,7 +572,7 @@ def _pp_zb_grads(model, params, x, y, sub, m, k_stages, s_idx, keep_prob,
     attn = _attn_for(model)
     blk_fn = _transformer_block
     if getattr(model, "remat", False):
-        blk_fn = jax.checkpoint(_transformer_block, static_argnums=(2, 3))
+        blk_fn = _remat(_transformer_block, (2, 3))
     v = int(v_stages)
     sched = build_zb_schedule(k_stages, m, v)
     kind_tbl = jnp.asarray(sched.kind)

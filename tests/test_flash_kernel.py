@@ -146,6 +146,59 @@ def test_fused_through_checkpoint(fused_on_cpu):
                               np.asarray(b, np.float32))
 
 
+def _calls(jaxpr, name):
+    """How many equations of ``jaxpr``, and of the jaxprs inside its
+    equations, call the jitted function ``name``."""
+    from tools.dttcheck.inventory import _sub_jaxprs
+
+    return sum((eqn.params.get("name") == name)
+               + sum(_calls(sub, name) for value in eqn.params.values()
+                     for sub in _sub_jaxprs(value))
+               for eqn in jaxpr.eqns)
+
+
+@pytest.mark.parametrize("form", ["fused", "scan"])
+def test_a_rematerialized_layer_runs_the_forward_once(form):
+    """Through the model's checkpoint (``transformer._remat``, the
+    trainer's ``--remat``) the backward pass has the layer's ``out`` and
+    logsumexp and does not make them again: the gradient's jaxpr holds the
+    forward (the kernel, the scan) once a layer where a checkpoint that
+    keeps nothing holds it twice, and every gradient is bit-equal to the
+    one taken with no checkpoint at all."""
+    from distributed_tensorflow_tpu.models.transformer import _remat
+
+    q, k, v, g = _operands(384, 2, seed=3)
+    layers = 2
+
+    def attend(q, k, v):
+        return blockwise_attention(q, k, v, 128, causal=True)
+
+    def loss(layer, q, k, v):
+        for _ in range(layers):
+            q = layer(q, k, v)
+        return jnp.sum(q.astype(jnp.float32) * g.astype(jnp.float32))
+
+    forms = {"none": attend, "kept": _remat(attend, ()),
+             "bare": jax.checkpoint(attend)}
+    # a CPU lowering traces both forms (and lowers the scan): count its own
+    names = {"fused": ("flash_forward", "flash_backward"),
+             "scan": ("_scan_forward", "_scan_backward")}[form]
+    with _kernels_interpreted() if form == "fused" \
+            else contextlib.nullcontext():
+        grads, forwards, backwards = {}, {}, {}
+        for name, layer in forms.items():
+            grad = jax.grad(functools.partial(loss, layer), (0, 1, 2))
+            grads[name] = grad(q, k, v)
+            jaxpr = jax.make_jaxpr(grad)(q, k, v).jaxpr
+            forwards[name] = _calls(jaxpr, names[0])
+            backwards[name] = _calls(jaxpr, names[1])
+    assert forwards == {"none": layers, "kept": layers, "bare": 2 * layers}
+    assert backwards == dict.fromkeys(forms, layers)
+    for a, b in zip(grads["none"], grads["kept"]):
+        assert np.array_equal(np.asarray(a, np.float32),
+                              np.asarray(b, np.float32))
+
+
 def _lm_loss_and_grads(dtype, attn_block):
     model = TransformerLM(vocab_size=64, seq_len=256, d_model=128,
                           num_heads=2, num_blocks=2, attn_block=attn_block,

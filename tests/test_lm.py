@@ -146,25 +146,39 @@ def test_lm_causality():
         assert not np.allclose(l1[0, 5:], l2[0, 5:])
 
 
-def test_lm_remat_matches():
+@pytest.mark.parametrize("attn_block", [None, 4], ids=["dense", "attn_block"])
+def test_lm_remat_matches(attn_block, monkeypatch):
     """remat=True recomputes blocks in backward; values and grads are
     bitwise-identical math (jax.checkpoint), so the loss trajectory must
-    match the plain form."""
+    match the plain form. What a block keeps of a blockwise attention (its
+    out and logsumexp) is what the recomputation would have made again:
+    the trajectory is bit-equal to the checkpoint that keeps nothing."""
+    from distributed_tensorflow_tpu.models import transformer
+
     mk = lambda remat: TransformerLM(vocab_size=16, seq_len=8, d_model=32,
-                                     num_heads=2, num_blocks=2, remat=remat)
-    plain, remat = mk(False), mk(True)
-    opt = get_optimizer("sgd", 0.1)
-    s1 = create_train_state(plain, opt, seed=0)
-    s2 = create_train_state(remat, opt, seed=0)
-    step1 = make_train_step(plain, opt, keep_prob=1.0)
-    step2 = make_train_step(remat, opt, keep_prob=1.0)
+                                     num_heads=2, num_blocks=2, remat=remat,
+                                     attn_block=attn_block)
     x = jnp.arange(32, dtype=jnp.int32).reshape(4, 8) % 16
     y = (x + 1) % 16
-    for _ in range(2):
-        s1, m1 = step1(s1, (x, y))
-        s2, m2 = step2(s2, (x, y))
-    np.testing.assert_allclose(float(m1["loss"]), float(m2["loss"]),
-                               rtol=1e-6)
+
+    def two_steps(model):
+        opt = get_optimizer("sgd", 0.1)
+        state = create_train_state(model, opt, seed=0)
+        step = make_train_step(model, opt, keep_prob=1.0)
+        for _ in range(2):
+            state, metrics = step(state, (x, y))
+        return float(metrics["loss"]), jax.tree.leaves(state.params)
+
+    plain, _ = two_steps(mk(False))
+    remat, params = two_steps(mk(True))
+    np.testing.assert_allclose(plain, remat, rtol=1e-6)
+    monkeypatch.setattr(
+        transformer, "_remat",
+        lambda fn, static: jax.checkpoint(fn, static_argnums=static))
+    bare, bare_params = two_steps(mk(True))
+    assert bare == remat
+    for a, b in zip(params, bare_params):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
 # -------------------------------------------------- SP per-token reduction

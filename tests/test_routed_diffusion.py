@@ -449,6 +449,35 @@ def test_an_overflow_is_a_failed_step_not_a_silent_drop(monkeypatch):
     assert float(metrics["moe_buffer_fill_max"]) <= 1
 
 
+def test_remat_keeps_the_counters_the_loss_and_the_gradients():
+    """The routed block under ``remat=True`` (its attention's out and
+    logsumexp kept, the rest made again in the backward pass) still
+    returns its routing counters, and the loss and every leaf's gradient
+    are those of ``remat=False``."""
+    x = jax.random.randint(jax.random.key(1), (4, 64), 0, 299)
+    got = {}
+    for remat in (False, True):
+        model = small_model(remat=remat)
+        params = model.init(jax.random.key(0))
+        batch = model.noise_batch((x, x), jax.random.key(2))
+        (loss, metrics), grads = jax.jit(jax.value_and_grad(
+            functools.partial(model.loss_with_metrics, train=True),
+            has_aux=True))(params, *batch)
+        got[remat] = (float(loss), jax.device_get(metrics),
+                      jax.tree.leaves(grads))
+    (loss, metrics, grads), (r_loss, r_metrics, r_grads) = got[0], got[1]
+    counters = {k for k in metrics if k.startswith("moe_")}
+    assert {"moe_rows_per_expert_max", "moe_rows_per_expert_mean",
+            "moe_overflow_rows", "moe_buffer_fill_max",
+            "moe_unrouted_frac"} <= counters
+    assert metrics.keys() == r_metrics.keys()
+    for k in metrics:
+        assert float(metrics[k]) == float(r_metrics[k]), k
+    assert loss == r_loss and np.isfinite(loss)
+    for a, b in zip(grads, r_grads):
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-9)
+
+
 def test_capacity_is_a_multiple_of_the_row_tile_and_never_past_every_pair():
     assert moe.routed_capacity(32768, 8, 16, 128, 1.25) == 40960
     assert moe.routed_capacity(32768, 8, 16, 128, 100.0) == 32768 * 8

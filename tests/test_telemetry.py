@@ -47,6 +47,42 @@ def clean_telemetry():
     faults.reset()
 
 
+# ------------------------------------------------- what --remat keeps
+
+@pytest.mark.parametrize("remat,attn_block,names", [
+    (True, 8, ["attention_lse", "attention_out"]),
+    (True, None, None),   # dense attention names nothing: nothing is kept
+    (False, 8, None),     # no checkpoint, no policy
+], ids=["remat-attn_block", "remat-dense", "attn_block-alone"])
+def test_remat_saved_instant_names_what_a_block_keeps(remat, attn_block,
+                                                      names):
+    """Tracing the gradient of a model with ``remat=True`` records one
+    ``remat_saved`` instant: the names its blocks' checkpoint keeps and
+    the bytes a block they cost (out (B, S, H, Dh) in the compute dtype,
+    the logsumexp (B, H, S) f32)."""
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_tensorflow_tpu.models.transformer import TransformerLM
+
+    b, s, h, dh = 2, 16, 2, 8
+    model = TransformerLM(vocab_size=16, seq_len=s, d_model=h * dh,
+                          num_heads=h, num_blocks=3, remat=remat,
+                          attn_block=attn_block, compute_dtype=jnp.bfloat16)
+    params = jax.eval_shape(lambda: model.init(jax.random.key(0)))
+    x = jax.ShapeDtypeStruct((b, s), jnp.int32)
+    jax.make_jaxpr(jax.grad(
+        lambda p, x: model.loss_with_metrics(p, x, x)[0]))(params, x)
+    saved = [r for r in telemetry.last_spans(100)
+             if r["name"] == "remat_saved"]
+    if names is None:
+        assert saved == []
+        return
+    (rec,) = saved  # once a traced model, not once a block
+    assert rec["instant"] and rec["names"] == names
+    assert rec["bytes_per_block"] == b * s * h * dh * 2 + b * h * s * 4
+
+
 # ------------------------------------------------------------- spans
 
 
