@@ -19,9 +19,10 @@ same loop through a PS-backed step function.
 
 from __future__ import annotations
 
+import math
 import time
 from dataclasses import dataclass
-from typing import Any
+from typing import Any, Callable, NamedTuple
 
 import jax
 
@@ -31,9 +32,18 @@ from distributed_tensorflow_tpu.checkpoint import (
 )
 from distributed_tensorflow_tpu.flags import coord_steps_from_flags
 from distributed_tensorflow_tpu.data import read_data_sets
+from distributed_tensorflow_tpu.data.device_data import (
+    put_device_data,
+    put_device_data_sp,
+)
 from distributed_tensorflow_tpu.data.pipeline import batch_iterator, prefetch_to_device
 from distributed_tensorflow_tpu.models import get_model
-from distributed_tensorflow_tpu.parallel import make_dp_train_step, make_mesh, shard_batch
+from distributed_tensorflow_tpu.parallel import (
+    MeshSpec,
+    make_dp_train_step,
+    make_mesh,
+    shard_batch,
+)
 from distributed_tensorflow_tpu.parallel.data_parallel import (
     local_batch_size,
     make_dp_eval_step,
@@ -46,7 +56,10 @@ from distributed_tensorflow_tpu.training import (
     make_train_step,
     schedule_from_flags,
 )
-from distributed_tensorflow_tpu.training import elastic
+# the module, not its builders: a layout looks a builder up when it builds
+# a chunk, so that what replaces one on the module (the benchmark's probe)
+# is what gets called
+from distributed_tensorflow_tpu.training import device_step, elastic
 from distributed_tensorflow_tpu.training.supervisor import Supervisor
 from distributed_tensorflow_tpu.training.train_state import evaluate
 from distributed_tensorflow_tpu.utils import (
@@ -322,6 +335,123 @@ def _sentinel_for(FLAGS, sv, logger):
                                 stop_fn=stop_fn)
 
 
+class _Scaffold(NamedTuple):
+    """What every loop stands in: made once a run by ``_scaffold``."""
+    sv: Supervisor
+    logger: MetricsLogger
+    meter: Throughput
+    stimer: StepTimer
+    eff: Any  # efficiency meter, None when accounting is off
+    rmon: Any  # resource monitor, None when the resource plane is off
+    snt: Any  # sentinel, None when unarmed or not the chief
+    els: Any  # elastic supervisor, None outside an elastic run
+    periodic_eval: Callable
+    coord: Any  # _HostCoordinator of a multi-process mesh run, else None
+    sync_every: int  # collective_sync_cadence of this run, in steps
+
+    def should_stop(self) -> bool:
+        """The vote's cached verdict where processes vote, else sv's."""
+        return (self.coord or self.sv).should_stop()
+
+    def checkpoint(self, state, step: int) -> None:
+        """The save that may be due at ``step``, or the vote on it, whose
+        wait is peer-coordination stall (mostly skew), booked apart."""
+        if self.coord is not None:
+            with _charged(self.eff, "coord"):
+                self.coord.tick(state, step)
+        else:
+            with _charged(self.eff, "ckpt"):
+                self.sv.maybe_checkpoint(state, step)
+
+
+def _scaffold(FLAGS, ds, model, opt, mesh, n_chips,
+              full_eval=None) -> _Scaffold:
+    """Supervisor, logger, meters, monitors, sentinel, elastic supervisor
+    and periodic eval of one run. Processes that share a mesh (sync mode,
+    more than one of them) agree on stops and saves through the
+    coordinator's vote; any other run asks its own Supervisor."""
+    sv = Supervisor(
+        is_chief=(FLAGS.task_index == 0),
+        logdir=FLAGS.logdir,
+        save_model_secs=FLAGS.save_model_secs,
+        max_to_keep=max_to_keep_from_flags(FLAGS),
+        background_save=background_save_from_flags(FLAGS),
+        sharded_spanning=bool(getattr(FLAGS, "sharded_checkpoint", True)),
+    )
+    logger = MetricsLogger(FLAGS.logdir if sv.is_chief else None,
+                           job_name=FLAGS.job_name or "worker",
+                           task_index=FLAGS.task_index)
+    stimer = StepTimer()
+    eff = efficiency.meter_from_flags(FLAGS, model, FLAGS.batch_size,
+                                      n_chips)
+    els = elastic.supervisor_from_flags(FLAGS)
+    coord = (_HostCoordinator(sv, coord_steps_from_flags(FLAGS),
+                              stimer=stimer, logger=logger, elastic_sv=els)
+             if mesh is not None and jax.process_count() > 1 else None)
+    return _Scaffold(
+        sv=sv, logger=logger, meter=Throughput(FLAGS.batch_size, n_chips),
+        stimer=stimer, eff=eff,
+        rmon=resources.monitor_from_flags(FLAGS, model, opt,
+                                          FLAGS.batch_size, n_chips),
+        snt=_sentinel_for(FLAGS, sv, logger), els=els,
+        periodic_eval=_periodic_test_eval(FLAGS, sv, model, ds, logger,
+                                          full_eval=full_eval, eff=eff),
+        coord=coord,
+        sync_every=collective_sync_cadence(mesh is not None))
+
+
+def _close_compile_window(sc: _Scaffold, params, step: int) -> None:
+    """After a loop's first dispatch, which carried the XLA compile (jit
+    traces and compiles synchronously inside the call): keep it out of the
+    throughput window and of the step breakdown, and let goodput see the
+    pre-compile window's work plus this wait as an init stall."""
+    if sc.eff is not None:
+        sc.eff.charge(sc.stimer.cumulative_work()[0], "init")
+    with trace_span("device_sync", step=step), _charged(sc.eff, "init"):
+        jax.block_until_ready(params)
+    sc.meter.reset()
+    sc.stimer.reset()
+
+
+def _finish(FLAGS, sc: _Scaffold, model, state, ds, step: int,
+            last_display: dict, full_eval=None) -> TrainResult:
+    """A loop's tail, after its managed block has saved ``state`` (in the
+    checkpoint's layout): the end-of-run test eval, the reference's last
+    line, the result."""
+    test_metrics = _final_test_eval(FLAGS, sc.sv, sc.periodic_eval, model,
+                                    state, ds, sc.logger, step,
+                                    full_eval=full_eval)
+    print("Optimization Finished!")
+    sc.logger.close()
+    return TrainResult(
+        final_step=step,
+        train_metrics=last_display,
+        test_metrics=test_metrics,
+        images_per_sec=sc.meter.images_per_sec,
+        images_per_sec_per_chip=sc.meter.images_per_sec_per_chip,
+        n_chips=sc.meter.n_chips,
+    )
+
+
+@dataclass(frozen=True)
+class _DeviceLayout:
+    """What differs between the layouts ``_train_device`` drives, built by
+    the caller that chose the mode. With ``to_host`` (the live state is not
+    the checkpoint's layout) the StateBox is updated, and so evals and
+    saves happen, only at boundaries; without it after every chunk."""
+    span: str  # name of the dispatch's span
+    put_data: Callable[[], Any]  # the train split, onto the device
+    build_chunk: Callable[[int], Callable]  # steps -> fn(live, data)
+    # host state -> live state (after a restore too); None: the state as is
+    to_live: Callable[[Any], Any] | None = None
+    # live state -> the checkpoint's layout; None: the live state is that
+    to_host: Callable[[Any], Any] | None = None
+    # (eval_fn, stage): a display is the reference's, the dropout-off eval
+    # of a fresh staged host batch before the chunk; None: the chunk's last
+    # training metrics at the boundary (no host batch to stall the ring on)
+    display: tuple[Callable, Callable] | None = None
+
+
 def train(FLAGS, mode: str = "local") -> TrainResult:
     """Run a full training job in "local" or "sync" mode.
 
@@ -473,8 +603,9 @@ def _train_once(FLAGS, mode: str = "local") -> TrainResult:
                              "model-axis strategies — pick one")
         return _train_pipeline(FLAGS, ds, model, opt, state, mode,
                                model_axis)
-    sp_device_model = None  # set by the SP branch for --device_data
-    ep_device_model = None  # set by the EP branch for --device_data
+    # --device_data: each branch below names the builder of its chunked
+    # step (``chunk_step``); SP and EP also stage the split their own way
+    put_split = lambda: put_device_data(ds.train, mesh)
     if getattr(FLAGS, "expert_parallel", False):
         # expert parallelism: MoE experts sharded --model_axis ways
         # (parallel/expert_parallel.py); the EP twin carries moe_axis
@@ -483,7 +614,6 @@ def _train_once(FLAGS, mode: str = "local") -> TrainResult:
         from distributed_tensorflow_tpu.models.transformer import (
             TransformerLM,
         )
-        from distributed_tensorflow_tpu.parallel import MeshSpec
         from distributed_tensorflow_tpu.parallel.expert_parallel import (
             ep_clip_transform,
             make_ep_eval_step,
@@ -550,7 +680,12 @@ def _train_once(FLAGS, mode: str = "local") -> TrainResult:
                      NamedSharding(mesh, P(DATA_AXIS, None)))
         stage = lambda b: put_global(_ep_specs, b)
         restage = lambda s: shard_state_ep(s, mesh)
-        ep_device_model = ep_model  # --device_data: the chunked EP step
+        # the split 1/D a data row; the step samples inside shard_map
+        put_split = lambda: put_device_data(ds.train, mesh,
+                                            data_sharded=True)
+        chunk_step = lambda n: device_step.make_ep_device_train_step(
+            ep_model, opt, mesh, FLAGS.batch_size,
+            keep_prob=FLAGS.keep_prob, chunk=n, grad_transform=clip)
     elif getattr(FLAGS, "seq_parallel", False):
         # sequence/context parallelism: tokens sharded --model_axis ways,
         # ring attention over the mesh's "model" axis
@@ -563,7 +698,6 @@ def _train_once(FLAGS, mode: str = "local") -> TrainResult:
             MiniTransformer,
             TransformerLM,
         )
-        from distributed_tensorflow_tpu.parallel import MeshSpec
         from distributed_tensorflow_tpu.parallel.mesh import (
             DATA_AXIS,
             MODEL_AXIS,
@@ -719,7 +853,14 @@ def _train_once(FLAGS, mode: str = "local") -> TrainResult:
             stage = lambda b: stage_impl(
                 (reshape_for_sp(sp_model, b[0]), b[1]))
         restage = lambda s: replicate_state(mesh, s)
-        sp_device_model = sp_model
+        # the split token-axis-sharded; the step samples inside shard_map
+        put_split = lambda: put_device_data_sp(
+            ds.train, mesh, is_lm, token_shape=(
+                None if is_lm else (sp_model.seq_len, sp_model.token_dim)))
+        chunk_step = lambda n: device_step.make_device_sp_train_step(
+            sp_model, opt, mesh, FLAGS.batch_size,
+            keep_prob=FLAGS.keep_prob, chunk=n, grad_transform=clip,
+            per_token_targets=is_lm)
         if n_procs == 1:
             # periodic + final full-split evals run THROUGH the sharded
             # eval step on the live mesh state (the dense twin only
@@ -734,7 +875,6 @@ def _train_once(FLAGS, mode: str = "local") -> TrainResult:
     elif mode == "sync" and model_axis > 1:
         # tensor parallelism (+DP on the remaining devices): GSPMD layout,
         # XLA inserts the collectives — parallel/tensor_parallel.py
-        from distributed_tensorflow_tpu.parallel import MeshSpec
         from distributed_tensorflow_tpu.parallel.mesh import DATA_AXIS
         from distributed_tensorflow_tpu.parallel.tensor_parallel import (
             has_tp_specs,
@@ -776,6 +916,11 @@ def _train_once(FLAGS, mode: str = "local") -> TrainResult:
         eval_fn = make_tp_eval_step(model, mesh)
         stage = lambda b: stage_batch_tp(mesh, b)
         restage = lambda s: shard_state_tp(s, mesh)
+        # GSPMD: the state's TP layout + the data-axis batch constraint
+        # drive the partitioner
+        chunk_step = lambda n: device_step.make_device_tp_train_step(
+            model, opt, mesh, FLAGS.batch_size, keep_prob=FLAGS.keep_prob,
+            chunk=n, grad_transform=clip, augment_fn=augment)
     elif mode == "sync":
         mesh = make_mesh()
         n_chips = mesh.devices.size
@@ -797,61 +942,44 @@ def _train_once(FLAGS, mode: str = "local") -> TrainResult:
                                      augment_fn=augment)
         eval_fn = make_dp_eval_step(model, mesh)
         stage = lambda b: shard_batch(mesh, b)
+        chunk_step = lambda n: device_step.make_device_dp_train_step(
+            model, opt, mesh, FLAGS.batch_size, keep_prob=FLAGS.keep_prob,
+            chunk=n, grad_transform=clip, augment_fn=augment)
     else:
         step_fn = make_train_step(model, opt, keep_prob=FLAGS.keep_prob,
                                   grad_transform=clip, accum_steps=accum,
                                   augment_fn=augment)
         eval_fn = make_eval_step(model)
         stage = None  # prefetch default: device_put to the default device
+        chunk_step = lambda n: device_step.make_device_train_step(
+            model, opt, FLAGS.batch_size, keep_prob=FLAGS.keep_prob,
+            chunk=n, grad_transform=clip, augment_fn=augment)
 
-    use_device_data = bool(getattr(FLAGS, "device_data", False))
-    if use_device_data:
+    if getattr(FLAGS, "device_data", False):
         if jax.process_count() > 1 and mesh is None:
             raise ValueError(
                 "--device_data under multi-process requires sync mode "
                 "(a global mesh to replicate the split over)"
             )
-        return _train_device_resident(
-            FLAGS, ds, model, opt, state, mesh, n_chips, eval_fn, stage, clip,
-            tp=(mode == "sync" and model_axis > 1 and sp_device_model is None
-                and ep_device_model is None),
-            restage=restage, augment_fn=augment,
-            sp_model=sp_device_model, per_token_targets=is_lm,
-            ep_model=ep_device_model)
+        # the live state is the checkpoint's layout (no ``to_host``), a
+        # restored one is placed on the mesh again; a display's batch is
+        # this process's slice of the global one, which ``stage`` assembles
+        return _train_device(
+            FLAGS, ds, model, opt, state, mesh, n_chips, _DeviceLayout(
+                span="device_chunk", put_data=put_split,
+                build_chunk=chunk_step, to_live=restage,
+                display=(eval_fn, stage or jax.device_put)))
 
-    sv = Supervisor(
-        is_chief=(FLAGS.task_index == 0),
-        logdir=FLAGS.logdir,
-        save_model_secs=FLAGS.save_model_secs,
-        max_to_keep=max_to_keep_from_flags(FLAGS),
-        background_save=background_save_from_flags(FLAGS),
-        sharded_spanning=bool(getattr(FLAGS, "sharded_checkpoint", True)),
-    )
-    logger = MetricsLogger(FLAGS.logdir if sv.is_chief else None,
-                           job_name=FLAGS.job_name or "worker",
-                           task_index=FLAGS.task_index)
-    meter = Throughput(FLAGS.batch_size, n_chips)
-    stimer = StepTimer()
-    eff = efficiency.meter_from_flags(FLAGS, model, FLAGS.batch_size,
-                                      n_chips)
-    rmon = resources.monitor_from_flags(FLAGS, model, opt,
-                                        FLAGS.batch_size, n_chips)
-    snt = _sentinel_for(FLAGS, sv, logger)
-    els = elastic.supervisor_from_flags(FLAGS)
+    sc = _scaffold(FLAGS, ds, model, opt, mesh, n_chips,
+                   full_eval=sp_full_eval)
+    sv, logger, meter, stimer = sc.sv, sc.logger, sc.meter, sc.stimer
+    eff, rmon, snt, els = sc.eff, sc.rmon, sc.snt, sc.els
     last_display = {}
-    periodic_eval = _periodic_test_eval(FLAGS, sv, model, ds, logger,
-                                        full_eval=sp_full_eval, eff=eff)
-
-    coord = (_HostCoordinator(sv, coord_steps_from_flags(FLAGS),
-                              stimer=stimer, logger=logger,
-                              elastic_sv=els)
-             if (mode == "sync" and n_procs > 1) else None)
-    should_stop = coord.should_stop if coord is not None else sv.should_stop
 
     with sv.managed(state) as box:
         state, step = box.state, box.step
         _log_recovery(sv, logger, step, eff)
-        periodic_eval.prime(step)
+        sc.periodic_eval.prime(step)
         if restage is not None:
             # a restored checkpoint arrives as host arrays; re-place it on
             # the mesh layout (no-op when the state is already placed)
@@ -867,10 +995,9 @@ def _train_once(FLAGS, mode: str = "local") -> TrainResult:
         profiling = False
         profile_done = not FLAGS.profile_dir
         compile_done = False
-        sync_every = collective_sync_cadence(mode == "sync")
         try:
             meter.reset()
-            while not should_stop() and step < FLAGS.training_iter:
+            while not sc.should_stop() and step < FLAGS.training_iter:
                 t0 = time.perf_counter()
                 batch = next(batches)
                 stimer.add("host_wait", time.perf_counter() - t0)
@@ -899,7 +1026,7 @@ def _train_once(FLAGS, mode: str = "local") -> TrainResult:
                 step += 1
                 meter.step()
                 stimer.steps()
-                if sync_every and step % sync_every == 0:
+                if sc.sync_every and step % sc.sync_every == 0:
                     # block on the metrics too: their tiny pmeans can
                     # still be in flight after the params' all-reduce
                     # completes, and a next program's gloo ops
@@ -911,35 +1038,16 @@ def _train_once(FLAGS, mode: str = "local") -> TrainResult:
                         jax.block_until_ready((state.params, step_m))
                     stimer.add("device", time.perf_counter() - t0)
                 if not compile_done:
-                    # first step carries XLA compile; keep it out of the
-                    # throughput window. Goodput must keep seeing it as
-                    # an init stall — and the compile happens INSIDE the
-                    # first dispatch call (jit traces+compiles
-                    # synchronously), so charge the pre-compile window's
-                    # accumulated work plus this block's wait
-                    if eff is not None:
-                        eff.charge(stimer.cumulative_work()[0], "init")
-                    with trace_span("device_sync", step=step), \
-                            _charged(eff, "init"):
-                        jax.block_until_ready(state.params)
-                    meter.reset()
-                    stimer.reset()  # compile stays out of the breakdown too
+                    _close_compile_window(sc, state.params, step)
                     compile_done = True
                 if profiling and step >= profile_stop_at:
                     jax.block_until_ready(state.params)
                     jax.profiler.stop_trace()
                     profiling = False
                     profile_done = True
-                periodic_eval(state, step)
+                sc.periodic_eval(state, step)
                 box.update(state, step)
-                if coord is not None:
-                    # the vote allgather's wait is peer-coordination
-                    # stall (mostly skew), not checkpoint time
-                    with _charged(eff, "coord"):
-                        coord.tick(state, step)
-                else:
-                    with _charged(eff, "ckpt"):
-                        sv.maybe_checkpoint(state, step)
+                sc.checkpoint(state, step)
                 if els is not None and els.poll(step):
                     # membership change due: the StateBox already holds
                     # this boundary's state — drain via the managed-exit
@@ -951,19 +1059,8 @@ def _train_once(FLAGS, mode: str = "local") -> TrainResult:
                 jax.profiler.stop_trace()
             batches.close()
 
-    test_metrics = _final_test_eval(FLAGS, sv, periodic_eval, model, state,
-                                    ds, logger, step,
-                                    full_eval=sp_full_eval)
-    print("Optimization Finished!")
-    logger.close()
-    return TrainResult(
-        final_step=step,
-        train_metrics=last_display,
-        test_metrics=test_metrics,
-        images_per_sec=meter.images_per_sec,
-        images_per_sec_per_chip=meter.images_per_sec_per_chip,
-        n_chips=n_chips,
-    )
+    return _finish(FLAGS, sc, model, state, ds, step, last_display,
+                   full_eval=sp_full_eval)
 
 
 def evaluate_only(FLAGS) -> dict[str, float]:
@@ -1378,9 +1475,10 @@ def _train_pipeline(FLAGS, ds, model, opt, state, mode,
     device owns V round-robin block groups and the fill/drain bubble
     shrinks ~V-fold — same math, bit-identical to V=1; checkpoints
     stay in the standard layout whatever V. With --device_data the
-    split stages data-sharded into HBM and the chunked sampler
-    (_train_pipeline_device) replaces the host-fed loop."""
-    from distributed_tensorflow_tpu.parallel import MeshSpec, make_mesh
+    split stages data-sharded into HBM (1/D a data row) and
+    ``_train_device`` drives the chunked sampler
+    (device_step.make_pp_device_train_step: every step samples its
+    per-shard batch inside ``shard_map``) under this same contract."""
     from distributed_tensorflow_tpu.parallel.mesh import DATA_AXIS
     from distributed_tensorflow_tpu.parallel.pipeline_parallel import (
         fetch_state_pp,
@@ -1448,45 +1546,37 @@ def _train_pipeline(FLAGS, ds, model, opt, state, mode,
             f"each data shard's slice ({FLAGS.batch_size // data_ways}) "
             f"must split into {micro} microbatches (--pp_microbatches)")
 
+    to_live = lambda s: shard_state_pp(s, mesh, virtual_stages=vstages)
+    to_host = lambda s: fetch_state_pp(s, model, k_stages=model_axis,
+                                       virtual_stages=vstages)
     if getattr(FLAGS, "device_data", False):
-        return _train_pipeline_device(FLAGS, ds, model, opt, state, mesh,
-                                      n_chips, micro, clip, vstages,
-                                      sched_name)
+        return _train_device(
+            FLAGS, ds, model, opt, state, mesh, n_chips, _DeviceLayout(
+                span="pp_chunk_zb" if sched_name == "zb" else "pp_chunk",
+                put_data=lambda: put_device_data(ds.train, mesh,
+                                                 data_sharded=True),
+                build_chunk=lambda n: device_step.make_pp_device_train_step(
+                    model, opt, mesh, FLAGS.batch_size, micro,
+                    keep_prob=FLAGS.keep_prob, chunk=n, grad_transform=clip,
+                    virtual_stages=vstages, schedule=sched_name),
+                to_live=to_live, to_host=to_host))
 
     step_fn = make_pp_train_step(model, opt, mesh, micro,
                                  keep_prob=FLAGS.keep_prob,
                                  grad_transform=clip,
                                  virtual_stages=vstages,
                                  schedule=sched_name)
-    sv = Supervisor(
-        is_chief=(FLAGS.task_index == 0),
-        logdir=FLAGS.logdir,
-        save_model_secs=FLAGS.save_model_secs,
-        max_to_keep=max_to_keep_from_flags(FLAGS),
-        background_save=background_save_from_flags(FLAGS),
-        sharded_spanning=bool(getattr(FLAGS, "sharded_checkpoint", True)),
-    )
-    logger = MetricsLogger(FLAGS.logdir if sv.is_chief else None,
-                           job_name=FLAGS.job_name or "worker",
-                           task_index=FLAGS.task_index)
-    meter = Throughput(FLAGS.batch_size, n_chips)
-    eff = efficiency.meter_from_flags(FLAGS, model, FLAGS.batch_size,
-                                      n_chips)
-    rmon = resources.monitor_from_flags(FLAGS, model, opt,
-                                        FLAGS.batch_size, n_chips)
-    snt = _sentinel_for(FLAGS, sv, logger)
-    els = elastic.supervisor_from_flags(FLAGS)
+    sc = _scaffold(FLAGS, ds, model, opt, mesh, n_chips)
+    sv, logger, meter, stimer = sc.sv, sc.logger, sc.meter, sc.stimer
+    eff, rmon, snt, els = sc.eff, sc.rmon, sc.snt, sc.els
     last_display = {}
-    periodic_eval = _periodic_test_eval(FLAGS, sv, model, ds, logger,
-                                        eff=eff)
     eval_every = max(0, getattr(FLAGS, "eval_step", 0))
 
-    stimer = StepTimer()
     with sv.managed(state) as box:
         step = box.step
         _log_recovery(sv, logger, step, eff)
-        periodic_eval.prime(step)
-        pp_state = shard_state_pp(box.state, mesh, virtual_stages=vstages)
+        sc.periodic_eval.prime(step)
+        pp_state = to_live(box.state)
         compile_done = False
         meter.reset()
         while not sv.should_stop() and step < FLAGS.training_iter:
@@ -1511,15 +1601,7 @@ def _train_pipeline(FLAGS, ds, model, opt, state, mode,
             meter.step(FLAGS.batch_size)
             stimer.steps()
             if not compile_done:
-                # the first dispatch carried the XLA compile: charge the
-                # pre-compile window's work + this wait as an init stall
-                if eff is not None:
-                    eff.charge(stimer.cumulative_work()[0], "init")
-                with trace_span("device_sync", step=step), \
-                        _charged(eff, "init"):
-                    jax.block_until_ready(pp_state.params)
-                meter.reset()
-                stimer.reset()  # compile stays out of the breakdown too
+                _close_compile_window(sc, pp_state.params, step)
                 compile_done = True
             # a due membership change pulls the next checkpoint boundary
             # to THIS step (the standard-layout fetch below is the drain
@@ -1537,9 +1619,7 @@ def _train_pipeline(FLAGS, ds, model, opt, state, mode,
                 with trace_span("boundary_fetch", step=step), \
                         telemetry.armed("pp_boundary_fetch", step=step), \
                         _charged(eff, "ckpt"):
-                    host = fetch_state_pp(pp_state, model,
-                                          k_stages=model_axis,
-                                          virtual_stages=vstages)
+                    host = to_host(pp_state)
                 stimer.add("device", time.perf_counter() - t0)
                 box.update(host, step)
                 if step % FLAGS.display_step == 0:
@@ -1548,203 +1628,15 @@ def _train_pipeline(FLAGS, ds, model, opt, state, mode,
                         step, last_display, logger,
                         lambda: _display_scalars(meter, stimer, eff, rmon),
                         eff, snt, host)
-                periodic_eval(host, step)
-                with _charged(eff, "ckpt"):
-                    sv.maybe_checkpoint(host, step)
+                sc.periodic_eval(host, step)
+                sc.checkpoint(host, step)
                 if due:
                     els.maybe_resize(step)
         jax.block_until_ready(pp_state.params)
-        host = fetch_state_pp(pp_state, model, k_stages=model_axis,
-                              virtual_stages=vstages)
+        host = to_host(pp_state)
         box.update(host, step)
 
-    test_metrics = _final_test_eval(FLAGS, sv, periodic_eval, model, host,
-                                    ds, logger, step)
-    print("Optimization Finished!")
-    logger.close()
-    return TrainResult(
-        final_step=step,
-        train_metrics=last_display,
-        test_metrics=test_metrics,
-        images_per_sec=meter.images_per_sec,
-        images_per_sec_per_chip=meter.images_per_sec_per_chip,
-        n_chips=n_chips,
-    )
-
-
-def _train_pipeline_device(FLAGS, ds, model, opt, state, mesh, n_chips,
-                           micro, clip, vstages: int = 1,
-                           sched_name: str = "auto") -> TrainResult:
-    """--pipeline --device_data: the GPipe stage ring over a DEVICE-
-    RESIDENT split. The split stages data-sharded into HBM once
-    (``put_device_data(..., data_sharded=True)``); every step samples
-    its per-shard batch inside ``shard_map`` from the step PRNG and
-    ``lax.scan`` runs ``--device_chunk`` steps per dispatch
-    (device_step.make_pp_device_train_step) — zero host->device bytes
-    per step, one compiled call per chunk. The live state keeps the
-    STACKED stage-sharded layout between dispatches; the standard-
-    layout host state (checkpoint format) is fetched only at display /
-    eval / cadence boundaries, exactly the host-fed PP loop's contract
-    (a hard kill can lose at most the steps since the last boundary).
-    Display shows the chunk's last training metrics (the documented
-    device-resident trade: no host batch exists to pre-eval)."""
-    import math
-
-    from distributed_tensorflow_tpu.data.device_data import put_device_data
-    from distributed_tensorflow_tpu.parallel.mesh import MODEL_AXIS
-    from distributed_tensorflow_tpu.parallel.pipeline_parallel import (
-        fetch_state_pp,
-        shard_state_pp,
-    )
-    from distributed_tensorflow_tpu.training.device_step import (
-        make_pp_device_train_step,
-    )
-
-    k_stages = mesh.shape[MODEL_AXIS]
-    with trace_span("data_put"):
-        data = put_device_data(ds.train, mesh, data_sharded=True)
-    chunk = max(1, math.gcd(FLAGS.display_step, max(1, FLAGS.device_chunk)))
-    if chunk != FLAGS.device_chunk:
-        print(f"--device_chunk={FLAGS.device_chunk} clamped to {chunk} so "
-              f"chunks land on --display_step={FLAGS.display_step} "
-              f"boundaries (dispatch amortization shrinks accordingly)")
-
-    chunk_fns: dict[int, Any] = {}
-
-    def run_chunk(pp_state, length: int):
-        fn = chunk_fns.get(length)
-        if fn is None:
-            fn = chunk_fns[length] = make_pp_device_train_step(
-                model, opt, mesh, FLAGS.batch_size, micro,
-                keep_prob=FLAGS.keep_prob, chunk=length,
-                grad_transform=clip, virtual_stages=vstages,
-                schedule=sched_name)
-        return fn(pp_state, data)
-
-    sv = Supervisor(
-        is_chief=(FLAGS.task_index == 0),
-        logdir=FLAGS.logdir,
-        save_model_secs=FLAGS.save_model_secs,
-        max_to_keep=max_to_keep_from_flags(FLAGS),
-        background_save=background_save_from_flags(FLAGS),
-        sharded_spanning=bool(getattr(FLAGS, "sharded_checkpoint", True)),
-    )
-    logger = MetricsLogger(FLAGS.logdir if sv.is_chief else None,
-                           job_name=FLAGS.job_name or "worker",
-                           task_index=FLAGS.task_index)
-    meter = Throughput(FLAGS.batch_size, n_chips)
-    eff = efficiency.meter_from_flags(FLAGS, model, FLAGS.batch_size,
-                                      n_chips)
-    rmon = resources.monitor_from_flags(FLAGS, model, opt,
-                                        FLAGS.batch_size, n_chips)
-    snt = _sentinel_for(FLAGS, sv, logger)
-    els = elastic.supervisor_from_flags(FLAGS)
-    last_display = {}
-    periodic_eval = _periodic_test_eval(FLAGS, sv, model, ds, logger,
-                                        eff=eff)
-    eval_every = max(0, getattr(FLAGS, "eval_step", 0))
-    sync_every = collective_sync_cadence(True)
-    chunks_done = 0
-
-    with sv.managed(state) as box:
-        step = box.step
-        _log_recovery(sv, logger, step, eff)
-        periodic_eval.prime(step)
-        pp_state = shard_state_pp(box.state, mesh, virtual_stages=vstages)
-        host = box.state
-        compile_done = False
-        meter.reset()
-        stimer = StepTimer()
-        while not sv.should_stop() and step < FLAGS.training_iter:
-            # realign to display boundaries after a resume from an
-            # arbitrary checkpointed step, then cap at the budget
-            to_boundary = -step % FLAGS.display_step or chunk
-            length = min(chunk, to_boundary, FLAGS.training_iter - step)
-            if rmon is not None:
-                # the chunk LENGTH is the signature the scan step
-                # specializes on (run_chunk caches one fn per length)
-                rmon.note_dispatch("pp_chunk", signature=(length,))
-            t0 = time.perf_counter()
-            chunk_span = ("pp_chunk_zb" if sched_name == "zb"
-                          else "pp_chunk")
-            with trace_span(chunk_span, step=step, length=length,
-                            schedule=sched_name), \
-                    telemetry.armed(chunk_span, step=step, length=length):
-                pp_state, m = run_chunk(pp_state, length)
-            stimer.add("dispatch", time.perf_counter() - t0)
-            step += length
-            meter.step(length * FLAGS.batch_size)
-            stimer.steps(length)
-            chunks_done += 1
-            if sync_every and chunks_done % max(1, sync_every // chunk) == 0:
-                t0 = time.perf_counter()
-                with trace_span("device_sync", step=step), \
-                        telemetry.armed("collective_sync", step=step):
-                    jax.block_until_ready(pp_state.params)
-                stimer.add("device", time.perf_counter() - t0)
-            if not compile_done:
-                # the first dispatch carried the XLA compile: charge the
-                # pre-compile window's work + this wait as an init stall
-                if eff is not None:
-                    eff.charge(stimer.cumulative_work()[0], "init")
-                with trace_span("device_sync", step=step), \
-                        _charged(eff, "init"):
-                    jax.block_until_ready(pp_state.params)
-                meter.reset()
-                stimer.reset()  # compile stays out of the breakdown too
-                compile_done = True
-            # eval boundaries use CROSSING semantics — a chunk can jump
-            # clean over `step % eval_every == 0` (chunks align to
-            # display_step, not eval_step), so fire on the chunk that
-            # crossed; periodic_eval's own crossing logic evaluates once
-            due = els is not None and els.poll(step)
-            boundary = (step % FLAGS.display_step == 0
-                        or (eval_every and
-                            (step - length) // eval_every
-                            != step // eval_every)
-                        or sv.checkpointer.cadence_due()
-                        or step >= FLAGS.training_iter
-                        or due)
-            if boundary:
-                # the fetch blocks on the chunk's device work —
-                # attributed to the device column like the host PP loop
-                t0 = time.perf_counter()
-                with trace_span("boundary_fetch", step=step), \
-                        telemetry.armed("pp_boundary_fetch", step=step), \
-                        _charged(eff, "ckpt"):
-                    host = fetch_state_pp(pp_state, model,
-                                          k_stages=k_stages,
-                                          virtual_stages=vstages)
-                stimer.add("device", time.perf_counter() - t0)
-                box.update(host, step)
-                if step % FLAGS.display_step == 0:
-                    last_display = {k: float(v) for k, v in m.items()}
-                    _display_log(
-                        step, last_display, logger,
-                        lambda: _display_scalars(meter, stimer, eff, rmon),
-                        eff, snt, host)
-                periodic_eval(host, step)
-                with _charged(eff, "ckpt"):
-                    sv.maybe_checkpoint(host, step)
-                if due:
-                    els.maybe_resize(step)
-        jax.block_until_ready(pp_state.params)
-        host = fetch_state_pp(pp_state, model, k_stages=k_stages,
-                              virtual_stages=vstages)
-        box.update(host, step)
-
-    test_metrics = _final_test_eval(FLAGS, sv, periodic_eval, model, host,
-                                    ds, logger, step)
-    print("Optimization Finished!")
-    logger.close()
-    return TrainResult(
-        final_step=step,
-        train_metrics=last_display,
-        test_metrics=test_metrics,
-        images_per_sec=meter.images_per_sec,
-        images_per_sec_per_chip=meter.images_per_sec_per_chip,
-        n_chips=n_chips,
-    )
+    return _finish(FLAGS, sc, model, host, ds, step, last_display)
 
 
 def _train_zero(FLAGS, ds, model, opt, state, mode, accum, augment_fn,
@@ -1794,9 +1686,7 @@ def _train_zero(FLAGS, ds, model, opt, state, mode, accum, augment_fn,
         raise ValueError(f"--zero={level} is single-process in this "
                          f"version (cross-host state shards would need "
                          f"the sharded-checkpoint collective fetch)")
-    from distributed_tensorflow_tpu.parallel import make_mesh as _mk
-
-    mesh = _mk()
+    mesh = make_mesh()
     n_chips = mesh.devices.size
     if n_chips == 1:
         print(f"--zero={level} on a 1-chip mesh: the data axis has "
@@ -1834,57 +1724,51 @@ def _train_zero(FLAGS, ds, model, opt, state, mode, accum, augment_fn,
             exposed_bytes=zero_exposed_comm_bytes(
                 g, g, level, d_eff, True, bucket_mb))
 
+    eval_fn = make_zero_eval_step(model, mesh, level)
+    stage = lambda b: shard_batch(mesh, b)
+    to_live = lambda s: shard_state_zero(s, mesh, level)
+    to_host = lambda s: fetch_state_zero(s, model, level)
     if getattr(FLAGS, "device_data", False):
-        return _train_zero_device(FLAGS, ds, model, opt, state, mesh,
-                                  n_chips, level, clip, augment_fn,
-                                  overlap, bucket_mb)
+        # the split replicated, as under plain DP: every rank samples its
+        # own rows with the DATA-folded key, the rows of a replicated-DP
+        # run, so a resume inside a chunk lands on its trajectory bit for bit
+        return _train_device(
+            FLAGS, ds, model, opt, state, mesh, n_chips, _DeviceLayout(
+                span="zero_chunk_overlap" if overlap else "zero_chunk",
+                put_data=lambda: put_device_data(ds.train, mesh),
+                build_chunk=lambda n: device_step.make_zero_device_train_step(
+                    model, opt, mesh, level, FLAGS.batch_size,
+                    keep_prob=FLAGS.keep_prob, chunk=n, grad_transform=clip,
+                    augment_fn=augment_fn, overlap=overlap,
+                    bucket_mb=bucket_mb),
+                to_live=to_live, to_host=to_host, display=(eval_fn, stage)))
 
     step_fn = make_zero_train_step(model, opt, mesh, level,
                                    keep_prob=FLAGS.keep_prob,
                                    grad_transform=clip, accum_steps=accum,
                                    augment_fn=augment_fn,
                                    overlap=overlap, bucket_mb=bucket_mb)
-    eval_fn = make_zero_eval_step(model, mesh, level)
-    sv = Supervisor(
-        is_chief=(FLAGS.task_index == 0),
-        logdir=FLAGS.logdir,
-        save_model_secs=FLAGS.save_model_secs,
-        max_to_keep=max_to_keep_from_flags(FLAGS),
-        background_save=background_save_from_flags(FLAGS),
-        sharded_spanning=bool(getattr(FLAGS, "sharded_checkpoint", True)),
-    )
-    logger = MetricsLogger(FLAGS.logdir if sv.is_chief else None,
-                           job_name=FLAGS.job_name or "worker",
-                           task_index=FLAGS.task_index)
-    meter = Throughput(FLAGS.batch_size, n_chips)
-    eff = efficiency.meter_from_flags(FLAGS, model, FLAGS.batch_size,
-                                      n_chips)
-    rmon = resources.monitor_from_flags(FLAGS, model, opt,
-                                        FLAGS.batch_size, n_chips)
-    snt = _sentinel_for(FLAGS, sv, logger)
-    els = elastic.supervisor_from_flags(FLAGS)
+    sc = _scaffold(FLAGS, ds, model, opt, mesh, n_chips)
+    sv, logger, meter, stimer = sc.sv, sc.logger, sc.meter, sc.stimer
+    eff, rmon, snt, els = sc.eff, sc.rmon, sc.snt, sc.els
     last_display = {}
-    periodic_eval = _periodic_test_eval(FLAGS, sv, model, ds, logger,
-                                        eff=eff)
     eval_every = max(0, getattr(FLAGS, "eval_step", 0))
-    sync_every = collective_sync_cadence(True)
 
     with sv.managed(state) as box:
         step = box.step
         _log_recovery(sv, logger, step, eff)
-        periodic_eval.prime(step)
-        z_state = shard_state_zero(box.state, mesh, level)
+        sc.periodic_eval.prime(step)
+        z_state = to_live(box.state)
         host = box.state
         batches = prefetch_to_device(
             batch_iterator(ds.train, FLAGS.batch_size,
                            raw=FLAGS.raw_input),
             size=2,
-            stage=lambda b: shard_batch(mesh, b),
+            stage=stage,
         )
         compile_done = False
         profiling = False
         profile_done = not FLAGS.profile_dir
-        stimer = StepTimer()
         try:
             meter.reset()
             while not sv.should_stop() and step < FLAGS.training_iter:
@@ -1922,22 +1806,14 @@ def _train_zero(FLAGS, ds, model, opt, state, mode, accum, augment_fn,
                 step += 1
                 meter.step()
                 stimer.steps()
-                if sync_every and step % sync_every == 0:
+                if sc.sync_every and step % sc.sync_every == 0:
                     t0 = time.perf_counter()
                     with trace_span("device_sync", step=step), \
                             telemetry.armed("collective_sync", step=step):
                         jax.block_until_ready((z_state.params, step_m))
                     stimer.add("device", time.perf_counter() - t0)
                 if not compile_done:
-                    # the first dispatch carried the XLA compile: charge
-                    # the pre-compile work + this wait as an init stall
-                    if eff is not None:
-                        eff.charge(stimer.cumulative_work()[0], "init")
-                    with trace_span("device_sync", step=step), \
-                            _charged(eff, "init"):
-                        jax.block_until_ready(z_state.params)
-                    meter.reset()
-                    stimer.reset()  # compile stays out of the breakdown too
+                    _close_compile_window(sc, z_state.params, step)
                     compile_done = True
                 if profiling and step >= profile_stop_at:
                     jax.block_until_ready(z_state.params)
@@ -1955,11 +1831,10 @@ def _train_zero(FLAGS, ds, model, opt, state, mode, accum, augment_fn,
                             telemetry.armed("zero_boundary_fetch",
                                             step=step), \
                             _charged(eff, "ckpt"):
-                        host = fetch_state_zero(z_state, model, level)
+                        host = to_host(z_state)
                         box.update(host, step)
-                    periodic_eval(host, step)
-                    with _charged(eff, "ckpt"):
-                        sv.maybe_checkpoint(host, step)
+                    sc.periodic_eval(host, step)
+                    sc.checkpoint(host, step)
                     if due:
                         els.maybe_resize(step)
             jax.block_until_ready(z_state.params)
@@ -1967,354 +1842,75 @@ def _train_zero(FLAGS, ds, model, opt, state, mode, accum, augment_fn,
             if profiling:
                 jax.profiler.stop_trace()
             batches.close()
-        host = fetch_state_zero(z_state, model, level)
+        host = to_host(z_state)
         box.update(host, step)
 
-    test_metrics = _final_test_eval(FLAGS, sv, periodic_eval, model, host,
-                                    ds, logger, step)
-    print("Optimization Finished!")
-    logger.close()
-    return TrainResult(
-        final_step=step,
-        train_metrics=last_display,
-        test_metrics=test_metrics,
-        images_per_sec=meter.images_per_sec,
-        images_per_sec_per_chip=meter.images_per_sec_per_chip,
-        n_chips=n_chips,
-    )
+    return _finish(FLAGS, sc, model, host, ds, step, last_display)
 
 
-def _train_zero_device(FLAGS, ds, model, opt, state, mesh, n_chips,
-                       level, clip, augment_fn, overlap: bool = False,
-                       bucket_mb: float = 4.0) -> TrainResult:
-    """--zero --device_data: the ZeRO-sharded update over a DEVICE-
-    RESIDENT split. The split stages replicated into HBM exactly like
-    the plain DP device loop (every rank samples its own rows with the
-    DATA-folded key — identical rows to a replicated-DP run), and
-    ``lax.scan`` runs ``--device_chunk`` steps per dispatch
-    (device_step.make_zero_device_train_step) — zero host->device bytes
-    per step. The live state keeps the ZeRO layout between dispatches;
-    the standard-layout host state (checkpoint format) is fetched only
-    at display / eval / cadence boundaries (the PP device loop's
-    contract, which also makes mid-chunk resume land on the replicated
-    trajectory bit-for-bit)."""
-    import math
 
-    from distributed_tensorflow_tpu.data.device_data import put_device_data
-    from distributed_tensorflow_tpu.parallel.zero import (
-        fetch_state_zero,
-        make_zero_eval_step,
-        shard_state_zero,
-    )
-    from distributed_tensorflow_tpu.training.device_step import (
-        make_zero_device_train_step,
-    )
 
+def _train_device(FLAGS, ds, model, opt, state, mesh, n_chips,
+                  layout: _DeviceLayout) -> TrainResult:
+    """--device_data training, every layout of it (plain: one chip, DP,
+    TP, SP, EP; ZeRO; pipeline): the train split resident in HBM, every
+    step's batch sampled on the device from the step's PRNG, ``lax.scan``
+    running ``--device_chunk`` steps a dispatch (training/device_step):
+    per training step nothing crosses the host boundary.
+
+    ``layout`` says what differs (``_DeviceLayout``). Where the live state
+    is not the checkpoint's layout it is fetched into that layout only at
+    boundaries: a display step, an ``--eval_step`` crossed inside the
+    chunk, a save that is due, the last step, a resize that is due. That
+    is when the StateBox updates, so clean exits and SIGTERM drains save
+    the exact final state and a hard kill loses at most the steps since
+    the last boundary. A display is the reference's (dropout off, before
+    the update, ``MNISTDist.py:179-182``) on one fresh host batch, or,
+    where the layout stages none, the chunk's last training metrics."""
     with trace_span("data_put"):
-        data = put_device_data(ds.train, mesh)
-    eval_fn = make_zero_eval_step(model, mesh, level)
-    chunk = max(1, math.gcd(FLAGS.display_step, max(1, FLAGS.device_chunk)))
-    if chunk != FLAGS.device_chunk:
-        print(f"--device_chunk={FLAGS.device_chunk} clamped to {chunk} so "
-              f"chunks land on --display_step={FLAGS.display_step} "
-              f"boundaries (dispatch amortization shrinks accordingly)")
-
-    chunk_fns: dict[int, Any] = {}
-
-    def run_chunk(z_state, length: int):
-        fn = chunk_fns.get(length)
-        if fn is None:
-            fn = chunk_fns[length] = make_zero_device_train_step(
-                model, opt, mesh, level, FLAGS.batch_size,
-                keep_prob=FLAGS.keep_prob, chunk=length,
-                grad_transform=clip, augment_fn=augment_fn,
-                overlap=overlap, bucket_mb=bucket_mb)
-        return fn(z_state, data)
-
-    sv = Supervisor(
-        is_chief=(FLAGS.task_index == 0),
-        logdir=FLAGS.logdir,
-        save_model_secs=FLAGS.save_model_secs,
-        max_to_keep=max_to_keep_from_flags(FLAGS),
-        background_save=background_save_from_flags(FLAGS),
-        sharded_spanning=bool(getattr(FLAGS, "sharded_checkpoint", True)),
-    )
-    logger = MetricsLogger(FLAGS.logdir if sv.is_chief else None,
-                           job_name=FLAGS.job_name or "worker",
-                           task_index=FLAGS.task_index)
-    meter = Throughput(FLAGS.batch_size, n_chips)
-    eff = efficiency.meter_from_flags(FLAGS, model, FLAGS.batch_size,
-                                      n_chips)
-    rmon = resources.monitor_from_flags(FLAGS, model, opt,
-                                        FLAGS.batch_size, n_chips)
-    snt = _sentinel_for(FLAGS, sv, logger)
-    els = elastic.supervisor_from_flags(FLAGS)
-    last_display = {}
-    periodic_eval = _periodic_test_eval(FLAGS, sv, model, ds, logger,
-                                        eff=eff)
-    eval_every = max(0, getattr(FLAGS, "eval_step", 0))
-    sync_every = collective_sync_cadence(True)
-    chunks_done = 0
-
-    with sv.managed(state) as box:
-        step = box.step
-        _log_recovery(sv, logger, step, eff)
-        periodic_eval.prime(step)
-        z_state = shard_state_zero(box.state, mesh, level)
-        host = box.state
-        compile_done = False
-        profiling = False
-        profile_done = not FLAGS.profile_dir
-        stimer = StepTimer()
-        meter.reset()
-        while not sv.should_stop() and step < FLAGS.training_iter:
-            if step % FLAGS.display_step == 0:
-                # reference display semantics, same as the DP device
-                # loop: dropout-off eval of a fresh host batch before
-                # training continues
-                last_display = _display_eval(
-                    step, eval_fn, z_state.params, z_state.model_state,
-                    eff, stimer,
-                    draw=lambda: shard_batch(
-                        mesh, ds.train.next_batch(FLAGS.batch_size)))
-                # `host` is this displayed step's state in the standard
-                # layout (fetched at the same boundary)
-                _display_log(
-                    step, last_display, logger,
-                    lambda: _display_scalars(meter, stimer, eff, rmon),
-                    eff, snt, host)
-            if compile_done and not profile_done and not profiling:
-                jax.profiler.start_trace(FLAGS.profile_dir)
-                profiling = True
-                profile_stop_at = step + max(FLAGS.profile_steps, chunk)
-            # realign to display boundaries after a resume from an
-            # arbitrary checkpointed step, then cap at the budget
-            to_boundary = -step % FLAGS.display_step or chunk
-            length = min(chunk, to_boundary, FLAGS.training_iter - step)
-            if rmon is not None:
-                rmon.note_dispatch("zero_chunk", signature=(length,))
-            t0 = time.perf_counter()
-            # the overlap pattern's chunks get their own span name (the
-            # level-3 warmup gather + double-buffered prefetch live
-            # inside this dispatch)
-            zspan = "zero_chunk_overlap" if overlap else "zero_chunk"
-            with trace_span(zspan, step=step, length=length), \
-                    telemetry.armed(zspan, step=step, length=length):
-                z_state, train_m = run_chunk(z_state, length)
-            stimer.add("dispatch", time.perf_counter() - t0)
-            step += length
-            meter.step(length * FLAGS.batch_size)
-            stimer.steps(length)
-            chunks_done += 1
-            if sync_every and chunks_done % max(1, sync_every // chunk) == 0:
-                t0 = time.perf_counter()
-                with trace_span("device_sync", step=step), \
-                        telemetry.armed("collective_sync", step=step):
-                    jax.block_until_ready((z_state.params, train_m))
-                stimer.add("device", time.perf_counter() - t0)
-            if not compile_done:
-                # the first dispatch carried the XLA compile: charge the
-                # pre-compile window's work + this wait as an init stall
-                if eff is not None:
-                    eff.charge(stimer.cumulative_work()[0], "init")
-                with trace_span("device_sync", step=step), \
-                        _charged(eff, "init"):
-                    jax.block_until_ready(z_state.params)
-                meter.reset()
-                stimer.reset()  # compile stays out of the breakdown too
-                compile_done = True
-            if profiling and step >= profile_stop_at:
-                jax.block_until_ready(z_state.params)
-                jax.profiler.stop_trace()
-                profiling = False
-                profile_done = True
-            due = els is not None and els.poll(step)
-            boundary = (step % FLAGS.display_step == 0
-                        or (eval_every and
-                            (step - length) // eval_every
-                            != step // eval_every)
-                        or sv.checkpointer.cadence_due()
-                        or step >= FLAGS.training_iter
-                        or due)
-            if boundary:
-                with trace_span("boundary_fetch", step=step), \
-                        telemetry.armed("zero_boundary_fetch", step=step), \
-                        _charged(eff, "ckpt"):
-                    host = fetch_state_zero(z_state, model, level)
-                box.update(host, step)
-                periodic_eval(host, step)
-                with _charged(eff, "ckpt"):
-                    sv.maybe_checkpoint(host, step)
-                if due:
-                    els.maybe_resize(step)
-        jax.block_until_ready(z_state.params)
-        if profiling:
-            jax.profiler.stop_trace()
-        host = fetch_state_zero(z_state, model, level)
-        box.update(host, step)
-
-    test_metrics = _final_test_eval(FLAGS, sv, periodic_eval, model, host,
-                                    ds, logger, step)
-    print("Optimization Finished!")
-    logger.close()
-    return TrainResult(
-        final_step=step,
-        train_metrics=last_display,
-        test_metrics=test_metrics,
-        images_per_sec=meter.images_per_sec,
-        images_per_sec_per_chip=meter.images_per_sec_per_chip,
-        n_chips=n_chips,
-    )
-
-
-def _train_device_resident(FLAGS, ds, model, opt, state, mesh, n_chips,
-                           eval_fn, stage, grad_transform=None,
-                           tp: bool = False, restage=None,
-                           augment_fn=None, sp_model=None,
-                           per_token_targets: bool = False,
-                           ep_model=None) -> TrainResult:
-    """--device_data training: the split resident in HBM, batches sampled on
-    device, ``lax.scan`` chunks amortizing dispatch (training/device_step).
-    Per training step NOTHING crosses the host boundary; per display step
-    one host batch is staged for the reference-semantics eval print
-    (dropout-off, before-the-update — ``MNISTDist.py:179-182``).
-    ``sp_model`` (seq_axis twin) routes the sequence-parallel composition:
-    the split stages token-axis-sharded and the chunked step samples
-    inside shard_map (device_step.make_device_sp_train_step).
-    ``ep_model`` (moe_axis twin) routes the expert-parallel composition:
-    the split stages data-axis-sharded and the chunked step samples
-    inside shard_map (device_step.make_ep_device_train_step);
-    ``grad_transform`` arrives already axis-aware (ep_clip_transform)."""
-    import math
-
-    from distributed_tensorflow_tpu.data.device_data import (
-        put_device_data,
-        put_device_data_sp,
-    )
-    from distributed_tensorflow_tpu.training.device_step import (
-        make_device_dp_train_step,
-        make_device_sp_train_step,
-        make_device_tp_train_step,
-        make_device_train_step,
-        make_ep_device_train_step,
-    )
-
-    with trace_span("data_put"):
-        if sp_model is not None:
-            token_shape = (None if per_token_targets
-                           else (sp_model.seq_len, sp_model.token_dim))
-            data = put_device_data_sp(ds.train, mesh, per_token_targets,
-                                      token_shape=token_shape)
-        elif ep_model is not None:
-            data = put_device_data(ds.train, mesh, data_sharded=True)
-        else:
-            data = put_device_data(ds.train, mesh)
         # the transfer is asynchronous: the span ends with the split
         # resident on the device
-        jax.block_until_ready(data)
+        data = jax.block_until_ready(layout.put_data())
     chunk = max(1, math.gcd(FLAGS.display_step, max(1, FLAGS.device_chunk)))
     if chunk != FLAGS.device_chunk:
         print(f"--device_chunk={FLAGS.device_chunk} clamped to {chunk} so "
               f"chunks land on --display_step={FLAGS.display_step} "
               f"boundaries (dispatch amortization shrinks accordingly)")
+    chunk_fns: dict[int, Callable] = {}  # one compiled chunk a length
 
-    def build_chunk_fn(length: int):
-        if sp_model is not None:
-            return make_device_sp_train_step(
-                sp_model, opt, mesh, FLAGS.batch_size,
-                keep_prob=FLAGS.keep_prob, chunk=length,
-                grad_transform=grad_transform,
-                per_token_targets=per_token_targets)
-        if ep_model is not None:
-            return make_ep_device_train_step(
-                ep_model, opt, mesh, FLAGS.batch_size,
-                keep_prob=FLAGS.keep_prob, chunk=length,
-                grad_transform=grad_transform)
-        if tp:
-            # GSPMD: the state's TP layout + the data-axis batch constraint
-            # drive the partitioner
-            return make_device_tp_train_step(
-                model, opt, mesh, FLAGS.batch_size,
-                keep_prob=FLAGS.keep_prob, chunk=length,
-                grad_transform=grad_transform, augment_fn=augment_fn)
-        if mesh is not None:
-            return make_device_dp_train_step(
-                model, opt, mesh, FLAGS.batch_size,
-                keep_prob=FLAGS.keep_prob, chunk=length,
-                grad_transform=grad_transform, augment_fn=augment_fn)
-        return make_device_train_step(
-            model, opt, FLAGS.batch_size,
-            keep_prob=FLAGS.keep_prob, chunk=length,
-            grad_transform=grad_transform, augment_fn=augment_fn)
-
-    chunk_fns: dict[int, Any] = {}
-
-    def run_chunk(state, length: int):
-        fn = chunk_fns.get(length)
-        if fn is None:
-            fn = chunk_fns[length] = build_chunk_fn(length)
-        return fn(state, data)
-
-    sv = Supervisor(
-        is_chief=(FLAGS.task_index == 0),
-        logdir=FLAGS.logdir,
-        save_model_secs=FLAGS.save_model_secs,
-        max_to_keep=max_to_keep_from_flags(FLAGS),
-        background_save=background_save_from_flags(FLAGS),
-        sharded_spanning=bool(getattr(FLAGS, "sharded_checkpoint", True)),
-    )
-    logger = MetricsLogger(FLAGS.logdir if sv.is_chief else None,
-                           job_name=FLAGS.job_name or "worker",
-                           task_index=FLAGS.task_index)
-    meter = Throughput(FLAGS.batch_size, n_chips)
-    eff = efficiency.meter_from_flags(FLAGS, model, FLAGS.batch_size,
-                                      n_chips)
-    rmon = resources.monitor_from_flags(FLAGS, model, opt,
-                                        FLAGS.batch_size, n_chips)
-    snt = _sentinel_for(FLAGS, sv, logger)
-    els = elastic.supervisor_from_flags(FLAGS)
+    sc = _scaffold(FLAGS, ds, model, opt, mesh, n_chips)
+    sv, logger, meter, stimer = sc.sv, sc.logger, sc.meter, sc.stimer
+    eff, rmon, snt, els, coord = sc.eff, sc.rmon, sc.snt, sc.els, sc.coord
+    eval_every = max(0, getattr(FLAGS, "eval_step", 0))
     last_display = {}
-    periodic_eval = _periodic_test_eval(FLAGS, sv, model, ds, logger,
-                                        eff=eff)
-    sync_every = collective_sync_cadence(mesh is not None)
     chunks_done = 0
-    stimer = StepTimer()
 
-    coord = (_HostCoordinator(sv, coord_steps_from_flags(FLAGS),
-                              stimer=stimer, logger=logger,
-                              elastic_sv=els)
-             if jax.process_count() > 1 else None)
-    should_stop = coord.should_stop if coord is not None else sv.should_stop
-
-    def draw_display_batch():
-        b = ds.train.next_batch(local_batch_size(FLAGS.batch_size))
-        return stage(b) if stage is not None else jax.device_put(b)
+    def log_display(step):
+        # the sentinel's last good state: the checkpoint-layout copy of
+        # this boundary, or a host snapshot of the live (donated) state
+        _display_log(
+            step, last_display, logger,
+            lambda: _display_scalars(meter, stimer, eff, rmon), eff, snt,
+            host if layout.to_host is not None
+            else lambda: _sentinel_host_state(live))
 
     with sv.managed(state) as box:
-        state, step = box.state, box.step
+        host, step = box.state, box.step
         _log_recovery(sv, logger, step, eff)
-        periodic_eval.prime(step)
-        if restage is not None:
-            # a restored checkpoint arrives as host arrays; re-place it on
-            # the TP mesh layout (no-op for a freshly placed state)
-            state = restage(state)
+        sc.periodic_eval.prime(step)
+        live = layout.to_live(host) if layout.to_live is not None else host
         compile_done = False
         profiling = False
         profile_done = not FLAGS.profile_dir
         meter.reset()
-        while not should_stop() and step < FLAGS.training_iter:
-            if step % FLAGS.display_step == 0:
-                # reference display semantics: dropout-off eval of a fresh
-                # minibatch before training continues (MNISTDist.py:179-182).
-                # Multi-process: each host draws its SLICE of the global
-                # batch — stage() assembles slices into the global array
+        while not sc.should_stop() and step < FLAGS.training_iter:
+            if layout.display is not None and step % FLAGS.display_step == 0:
+                eval_fn, stage = layout.display
                 last_display = _display_eval(
-                    step, eval_fn, state.params, state.model_state, eff,
-                    stimer, draw=draw_display_batch)
-                _display_log(
-                    step, last_display, logger,
-                    lambda: _display_scalars(meter, stimer, eff, rmon),
-                    eff, snt, lambda: _sentinel_host_state(state))
+                    step, eval_fn, live.params, live.model_state, eff, stimer,
+                    draw=lambda: stage(ds.train.next_batch(
+                        local_batch_size(FLAGS.batch_size))))
+                log_display(step)
             if compile_done and not profile_done and not profiling:
                 jax.profiler.start_trace(FLAGS.profile_dir)
                 profiling = True
@@ -2324,70 +1920,78 @@ def _train_device_resident(FLAGS, ds, model, opt, state, mesh, n_chips,
             to_boundary = -step % FLAGS.display_step or chunk
             length = min(chunk, to_boundary, FLAGS.training_iter - step)
             if rmon is not None:
-                rmon.note_dispatch("device_chunk", signature=(length,))
+                # the chunk's LENGTH is the signature the scan specializes on
+                rmon.note_dispatch(layout.span, signature=(length,))
             t0 = time.perf_counter()
-            with trace_span("device_chunk", step=step, length=length), \
-                    telemetry.armed("device_chunk", step=step,
-                                    length=length):
-                state, train_m = run_chunk(state, length)
+            with trace_span(layout.span, step=step, length=length), \
+                    telemetry.armed(layout.span, step=step, length=length):
+                fn = chunk_fns.get(length)
+                if fn is None:
+                    fn = chunk_fns[length] = layout.build_chunk(length)
+                live, train_m = fn(live, data)
             stimer.add("dispatch", time.perf_counter() - t0)
             step += length
             meter.step(length * FLAGS.batch_size)
             stimer.steps(length)
             chunks_done += 1
-            if sync_every and chunks_done % max(1, sync_every // chunk) == 0:
-                # metrics included: their in-flight pmeans must not
-                # interleave with the next program's gloo ops (see
-                # collective_sync_cadence)
+            if sc.sync_every and \
+                    chunks_done % max(1, sc.sync_every // chunk) == 0:
+                # metrics included: their in-flight pmeans must not interleave
+                # with the next program's gloo ops (collective_sync_cadence)
                 t0 = time.perf_counter()
                 with trace_span("device_sync", step=step), \
                         telemetry.armed("collective_sync", step=step):
-                    jax.block_until_ready((state.params, train_m))
+                    jax.block_until_ready((live.params, train_m))
                 stimer.add("device", time.perf_counter() - t0)
             if not compile_done:
-                # the first dispatch carried the XLA compile: charge the
-                # pre-compile window's work + this wait as an init stall
-                if eff is not None:
-                    eff.charge(stimer.cumulative_work()[0], "init")
-                with trace_span("device_sync", step=step), \
-                        _charged(eff, "init"):
-                    jax.block_until_ready(state.params)
-                meter.reset()
-                stimer.reset()  # compile stays out of the breakdown too
+                _close_compile_window(sc, live.params, step)
                 compile_done = True
             if profiling and step >= profile_stop_at:
-                jax.block_until_ready(state.params)
+                jax.block_until_ready(live.params)
                 jax.profiler.stop_trace()
                 profiling = False
                 profile_done = True
-            periodic_eval(state, step)
-            box.update(state, step)
-            if coord is not None:
-                # the vote allgather's wait is peer-coordination stall
-                # (mostly skew), not checkpoint time — label it apart
-                with _charged(eff, "coord"):
-                    coord.tick(state, step)
+            # without a coordinator a due membership change makes this
+            # chunk's end a boundary; with one the change becomes due in
+            # the vote, so the poll follows the tick
+            due = coord is None and els is not None and els.poll(step)
+            if layout.to_host is None:
+                host = live
             else:
-                with _charged(eff, "ckpt"):
-                    sv.maybe_checkpoint(state, step)
-            if els is not None and els.poll(step):
-                # membership change due: the StateBox already holds this
-                # boundary's state — drain via the managed-exit save and
-                # re-form (raises ResizeRequired)
+                # an eval boundary by CROSSING: chunks align to
+                # display_step, not eval_step, and can jump clean over a
+                # multiple of it; periodic_eval evaluates once a crossing
+                if not (step % FLAGS.display_step == 0
+                        or (eval_every and (step - length) // eval_every
+                            != step // eval_every)
+                        or sv.checkpointer.cadence_due()
+                        or step >= FLAGS.training_iter or due):
+                    continue
+                # the fetch blocks on the chunk's device work: booked in
+                # the breakdown's device column
+                t0 = time.perf_counter()
+                with trace_span("boundary_fetch", step=step), \
+                        telemetry.armed("boundary_fetch", step=step), \
+                        _charged(eff, "ckpt"):
+                    host = layout.to_host(live)
+                stimer.add("device", time.perf_counter() - t0)
+            box.update(host, step)
+            if layout.display is None and step % FLAGS.display_step == 0:
+                last_display = {k: float(v) for k, v in train_m.items()}
+                log_display(step)
+            sc.periodic_eval(host, step)
+            sc.checkpoint(host, step)
+            if coord is not None:
+                due = els is not None and els.poll(step)
+            if due:
+                # the StateBox holds this boundary's state: drain via the
+                # managed-exit save and re-form (raises ResizeRequired)
                 els.maybe_resize(step)
-        jax.block_until_ready(state.params)
+        jax.block_until_ready(live.params)
         if profiling:
             jax.profiler.stop_trace()
+        if layout.to_host is not None:
+            host = layout.to_host(live)
+            box.update(host, step)
 
-    test_metrics = _final_test_eval(FLAGS, sv, periodic_eval, model, state,
-                                    ds, logger, step)
-    print("Optimization Finished!")
-    logger.close()
-    return TrainResult(
-        final_step=step,
-        train_metrics=last_display,
-        test_metrics=test_metrics,
-        images_per_sec=meter.images_per_sec,
-        images_per_sec_per_chip=meter.images_per_sec_per_chip,
-        n_chips=n_chips,
-    )
+    return _finish(FLAGS, sc, model, host, ds, step, last_display)

@@ -1,4 +1,4 @@
-"""DTT003 violating fixture: a loop variant that forgets the scalar
+"""DTT003 violating fixture: a ``_train_*`` loop that forgets the scalar
 contract and the elastic poll."""
 
 

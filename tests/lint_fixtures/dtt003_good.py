@@ -1,4 +1,5 @@
-"""DTT003 conforming fixture: the full loop-variant contract."""
+"""DTT003 conforming fixture: a ``_train_*`` loop (as the device-resident
+driver and each host-fed loop are) that wires the full contract."""
 
 
 def _train_ok(FLAGS, ds, sv, logger, meter, stimer, eff, rmon, els):

@@ -1,5 +1,5 @@
-"""DTT005 conforming fixture: literal, conditional-variable and
-parameterized span names, all in the table."""
+"""DTT005 conforming fixture: literal, conditional-variable,
+field-carried and parameterized span names, all in the table."""
 
 
 def run(step, zb, point, tracer):
@@ -9,3 +9,12 @@ def run(step, zb, point, tracer):
     with trace_span(name, step=step):  # noqa: F821
         pass
     tracer.record_instant(f"fault:{point}", step=step)
+
+
+def drive(layout, step):
+    with trace_span(layout.span, step=step):  # noqa: F821
+        pass
+
+
+def choose(zb, step):
+    drive(Layout(span="field_a" if zb else "field_b"), step)  # noqa: F821

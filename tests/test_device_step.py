@@ -182,13 +182,30 @@ def test_train_loop_device_data(tmp_path, capsys):
     assert "Optimization Finished!" in out
 
 
-def test_train_loop_device_data_resume_realigns_display(tmp_path, capsys):
+# the three layouts of the device-resident driver (training/loop.py
+# ``_train_device``): (mode, the flags that choose the layout)
+_TINY_PP = ["--model=lm", "--dataset=lm", "--seq_len=32", "--vocab_size=16",
+            "--d_model=32", "--num_heads=2", "--num_blocks=2",
+            "--model_axis=2", "--pipeline"]
+DEVICE_LAYOUTS = {
+    "plain": ("local", []),
+    "zero": ("sync", ["--zero=1", "--model=mlp"]),
+    "pipeline": ("sync", _TINY_PP),
+}
+
+
+@pytest.mark.parametrize("layout", sorted(DEVICE_LAYOUTS))
+def test_train_loop_device_data_resume_realigns_display(tmp_path, capsys,
+                                                        layout):
     """Resuming from a step that is not a chunk multiple must realign to
-    display boundaries instead of silently never displaying again."""
+    display boundaries instead of silently never displaying again, in
+    every layout (under ZeRO and the pipeline the resumed state goes
+    through the layout's own placement first)."""
     from distributed_tensorflow_tpu import flags
     from distributed_tensorflow_tpu.training.loop import train
 
     flags.define_reference_flags()
+    mode, layout_flags = DEVICE_LAYOUTS[layout]
 
     def run(training_iter):
         flags.FLAGS._reset()
@@ -202,9 +219,10 @@ def test_train_loop_device_data_resume_realigns_display(tmp_path, capsys):
             "--save_model_secs=100000",
             "--device_data",
             "--device_chunk=10",
+            *layout_flags,
         ])
         try:
-            return train(flags.FLAGS, mode="local")
+            return train(flags.FLAGS, mode=mode)
         finally:
             flags.FLAGS._reset()
 
@@ -216,13 +234,17 @@ def test_train_loop_device_data_resume_realigns_display(tmp_path, capsys):
     assert "step:  20 mini_batch loss:" in out
 
 
-def test_train_loop_device_data_profile_dir(tmp_path):
+@pytest.mark.parametrize("layout", ["plain", "pipeline"])
+def test_train_loop_device_data_profile_dir(tmp_path, layout):
+    """--profile_dir opens the profiler's window in the one driver, so
+    under --pipeline too (whose own copy of the loop had none)."""
     import glob
 
     from distributed_tensorflow_tpu import flags
     from distributed_tensorflow_tpu.training.loop import train
 
     flags.define_reference_flags()
+    mode, layout_flags = DEVICE_LAYOUTS[layout]
     flags.FLAGS._reset()
     flags.FLAGS._parse([
         f"--logdir={tmp_path}/logs",
@@ -235,9 +257,10 @@ def test_train_loop_device_data_profile_dir(tmp_path):
         "--device_chunk=5",
         f"--profile_dir={tmp_path}/prof",
         "--profile_steps=5",
+        *layout_flags,
     ])
     try:
-        train(flags.FLAGS, mode="local")
+        train(flags.FLAGS, mode=mode)
     finally:
         flags.FLAGS._reset()
     assert glob.glob(f"{tmp_path}/prof/**/*.trace*", recursive=True) or \

@@ -477,6 +477,13 @@ LOOP_VARIANTS = {
               "--num_blocks=4", "--model_axis=2", "--pipeline",
               "--pp_schedule=zb"],
     "zero": ["--zero=1"],
+    # the device-resident driver's other two layouts ("device_resident"
+    # is its plain one): the same contract from the one loop
+    "pp_device": ["--model=lm", "--dataset=lm", "--seq_len=32",
+                  "--vocab_size=16", "--d_model=32", "--num_heads=2",
+                  "--num_blocks=2", "--model_axis=2", "--pipeline",
+                  "--device_data", "--device_chunk=5"],
+    "zero_device": ["--zero=1", "--device_data", "--device_chunk=5"],
     # r14: the overlapped-ZeRO collective pattern rides its own spans
     # (zero_step_overlap) and ledger pricing — same contract
     "zero_overlap": ["--zero=3", "--zero_overlap",
@@ -500,7 +507,8 @@ STANDARD_SCALARS = (
 @pytest.mark.parametrize("variant", sorted(LOOP_VARIANTS))
 def test_scalar_contract_every_loop_variant(tmp_path, fresh_flags,
                                             variant):
-    """Table-driven: all four loop variants emit the STANDARD scalar
+    """Table-driven: every host-fed loop and every layout of the
+    device-resident driver emits the STANDARD scalar
     set (throughput, breakdown, efficiency, hbm, compiles, comm) in
     metrics.jsonl, and the resource-plane markers land in the span
     sink."""

@@ -19,9 +19,10 @@ docs/ARCHITECTURE.md "Static analysis"):
                            forwarded parameter — never a string literal
   DTT002 ledger-coverage   a parallel/ module with collectives must
                            export a ``*_comm_rows`` pricing builder
-  DTT003 scalar-contract   every ``_train_*`` loop variant emits the
-                           standard scalar families and polls
-                           ``maybe_resize``
+  DTT003 scalar-contract   every ``_train_*`` loop of training/loop.py
+                           (the device-resident driver, the three
+                           host-fed loops) emits the standard scalar
+                           families and polls ``maybe_resize``
   DTT004 fault-registry    fired point names exist in
                            ``INJECTION_POINTS``; no registered point is
                            orphaned

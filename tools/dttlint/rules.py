@@ -208,9 +208,13 @@ _LOOP_CONTRACT = {
 
 
 def rule_scalar_contract(index) -> list:
-    """DTT003: every ``_train_*`` loop variant must statically wire the
-    full scalar contract and poll the elastic supervisor — the bug
-    class PR 8 had to add a runtime contract test for."""
+    """DTT003: every ``_train_*`` loop must statically wire the full
+    scalar contract and poll the elastic supervisor — the bug class PR 8
+    had to add a runtime contract test for. In training/loop.py that is
+    the device-resident driver (``_train_device``, every ``--device_data``
+    layout) and the three host-fed loops (``_train_once``,
+    ``_train_pipeline``, ``_train_zero``); the rule goes, with its tests,
+    once those three are one loop too."""
     out = []
     for rel, tree in index.trees.items():
         for node in tree.body:
@@ -313,10 +317,13 @@ def _doc_span_names(doc_text: str) -> tuple[set, set]:
     return exact, prefixes
 
 
-def _resolve_span_name(first, func_def) -> tuple[list, list]:
+def _resolve_span_name(first, func_def, tree=None) -> tuple[list, list]:
     """First arg of a span call -> (exact names, prefix candidates).
     Name args resolve through assignments in the enclosing function
-    (the span_name/chunk_span/zspan conditional-constant pattern)."""
+    (the span_name/zspan conditional-constant pattern); attribute args
+    (``layout.span``) through the keyword arguments of that name anywhere
+    in the module (``_DeviceLayout(span=...)``: the caller that chose the
+    mode names the span, the one driver emits it)."""
     if isinstance(first, ast.Constant) and isinstance(first.value, str):
         return [first.value], []
     if isinstance(first, ast.JoinedStr):
@@ -331,6 +338,12 @@ def _resolve_span_name(first, func_def) -> tuple[list, list]:
             if isinstance(sub, ast.Assign) and any(
                     isinstance(t, ast.Name) and t.id == first.id
                     for t in sub.targets):
+                names += _value_constants(sub.value)
+        return names, []
+    if isinstance(first, ast.Attribute) and tree is not None:
+        names = []
+        for sub in ast.walk(tree):
+            if isinstance(sub, ast.keyword) and sub.arg == first.attr:
                 names += _value_constants(sub.value)
         return names, []
     return [], []
@@ -403,7 +416,7 @@ def rule_span_catalog(index) -> list:
                     and node.args):
                 continue
             names, prefixes = _resolve_span_name(
-                node.args[0], enclosing.get(id(node)))
+                node.args[0], enclosing.get(id(node)), tree)
             for name in names:
                 seen_exact.add(name)
                 if name in exact_doc:
