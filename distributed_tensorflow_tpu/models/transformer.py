@@ -705,6 +705,7 @@ class TransformerLM:
                 "rows_per_expert_mean": by["rows_per_expert_mean"].mean(),
                 "overflow_rows": by["overflow_rows"].sum(),
                 "buffer_fill_max": by["buffer_fill"].max(),
+                "tiles_run_frac": by["tiles_run_frac"].mean(),
                 "unrouted_frac": by["unrouted_frac"].mean(),
             }
         elif self.moe_experts:

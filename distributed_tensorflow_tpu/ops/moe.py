@@ -147,7 +147,9 @@ def routed_capacity(tokens: int, top_k: int, held: int, experts: int,
     """Rows of the sorted buffer: ``capacity_factor`` times the (row,
     expert) pairs that uniform routing sends to the ``held`` of
     ``experts`` experts, rounded up to the grouped product's row tile, and
-    never more than every pair there is."""
+    never more than every pair there is. The rows are memory and the static
+    passes of the dispatch (sort, gather, add-back); the grouped products
+    cost the pairs that arrive, not these rows."""
     expected = tokens * top_k * held / experts
     rows = min(math.ceil(capacity_factor * expected), tokens * min(top_k, held))
     return max(ROW_TILE, -(-rows // ROW_TILE) * ROW_TILE)
@@ -192,6 +194,15 @@ def _ragged(lhs, rhs, sizes):
     return lax.ragged_dot(lhs, rhs, sizes, preferred_element_type=lhs.dtype)
 
 
+def _ragged_grads(lhs, rhs, sizes, g):
+    return jax.vjp(lambda a, b: _ragged(a, b, sizes), lhs, rhs)[1](g)
+
+
+def _pallas_grads(lhs, rhs, sizes, g):
+    return (_grouped_pallas(g, rhs, sizes, transpose_rhs=True),
+            _grouped_pallas_rhs_grad(lhs, g, sizes, rhs.shape[0], rhs.dtype))
+
+
 def _path_marked(fn, path, pass_name):
     from distributed_tensorflow_tpu.utils.profiling import lowering_instant
 
@@ -216,11 +227,17 @@ def _pallas_takes(lhs, rhs) -> bool:
 def grouped_matmul(lhs, rhs, sizes):
     """(m, k) rows sorted by group times the (g, k, n) matrix of each
     row's group: rows ``sum(sizes[:i]) .. sum(sizes[:i+1])`` meet
-    ``rhs[i]``. What the rows past ``sum(sizes)`` hold is not defined
-    (``routed_experts`` leaves none). On a TPU, for bf16 at aligned shapes, the
-    Pallas grouped product (``jax.experimental.pallas.ops.tpu.megablox``)
-    visits only the row tiles a group reaches into; elsewhere
-    ``lax.ragged_dot``. Which was lowered is the ``moe_path`` instant."""
+    ``rhs[i]``. ``sum(sizes)`` may be less than m. The rows past it belong
+    to no group: the gradient with respect to ``rhs[i]`` sums over group
+    i's rows alone whatever ``lhs`` and the incoming gradient hold there,
+    and what the result and the gradient with respect to ``lhs`` hold there
+    is NOT DEFINED: the caller selects it away (``jnp.where``, never a
+    product: it may be a NaN), as ``routed_experts`` does. On a TPU, for
+    bf16 at aligned shapes, the Pallas grouped product
+    (``jax.experimental.pallas.ops.tpu.megablox``) visits only the row tiles
+    a group reaches into and leaves the others unwritten, so its cost
+    follows ``sizes`` and not m; elsewhere ``lax.ragged_dot``. Which was
+    lowered is the ``moe_path`` instant."""
     return _grouped_forward(lhs, rhs, sizes)
 
 
@@ -241,22 +258,13 @@ def _grouped_fwd(lhs, rhs, sizes):
 def _grouped_bwd(res, g):
     lhs, rhs, sizes = res
     g = g.astype(lhs.dtype)
-
-    def plain(lhs, rhs, sizes, g):
-        return jax.vjp(lambda a, b: _ragged(a, b, sizes), lhs, rhs)[1](g)
-
-    def pallas(lhs, rhs, sizes, g):
-        return (_grouped_pallas(g, rhs, sizes, transpose_rhs=True),
-                _grouped_pallas_rhs_grad(lhs, g, sizes, rhs.shape[0],
-                                         rhs.dtype))
-
-    plain = _path_marked(plain, "ragged_dot", "backward")
+    plain = _path_marked(_ragged_grads, "ragged_dot", "backward")
     if not _pallas_takes(lhs, rhs):
         dl, dr = plain(lhs, rhs, sizes, g)
     else:
         dl, dr = lax.platform_dependent(
             lhs, rhs, sizes, g, default=plain,
-            tpu=_path_marked(pallas, "pallas_gmm", "backward"))
+            tpu=_path_marked(_pallas_grads, "pallas_gmm", "backward"))
     import numpy as np
 
     from jax.dtypes import float0
@@ -265,6 +273,35 @@ def _grouped_bwd(res, g):
 
 
 grouped_matmul.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+def row_tiles_run(sizes, tile: int):
+    """Row-tile visits of the forward grouped product over groups of
+    ``sizes`` rows at ``tile`` rows a tile: the tiles each non-empty group
+    reaches into, summed (a tile two groups share is visited by both), which
+    is ``num_active_tiles`` of ``megablox``'s ``make_group_metadata``."""
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    return jnp.sum(
+        jnp.where(sizes > 0, -(-ends // tile) - starts // tile, 0))
+
+
+@jax.custom_vjp
+def _gather_live(hf, row, live):
+    """``hf[row]``, whose gradient adds back the ``live`` rows' alone: the
+    others go to an index past ``hf``, which the scatter-add drops unread
+    (what a grouped product left unwritten there is no number to add)."""
+    return hf[row]
+
+
+def _gather_live_bwd(res, g):
+    hf, row, live = res
+    return (jnp.zeros_like(hf).at[jnp.where(live, row, hf.shape[0])].add(
+        g, mode="drop"), None, None)
+
+
+_gather_live.defvjp(lambda hf, row, live: (hf[row], (hf, row, live)),
+                    _gather_live_bwd)
 
 
 def routed_experts(h, params, *, top_k: int, first_expert: int = 0,
@@ -283,15 +320,22 @@ def routed_experts(h, params, *, top_k: int, first_expert: int = 0,
     Rows are not dropped: the (row, expert) pairs whose expert is held
     are sorted by expert into a buffer of ``routed_capacity`` rows (static
     shapes), the experts run as two grouped products over it, and the
-    results are scatter-added back in f32. The buffer's rows past the last
-    pair hold pairs of experts not held, at weight nought, and ride with
-    the last held expert: every row of the buffer is written, nothing is
-    masked, and a step costs the same whatever the routing (the buffer's
-    size is the cost: ``capacity_factor``). ``aux``: the held experts'
-    rows (``rows_per_expert_max`` / ``_mean``), ``buffer_fill`` (pairs over
-    the buffer's rows), ``unrouted_frac``, and ``overflow_rows``, the pairs
-    the buffer could not take: the caller fails the step when it is not 0
-    (``TransformerLM`` makes the loss NaN), never a silent drop."""
+    results are scatter-added back in f32. The groups end at the last
+    pair: the buffer's rows past it (pairs of experts not held, at weight
+    nought) go through no expert. What the products leave there, and all
+    that is computed from it, ends in a select (``jnp.where``) inside the
+    passes that read it (the gate, the weighting) or is dropped by index
+    (``_gather_live``), never in a product with a weight of nought: no pass
+    over the buffer is added for it. So the buffer's size (``capacity_factor``) is memory and
+    the static passes (sort, gather, add-back), and the products cost the
+    pairs the routing sent: a step's time follows the routing. ``aux``: the
+    held experts' rows (``rows_per_expert_max`` / ``_mean``),
+    ``buffer_fill`` (pairs over the buffer's rows), ``tiles_run_frac``
+    (``row_tiles_run`` of the forward product over the buffer's row tiles:
+    the share of the buffer the products compute, a tile that two groups
+    share counted twice), ``unrouted_frac``, and ``overflow_rows``, the
+    pairs the buffer could not take: the caller fails the step when it is
+    not 0 (``TransformerLM`` makes the loss NaN), never a silent drop."""
     from distributed_tensorflow_tpu.utils.profiling import scope
 
     b, s, d = h.shape
@@ -323,16 +367,17 @@ def routed_experts(h, params, *, top_k: int, first_expert: int = 0,
         # (a buffer with more room than there are pairs: the rest is
         # never valid)
         order = jnp.pad(order, (0, max(0, cap - t * top_k)))[:cap]
-        row = order // top_k
         counts = jnp.sum(
             jax.nn.one_hot(key, held, dtype=jnp.int32), axis=0)  # (held,)
         total = jnp.sum(counts)
+        # the last group ends at the last pair (at the buffer's end when
+        # it overflows): the products leave the rows past it alone
         ends = jnp.minimum(jnp.cumsum(counts), cap)
-        # the rows past the last pair ride with the last expert
-        sizes = jnp.diff(ends.at[-1].set(cap), prepend=0).astype(jnp.int32)
-        weight = jnp.where(jnp.arange(cap) < ends[-1],
-                           gate.reshape(t * top_k)[order], 0.0)
-        xs = hf[row]                                            # (cap, d)
+        sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
+        live = jnp.arange(cap) < ends[-1]
+        weight = jnp.where(live, gate.reshape(t * top_k)[order], 0.0)
+        row = order // top_k
+        xs = _gather_live(hf, row, live)                        # (cap, d)
         if cd is not None:
             xs = xs.astype(cd)
 
@@ -340,17 +385,26 @@ def routed_experts(h, params, *, top_k: int, first_expert: int = 0,
         w1, w2 = params["w1"], params["w2"]
         if cd is not None:
             w1, w2 = w1.astype(cd), w2.astype(cd)
+        # what a product left past the last pair (it may be a NaN) is
+        # selected away inside the pass that reads it: after the gate's
+        # slices and after the weighting, where XLA fuses the select and
+        # its transpose into the passes there were (a select on the whole
+        # of ``up``, or on ``ys`` before its weight, is a pass of its own)
+        keep = live[:, None]
         up = grouped_matmul(xs, w1, sizes)                      # (cap, 2 f)
-        act = jax.nn.silu(up[:, :f]) * up[:, f:]
+        act = (jax.nn.silu(jnp.where(keep, up[:, :f], 0))
+               * jnp.where(keep, up[:, f:], 0))
         ys = grouped_matmul(act, w2, sizes)                     # (cap, d)
 
     with scope("moe_router"):
-        y = jnp.zeros((t, d), jnp.float32).at[row].add(
-            ys.astype(jnp.float32) * weight[:, None])
+        y = jnp.zeros((t, d), jnp.float32).at[row].add(jnp.where(
+            keep, ys.astype(jnp.float32) * weight[:, None], 0))
         aux = {
             "rows_per_expert_max": jnp.max(counts).astype(jnp.float32),
             "rows_per_expert_mean": jnp.mean(counts.astype(jnp.float32)),
             "buffer_fill": total.astype(jnp.float32) / cap,
+            "tiles_run_frac": row_tiles_run(sizes, ROW_TILE).astype(
+                jnp.float32) / (cap // ROW_TILE),
             "overflow_rows": (total - ends[-1]).astype(jnp.float32),
             "unrouted_frac": 1.0 - jnp.mean(
                 jnp.any(is_held, axis=-1).astype(jnp.float32)),

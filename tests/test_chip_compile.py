@@ -261,9 +261,21 @@ def test_routed_layer_compiles_at_the_cells_widths(one_chip):
     buffer of 4 x: 32,768 rows; the whole takes 45 s to compile):
     the four grouped products of a pass are Mosaic kernels under the
     ``moe_experts`` scope (a tile that does not fit VMEM fails here, as
-    the transposed product's 1,024-row tile did), the routing, the gather
-    and the add-back are under ``moe_router``."""
+    the transposed product's 1,024-row tile did; the ``moe_path`` instants
+    say the same), the routing, the gather and the add-back are under
+    ``moe_router``. The products' groups end at the last pair, and what
+    they leave unwritten past it is selected away inside the passes that
+    were there: as when every row went through the experts, seven fusions
+    read or write an array of the buffer's rows and a width (the gather,
+    the gather of the output's gradient, its weighting with the weights'
+    gradient, the gate, the gate's backward, the sum of its two gradients,
+    the scatter-add of the rows' gradient) and six of them write one. A
+    select that failed to fuse is a pass of its own over the buffer (two
+    more with the selects at ``grouped_matmul``'s edge; one more, a sum
+    that reads the buffer again, with the select before the weighting
+    instead of after it) and fails here, not on the chip."""
     from distributed_tensorflow_tpu.ops.moe import routed_experts
+    from distributed_tensorflow_tpu.utils import telemetry
 
     h = jax.ShapeDtypeStruct((1, 8192, 2048), jnp.bfloat16)
     params = {"router": jax.ShapeDtypeStruct((2048, 128), jnp.float32),
@@ -275,11 +287,27 @@ def test_routed_layer_compiles_at_the_cells_widths(one_chip):
                                 compute_dtype=jnp.bfloat16)
         return y.astype(jnp.float32).sum() + aux["overflow_rows"]
 
+    telemetry.get_tracer().clear()
     hlo = jax.jit(jax.grad(loss, (0, 1))).lower(
         *_on(one_chip, (h, params))).compile().as_text()
+    lowered = [(r["path"], r["pass"]) for r in telemetry.last_spans(100)
+               if r["name"] == "moe_path"]
+    assert sorted(lowered) == ([("pallas_gmm", "backward")] * 2
+                               + [("pallas_gmm", "forward")] * 2)
     paths = re.findall(
         r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', hlo)
     # forward: 2 products; backward: 2 for the rows, 2 for the matrices
     assert len(paths) == 6 and all("moe_experts" in p for p in paths)
     assert sum("tgmm" in p for p in paths) == 2
     assert "bf16[32768,2048]" in hlo and "moe_router" in hlo
+    entry = hlo[hlo.index("ENTRY"):]
+    buffer = re.compile(r"\[32768,\d+\]")
+    types = dict(re.findall(
+        r"^\s*(?:ROOT )?(%[\w.-]+) = (\(.*?\)|\S+) ", entry, flags=re.M))
+    fusions = re.findall(
+        r"^\s*(?:ROOT )?%[\w.-]+ = (\(.*?\)|\S+) fusion\((.*?)\), kind=",
+        entry, flags=re.M)
+    writes = [ty for ty, _ in fusions if buffer.search(ty)]
+    touches = [ty for ty, args in fusions if buffer.search(ty) or any(
+        buffer.search(types.get(a, "")) for a in re.findall(r"%[\w.-]+", args))]
+    assert (len(writes), len(touches)) == (6, 7), touches

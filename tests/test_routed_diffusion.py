@@ -13,6 +13,9 @@ brought, each held to something written independently:
   case bit-equal to the form it had before the mask became a description;
 - dropless routing: no row lost however the router piles them up, and the
   overflow counter rises only past the stated capacity;
+- the experts' products stop at the last pair: what the kernels leave
+  unwritten past it (NaN standing in for it here) reaches no output and no
+  gradient, and ``tiles_run_frac`` is the kernel's own count of its work;
 - the streamed head's row weights, the noise's key, the flags' validators.
 
 d 64, 4 query / 2 key-value heads of width 16, 16 experts of width 32 with
@@ -469,7 +472,7 @@ def test_remat_keeps_the_counters_the_loss_and_the_gradients():
     counters = {k for k in metrics if k.startswith("moe_")}
     assert {"moe_rows_per_expert_max", "moe_rows_per_expert_mean",
             "moe_overflow_rows", "moe_buffer_fill_max",
-            "moe_unrouted_frac"} <= counters
+            "moe_tiles_run_frac", "moe_unrouted_frac"} <= counters
     assert metrics.keys() == r_metrics.keys()
     for k in metrics:
         assert float(metrics[k]) == float(r_metrics[k]), k
@@ -482,6 +485,166 @@ def test_capacity_is_a_multiple_of_the_row_tile_and_never_past_every_pair():
     assert moe.routed_capacity(32768, 8, 16, 128, 1.25) == 40960
     assert moe.routed_capacity(32768, 8, 16, 128, 100.0) == 32768 * 8
     assert moe.routed_capacity(16, 2, 4, 8, 1.0) == moe.ROW_TILE
+
+
+# ---- the products stop at the last pair ---------------------------------------
+
+def test_grouped_matmul_with_rows_past_the_last_group():
+    """``sum(sizes) < m``: the groups' rows are the full product's, the
+    matrices' gradient sums over the groups' rows alone whatever ``lhs`` and
+    the incoming gradient hold past them; what comes back for those rows is
+    the caller's to select away."""
+    k = jax.random.split(jax.random.key(3), 3)
+    sizes = jnp.array([5, 0, 7], jnp.int32)
+    live, group = 12, np.repeat(np.arange(3), [5, 0, 7])
+    lhs = jax.random.normal(k[0], (24, 8)).at[live:].set(jnp.nan)
+    rhs = jax.random.normal(k[1], (3, 8, 16))
+    g = jax.random.normal(k[2], (24, 16)).at[live:].set(jnp.nan)
+    with jax.default_matmul_precision("highest"):
+        out, vjp = jax.vjp(lambda a, b: moe.grouped_matmul(a, b, sizes),
+                           lhs, rhs)
+        dl, dr = vjp(g)
+    want = np.einsum("mk,mkn->mn", lhs[:live], np.asarray(rhs)[group])
+    np.testing.assert_allclose(out[:live], want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(
+        dl[:live], np.einsum("mn,mkn->mk", g[:live], np.asarray(rhs)[group]),
+        rtol=1e-5, atol=1e-5)
+    want_dr = np.zeros((3, 8, 16), np.float32)
+    np.add.at(want_dr, group,
+              np.einsum("mk,mn->mkn", lhs[:live], g[:live]))
+    np.testing.assert_allclose(dr, want_dr, rtol=1e-5, atol=1e-5)
+    assert not np.any(want_dr[1]) and np.all(np.isfinite(dr))
+
+
+def _poisoned(fn, which):
+    """``fn`` (a grouped product or its gradients) leaving NaN where the
+    Pallas kernels leave memory unwritten: the rows past ``sum(sizes)`` of
+    the result, or of the gradient with respect to the rows."""
+    def run(lhs, rhs, sizes, *g):
+        out = fn(lhs, rhs, sizes, *g)
+        dead = (jnp.arange(lhs.shape[0]) >= jnp.sum(sizes))[:, None]
+        if which == "result":
+            return jnp.where(dead, jnp.nan, out)
+        return jnp.where(dead, jnp.nan, out[0]), out[1]
+    return run
+
+
+def test_what_the_products_leave_unwritten_is_never_read_as_a_number(
+        monkeypatch):
+    """On the chip the rows past the last pair are uninitialised memory in
+    the products' results and in their gradients with respect to the rows.
+    With NaN standing there, the layer's output and the gradients of its
+    input, its router and both expert matrices are finite and those of the
+    plain run: each of those rows is selected away or dropped by index,
+    never multiplied by a weight of nought."""
+    b, full = _uncut_layer(4)
+    share = {"router": full["router"], "w1": full["w1"][4:8],
+             "w2": full["w2"][4:8]}
+    probe = jax.random.normal(jax.random.key(5), b.shape)
+
+    def run(b, share):
+        def loss(b, share):
+            y, aux = moe.routed_experts(b, share, top_k=4, first_expert=4,
+                                        capacity_factor=4.0)
+            return jnp.sum(y * probe), (y, aux)
+        (_, (y, aux)), grads = jax.value_and_grad(
+            loss, (0, 1), has_aux=True)(b, share)
+        return y, aux, jax.tree.leaves(grads)
+
+    with jax.default_matmul_precision("highest"):
+        y, aux, grads = run(b, share)
+        assert 0 < float(aux["buffer_fill"]) < 1  # there are such rows
+        monkeypatch.setattr(moe, "_ragged", _poisoned(moe._ragged, "result"))
+        monkeypatch.setattr(moe, "_ragged_grads",
+                            _poisoned(moe._ragged_grads, "gradients"))
+        # the stand-ins do poison what grouped_matmul hands back
+        sizes = jnp.array([3, 2], jnp.int32)
+        out, vjp = jax.vjp(
+            lambda a: moe.grouped_matmul(a, jnp.ones((2, 4, 4)), sizes),
+            jnp.ones((8, 4)))
+        assert np.all(np.isnan(out[5:])) and np.all(np.isfinite(out[:5]))
+        assert np.all(np.isnan(vjp(jnp.ones((8, 4)))[0][5:]))
+        p_y, p_aux, p_grads = run(b, share)
+    assert np.all(np.isfinite(p_y))
+    np.testing.assert_array_equal(p_y, y)
+    assert len(grads) == len(p_grads) == 4
+    for want, got in zip(grads, p_grads):
+        assert np.all(np.isfinite(got)) and np.any(got)
+        np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("why,counts", [
+    ("an empty group", [5, 0, 9, 3]),
+    ("a group ending on a tile's edge", [16, 5, 0, 2]),
+    ("every group on an edge", [8, 8, 16, 8]),
+    ("all pairs in one group", [0, 0, 37, 0]),
+    ("groups cut inside one tile", [3, 2, 1, 1]),
+    ("a full buffer", [16, 16, 16, 16]),
+    ("an overflow", [30, 30, 30, 30]),
+    ("no pair at all", [0, 0, 0, 0]),
+])
+def test_tiles_run_is_the_kernels_own_count(why, counts):
+    """``row_tiles_run`` against ``num_active_tiles`` of the Pallas grouped
+    product's ``make_group_metadata``, for group sizes made as
+    ``routed_experts`` makes them (clipped at the buffer's 64 rows)."""
+    cap, tile = 64, 8
+    ends = np.minimum(np.cumsum(counts), cap)
+    sizes = jnp.asarray(np.diff(ends, prepend=0), jnp.int32)
+    _, theirs = moe._megablox().make_group_metadata(
+        group_sizes=sizes, m=cap, tm=tile, start_group=jnp.int32(0),
+        num_nonzero_groups=len(counts), visit_empty_groups=False)
+    assert int(moe.row_tiles_run(sizes, tile)) == int(theirs), why
+    if why in ("a full buffer", "an overflow"):  # and the tiles cut in two
+        assert int(theirs) == {"a full buffer": 8, "an overflow": 10}[why]
+    if why == "no pair at all":
+        assert int(theirs) == 0
+
+
+def test_the_layer_reports_the_tiles_its_products_run(monkeypatch):
+    """``tiles_run_frac`` is the count above for the layer's own routing
+    (made again here from the logits), over the buffer's row tiles."""
+    monkeypatch.setattr(moe, "ROW_TILE", 8)
+    b, full = _uncut_layer(2)
+    share = {"router": full["router"], "w1": full["w1"][:4],
+             "w2": full["w2"][:4]}
+    rows = b.shape[0] * b.shape[1]
+    with jax.default_matmul_precision("highest"):
+        _, aux = moe.routed_experts(b, share, top_k=4, capacity_factor=4.0)
+        logits = np.asarray(b.reshape(rows, 64) @ full["router"])
+    chosen = np.argsort(-logits, axis=-1)[:, :4]
+    counts = np.bincount(chosen[chosen < 4], minlength=4)
+    cap = moe.routed_capacity(rows, 4, 4, 16, 4.0)
+    assert counts.sum() < cap and cap % 8 == 0
+    ends = np.cumsum(counts)
+    tiles = sum(-(-e // 8) - (e - c) // 8
+                for e, c in zip(ends, counts) if c)
+    assert float(aux["tiles_run_frac"]) == pytest.approx(tiles / (cap // 8))
+    assert float(aux["buffer_fill"]) == pytest.approx(counts.sum() / cap)
+    assert float(aux["buffer_fill"]) <= float(aux["tiles_run_frac"]) < 1
+
+
+def test_an_overflow_still_counts_fails_the_step_and_runs_every_tile(
+        monkeypatch):
+    """The groups are clipped at the buffer's end: an overflow is counted,
+    makes the loss NaN and has the products run every row tile; with room,
+    ``moe_tiles_run_frac`` (the layers' mean) is under 1."""
+    monkeypatch.setattr(moe, "ROW_TILE", 8)
+    params = small_model().init(jax.random.key(0))
+    x = jax.random.randint(jax.random.key(1), (4, 64), 0, 299)
+    got = {}
+    for capacity in (0.25, 4.0):
+        model = small_model(moe_capacity=capacity)
+        batch = model.noise_batch((x, x), jax.random.key(2))
+        got[capacity] = jax.jit(functools.partial(
+            model.loss_with_metrics, train=True))(params, *batch)
+    loss, metrics = got[0.25]
+    assert float(metrics["moe_overflow_rows"]) > 0 and np.isnan(float(loss))
+    assert float(metrics["moe_tiles_run_frac"]) >= 1
+    loss, metrics = got[4.0]
+    assert float(metrics["moe_overflow_rows"]) == 0
+    assert np.isfinite(float(loss))
+    assert (float(metrics["moe_buffer_fill_max"]) / 2
+            < float(metrics["moe_tiles_run_frac"]) < 1)
 
 
 # ---- the streamed head, the noise, the flags ---------------------------------
@@ -546,7 +709,7 @@ def test_the_noise_comes_from_the_key_and_the_eval_agrees_with_itself():
     assert set(one) >= {"loss", "accuracy", "diffusion_masked_frac",
                         "moe_rows_per_expert_max", "moe_rows_per_expert_mean",
                         "moe_overflow_rows", "moe_unrouted_frac",
-                        "moe_buffer_fill_max"}
+                        "moe_buffer_fill_max", "moe_tiles_run_frac"}
     # about (1 - 4/16)^4 = 0.32 of the rows choose none of the four held
     assert 0.2 < float(one["moe_unrouted_frac"]) < 0.45
 
@@ -653,6 +816,8 @@ def test_the_trainer_runs_the_configuration_from_flags_alone(tmp_path):
     for r in display:
         assert np.isfinite(r["mini_batch_loss"])
         assert r["moe_overflow_rows"] == 0 and 0 < r["moe_buffer_fill_max"] <= 1
+        # the buffer is one row tile here, which each held expert visits
+        assert r["moe_tiles_run_frac"] in (1, 2, 3, 4)
         assert r["moe_rows_per_expert_max"] >= r["moe_rows_per_expert_mean"] > 0
         assert 0.2 < r["moe_unrouted_frac"] < 0.45
         assert 0.3 < r["diffusion_masked_frac"] < 0.7
