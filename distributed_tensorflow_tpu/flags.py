@@ -390,6 +390,41 @@ def define_reference_flags():
                    "experts alike)")
     DEFINE_boolean("biases", True, "Biases on the feed-forward and the "
                    "output head (the attention projections have none)")
+    DEFINE_string("layer_plan", "", "Layers that DIFFER: one entry a "
+                  "layer, <attention>:<query heads>:<feed-forward> joined "
+                  "by commas, attention full (the model's causal mask) or "
+                  "window (--attn_window keys), feed-forward dense (the "
+                  "--mlp_gated MLP of 4 x --d_model) or routed "
+                  "(--moe_top_k); e.g. full:48:dense,window:64:routed. "
+                  "As many entries as --num_blocks. Empty: every layer "
+                  "alike, from the other flags. Local and data-parallel "
+                  "training only: the steps that split a model over the "
+                  "mesh's model axis, and serving, refuse it")
+    DEFINE_integer("attn_window", 0, "Keys a query of a window layer of "
+                   "--layer_plan sees: itself and the window - 1 before "
+                   "it")
+    DEFINE_float("window_rope_theta", 0.0, "Rotary base of --layer_plan's "
+                 "window layers, on the whole head width, unscaled; 0 = "
+                 "the full layers' --rope_theta, --rope_fraction and "
+                 "--rope_yarn")
+    DEFINE_float("rope_fraction", 1.0, "Share of the head width that "
+                 "--rope_theta rotates (the first dimensions; the rest "
+                 "pass through)")
+    DEFINE_string("rope_yarn", "", "YaRN scaling of --rope_theta's "
+                  "frequencies: factor,original_positions,beta_fast,"
+                  "beta_slow,attention_factor (the last multiplies cos "
+                  "and sin); empty = none")
+    DEFINE_boolean("attn_gate", False, "A sigmoid gate a head and row on "
+                   "the attention's output, from the layer's normalised "
+                   "input through a (d_model, heads) matrix")
+    DEFINE_integer("moe_shared_dim", 0, "If > 0, beside the routed "
+                   "experts of --moe_top_k one gated expert of this width "
+                   "that every row takes, whole on every chip")
+    DEFINE_string("moe_scoring", "softmax", "How --moe_top_k's router "
+                  "turns logits into the scores it ranks and weights by: "
+                  "softmax over all experts, or sigmoid of each")
+    DEFINE_float("moe_scale", 1.0, "Factor on --moe_top_k's renormalised "
+                 "top-k weights")
     DEFINE_string("objective", "next_token", "The LM's training "
                   "objective: next_token (causal, shifted targets) or "
                   "masked_diffusion (diffusion over blocks of "
@@ -642,6 +677,7 @@ def define_reference_flags():
     FLAGS._register_validator(_validate_core_flags)
     FLAGS._register_validator(_validate_model_data_flags)
     FLAGS._register_validator(_validate_lm_arch_flags)
+    FLAGS._register_validator(_validate_layer_plan_flags)
     FLAGS._register_validator(_validate_pairing_flags)
     FLAGS._register_validator(_validate_pipeline_flags)
     FLAGS._register_validator(_validate_elastic_flags)
@@ -1025,6 +1061,92 @@ def _validate_lm_arch_flags(values: dict):
                     f"--objective masked_diffusion runs in the local and "
                     f"sync data-parallel device steps; --{other} builds "
                     f"another step that draws no noise")
+
+
+def _validate_layer_plan_flags(values: dict):
+    """--layer_plan and the flags of the mechanisms it names: each one's
+    own range, the pairs that would be inert, and the steps that run one
+    kind of layer and so refuse a plan."""
+    from distributed_tensorflow_tpu.models.transformer import (
+        parse_layer_plan,
+        parse_rope_yarn,
+    )
+    from distributed_tensorflow_tpu.ops.moe import SCORINGS
+
+    _require(values, "attn_window", lambda v: int(v) >= 0,
+             "must be >= 0 (0 = no window layers)")
+    _require(values, "window_rope_theta", lambda v: float(v) >= 0,
+             "must be >= 0 (0 = the full layers' rotary form)")
+    _require(values, "rope_fraction", lambda v: 0 < float(v) <= 1,
+             "must lie in (0, 1]")
+    _require(values, "attn_gate", lambda v: isinstance(v, bool),
+             "must be a boolean")
+    _require(values, "moe_shared_dim", lambda v: int(v) >= 0,
+             "must be >= 0 (0 = no shared expert)")
+    _require(values, "moe_scoring", lambda v: v in SCORINGS,
+             f"must be one of {', '.join(SCORINGS)}")
+    _require(values, "moe_scale", lambda v: float(v) > 0, "must be > 0")
+    parse_rope_yarn(values.get("rope_yarn") or "")
+    fraction, head_dim = values.get("rope_fraction"), values.get("head_dim")
+    if fraction and head_dim and (float(fraction) * int(head_dim)) % 2:
+        raise ValueError(f"--rope_fraction={fraction} of --head_dim="
+                         f"{head_dim} must be an even number of dimensions")
+    if not values.get("rope_theta") and (
+            values.get("rope_yarn") or values.get("window_rope_theta")
+            or float(values.get("rope_fraction") or 1.0) != 1.0):
+        raise ValueError("--rope_fraction, --rope_yarn and "
+                         "--window_rope_theta shape rotary positions: "
+                         "without --rope_theta they would silently change "
+                         "nothing")
+    if not values.get("moe_top_k") and (
+            values.get("moe_shared_dim")
+            or values.get("moe_scoring") not in (None, "softmax")
+            or float(values.get("moe_scale") or 1.0) != 1.0):
+        raise ValueError("--moe_shared_dim, --moe_scoring and --moe_scale "
+                         "shape the routed layer: without --moe_top_k they "
+                         "would silently change nothing")
+    plan = values.get("layer_plan")
+    if not plan:
+        if values.get("attn_window") or values.get("window_rope_theta"):
+            raise ValueError("--attn_window and --window_rope_theta are "
+                             "the window layers' of --layer_plan: without "
+                             "it they would silently change nothing")
+        return
+    entries = parse_layer_plan(plan)
+    blocks = values.get("num_blocks")
+    if blocks is not None and len(entries) != int(blocks):
+        raise ValueError(f"--layer_plan names {len(entries)} layers, "
+                         f"--num_blocks={blocks}")
+    kv = int(values.get("num_kv_heads") or 0)
+    for attention, heads, ffn in entries:
+        if kv and heads % kv:
+            raise ValueError(f"--layer_plan: {heads} query heads do not "
+                             f"divide over --num_kv_heads={kv}")
+        if attention == "window" and not values.get("attn_window"):
+            raise ValueError("--layer_plan names a window layer: add "
+                             "--attn_window")
+        if ffn == "routed" and not values.get("moe_top_k"):
+            raise ValueError("--layer_plan names a routed layer: add "
+                             "--moe_top_k (and --moe_experts)")
+    if values.get("moe_top_k") and not any(e[2] == "routed" for e in entries):
+        raise ValueError("--moe_top_k is set and --layer_plan names no "
+                         "routed layer")
+    if values.get("attn_window") and not any(
+            e[0] == "window" for e in entries):
+        raise ValueError("--attn_window is set and --layer_plan names no "
+                         "window layer")
+    if values.get("objective") not in (None, "next_token"):
+        raise ValueError("--layer_plan runs under --objective next_token")
+    for other in ("seq_parallel", "pipeline", "expert_parallel"):
+        if values.get(other):
+            raise ValueError(
+                f"--layer_plan runs in the local and data-parallel "
+                f"steps; --{other} builds a step whose layers are one "
+                f"kind — drop one")
+    if int(values.get("model_axis") or 1) > 1:
+        raise ValueError("--layer_plan's layers have head counts of their "
+                         "own; --model_axis > 1 (tensor parallelism) "
+                         "splits one head count — drop one")
 
 
 def _validate_pairing_flags(values: dict):

@@ -21,6 +21,7 @@ parallel/sequence_parallel.py for the derivation).
 
 from __future__ import annotations
 
+import math
 from typing import Any, NamedTuple
 
 import jax
@@ -50,11 +51,15 @@ def _layernorm(x, gain, bias, eps=1e-5):
 
 
 class BlockArch(NamedTuple):
-    """The choices of a block beyond the first form's (LayerNorm, learned
-    positions added at the embedding, one head count, a ReLU MLP with
-    biases): read by the two halves and their parameter builders, so that
-    every caller of those (training, ``serving/decode.py``) takes the
-    same choices from one place. ``None`` stands for the first form."""
+    """The choices of ONE LAYER beyond the first form's (LayerNorm, learned
+    positions added at the embedding, the model's head count under the
+    model's mask, a ReLU MLP with biases): read by the two halves, the
+    block forms and their parameter builders, so that every caller of
+    those (training, ``serving/decode.py``) takes the same choices from one
+    place. ``None`` stands for the first form. A model's layers are alike
+    unless it is given a plan (``TransformerLM(layer_plan=...)``): then
+    each layer has its own value, and ``TransformerLM.plan`` is the tuple
+    of them that ``init`` and the forward pass walk."""
 
     norm: str = "layernorm"     # or "rmsnorm" (no bias leaf)
     norm_eps: float = 1e-5
@@ -63,6 +68,58 @@ class BlockArch(NamedTuple):
     qk_norm: bool = False       # RMSNorm over the head width on q and k
     gated: bool = False         # feed-forward silu(gate) * up
     biases: bool = True         # on the feed-forward
+    # a layer's own, where a plan gives them
+    heads: int = 0              # query heads; 0: the model's
+    window: int = 0             # > 0: attention over the last `window` keys
+    rope_fraction: float = 1.0  # share of the head width that is rotated
+    rope_yarn: tuple = ()       # (factor, original positions, beta_fast,
+    #                             beta_slow, attention factor), or none
+    attn_gate: bool = False     # sigmoid gate a head and row on attention
+    ffn: str = "dense"          # or "switch" / "routed" (ops/moe.py)
+    shared_dim: int = 0         # routed: a gated expert every row takes
+
+
+LAYER_ATTENTIONS = ("full", "window")
+LAYER_FFNS = ("dense", "routed")
+
+
+def parse_layer_plan(text: str) -> list[tuple[str, int, str]]:
+    """``"full:48:dense,window:64:routed"`` -> [(attention, query heads,
+    feed-forward)], one a layer; anything else is a ValueError that says
+    what an entry is."""
+    out = []
+    for entry in text.split(","):
+        parts = entry.strip().split(":")
+        if len(parts) != 3 or parts[0] not in LAYER_ATTENTIONS \
+                or parts[2] not in LAYER_FFNS or not parts[1].isdigit() \
+                or int(parts[1]) < 1:
+            raise ValueError(
+                f"layer_plan entry {entry!r} is not <attention>:<query "
+                f"heads>:<feed-forward> with attention one of "
+                f"{LAYER_ATTENTIONS}, heads >= 1 and feed-forward one of "
+                f"{LAYER_FFNS}")
+        out.append((parts[0], int(parts[1]), parts[2]))
+    return out
+
+
+def parse_rope_yarn(value) -> tuple:
+    """``"64,4096,64,1,1.4158883"`` (or the five numbers) -> (factor,
+    original positions, beta_fast, beta_slow, attention factor); empty:
+    none."""
+    if not value:
+        return ()
+    parts = value.split(",") if isinstance(value, str) else list(value)
+    try:
+        yarn = tuple(float(x) for x in parts)
+    except ValueError:
+        yarn = ()
+    if len(yarn) != 5 or yarn[0] < 1 or yarn[1] < 1 \
+            or not yarn[2] > yarn[3] > 0 or yarn[4] <= 0:
+        raise ValueError(
+            f"rope_yarn {value!r} is not factor,original_positions,"
+            f"beta_fast,beta_slow,attention_factor (factor >= 1, beta_fast "
+            f"> beta_slow > 0)")
+    return yarn
 
 
 def _rmsnorm(x, gain, eps):
@@ -89,24 +146,59 @@ def _norm_params(prefix, d, dtype, arch):
     return out
 
 
-def rope(x, pos, theta):
+def yarn_frequencies(theta, dr: int, yarn):
+    """(the Dr / 2 inverse frequencies, the factor on cos and sin) of YaRN
+    (arXiv:2309.00071, as ``rope_type: yarn`` computes it): frequency i of
+    base ``theta`` over ``dr`` rotated dimensions is kept where it turns
+    more than ``beta_fast`` times over the ``original`` positions, divided
+    by ``factor`` where it turns fewer than ``beta_slow`` times, and
+    blended linearly between the two dimensions where that happens."""
+    factor, original, beta_fast, beta_slow, attention_factor = yarn
+    base = theta ** (-jnp.arange(0, dr, 2, dtype=jnp.float32) / dr)
+
+    def turns_at(turns):  # the dimension that turns this often
+        return (dr * math.log(original / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    lo = max(math.floor(turns_at(beta_fast)), 0)
+    hi = min(math.ceil(turns_at(beta_slow)), dr - 1)
+    ramp = jnp.clip((jnp.arange(dr // 2, dtype=jnp.float32) - lo)
+                    / max(hi - lo, 1e-3), 0.0, 1.0)
+    return base / factor * ramp + base * (1.0 - ramp), attention_factor
+
+
+def rope(x, pos, theta, fraction: float = 1.0, yarn=()):
     """Rotary positions in the rotate-half form: x (B, S, H, Dh), pos (S,)
-    position ids. f32 inside, x's dtype out."""
+    position ids. f32 inside, x's dtype out. ``fraction`` < 1 rotates the
+    first ``fraction * Dh`` of the head width and passes the rest through;
+    ``yarn`` blends the frequencies (``yarn_frequencies``) and scales cos
+    and sin."""
     dh = x.shape[-1]
-    inv = theta ** (-jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
-    ang = pos.astype(jnp.float32)[:, None] * inv[None, :]       # (S, Dh/2)
+    dr = dh if fraction == 1.0 else int(round(dh * fraction))
+    if yarn:
+        inv, factor = yarn_frequencies(theta, dr, yarn)
+    else:
+        inv = theta ** (-jnp.arange(0, dr, 2, dtype=jnp.float32) / dr)
+    ang = pos.astype(jnp.float32)[:, None] * inv[None, :]       # (S, Dr/2)
     cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], -1)[None, :, None]
     sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], -1)[None, :, None]
+    if yarn:
+        cos, sin = cos * factor, sin * factor
     xf = x.astype(jnp.float32)
-    x1, x2 = xf[..., : dh // 2], xf[..., dh // 2:]
-    return (xf * cos + jnp.concatenate([-x2, x1], -1) * sin).astype(x.dtype)
+    xr = xf if dr == dh else xf[..., :dr]
+    x1, x2 = xr[..., : dr // 2], xr[..., dr // 2:]
+    out = xr * cos + jnp.concatenate([-x2, x1], -1) * sin
+    if dr != dh:
+        out = jnp.concatenate([out, xf[..., dr:]], -1)
+    return out.astype(x.dtype)
 
 
 def _attn_half_params(w, d, h, dh, dtype, arch=None):
     """The attention half's parameters — ONE constructor for the dense
     and MoE block forms (like _attn_half on the compute side), so the
     layouts cannot diverge. Fewer key/value heads than query heads split
-    ``qkv`` into ``q`` and ``kv``; ``qk_norm`` adds a gain a head width."""
+    ``qkv`` into ``q`` and ``kv``; ``qk_norm`` adds a gain a head width;
+    ``attn_gate`` a (d, H) matrix, one gate a head."""
     kv = h if arch is None or not arch.kv_heads else arch.kv_heads
     out = _norm_params("ln1_", d, dtype, arch)
     if kv == h:
@@ -118,6 +210,8 @@ def _attn_half_params(w, d, h, dh, dtype, arch=None):
         out["q_norm_g"] = jnp.ones((dh,), dtype)
         out["k_norm_g"] = jnp.ones((dh,), dtype)
     out["proj"] = w((h * dh, d))
+    if arch is not None and arch.attn_gate:
+        out["gate"] = w((d, h))
     out.update(_norm_params("ln2_", d, dtype, arch))
     return out
 
@@ -200,8 +294,14 @@ def _attn_half_kv(h, blk, attn_fn, cd, arch=None, pos=None):
     if arch is not None and arch.rope_theta:
         if pos is None:
             pos = jnp.arange(q.shape[1])
-        q, k = rope(q, pos, arch.rope_theta), rope(k, pos, arch.rope_theta)
+        form = (arch.rope_theta, arch.rope_fraction, arch.rope_yarn)
+        q, k = rope(q, pos, *form), rope(k, pos, *form)
     a = attn_fn(q, k, v)
+    if arch is not None and arch.attn_gate:
+        # one gate a head and row, from the normalised input
+        gate = jnp.einsum("bsd,dh->bsh", y, blk["gate"].astype(y.dtype))
+        a = a * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
+            a.dtype)[..., None]
     a = a.reshape(*a.shape[:2], -1)  # (B, S, H*Dh)
     return h + nn.dense(a, blk["proj"], compute_dtype=cd), k, v
 
@@ -210,8 +310,10 @@ def _routed_block_params(w, d, h, dh, ffn_dim, num_experts, held, dtype,
                          arch):
     """Routed block: the attention half of ``_block_params``; the
     feed-forward is ``held`` gated experts of width ``ffn_dim`` behind a
-    router over ``num_experts`` (``ops/moe.py:routed_experts``)."""
-    return {
+    router over ``num_experts`` (``ops/moe.py:routed_experts``), and where
+    ``arch.shared_dim`` one more gated expert, whole here, that every row
+    takes."""
+    out = {
         **_attn_half_params(w, d, h, dh, dtype, arch),
         "moe": {
             "router": w((d, num_experts)),
@@ -219,22 +321,48 @@ def _routed_block_params(w, d, h, dh, ffn_dim, num_experts, held, dtype,
             "w2": w((held, ffn_dim, d)),
         },
     }
+    if arch.shared_dim:
+        out["shared"] = {"w1": w((d, 2 * arch.shared_dim)),
+                         "w2": w((arch.shared_dim, d))}
+    return out
 
 
-def _transformer_block_routed(h, blk, attn_fn, cd, arch, pos, top_k,
-                              first_expert, capacity_factor):
+class Routing(NamedTuple):
+    """The routed layers' choices, the model's (``ops/moe.py``)."""
+
+    top_k: int
+    first_expert: int = 0
+    capacity: float = 1.25
+    scoring: str = "softmax"
+    scale: float = 1.0
+
+
+def _shared_expert(y, shared, cd):
+    """The expert every row takes: silu(gate) * up from one (d, 2 f)
+    matrix, the gate's columns first (as the routed experts')."""
+    up = nn.dense(y, shared["w1"], compute_dtype=cd)
+    f = up.shape[-1] // 2
+    return nn.dense(jax.nn.silu(up[..., :f]) * up[..., f:], shared["w2"],
+                    compute_dtype=cd)
+
+
+def _transformer_block_routed(h, blk, attn_fn, cd, arch, pos, routing):
     """Routed block form: returns (h, the layer's routing counters)."""
     from distributed_tensorflow_tpu.ops.moe import routed_experts
 
     h = _attn_half(h, blk, attn_fn, cd, arch, pos)
     with scope("moe_router"):
         y = _norm(h, blk, "ln2_", arch)
-    y, aux = routed_experts(y, blk["moe"], top_k=top_k,
-                            first_expert=first_expert,
-                            capacity_factor=capacity_factor,
-                            compute_dtype=cd)
+    out, aux = routed_experts(y, blk["moe"], top_k=routing.top_k,
+                              first_expert=routing.first_expert,
+                              capacity_factor=routing.capacity,
+                              compute_dtype=cd, scoring=routing.scoring,
+                              scale=routing.scale)
+    if "shared" in blk:
+        with scope("moe_shared"):
+            out = out + _shared_expert(y, blk["shared"], cd)
     with scope("moe_router"):
-        return h + y, aux
+        return h + out, aux
 
 
 def _moe_block_params(w, d, h, dh, mlp_dim, num_experts, dtype):
@@ -265,7 +393,19 @@ def _transformer_block_moe(h, blk, attn_fn, cd, capacity_factor,
         return h + y, aux["lb_loss"]
 
 
-def _remat(fn, static_argnums):
+def _planned_block(h, blk, attn_fn, cd, arch, pos, moe):
+    """One layer as its plan entry (``arch``, a ``BlockArch``) says:
+    (h, what its feed-forward reports: the routed layer's counters, the
+    Switch layer's load-balance loss, None). ``moe``: the ``Routing`` of a
+    routed layer, (capacity, axis) of a Switch layer."""
+    if arch.ffn == "routed":
+        return _transformer_block_routed(h, blk, attn_fn, cd, arch, pos, moe)
+    if arch.ffn == "switch":
+        return _transformer_block_moe(h, blk, attn_fn, cd, *moe)
+    return _transformer_block(h, blk, attn_fn, cd, arch, pos), None
+
+
+def _remat(fn, static_argnums, **says):
     """``fn`` (a block) under ``--remat``: ``jax.checkpoint`` whose backward
     pass recomputes the block from its input, all but the values that
     ``ops/attention.py`` names (``REMAT_KEPT``: a blockwise attention's
@@ -276,7 +416,8 @@ def _remat(fn, static_argnums):
     The one wrapper of every site that rematerializes a block.
 
     The first block that shows the policy every name records the
-    ``remat_saved`` instant: the names and the bytes a block they cost."""
+    ``remat_saved`` instant: the names and the bytes a block they cost
+    (and ``says``: of a plan, which kind of layer this is)."""
     named = jax.checkpoint_policies.save_only_these_names(*REMAT_KEPT)
     kept = {}
 
@@ -287,7 +428,7 @@ def _remat(fn, static_argnums):
             if len(kept) == len(REMAT_KEPT):
                 telemetry.get_tracer().record_instant(
                     "remat_saved", names=sorted(kept),
-                    bytes_per_block=sum(kept.values()))
+                    bytes_per_block=sum(kept.values()), **says)
         return keep
 
     return jax.checkpoint(fn, static_argnums=static_argnums, policy=policy)
@@ -459,6 +600,19 @@ class TransformerLM:
     ``moe_first_expert`` on; ``moe_capacity`` sizes its sorted buffer,
     which is what a step costs; an overflow makes the loss NaN).
 
+    ``layer_plan`` makes the layers DIFFER: one entry a layer,
+    ``<attention>:<query heads>:<feed-forward>`` joined by commas
+    (``parse_layer_plan``), attention ``full`` (the model's mask) or
+    ``window`` (the causal window of ``attn_window`` keys, rotary positions
+    of ``window_rope_theta`` on the whole head width), feed-forward
+    ``dense`` or ``routed``. ``rope_fraction`` and ``rope_yarn`` shape the
+    full layers' rotary positions, ``attn_gate`` gates every layer's
+    attention output a head and row, ``moe_shared_dim`` puts a shared gated
+    expert beside every routed layer's, ``moe_scoring`` and ``moe_scale``
+    say how the router's logits become weights. ``plan`` is the tuple of
+    the layers' ``BlockArch`` values that ``init`` and the forward pass
+    walk; without ``layer_plan`` its entries are one value.
+
     ``objective="masked_diffusion"`` trains by diffusion over blocks of
     ``diffusion_block`` tokens: ``noise_batch`` (called under the step's
     key, and under a key folded from ``noise_seed`` by the eval) masks
@@ -505,6 +659,15 @@ class TransformerLM:
         diffusion_block: int = 4,
         diffusion_t_min: float = 1e-3,
         noise_seed: int = 0,
+        layer_plan: str = "",
+        attn_window: int = 0,
+        window_rope_theta: float = 0.0,
+        rope_fraction: float = 1.0,
+        rope_yarn: str | tuple = (),
+        attn_gate: bool = False,
+        moe_shared_dim: int = 0,
+        moe_scoring: str = "softmax",
+        moe_scale: float = 1.0,
         **_unused,
     ):
         if d_model % num_heads and not head_dim:
@@ -557,11 +720,31 @@ class TransformerLM:
         self.diffusion_block = int(diffusion_block)
         self.diffusion_t_min = float(diffusion_t_min)
         self.noise_seed = int(noise_seed)
+        ffn = "routed" if self.moe_top_k else \
+            "switch" if self.moe_experts else "dense"
         arch = BlockArch(norm, float(norm_eps), float(rope_theta),
                          int(num_kv_heads), bool(qk_norm), bool(mlp_gated),
-                         bool(biases))
+                         bool(biases), rope_fraction=float(rope_fraction),
+                         rope_yarn=parse_rope_yarn(rope_yarn),
+                         attn_gate=bool(attn_gate), ffn=ffn,
+                         shared_dim=int(moe_shared_dim) if self.moe_top_k
+                         else 0)
+        if (arch.rope_fraction != 1.0 or arch.rope_yarn) and not rope_theta:
+            raise ValueError("rope_fraction and rope_yarn shape rotary "
+                             "positions: they need rope_theta > 0")
+        if moe_shared_dim and not self.moe_top_k:
+            raise ValueError("moe_shared_dim is the routed layer's shared "
+                             "expert: it needs moe_top_k > 0")
         # None is the first form: its callers, its tree and its program
-        self.arch = None if arch == BlockArch() else arch
+        # (what the layers' feed-forward is, is the plan's to say)
+        self.arch = None if arch._replace(ffn="dense") == BlockArch() \
+            else arch
+        self.routing = Routing(self.moe_top_k, self.moe_first_expert,
+                               self.moe_capacity, str(moe_scoring),
+                               float(moe_scale))
+        self.layer_plan = str(layer_plan or "")
+        self.attn_window = int(attn_window)
+        self.plan = self._build_plan(arch, float(window_rope_theta))
         if self.moe_top_k and (moe_axis is not None or not (
                 0 <= self.moe_first_expert
                 <= self.moe_experts - self.moe_held_experts)):
@@ -574,6 +757,49 @@ class TransformerLM:
             # found by the steps and the eval (getattr): absent otherwise
             self.noise_batch = self._noise_batch
 
+    def _build_plan(self, arch, window_rope_theta):
+        """One ``BlockArch`` a layer. Without ``layer_plan`` every layer is
+        the model's one value; with it, entry l says layer l's attention,
+        query heads and feed-forward."""
+        if not self.layer_plan:
+            if self.attn_window or window_rope_theta:
+                raise ValueError("attn_window and window_rope_theta are the "
+                                 "window layers': name those in layer_plan")
+            return (arch,) * self.num_blocks
+        if self.seq_axis is not None or self.moe_axis is not None \
+                or self.objective != "next_token":
+            raise ValueError("layer_plan runs under the next-token "
+                             "objective on one device's whole sequence: no "
+                             "seq_axis, no moe_axis")
+        entries = parse_layer_plan(self.layer_plan)
+        if len(entries) != self.num_blocks:
+            raise ValueError(f"layer_plan names {len(entries)} layers, "
+                             f"num_blocks is {self.num_blocks}")
+        kv = arch.kv_heads
+        plan = []
+        for attention, heads, ffn in entries:
+            if kv and heads % kv:
+                raise ValueError(f"layer_plan: {heads} query heads do not "
+                                 f"divide over {kv} key/value heads")
+            if ffn == "routed" and not self.moe_top_k:
+                raise ValueError("layer_plan names a routed layer: it "
+                                 "needs moe_top_k > 0")
+            layer = arch._replace(heads=heads, ffn=ffn, shared_dim=(
+                arch.shared_dim if ffn == "routed" else 0))
+            if attention == "window":
+                if self.attn_window < 1:
+                    raise ValueError("layer_plan names a window layer: it "
+                                     "needs attn_window > 0")
+                layer = layer._replace(window=self.attn_window)
+                if window_rope_theta:
+                    layer = layer._replace(rope_theta=window_rope_theta,
+                                           rope_fraction=1.0, rope_yarn=())
+            plan.append(layer)
+        if self.moe_top_k and not any(x.ffn == "routed" for x in plan):
+            raise ValueError("moe_top_k > 0 and layer_plan names no routed "
+                             "layer")
+        return tuple(plan)
+
     @property
     def mask_token(self) -> int:
         """The id that stands for a masked position: the vocabulary's
@@ -581,9 +807,12 @@ class TransformerLM:
         return self.vocab_size - 1
 
     def init(self, key, dtype=jnp.float32):
-        d, h, dh = self.d_model, self.num_heads, self.head_dim
+        d, dh = self.d_model, self.head_dim
         arch = self.arch
-        keys = iter(jax.random.split(key, 4 + 8 * self.num_blocks))
+        # 8 keys a layer; a plan's layers may hold more matrices (a gate, a
+        # shared expert): 12
+        keys = iter(jax.random.split(
+            key, 4 + (12 if self.layer_plan else 8) * self.num_blocks))
 
         def w(shape, stddev=0.02):
             return truncated_normal_init(next(keys), shape, stddev, dtype)
@@ -596,17 +825,18 @@ class TransformerLM:
         params["head"] = {"w": w((d, self.vocab_size))}
         if arch is None or arch.biases:
             params["head"]["b"] = jnp.zeros((self.vocab_size,), dtype)
-        for _ in range(self.num_blocks):
-            if self.moe_top_k:
+        for layer in self.plan:
+            h = layer.heads or self.num_heads
+            if layer.ffn == "routed":
                 params["blocks"].append(_routed_block_params(
                     w, d, h, dh, self.moe_ffn_dim, self.moe_experts,
-                    self.moe_held_experts, dtype, arch))
-            elif self.moe_experts:
+                    self.moe_held_experts, dtype, layer))
+            elif layer.ffn == "switch":
                 params["blocks"].append(_moe_block_params(
                     w, d, h, dh, self.mlp_dim, self.moe_experts, dtype))
             else:
-                params["blocks"].append(
-                    _block_params(w, d, h, dh, self.mlp_dim, dtype, arch))
+                params["blocks"].append(_block_params(
+                    w, d, h, dh, self.mlp_dim, dtype, layer))
         return params
 
     def _noise_batch(self, batch, key):
@@ -634,16 +864,21 @@ class TransformerLM:
         return self._hidden_and_aux(params, x, keep_prob=keep_prob,
                                     rng=rng, train=train)[0]
 
-    def attention_fn(self):
+    def attention_fn(self, window: int = 0):
         """``(q, k, v) -> out``, all (B, S, H, Dh): this model's causal
-        attention flavor (ring / blockwise / dense). The one place the
-        choice is made: the pipeline stages call it, and the TP step
-        wraps it per head shard (parallel/tensor_parallel.py)."""
+        attention flavor (ring / blockwise / dense), or with ``window`` a
+        window layer's. The one place the choice is made: the pipeline
+        stages call it, and the TP step wraps it per head shard
+        (parallel/tensor_parallel.py)."""
         if self.seq_axis is not None:
             return lambda q, k, v: ring_attention(
                 q, k, v, self.seq_axis, causal=True)
-        if self.objective == "masked_diffusion":
+        mask = None
+        if window:
+            mask = Mask("window", window=window)
+        elif self.objective == "masked_diffusion":
             mask = Mask("block_diffusion", self.seq_len, self.diffusion_block)
+        if mask is not None:
             if self.attn_block is not None:
                 return lambda q, k, v: blockwise_attention(
                     q, k, v, self.attn_block, mask=mask)
@@ -652,6 +887,31 @@ class TransformerLM:
             return lambda q, k, v: blockwise_attention(
                 q, k, v, self.attn_block, causal=True)
         return lambda q, k, v: multi_head_attention(q, k, v, causal=True)
+
+    def _layer_fns(self):
+        """layer's ``BlockArch`` -> ``(h, blk, ids) -> (h, aux)``: each
+        KIND of layer of the plan once (its attention, its block form, its
+        checkpoint under ``remat``), so that layers alike share a trace."""
+        cd = self.compute_dtype
+        moe = {"routed": self.routing,
+               "switch": (self.moe_capacity, self.moe_axis)}
+        full = self.attention_fn()
+        fns = {}
+        for layer in dict.fromkeys(self.plan):
+            attn = self.attention_fn(layer.window) if layer.window else full
+            block = _planned_block
+            if self.remat:
+                says = {}
+                if self.layer_plan:
+                    says = {"attention": "window" if layer.window else "full",
+                            "heads": layer.heads, "ffn": layer.ffn}
+                block = _remat(_planned_block, (2, 3, 4, 6), **says)
+
+            def run(h, blk, ids, block=block, attn=attn, layer=layer):
+                return block(h, blk, attn, cd, layer, ids, moe.get(layer.ffn))
+
+            fns[layer] = run
+        return fns
 
     def _hidden_and_aux(self, params, x, *, keep_prob=1.0, rng=None,
                         train: bool = False):
@@ -686,19 +946,17 @@ class TransformerLM:
             if diffusion:
                 ids = jnp.tile(jnp.arange(self.seq_len), 2)
 
-        attn = self.attention_fn()
-
+        fns = self._layer_fns()
         lb_total = jnp.float32(0.0)
-        if self.moe_top_k:
-            routed = _transformer_block_routed
-            if self.remat:
-                routed = _remat(routed, (2, 3, 4, 6, 7, 8))
-            layers = []
-            for blk in params["blocks"]:
-                h, aux = routed(h, blk, attn, cd, arch, ids, self.moe_top_k,
-                                self.moe_first_expert, self.moe_capacity)
-                layers.append(aux)
-            by = {k: jnp.stack([a[k] for a in layers]) for k in layers[0]}
+        routed = []
+        for blk, layer in zip(params["blocks"], self.plan):
+            h, aux = fns[layer](h, blk, ids)
+            if layer.ffn == "routed":
+                routed.append(aux)
+            elif layer.ffn == "switch":
+                lb_total = lb_total + aux
+        if routed:
+            by = {k: jnp.stack([a[k] for a in routed]) for k in routed[0]}
             # the fullest expert and buffer of any layer, the layers' means
             lb_total = {
                 "rows_per_expert_max": by["rows_per_expert_max"].max(),
@@ -708,20 +966,6 @@ class TransformerLM:
                 "tiles_run_frac": by["tiles_run_frac"].mean(),
                 "unrouted_frac": by["unrouted_frac"].mean(),
             }
-        elif self.moe_experts:
-            moe_fn = _transformer_block_moe
-            if self.remat:
-                moe_fn = _remat(_transformer_block_moe, (2, 3, 4, 5))
-            for blk in params["blocks"]:
-                h, lb = moe_fn(h, blk, attn, cd, self.moe_capacity,
-                               self.moe_axis)
-                lb_total = lb_total + lb
-        else:
-            blk_fn = _transformer_block
-            if self.remat:
-                blk_fn = _remat(_transformer_block, (2, 3, 4))
-            for blk in params["blocks"]:
-                h = blk_fn(h, blk, attn, cd, arch, ids)
 
         with scope("lm_head"):
             if diffusion:

@@ -41,10 +41,15 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
-from distributed_tensorflow_tpu.utils.profiling import lowering_instant, scoped
+from distributed_tensorflow_tpu.utils.profiling import (
+    lowering_instant,
+    scope,
+    scoped,
+)
 
 # a finite stand-in for -inf in the masks that can leave a query row with
 # no visible key inside one key block: exp(MASK_VALUE - m) is an exact 0
@@ -63,6 +68,12 @@ class Mask:
     skipped tile from moving bytes (``next_key_tile`` / ``next_query_tile``).
 
     - ``Mask("causal")``: key j <= query i.
+    - ``Mask("window", window=W)``: the causal window, query i sees the W
+      keys ``i - W < j <= i``. A query tile reaches ``window // tk + 1`` key
+      tiles or so, whatever the sequence's length, so the kernels' grids
+      are BANDED for it (``key_steps`` / ``query_steps`` count the band,
+      ``key_tile`` / ``query_tile`` place a step of it) where the other
+      kinds walk every tile and skip.
     - ``Mask("block_diffusion", half=S, block=L)``: the sequence is
       ``[noised ; clean]``, 2 S rows whose position is ``i mod S`` and whose
       diffusion block is ``position // L``. Noised rows see the noised rows
@@ -74,10 +85,14 @@ class Mask:
     kind: str = "causal"
     half: int = 0
     block: int = 0
+    window: int = 0
 
     def __post_init__(self):
-        if self.kind not in ("causal", "block_diffusion"):
+        if self.kind not in ("causal", "window", "block_diffusion"):
             raise ValueError(f"unknown attention mask {self.kind!r}")
+        if (self.kind == "window") != (self.window > 0):
+            raise ValueError(f"a window of {self.window} keys and the mask "
+                             f"{self.kind!r} do not go together")
         if self.kind == "block_diffusion" and (
                 self.half < 1 or self.block < 1 or self.half % self.block):
             raise ValueError(f"block diffusion needs blocks of {self.block} "
@@ -97,6 +112,9 @@ class Mask:
         """Boolean mask of broadcastable int index arrays."""
         if self.kind == "causal":
             return keys <= queries
+        if self.kind == "window":
+            return jnp.logical_and(keys <= queries,
+                                   keys > queries - self.window)
         s = self.half
         q_clean, k_clean = queries >= s, keys >= s
         start = self._block_start(jnp.where(q_clean, queries - s, queries))
@@ -114,30 +132,37 @@ class Mask:
         """(visible, runs) of the tile of queries q0..q1 and keys k0..k1
         (inclusive; scalars, traced or not): every pair attends / some pair
         does. A block-diffusion tile lies within one half on each side
-        (``tiles_fit``)."""
+        (``tiles_fit``). Host integers and arrays are answered on the host
+        (``tiles_run`` asks while a pass is traced)."""
+        xp = np if all(isinstance(x, (int, np.integer, np.ndarray))
+                       for x in (q0, q1, k0, k1)) else jnp
         if self.kind == "causal":
             return k1 <= q0, k0 <= q1
+        if self.kind == "window":
+            w = self.window
+            return (xp.logical_and(k1 <= q0, k0 > q1 - w),
+                    xp.logical_and(k0 <= q1, k1 > q0 - w))
         s, lb = self.half, self.block
         q_clean, k_clean = q0 >= s, k0 >= s
-        bq0 = jnp.where(q_clean, q0 - s, q0) // lb
-        bq1 = jnp.where(q_clean, q1 - s, q1) // lb
-        bk0 = jnp.where(k_clean, k0 - s, k0) // lb
-        bk1 = jnp.where(k_clean, k1 - s, k1) // lb
-        nn = jnp.logical_not(jnp.logical_or(q_clean, k_clean))
+        bq0 = xp.where(q_clean, q0 - s, q0) // lb
+        bq1 = xp.where(q_clean, q1 - s, q1) // lb
+        bk0 = xp.where(k_clean, k0 - s, k0) // lb
+        bk1 = xp.where(k_clean, k1 - s, k1) // lb
+        nn = xp.logical_not(xp.logical_or(q_clean, k_clean))
         same = q_clean == k_clean
-        runs = jnp.where(nn, jnp.logical_and(bk0 <= bq1, bq0 <= bk1),
-                         jnp.where(same, bk0 <= bq1, bk0 < bq1))
-        visible = jnp.where(
-            nn, jnp.logical_and(jnp.logical_and(bq0 == bq1, bk0 == bk1),
-                                bq0 == bk0),
-            jnp.where(same, bk1 <= bq0, bk1 < bq0))
-        never = jnp.logical_and(q_clean, jnp.logical_not(k_clean))
-        return (jnp.logical_and(visible, jnp.logical_not(never)),
-                jnp.logical_and(runs, jnp.logical_not(never)))
+        runs = xp.where(nn, xp.logical_and(bk0 <= bq1, bq0 <= bk1),
+                        xp.where(same, bk0 <= bq1, bk0 < bq1))
+        visible = xp.where(
+            nn, xp.logical_and(xp.logical_and(bq0 == bq1, bk0 == bk1),
+                               bq0 == bk0),
+            xp.where(same, bk1 <= bq0, bk1 < bq0))
+        never = xp.logical_and(q_clean, xp.logical_not(k_clean))
+        return (xp.logical_and(visible, xp.logical_not(never)),
+                xp.logical_and(runs, xp.logical_not(never)))
 
     def tiles_fit(self, seq_len: int, tq: int, tk: int) -> bool:
         """Whether tiles of tq queries and tk keys suit the kernels."""
-        if self.kind == "causal":
+        if self.kind != "block_diffusion":
             return True
         return (seq_len == 2 * self.half and self.half % tq == 0
                 and self.half % tk == 0
@@ -149,6 +174,9 @@ class Mask:
         that did, so that a skipped step fetches nothing of its own."""
         if self.kind == "causal":
             return jnp.minimum(j, ((i + 1) * tq - 1) // tk)
+        if self.kind == "window":
+            return jnp.clip(j, self._first_key_tile(i, tq, tk),
+                            ((i + 1) * tq - 1) // tk)
         s, lb = self.half, self.block
         nk = s // tk
         q0, q1 = i * tq, (i + 1) * tq - 1
@@ -161,11 +189,16 @@ class Mask:
         clean = (nk, nk + ((last_block + 1) * lb - 1) // tk, last_block >= 0)
         return _next_in_ranges(j, (noised, clean))
 
-    def next_query_tile(self, j, i, tq, tk):
+    def next_query_tile(self, j, i, tq, tk, n_tiles=None):
         """The query tile to hold at grid step (key tile j, query tile i)
-        of the backward kernel: as ``next_key_tile``."""
+        of the backward kernel: as ``next_key_tile`` (``n_tiles``: how many
+        query tiles there are, which only the window has to be told)."""
         if self.kind == "causal":
             return jnp.maximum(i, (j * tk) // tq)
+        if self.kind == "window":
+            return jnp.clip(i, (j * tk) // tq,
+                            jnp.minimum(self._last_query_tile(j, tq, tk),
+                                        n_tiles - 1))
         s, lb = self.half, self.block
         nq = s // tq
         k0, k1 = j * tk, (j + 1) * tk - 1
@@ -180,6 +213,64 @@ class Mask:
                   first < s)
         clean = (nq + b0 * lb // tq, 2 * nq - 1, k_clean)
         return _next_in_ranges(i, (noised, clean))
+
+
+    # ---- the kernels' grids: every tile (the index maps skip), or a band
+
+    @property
+    def banded(self) -> bool:
+        """Whether the kernels' grids walk a band of tiles and not all."""
+        return self.kind == "window"
+
+    def _first_key_tile(self, i, tq, tk):
+        """Window: the tile of the first key query tile i sees."""
+        return jnp.maximum(i * tq - self.window + 1, 0) // tk
+
+    def _last_query_tile(self, j, tq, tk):
+        """Window: the tile of the last query that sees key tile j (it may
+        lie past the sequence's end)."""
+        return ((j + 1) * tk + self.window - 2) // tq
+
+    def key_steps(self, seq_len: int, tq: int, tk: int) -> int:
+        """Steps of the forward grid's key dimension: every key tile, of
+        which the index map skips those that do not run; under a window the
+        most key tiles one query tile reaches."""
+        if not self.banded:
+            return seq_len // tk
+        return max(((i + 1) * tq - 1) // tk
+                   - max(i * tq - self.window + 1, 0) // tk + 1
+                   for i in range(seq_len // tq))
+
+    def key_tile(self, i, step, steps: int, tq: int, tk: int):
+        """The key tile of grid step ``step`` of query tile i. Under a
+        window the band ENDS at the tile of the diagonal, so an early query
+        tile's first steps fall before key 0 (a negative tile: the kernel
+        runs nothing there, ``next_key_tile`` holds the first)."""
+        if not self.banded:
+            return step
+        return ((i + 1) * tq - 1) // tk - (steps - 1) + step
+
+    def query_steps(self, seq_len: int, tq: int, tk: int) -> int:
+        """As ``key_steps``, of the backward grid's query dimension."""
+        if not self.banded:
+            return seq_len // tq
+        nq = seq_len // tq
+        return max(min(self._last_query_tile(j, tq, tk), nq - 1)
+                   - (j * tk) // tq + 1 for j in range(seq_len // tk))
+
+    def query_tile(self, j, step, tq: int, tk: int):
+        """The query tile of grid step ``step`` of key tile j. Under a
+        window the band STARTS at the tile of the diagonal, so a late key
+        tile's last steps fall past the sequence's end."""
+        if not self.banded:
+            return step
+        return (j * tk) // tq + step
+
+    def tiles_run(self, seq_len: int, tq: int, tk: int) -> int:
+        """How many (query tile, key tile) pairs run, of one head."""
+        q0 = np.arange(0, seq_len, tq)[:, None]
+        k0 = np.arange(0, seq_len, tk)[None, :]
+        return int(np.sum(self.tile(q0, q0 + tq - 1, k0, k0 + tk - 1)[1]))
 
 
 CAUSAL = Mask("causal")
@@ -205,7 +296,14 @@ def _as_mask(causal, mask):
     return CAUSAL if causal else None
 
 
-@scoped("attention")
+def _scope_of(mask):
+    """The scope an attention under ``mask`` runs in: ``attention``, or the
+    window layers' own beside it (never inside it)."""
+    if mask is not None and mask.kind == "window":
+        return scope("attention_window")
+    return scope("attention")
+
+
 def multi_head_attention(q, k, v, causal: bool = False, mask=None):
     """Dense (all-to-all) multi-head attention.
 
@@ -215,17 +313,18 @@ def multi_head_attention(q, k, v, causal: bool = False, mask=None):
     may have fewer heads than q (grouped-query attention): query head n
     reads key/value head n // (H // Hkv).
     """
-    dh = q.shape[-1]
-    k, v = _repeat_kv(q, k, v)
     mask = _as_mask(causal, mask)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
-    s = s / jnp.sqrt(jnp.float32(dh))
-    if mask is not None:
-        sq, sk = s.shape[-2], s.shape[-1]
-        s = jnp.where(mask.allowed(jnp.arange(sq)[:, None],
-                                   jnp.arange(sk)[None, :]), s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p.astype(q.dtype), v)
+    with _scope_of(mask):
+        dh = q.shape[-1]
+        k, v = _repeat_kv(q, k, v)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
+        s = s / jnp.sqrt(jnp.float32(dh))
+        if mask is not None:
+            sq, sk = s.shape[-2], s.shape[-1]
+            s = jnp.where(mask.allowed(jnp.arange(sq)[:, None],
+                                       jnp.arange(sk)[None, :]), s, -jnp.inf)
+        p = jax.nn.softmax(s, axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", p.astype(q.dtype), v)
 
 
 def _repeat_kv(q, k, v):
@@ -381,6 +480,8 @@ def _pick(pass_name, scan, q, k, v, block_size, mask, *rest):
     if mask is not None and mask.kind != "causal":
         # told apart in the record only where there is something to tell
         note.update(mask=mask.kind, kv_heads=k.shape[2])
+    if mask is not None and mask.kind == "window":
+        note.update(window=mask.window)
 
     def run_scan(q, *xs):
         q = lowering_instant("attention_path", q, path="scan", q_tile=s,
@@ -396,9 +497,15 @@ def _pick(pass_name, scan, q, k, v, block_size, mask, *rest):
              "backward": flash_attention.flash_backward}[pass_name]
 
     def run_fused(q, *xs):
-        q = lowering_instant("attention_path", q, path="fused",
-                             q_tile=flash_attention.query_tile(s, mask),
-                             **note)
+        tq = flash_attention.query_tile(s, mask)
+        steps = (s // tq) * mask.key_steps(s, tq, block_size) \
+            if pass_name == "forward" \
+            else (s // block_size) * mask.query_steps(s, tq, block_size)
+        # the tiles that run over the steps of the lowered pass's grid, a
+        # head: what is left between them is skipped steps
+        q = lowering_instant("attention_path", q, path="fused", q_tile=tq,
+                             tiles_run=mask.tiles_run(s, tq, block_size),
+                             grid_steps=steps, **note)
         if mask.kind == "causal":  # today's call, and so today's trace
             return fused(q, *xs, block_size)
         return fused(q, *xs, block_size, mask)
@@ -443,10 +550,10 @@ def _scan_forward(q, k, v, block_size, mask):
     return jnp.einsum("bhqd->bqhd", o).astype(q.dtype), lse
 
 
-@scoped("attention")
 def _forward(q, k, v, block_size, mask):
     """(out, lse) by the fused kernel or the scan."""
-    return _pick("forward", _scan_forward, q, k, v, block_size, mask)
+    with _scope_of(mask):
+        return _pick("forward", _scan_forward, q, k, v, block_size, mask)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -514,13 +621,13 @@ def _scan_backward(q, k, v, out, lse, g, block_size, mask):
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-@scoped("attention")
 def _blockwise_bwd(block_size, mask, res, g):
     """(dq, dk, dv) by the fused kernel or the scan, from the residuals
     both forwards save in one form: (q, k, v, out, logsumexp)."""
     q, k, v, out, lse = res
-    return _pick("backward", _scan_backward, q, k, v, block_size, mask,
-                 out, lse, g)
+    with _scope_of(mask):
+        return _pick("backward", _scan_backward, q, k, v, block_size, mask,
+                     out, lse, g)
 
 
 _blockwise.defvjp(_blockwise_fwd, _blockwise_bwd)

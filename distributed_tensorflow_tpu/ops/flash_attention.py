@@ -6,11 +6,16 @@ shapes allow (its ``fusable``); its ``lax.scan`` form is the same mathematics
 and the reference the tests hold the kernels to. Only q, k, v, o, one f32
 logsumexp a row and the three gradients cross HBM.
 
-The mask is a static description (``ops/attention.py:Mask``: causal, or
-block diffusion over a doubled sequence) that gives the kernels the
-three-way test of a tile (it runs unmasked, masked, or not at all), the
-mask inside a tile, and the index maps that make a skipped tile move no
-bytes. Key/value heads may be fewer than query heads: the k/v index map
+The mask is a static description (``ops/attention.py:Mask``: causal, the
+causal window, or block diffusion over a doubled sequence) that gives the
+kernels the three-way test of a tile (it runs unmasked, masked, or not at
+all), the mask inside a tile, the index maps that make a skipped tile move
+no bytes, and the grid's inner dimension: every tile of the other side
+(causal and block diffusion: the maps skip), or under a window the BAND of
+tiles one tile reaches (``window // tile + 1`` or so: at S = 8,192 with
+512-wide tiles 2 steps a query tile and not 16, of which 31 of 32 run; a
+skipped step costs about 0.35 us, PERF.md section 6, and 225 of them a
+head would cost more than the 31 tiles that run). Key/value heads may be fewer than query heads: the k/v index map
 sends a group's query heads to its one key/value head, and the backward
 writes dk, dv a query head, summed over the group outside the kernel.
 
@@ -106,23 +111,30 @@ def _scores(kt, qt, scale, i, j, tq, tk, masked, mask):
     return st, qt, (None if exact else scale)
 
 
-def _when_tile_runs(i, j, tq, tk, mask, fold):
+def _when_tile_runs(i, j, tq, tk, mask, fold, inside=None):
     """``fold(masked)`` for tile (query tile i, key tile j): unmasked
     where every pair attends (causal: its last key <= its first query),
     masked where only some do (the diagonal crosses it), not at all where
-    none does (its first key > its last query)."""
+    none does (its first key > its last query), nor where a banded grid's
+    step lies outside the sequence (``inside`` false)."""
     visible, runs = mask.tile(i * tq, (i + 1) * tq - 1,
                               j * tk, (j + 1) * tk - 1)
+    if inside is not None:
+        visible = jnp.logical_and(visible, inside)
+        runs = jnp.logical_and(runs, inside)
     pl.when(visible)(lambda: fold(False))
     pl.when(jnp.logical_and(runs, jnp.logical_not(visible)))(
         lambda: fold(True))
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
-                scale, tq, tk, mask):
-    i, j = pl.program_id(1), pl.program_id(2)
+                scale, tq, tk, mask, steps):
+    i, step = pl.program_id(1), pl.program_id(2)
+    # the key tile of this step: the step itself, or its place in the band
+    j = mask.key_tile(i, step, steps, tq, tk)
+    inside = j >= 0 if mask.banded else None
 
-    @pl.when(j == 0)
+    @pl.when(step == 0)
     def _():
         m_sc[...] = jnp.full_like(m_sc, -jnp.inf)
         l_sc[...] = jnp.zeros_like(l_sc)
@@ -140,9 +152,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
         acc_sc[...] = alpha * acc_sc[...] + _dot(vt, pt.astype(vt.dtype))
         m_sc[...] = m_next
 
-    _when_tile_runs(i, j, tq, tk, mask, fold)
+    _when_tile_runs(i, j, tq, tk, mask, fold, inside)
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _():
         l = l_sc[...]
         o_ref[...] = (acc_sc[...] / l).astype(o_ref.dtype)
@@ -188,17 +200,20 @@ def flash_forward(q, k, v, block_size: int, mask=None):
     tk = block_size
     tq = query_tile(s, mask)
 
-    def kv_map(g, i, j):
+    steps = mask.key_steps(s, tq, tk)
+
+    def kv_map(g, i, step):
         # stop at the query tile's last key tile (causal), hold the next
         # tile that runs: a repeated block index is not fetched again, so
         # the skipped steps move nothing
+        j = mask.key_tile(i, step, steps, tq, tk)
         return _kv_head(g, b, group), 0, mask.next_key_tile(i, j, tq, tk)
 
     row = pltpu.VMEM((1, tq), jnp.float32)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=dh ** -0.5, tq=tq, tk=tk,
-                          mask=mask),
-        grid=(h * b, s // tq, s // tk),
+                          mask=mask, steps=steps),
+        grid=(h * b, s // tq, steps),
         in_specs=[pl.BlockSpec((None, dh, tq), lambda g, i, j: (g, 0, i)),
                   pl.BlockSpec((None, dh, tk), kv_map),
                   pl.BlockSpec((None, dh, tk), kv_map)],
@@ -219,14 +234,17 @@ def flash_forward(q, k, v, block_size: int, mask=None):
 def _bwd_kernel(q_ref, do_ref, lse_ref, d_ref, k_ref, v_ref,
                 dq_ref, dk_ref, dv_ref, dq_sc, dk_sc, dv_sc, *,
                 scale, tq, tk, mask):
-    j, i = pl.program_id(1), pl.program_id(2)
-    last_i = pl.num_programs(2) - 1
+    j, step = pl.program_id(1), pl.program_id(2)
+    last_step = pl.num_programs(2) - 1
+    # the query tile of this step: the step itself, or its place in the band
+    i = mask.query_tile(j, step, tq, tk)
+    inside = i < dq_sc.shape[0] if mask.banded else None
 
-    @pl.when(jnp.logical_and(j == 0, i == 0))
+    @pl.when(jnp.logical_and(j == 0, step == 0))
     def _():
         dq_sc[...] = jnp.zeros_like(dq_sc)
 
-    @pl.when(i == 0)
+    @pl.when(step == 0)
     def _():
         dk_sc[...] = jnp.zeros_like(dk_sc)
         dv_sc[...] = jnp.zeros_like(dv_sc)
@@ -243,14 +261,15 @@ def _bwd_kernel(q_ref, do_ref, lse_ref, d_ref, k_ref, v_ref,
         dk_sc[...] += dk if owed is None else dk * owed
         dq_sc[i] += _dot(kt, dst)
 
-    _when_tile_runs(i, j, tq, tk, mask, fold)
+    _when_tile_runs(i, j, tq, tk, mask, fold, inside)
 
-    @pl.when(i == last_i)
+    @pl.when(step == last_step)
     def _():
         dk_ref[...] = dk_sc[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_sc[...].astype(dv_ref.dtype)
 
-    @pl.when(jnp.logical_and(j == pl.num_programs(1) - 1, i == last_i))
+    @pl.when(jnp.logical_and(j == pl.num_programs(1) - 1,
+                             step == last_step))
     def _():
         for n in range(dq_sc.shape[0]):
             dq_ref[:, n * tq:(n + 1) * tq] = (dq_sc[n] * scale).astype(
@@ -271,9 +290,10 @@ def flash_backward(q, k, v, out, lse, g, block_size: int, mask=None):
     dd = jnp.einsum("bshd,bshd->hbs", g.astype(jnp.float32),
                     out.astype(jnp.float32))
 
-    def q_map(g_, j, i):
+    def q_map(g_, j, step):
         # start at the key tile's first query tile (see kv_map above)
-        return g_, 0, mask.next_query_tile(j, i, tq, tk)
+        i = mask.query_tile(j, step, tq, tk)
+        return g_, 0, mask.next_query_tile(j, i, tq, tk, s // tq)
 
     def kv_map(g_, j, i):
         return g_, 0, j
@@ -285,7 +305,7 @@ def flash_backward(q, k, v, out, lse, g, block_size: int, mask=None):
     dq, dk, dv = pl.pallas_call(
         functools.partial(_bwd_kernel, scale=dh ** -0.5, tq=tq, tk=tk,
                           mask=mask),
-        grid=(h * b, s // tk, s // tq),
+        grid=(h * b, s // tk, mask.query_steps(s, tq, tk)),
         in_specs=[pl.BlockSpec((None, dh, tq), q_map),
                   pl.BlockSpec((None, dh, tq), q_map),
                   pl.BlockSpec((None, 1, tq), q_map),

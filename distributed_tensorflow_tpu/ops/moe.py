@@ -127,19 +127,31 @@ GROUPED_TILING = (1024, 1024, 1024)
 ROW_TILE = GROUPED_TILING[0]
 
 
+# what a product's tiles may take of the kernel's fast memory, by the
+# reckoning of ``_tiling``: the compiler's scoped limit is 16 MiB and a
+# (1024, 1024, 1024) tiling (16 MiB here) asked 18.4 of it; (1024, 1024, 768)
+# and (1024, 768, 1024), 13 and 14 MiB here, fit (described-chip compiles)
+GROUPED_VMEM_BYTES = 15 * 1024 * 1024
+
+
 def _tiling(m: int, k: int, n: int) -> tuple[int, int, int]:
     """Tiles for an (m, k) x (k, n) grouped product: the largest allowed,
     the contraction and column tiles divisors of k and n in whole lanes
     where they have one (a ragged last tile is masked in f32 and costs the
-    kernel its VMEM)."""
+    kernel its VMEM), the column tile narrowed until two buffers of each
+    bf16 tile and the f32 accumulator fit the kernel's fast memory."""
     def divisor(size, most):
         tile = min(most, size)
         while tile > 128 and (size % tile or tile % 128):
             tile -= 128
         return tile
 
-    return (min(GROUPED_TILING[0], m), divisor(k, GROUPED_TILING[1]),
-            divisor(n, GROUPED_TILING[2]))
+    tm, tk = min(GROUPED_TILING[0], m), divisor(k, GROUPED_TILING[1])
+    tn = divisor(n, GROUPED_TILING[2])
+    while tn > 128 and (4 * (tm * tk + tk * tn + tm * tn) + 4 * tm * tn
+                        > GROUPED_VMEM_BYTES):
+        tn = divisor(n, tn - 128)
+    return tm, tk, tn
 
 
 def routed_capacity(tokens: int, top_k: int, held: int, experts: int,
@@ -153,6 +165,10 @@ def routed_capacity(tokens: int, top_k: int, held: int, experts: int,
     expected = tokens * top_k * held / experts
     rows = min(math.ceil(capacity_factor * expected), tokens * min(top_k, held))
     return max(ROW_TILE, -(-rows // ROW_TILE) * ROW_TILE)
+
+
+# how the router's logits become the scores it ranks and weights by
+SCORINGS = ("softmax", "sigmoid")
 
 
 def _megablox():
@@ -305,7 +321,8 @@ _gather_live.defvjp(lambda hf, row, live: (hf[row], (hf, row, live)),
 
 
 def routed_experts(h, params, *, top_k: int, first_expert: int = 0,
-                   capacity_factor: float = 1.25, compute_dtype=None):
+                   capacity_factor: float = 1.25, compute_dtype=None,
+                   scoring: str = "softmax", scale: float = 1.0):
     """(B, S, d) -> ((B, S, d) f32-accumulated in h's dtype, aux): the
     dropless top-k mixture of gated experts, of which this chip holds
     ``params["w1"].shape[0]``, numbered from ``first_expert`` among the
@@ -313,7 +330,9 @@ def routed_experts(h, params, *, top_k: int, first_expert: int = 0,
 
     ``params``: ``router`` (d, E), ``w1`` (held, d, 2 f: the gate's
     columns, then the up projection's), ``w2`` (held, f, d). A row's
-    weights are its top-k softmax probabilities renormalised to one; the
+    weights are its top-k scores renormalised to one and multiplied by
+    ``scale``; the scores are the softmax of the router's logits over all
+    experts, or (``scoring="sigmoid"``) each logit's own sigmoid; the
     experts not held add nothing (their part is another chip's), and no
     code stands in for them.
 
@@ -348,6 +367,9 @@ def routed_experts(h, params, *, top_k: int, first_expert: int = 0,
     if not 0 <= first_expert <= e_total - held:
         raise ValueError(f"experts {first_expert}..{first_expert + held - 1} "
                          f"are not among the router's {e_total}")
+    if scoring not in SCORINGS:
+        raise ValueError(f"the router scores by one of {SCORINGS}, not by "
+                         f"{scoring!r}")
     cap = routed_capacity(t, top_k, held, e_total, capacity_factor)
 
     with scope("moe_router"):
@@ -356,9 +378,12 @@ def routed_experts(h, params, *, top_k: int, first_expert: int = 0,
         logits = jnp.dot(hf.astype(jnp.float32),
                          params["router"].astype(jnp.float32),
                          precision=lax.Precision.HIGHEST)
-        probs = jax.nn.softmax(logits, axis=-1)
+        probs = (jax.nn.sigmoid(logits) if scoring == "sigmoid"
+                 else jax.nn.softmax(logits, axis=-1))
         top_p, top_e = lax.top_k(probs, top_k)                  # (T, k)
         gate = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        if scale != 1.0:
+            gate = gate * scale
         local = top_e - first_expert
         is_held = jnp.logical_and(local >= 0, local < held)
         # the pairs of held experts first, by expert, in row order

@@ -292,6 +292,10 @@ def _pp_step_fn(model, optimizer, mesh, microbatches: int,
         raise ValueError("pipeline parallelism stages BLOCKS; it does "
                          "not compose with seq_axis (ring attention) — "
                          "pick one model-axis strategy")
+    if getattr(model, "layer_plan", ""):
+        raise ValueError("pipeline parallelism stacks layers of one kind "
+                         "into a stage's scan; a model with a layer_plan "
+                         "(layers that differ) is not staged yet")
     if getattr(model, "moe_experts", 0):
         raise ValueError("pipeline parallelism is not wired for MoE "
                          "blocks (the stage scan runs the dense block "

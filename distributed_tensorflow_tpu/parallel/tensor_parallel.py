@@ -218,6 +218,10 @@ def shard_attention(model, mesh: Mesh):
     those is the same mathematics with local shapes, and each shard
     chooses kernel or scan from ITS shapes as a single chip would. Models
     without ``attn_block`` (dense attention is plain XLA) pass through."""
+    if getattr(model, "layer_plan", ""):
+        raise ValueError("tensor parallelism splits one head count over "
+                         "the model axis; a model with a layer_plan (head "
+                         "counts and masks a layer) is not split yet")
     if getattr(model, "attn_block", None) is None:
         return model
     spec = P(DATA_AXIS, None, MODEL_AXIS, None)

@@ -66,6 +66,11 @@ def check_decodable(model) -> None:
                          "seq_axis=None")
     if model.moe_experts:
         raise ValueError("KV-cache decode does not support MoE blocks yet")
+    if getattr(model, "layer_plan", ""):
+        raise ValueError("KV-cache decode runs one kind of layer under one "
+                         "cache layout; a model with a layer_plan (window "
+                         "layers, head counts of their own) is not served "
+                         "yet")
 
 
 def make_prefill(model, jit: bool = True):

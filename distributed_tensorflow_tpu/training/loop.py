@@ -143,7 +143,10 @@ def _lm_arch_kwargs(FLAGS) -> dict:
     names = ("norm", "norm_eps", "rope_theta", "num_kv_heads", "head_dim",
              "qk_norm", "mlp_gated", "biases", "moe_top_k", "moe_ffn_dim",
              "moe_first_expert", "moe_held_experts", "objective",
-             "diffusion_block", "diffusion_t_min")
+             "diffusion_block", "diffusion_t_min", "layer_plan",
+             "attn_window", "window_rope_theta", "rope_fraction",
+             "rope_yarn", "attn_gate", "moe_shared_dim", "moe_scoring",
+             "moe_scale")
     out = {n: getattr(FLAGS, n) for n in names if hasattr(FLAGS, n)}
     out["noise_seed"] = int(FLAGS.seed)
     return out

@@ -70,7 +70,8 @@ WATCHDOG_LAST_SPANS = 32
 # in its ``op_name`` path (``benchmark/harness/scopes.py`` imports this
 # tuple, tests/test_scopes.py holds the compiled step to it).
 SCOPES = ("attention", "attn_proj", "mlp", "lm_head", "embed", "optimizer",
-          "sample_batch", "moe_router", "moe_experts")
+          "sample_batch", "moe_router", "moe_experts", "attention_window",
+          "moe_shared")
 
 
 def _json_safe(v):

@@ -311,3 +311,69 @@ def test_routed_layer_compiles_at_the_cells_widths(one_chip):
     touches = [ty for ty, args in fusions if buffer.search(ty) or any(
         buffer.search(types.get(a, "")) for a in re.findall(r"%[\w.-]+", args))]
     assert (len(writes), len(touches)) == (6, 7), touches
+
+
+def test_the_planned_cells_step_compiles_with_both_masks_fused(one_chip):
+    """``laguna-xs2.train-s8192``, built from its configuration's own file
+    through its family's flags: the whole step (2 x 8,192 tokens, five
+    layers of two kinds at the published widths, remat, adam on f32
+    masters) for the described chip. The two full layers' attention lowers
+    to the flash kernels under ``attention`` (136 of 256 tiles a head), the
+    three window layers' to the same kernels over their banded grid under
+    ``attention_window`` (31 of 32 steps), one forward a layer under remat;
+    the experts' products are Mosaic kernels whose tiles fit VMEM (a
+    (1024, 1024, 1024) tiling of the (2048, 1024) expert matrix did not);
+    and ``memory_analysis()`` is what the configuration's ``bytes``
+    records."""
+    from benchmark.harness import manifest
+    from distributed_tensorflow_tpu.data.device_data import DeviceData
+    from distributed_tensorflow_tpu.utils import telemetry
+
+    cell = manifest.load_cell("laguna-xs2.train-s8192")
+    trainer = cell.config["trainer"]
+    seq, batch = cell.mix["seq_len"], cell.mix["batch_per_chip"]
+    model = TransformerLM(
+        seq_len=seq, compute_dtype=jnp.bfloat16,
+        attn_block=trainer["attn_block"], ce_block=trainer["ce_block"],
+        remat=trainer["remat"], moe_capacity=trainer["moe_capacity"],
+        **cell.family().trainer_flags(cell.config, cell.mix))
+    opt = adam(trainer["learning_rate"])
+    state = jax.eval_shape(lambda: create_train_state(model, opt, seed=0))
+    data = DeviceData(jax.ShapeDtypeStruct((4096, seq), jnp.uint16),
+                      jax.ShapeDtypeStruct((4096, seq), jnp.uint16))
+    step = make_device_train_step(model, opt, batch, keep_prob=1.0, chunk=1)
+    telemetry.get_tracer().clear()
+    compiled = step.lower(*_on(one_chip, (state, data))).compile()
+    notes = [r for r in telemetry.last_spans(200)
+             if r["name"] == "attention_path"]
+    assert sorted((n["pass"], n.get("mask", "causal"), n["path"],
+                   n["tiles_run"], n["grid_steps"]) for n in notes) == [
+        ("backward", "causal", "fused", 136, 256),
+        ("backward", "window", "fused", 31, 32),
+        ("forward", "causal", "fused", 136, 256),
+        ("forward", "window", "fused", 31, 32)]
+    hlo = compiled.as_text()
+    paths = re.findall(
+        r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', hlo)
+    kernels = {}
+    for p in paths:
+        scope = [e for e in re.findall(r"[A-Za-z_]\w*", p)
+                 if e in telemetry.SCOPES][-1]
+        name = re.search(r"flash_attention_\w+|tgmm|gmm", p).group(0)
+        kernels[scope, name] = kernels.get((scope, name), 0) + 1
+    assert kernels == {
+        ("attention", "flash_attention_fwd"): 2,
+        ("attention", "flash_attention_bwd"): 2,
+        ("attention_window", "flash_attention_fwd"): 3,
+        ("attention_window", "flash_attention_bwd"): 3,
+        # a routed layer: two products forward, again under remat, two for
+        # the rows and two for the matrices backward
+        ("moe_experts", "gmm"): 4 * 6, ("moe_experts", "tgmm"): 4 * 2}
+    assert "[2,48,8192,512]" not in hlo and "[2,64,8192,512]" not in hlo
+    assert _device_bytes(compiled) < 0.65 * V5E_HBM_BYTES
+    ma = compiled.memory_analysis()
+    recorded = cell.config["bytes"]["compiled_step_for_described_v5e"]
+    assert ma.argument_size_in_bytes == recorded["arguments"]
+    assert abs(ma.temp_size_in_bytes - recorded["temp"]) < 0.02 * recorded["temp"]
+    assert cell.config["bytes"]["parameters"] == model.num_params() \
+        == cell.family().total_params(cell.sizes)

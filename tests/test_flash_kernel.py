@@ -115,6 +115,62 @@ def test_fused_matches_the_reference(s, tile, h, reference):
         assert _rel(a, b) < TOL, (name, _rel(a, b))
 
 
+@pytest.mark.parametrize("s,window,tile,h,hkv", [
+    (512, 128, 128, 2, 2), (512, 200, 128, 4, 2), (512, 256, 256, 2, 1),
+    (256, 100, 128, 2, 2), (384, 1000, 128, 2, 1)])
+def test_fused_window_matches_the_dense_window_and_the_scan(fused_on_cpu, s,
+                                                            window, tile, h,
+                                                            hkv):
+    """The causal window over the kernels' BANDED grids (a window that is
+    and is not a multiple of the tile, one wider than the sequence,
+    grouped-query heads): out, dq, dk, dv against the dense f32 window and
+    the scan on the same bf16 operands; the instant says the band."""
+    mask = attention.Mask("window", window=window)
+    q, _, _, g = _operands(s, h)
+    _, k, v, _ = _operands(s, hkv, seed=5)
+
+    def attend(q, k, v):
+        return blockwise_attention(q, k, v, tile, mask=mask)
+
+    telemetry.get_tracer().clear()
+    got = _value_and_grads(attend, q, k, v, g)
+    notes = {n["pass"]: n for n in telemetry.last_spans(100)
+             if n["name"] == "attention_path" and n["path"] == "fused"}
+    tq = flash_attention.query_tile(s, mask)
+    assert notes["forward"]["mask"] == "window" \
+        and notes["forward"]["window"] == window
+    assert notes["forward"]["tiles_run"] == mask.tiles_run(s, tq, tile)
+    assert notes["forward"]["grid_steps"] == (
+        s // tq) * mask.key_steps(s, tq, tile) <= (s // tq) * (s // tile)
+    assert notes["backward"]["grid_steps"] == (
+        s // tile) * mask.query_steps(s, tq, tile)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    dense = _value_and_grads(
+        lambda q, k, v: multi_head_attention(q, k, v, mask=mask), *f32, g)
+    flash_attention.flash_forward.clear_cache()
+    flash_attention.flash_backward.clear_cache()
+    attention._by_platform, steered = (
+        lambda fused, scan, *args: scan(*args)), attention._by_platform
+    try:
+        scan = _value_and_grads(attend, q, k, v, g)
+    finally:
+        attention._by_platform = steered
+    for name, a, b, c in zip(("out", "dq", "dk", "dv"), got, dense, scan):
+        assert a.shape == b.shape and a.dtype == jnp.bfloat16
+        assert _rel(a, b) < TOL and _rel(a, c) < TOL, name
+
+
+def test_the_causal_kernels_grid_is_every_tile_as_it_was(fused_on_cpu):
+    """Causal and block-diffusion masks walk every tile and skip through
+    the index maps: the band is the window's alone."""
+    q, k, v, _ = _operands(1024, 2)  # two query tiles of 512, eight of keys
+    _, notes = _paths(
+        lambda q, k, v: blockwise_attention(q, k, v, 128, causal=True),
+        q, k, v)
+    assert [(n["tiles_run"], n["grid_steps"]) for n in notes] == [(12, 16)]
+    assert "window" not in notes[0] and "mask" not in notes[0]
+
+
 def test_a_query_tile_smaller_than_the_key_tile(fused_on_cpu, monkeypatch):
     """The tiles need not be equal: 128 queries against 256 keys (the
     cases above have query tiles as large as the key tile or larger)."""

@@ -3,7 +3,10 @@ catalogued ``jax.named_scope`` reaches the ``op_name`` of some operation of
 one ``TransformerLM`` train step, with ``--remat`` and without, for dense and
 blockwise attention, with the dense MLP (``mlp``) or the dropless routed
 layer in its place (``moe_router``, ``moe_experts``, under the
-masked-diffusion objective); a scope changes metadata only, so the step's outputs
+masked-diffusion objective), or with a plan of layers that differ (a dense
+full-attention layer and a routed window layer with a shared expert:
+``attention_window`` and ``moe_shared`` beside all the others); a scope
+changes metadata only, so the step's outputs
 are bit-equal with ``jax.named_scope`` patched to a no-op; and the program
 opens no scope that the catalog does not hold."""
 
@@ -31,14 +34,20 @@ from distributed_tensorflow_tpu.utils.telemetry import SCOPES
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROUTED = {"moe_router", "moe_experts"}
+PLANNED = {"attention_window", "moe_shared"}
 FORMS = [(remat, block, False) for remat in (False, True)
-         for block in (None, 16)] + [(False, 16, True), (True, 16, True)]
+         for block in (None, 16)] + [(False, 16, True), (True, 16, True),
+                                     (False, None, "plan"), (True, 16, "plan")]
 
 
 def scopes_of(routed) -> set:
     """The catalog's names a form opens: the feed-forward is the dense
-    MLP or the routed layer, never both."""
-    return set(SCOPES) - ({"mlp"} if routed else ROUTED)
+    MLP or the routed layer, never both, unless a plan gives a layer of
+    each, which also has the window layers' attention and the shared
+    expert."""
+    if routed == "plan":
+        return set(SCOPES)
+    return set(SCOPES) - PLANNED - ({"mlp"} if routed else ROUTED)
 
 
 def build(remat, attn_block, routed=False):
@@ -47,8 +56,14 @@ def build(remat, attn_block, routed=False):
         kw = dict(norm="rmsnorm", rope_theta=1e4, num_kv_heads=1, head_dim=16,
                   qk_norm=True, mlp_gated=True, biases=False, moe_experts=8,
                   moe_top_k=2, moe_ffn_dim=32, moe_held_experts=4,
-                  moe_capacity=2.0, objective="masked_diffusion",
-                  diffusion_block=4)
+                  moe_capacity=2.0)
+        if routed == "plan":
+            kw.update(layer_plan="full:2:dense,window:3:routed",
+                      attn_window=8, window_rope_theta=1e2, rope_fraction=0.5,
+                      rope_yarn="4,16,8,1,1.1", attn_gate=True,
+                      moe_shared_dim=32, moe_scoring="sigmoid", moe_scale=2.5)
+        else:
+            kw.update(objective="masked_diffusion", diffusion_block=4)
     model = get_model("lm", vocab_size=300, seq_len=64, d_model=32,
                       num_heads=2, num_blocks=2, compute_dtype=jnp.bfloat16,
                       attn_block=attn_block, remat=remat, ce_block=16, **kw)
@@ -76,6 +91,9 @@ def test_every_scope_of_the_catalog_is_in_the_compiled_step(remat,
     paths = set(re.findall(r'op_name="([^"]*)"', text))
     found = set().union(*(scopes_in(p) for p in paths))
     assert found == scopes_of(routed)
+    if routed == "plan":  # beside attention, never inside it
+        assert not any({"attention", "attention_window"} <= scopes_in(p)
+                       for p in paths)
     if routed:  # the grouped products' backward carries its name too
         assert any("transpose(" in p and "moe_experts" in scopes_in(p)
                    for p in paths)
