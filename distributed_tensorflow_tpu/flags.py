@@ -352,9 +352,9 @@ def define_reference_flags():
                  "factor (tokens beyond ceil(cf*T/E) drop to the "
                  "residual stream — Switch semantics). Under --moe_top_k: "
                  "the sorted buffer's rows over the pairs uniform routing "
-                 "expects; they are memory and the static passes (sort, "
-                 "gather, add-back), the experts' products cost the pairs "
-                 "that arrive, and pairs past the buffer fail the step")
+                 "expects; they are memory, the experts' products and "
+                 "the dispatch (gather, add-back) cost the pairs that "
+                 "arrive, and pairs past the buffer fail the step")
     DEFINE_float("moe_aux", 0.01, "Load-balance auxiliary loss "
                  "coefficient for --moe_experts")
     DEFINE_integer("moe_top_k", 0, "If > 0, the --moe_experts layer is "

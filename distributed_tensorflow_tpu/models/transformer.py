@@ -964,6 +964,7 @@ class TransformerLM:
                 "overflow_rows": by["overflow_rows"].sum(),
                 "buffer_fill_max": by["buffer_fill"].max(),
                 "tiles_run_frac": by["tiles_run_frac"].mean(),
+                "dispatch_tiles_frac": by["dispatch_tiles_frac"].mean(),
                 "unrouted_frac": by["unrouted_frac"].mean(),
             }
 

@@ -1,7 +1,14 @@
-"""Switch-style top-1 mixture-of-experts MLP — the EP compute core.
+"""The two mixture-of-experts feed-forwards: ``switch_moe``, the Switch-style
+top-1 layer that is the EP compute core (described first, below), and
+``routed_experts``, the dropless top-k layer of the routed block
+(``models/transformer.py``): the pairs of held experts sorted into a buffer,
+two Pallas grouped products over the pairs that arrived, and a dispatch
+(gather, f32 add-back, their gradients) that runs over the buffer's live row
+tiles alone, in loops whose trip count is the routing's (its own docstring
+has the rest).
 
-The reference has no MoE (SURVEY.md §2c: expert parallelism ABSENT);
-this is the build's fifth parallelism family, designed XLA-first: all
+``switch_moe``. The reference has no MoE (SURVEY.md §2c: expert parallelism
+ABSENT); this is the build's fifth parallelism family, designed XLA-first: all
 static shapes, routing + dispatch as one-hot EINSUMS (the Switch
 Transformer formulation), no gather loops, so the MXU sees three big
 batched matmuls per expert group and the compiler fuses the rest.
@@ -30,6 +37,7 @@ takes the axis.
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -159,9 +167,10 @@ def routed_capacity(tokens: int, top_k: int, held: int, experts: int,
     """Rows of the sorted buffer: ``capacity_factor`` times the (row,
     expert) pairs that uniform routing sends to the ``held`` of
     ``experts`` experts, rounded up to the grouped product's row tile, and
-    never more than every pair there is. The rows are memory and the static
-    passes of the dispatch (sort, gather, add-back); the grouped products
-    cost the pairs that arrive, not these rows."""
+    never more than every pair there is. The rows are memory, and room
+    before an overflow; the sort costs every pair there is, and the grouped
+    products and the dispatch (gather, add-back, their gradients) cost the
+    row tiles the pairs that arrive reach into, not these rows."""
     expected = tokens * top_k * held / experts
     rows = min(math.ceil(capacity_factor * expected), tokens * min(top_k, held))
     return max(ROW_TILE, -(-rows // ROW_TILE) * ROW_TILE)
@@ -302,22 +311,118 @@ def row_tiles_run(sizes, tile: int):
         jnp.where(sizes > 0, -(-ends // tile) - starts // tile, 0))
 
 
+def _tile(a, start):
+    """``ROW_TILE`` rows of ``a`` from row ``start`` on."""
+    return lax.dynamic_slice_in_dim(a, start, ROW_TILE)
+
+
+def _live_tiles(pairs):
+    """The row tiles that ``pairs`` rows from the buffer's start reach into."""
+    return -(-pairs // ROW_TILE)
+
+
+def _over_live_tiles(pairs, carry, tile_fn):
+    """``carry = tile_fn(start, live, carry)`` for the row tiles of the
+    sorted buffer that its first ``pairs`` rows reach into: ``start`` is a
+    tile's first row, ``live`` says which of its rows are among the pairs
+    (all but in the last tile). ``pairs`` is traced (the routing's), so this
+    is a ``while`` whose trip count is not a constant: a pass costs the
+    tiles the pairs reach, and reverse mode cannot go through it (the passes
+    below are ``custom_vjp``s whose two halves are each one such loop). The
+    carries are updated in place."""
+    def body(i, carry):
+        start = i * ROW_TILE
+        return tile_fn(start, start + jnp.arange(ROW_TILE) < pairs, carry)
+
+    return lax.fori_loop(0, _live_tiles(pairs), body, carry)
+
+
+def _unwritten(shape, dtype):
+    """What a loop's carry holds before a tile is written, and past the last
+    live tile for good: nothing reads it. Zeros, at 0.8 ms a 512 MB buffer:
+    the scheduler hoists an operand-less ``lax.empty`` of every layer and
+    pass to the step's start (+3.35 GB in the routed cell's described-chip
+    compile)."""
+    return jnp.zeros(shape, dtype)
+
+
 @jax.custom_vjp
-def _gather_live(hf, row, live):
-    """``hf[row]``, whose gradient adds back the ``live`` rows' alone: the
-    others go to an index past ``hf``, which the scatter-add drops unread
-    (what a grouped product left unwritten there is no number to add)."""
-    return hf[row]
+def _gather_live(hf, row, pairs):
+    """``hf[row]`` over the row tiles the buffer's first ``pairs`` rows
+    reach into (the tiles past them are never written), whose gradient adds
+    back those rows' alone: the others go to an index past ``hf``, which
+    the scatter-add drops unread (what a grouped product left unwritten
+    there is no number to add)."""
+    return _gather_live_fwd(hf, row, pairs)[0]
+
+
+def _gather_live_fwd(hf, row, pairs):
+    def tile(start, live, xs):
+        return lax.dynamic_update_slice_in_dim(
+            xs, hf[_tile(row, start)], start, 0)
+
+    xs = _over_live_tiles(
+        pairs, _unwritten((row.shape[0], hf.shape[1]), hf.dtype), tile)
+    return xs, (hf, row, pairs)
 
 
 def _gather_live_bwd(res, g):
-    hf, row, live = res
-    return (jnp.zeros_like(hf).at[jnp.where(live, row, hf.shape[0])].add(
-        g, mode="drop"), None, None)
+    hf, row, pairs = res
+
+    def tile(start, live, acc):
+        to = jnp.where(live, _tile(row, start), hf.shape[0])
+        return acc.at[to].add(_tile(g, start), mode="drop")
+
+    return _over_live_tiles(pairs, jnp.zeros_like(hf), tile), None, None
 
 
-_gather_live.defvjp(lambda hf, row, live: (hf[row], (hf, row, live)),
-                    _gather_live_bwd)
+_gather_live.defvjp(_gather_live_fwd, _gather_live_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _add_back_live(t, dtype, ys, weight, row, pairs):
+    """(t, d) of ``dtype``, summed in f32: row ``row[i]`` gains ``ys[i] *
+    weight[i]`` for the buffer's first ``pairs`` rows; the row tiles past
+    them are never read, and neither pass writes their part of the gradient
+    with respect to ``ys``. A dead row of the last live tile ends in a
+    select, after the product: what stands in ``ys`` there may be a NaN."""
+    return _add_back_live_fwd(t, dtype, ys, weight, row, pairs)[0]
+
+
+def _add_back_live_fwd(t, dtype, ys, weight, row, pairs):
+    def tile(start, live, y):
+        part = (_tile(ys, start).astype(jnp.float32)
+                * _tile(weight, start)[:, None])
+        return y.at[_tile(row, start)].add(jnp.where(live[:, None], part, 0))
+
+    y = _over_live_tiles(
+        pairs, jnp.zeros((t, ys.shape[1]), jnp.float32), tile)
+    return y.astype(dtype), (ys, weight, row, pairs)
+
+
+def _add_back_live_bwd(t, dtype, res, g):
+    ys, weight, row, pairs = res
+
+    def tile(start, live, grads):
+        d_ys, d_weight = grads
+        keep = live[:, None]
+        g_rows = g[_tile(row, start)].astype(jnp.float32)
+        d_ys = lax.dynamic_update_slice_in_dim(
+            d_ys, jnp.where(keep, g_rows * _tile(weight, start)[:, None],
+                            0).astype(ys.dtype), start, 0)
+        # the weights' gradient goes on to the router: exact row sums
+        d_weight = lax.dynamic_update_slice_in_dim(
+            d_weight, jnp.sum(jnp.where(
+                keep, g_rows * _tile(ys, start).astype(jnp.float32), 0),
+                axis=-1), start, 0)
+        return d_ys, d_weight
+
+    d_ys, d_weight = _over_live_tiles(
+        pairs, (_unwritten(ys.shape, ys.dtype), jnp.zeros_like(weight)), tile)
+    return d_ys, d_weight, None, None
+
+
+_add_back_live.defvjp(_add_back_live_fwd, _add_back_live_bwd)
 
 
 def routed_experts(h, params, *, top_k: int, first_expert: int = 0,
@@ -345,14 +450,21 @@ def routed_experts(h, params, *, top_k: int, first_expert: int = 0,
     that is computed from it, ends in a select (``jnp.where``) inside the
     passes that read it (the gate, the weighting) or is dropped by index
     (``_gather_live``), never in a product with a weight of nought: no pass
-    over the buffer is added for it. So the buffer's size (``capacity_factor``) is memory and
-    the static passes (sort, gather, add-back), and the products cost the
-    pairs the routing sent: a step's time follows the routing. ``aux``: the
+    over the buffer is added for it. The dispatch stops at the last pair
+    too: the gather, the weighting with its add-back, and the gradients of
+    both are loops over the buffer's first ``ceil(pairs / ROW_TILE)`` row
+    tiles (``_over_live_tiles``: a ``while`` with a traced trip count, its
+    carries updated in place), and no pass writes or reads the tiles past
+    them. So the buffer's size (``capacity_factor``) is memory, the sort is
+    static, and products and dispatch cost the pairs the routing sent: a
+    step's time follows the routing. ``aux``: the
     held experts' rows (``rows_per_expert_max`` / ``_mean``),
     ``buffer_fill`` (pairs over the buffer's rows), ``tiles_run_frac``
     (``row_tiles_run`` of the forward product over the buffer's row tiles:
     the share of the buffer the products compute, a tile that two groups
-    share counted twice), ``unrouted_frac``, and ``overflow_rows``, the
+    share counted twice), ``dispatch_tiles_frac`` (the live row tiles over
+    the buffer's: the share the dispatch's loops run, 1 when the early stop
+    saved nothing), ``unrouted_frac``, and ``overflow_rows``, the
     pairs the buffer could not take: the caller fails the step when it is
     not 0 (``TransformerLM`` makes the loss NaN), never a silent drop."""
     from distributed_tensorflow_tpu.utils.profiling import scope
@@ -402,9 +514,10 @@ def routed_experts(h, params, *, top_k: int, first_expert: int = 0,
         live = jnp.arange(cap) < ends[-1]
         weight = jnp.where(live, gate.reshape(t * top_k)[order], 0.0)
         row = order // top_k
-        xs = _gather_live(hf, row, live)                        # (cap, d)
-        if cd is not None:
-            xs = xs.astype(cd)
+        # the passes over the buffer (this gather, the add-back, their
+        # gradients) stop at the last tile the pairs reach into
+        xs = _gather_live(hf if cd is None else hf.astype(cd), row,
+                          ends[-1])                             # (cap, d)
 
     with scope("moe_experts"):
         w1, w2 = params["w1"], params["w2"]
@@ -422,16 +535,17 @@ def routed_experts(h, params, *, top_k: int, first_expert: int = 0,
         ys = grouped_matmul(act, w2, sizes)                     # (cap, d)
 
     with scope("moe_router"):
-        y = jnp.zeros((t, d), jnp.float32).at[row].add(jnp.where(
-            keep, ys.astype(jnp.float32) * weight[:, None], 0))
+        y = _add_back_live(t, h.dtype, ys, weight, row, ends[-1])
         aux = {
             "rows_per_expert_max": jnp.max(counts).astype(jnp.float32),
             "rows_per_expert_mean": jnp.mean(counts.astype(jnp.float32)),
             "buffer_fill": total.astype(jnp.float32) / cap,
             "tiles_run_frac": row_tiles_run(sizes, ROW_TILE).astype(
                 jnp.float32) / (cap // ROW_TILE),
+            "dispatch_tiles_frac": (_live_tiles(ends[-1]) * ROW_TILE).astype(
+                jnp.float32) / cap,
             "overflow_rows": (total - ends[-1]).astype(jnp.float32),
             "unrouted_frac": 1.0 - jnp.mean(
                 jnp.any(is_held, axis=-1).astype(jnp.float32)),
         }
-    return y.astype(h.dtype).reshape(b, s, d), aux
+    return y.reshape(b, s, d), aux

@@ -264,7 +264,8 @@ def _display_log(step, display, logger, scalars, eff, snt,
             step, display["loss"], display["accuracy"],
             {k: v for k, v in display.items()
              if k.startswith(("moe_rows", "moe_overflow", "moe_unrouted",
-                              "moe_buffer", "moe_tiles", "diffusion_"))})
+                              "moe_buffer", "moe_tiles", "moe_dispatch",
+                              "diffusion_"))})
         logger.scalars(step, scalars())
         logger.flush()
         telemetry.get_tracer().flush()

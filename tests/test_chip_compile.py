@@ -263,17 +263,17 @@ def test_routed_layer_compiles_at_the_cells_widths(one_chip):
     ``moe_experts`` scope (a tile that does not fit VMEM fails here, as
     the transposed product's 1,024-row tile did; the ``moe_path`` instants
     say the same), the routing, the gather and the add-back are under
-    ``moe_router``. The products' groups end at the last pair, and what
-    they leave unwritten past it is selected away inside the passes that
-    were there: as when every row went through the experts, seven fusions
-    read or write an array of the buffer's rows and a width (the gather,
-    the gather of the output's gradient, its weighting with the weights'
-    gradient, the gate, the gate's backward, the sum of its two gradients,
-    the scatter-add of the rows' gradient) and six of them write one. A
-    select that failed to fuse is a pass of its own over the buffer (two
-    more with the selects at ``grouped_matmul``'s edge; one more, a sum
-    that reads the buffer again, with the select before the weighting
-    instead of after it) and fails here, not on the chip."""
+    ``moe_router``. The products' groups end at the last pair, and so does
+    the dispatch: the gather, the add-back and the gradients of both are
+    four ``while`` loops under ``moe_router`` whose trip count the compiler
+    does not know (the routing's live row tiles), and no body copies a
+    carry: not the buffer's (32,768 rows), not an accumulator's (8,192 rows
+    of the width). Outside the loops three fusions still write an array of
+    the buffer's rows, all the gate's (its product, its backward, the sum of
+    its two gradients: the selects that drop what the products left
+    unwritten are fused into them), and they are the only fusions that read
+    one. The whole (1.31 GB) takes no more than 0.1 GB over what it took
+    (1.335 GB) when every pass ran the whole buffer."""
     from distributed_tensorflow_tpu.ops.moe import routed_experts
     from distributed_tensorflow_tpu.utils import telemetry
 
@@ -288,8 +288,9 @@ def test_routed_layer_compiles_at_the_cells_widths(one_chip):
         return y.astype(jnp.float32).sum() + aux["overflow_rows"]
 
     telemetry.get_tracer().clear()
-    hlo = jax.jit(jax.grad(loss, (0, 1))).lower(
-        *_on(one_chip, (h, params))).compile().as_text()
+    compiled = jax.jit(jax.value_and_grad(loss, (0, 1))).lower(
+        *_on(one_chip, (h, params))).compile()
+    hlo = compiled.as_text()
     lowered = [(r["path"], r["pass"]) for r in telemetry.last_spans(100)
                if r["name"] == "moe_path"]
     assert sorted(lowered) == ([("pallas_gmm", "backward")] * 2
@@ -300,7 +301,19 @@ def test_routed_layer_compiles_at_the_cells_widths(one_chip):
     assert len(paths) == 6 and all("moe_experts" in p for p in paths)
     assert sum("tgmm" in p for p in paths) == 2
     assert "bf16[32768,2048]" in hlo and "moe_router" in hlo
+    computations = dict(re.findall(
+        r"^(?:ENTRY )?(%[\w.-]+) \(.*?\n((?:  .*\n)+)", hlo, flags=re.M))
     entry = hlo[hlo.index("ENTRY"):]
+    loops = [line for line in entry.splitlines()
+             if " while(" in line and "moe_router" in line]
+    assert len(loops) == 4, loops
+    assert not any("known_trip_count" in line for line in loops)
+    carry = re.compile(r"\[(?:32768,\d+|8192,2048)\]")
+    for line in loops:
+        body = computations[re.search(r"body=(%[\w.-]+)", line).group(1)]
+        copies = [op for op in body.splitlines() if re.search(
+            r"= \S+ copy(?:-start)?\(", op) and carry.search(op)]
+        assert not copies, copies
     buffer = re.compile(r"\[32768,\d+\]")
     types = dict(re.findall(
         r"^\s*(?:ROOT )?(%[\w.-]+) = (\(.*?\)|\S+) ", entry, flags=re.M))
@@ -310,7 +323,8 @@ def test_routed_layer_compiles_at_the_cells_widths(one_chip):
     writes = [ty for ty, _ in fusions if buffer.search(ty)]
     touches = [ty for ty, args in fusions if buffer.search(ty) or any(
         buffer.search(types.get(a, "")) for a in re.findall(r"%[\w.-]+", args))]
-    assert (len(writes), len(touches)) == (6, 7), touches
+    assert (len(writes), len(touches)) == (3, 3), touches
+    assert _device_bytes(compiled) < 1.335e9 + 0.1e9
 
 
 def test_the_planned_cells_step_compiles_with_both_masks_fused(one_chip):
@@ -323,8 +337,8 @@ def test_the_planned_cells_step_compiles_with_both_masks_fused(one_chip):
     ``attention_window`` (31 of 32 steps), one forward a layer under remat;
     the experts' products are Mosaic kernels whose tiles fit VMEM (a
     (1024, 1024, 1024) tiling of the (2048, 1024) expert matrix did not);
-    and ``memory_analysis()`` is what the configuration's ``bytes``
-    records."""
+    and ``memory_analysis()`` holds the arguments the configuration's
+    ``bytes`` records and no more temporaries than it records."""
     from benchmark.harness import manifest
     from distributed_tensorflow_tpu.data.device_data import DeviceData
     from distributed_tensorflow_tpu.utils import telemetry
@@ -374,6 +388,9 @@ def test_the_planned_cells_step_compiles_with_both_masks_fused(one_chip):
     ma = compiled.memory_analysis()
     recorded = cell.config["bytes"]["compiled_step_for_described_v5e"]
     assert ma.argument_size_in_bytes == recorded["arguments"]
-    assert abs(ma.temp_size_in_bytes - recorded["temp"]) < 0.02 * recorded["temp"]
+    # the file records PR 33's program (3.457 GB of temporaries); since the
+    # dispatch runs the live row tiles alone it is 3.317 GB (no f32 gather of
+    # the whole buffer), and a PR that is not the benchmark's may not edit it
+    assert 0.94 * recorded["temp"] < ma.temp_size_in_bytes <= recorded["temp"]
     assert cell.config["bytes"]["parameters"] == model.num_params() \
         == cell.family().total_params(cell.sizes)

@@ -16,6 +16,10 @@ brought, each held to something written independently:
 - the experts' products stop at the last pair: what the kernels leave
   unwritten past it (NaN standing in for it here) reaches no output and no
   gradient, and ``tiles_run_frac`` is the kernel's own count of its work;
+- the dispatch stops there too: gather, add-back and their gradients are
+  loops over the buffer's live row tiles (a ``while`` with a traced bound),
+  held to a plain per-expert layer at every fill, with NaN in every row
+  nobody wrote, and ``dispatch_tiles_frac`` is the tiles they ran;
 - the streamed head's row weights, the noise's key, the flags' validators.
 
 d 64, 4 query / 2 key-value heads of width 16, 16 experts of width 32 with
@@ -26,6 +30,7 @@ import contextlib
 import functools
 import json
 import os
+import re
 import statistics
 import subprocess
 import sys
@@ -472,7 +477,8 @@ def test_remat_keeps_the_counters_the_loss_and_the_gradients():
     counters = {k for k in metrics if k.startswith("moe_")}
     assert {"moe_rows_per_expert_max", "moe_rows_per_expert_mean",
             "moe_overflow_rows", "moe_buffer_fill_max",
-            "moe_tiles_run_frac", "moe_unrouted_frac"} <= counters
+            "moe_tiles_run_frac", "moe_dispatch_tiles_frac",
+            "moe_unrouted_frac"} <= counters
     assert metrics.keys() == r_metrics.keys()
     for k in metrics:
         assert float(metrics[k]) == float(r_metrics[k]), k
@@ -647,6 +653,153 @@ def test_an_overflow_still_counts_fails_the_step_and_runs_every_tile(
             < float(metrics["moe_tiles_run_frac"]) < 1)
 
 
+# ---- the dispatch stops at the last pair --------------------------------------
+
+DISPATCH = {"rows": 24, "d": 16, "experts": 8, "held": 2, "first": 2,
+            "top_k": 2, "f": 8, "tile": 8}
+# held pairs for a buffer of 2 x the 12 expected: 24 rows, three tiles of 8
+FILLS = {"no held pair": 0, "one pair": 1, "a part-full last tile": 11,
+         "the pairs end on a tile's edge": 16, "an overflow": 30}
+
+
+def _dispatch_case(pairs, seed=0):
+    """A layer whose logits are written out: ``h``'s first columns are the
+    logits (the router is the identity there), so each row's two experts
+    are chosen here: two of the six not held, but for ``pairs`` (row, slot)
+    places, which go to the two held ones (a row's two slots to both)."""
+    z = DISPATCH
+    rng = np.random.default_rng(seed)
+    logits = rng.normal(size=(z["rows"], z["experts"])).astype(np.float32)
+    others = [e for e in range(z["experts"])
+              if not z["first"] <= e < z["first"] + z["held"]]
+    places = [(r, j) for j in range(z["top_k"]) for r in range(z["rows"])]
+    chosen = set(places[:pairs])
+    for r in range(z["rows"]):
+        free = list(rng.permutation(others)[:z["top_k"]])
+        for j in range(z["top_k"]):
+            e = z["first"] + j if (r, j) in chosen else free[j]
+            logits[r, e] += 6.0 + j
+    h = rng.normal(size=(z["rows"], z["d"])).astype(np.float32)
+    h[:, :z["experts"]] = logits
+    router = np.zeros((z["d"], z["experts"]), np.float32)
+    router[:z["experts"]] = np.eye(z["experts"])
+    k = jax.random.split(jax.random.key(seed), 3)
+    w1 = jax.random.normal(k[0], (z["held"], z["d"], 2 * z["f"])) * .3
+    w2 = jax.random.normal(k[1], (z["held"], z["f"], z["d"])) * .3
+    params = {"router": jnp.asarray(router), "w1": w1, "w2": w2}
+    probe = jax.random.normal(k[2], (1, z["rows"], z["d"]))
+    return jnp.asarray(h)[None], params, probe
+
+
+def _plain_layer(h, params, scoring, scale, cap):
+    """Every held expert on every row, each row's result weighted where the
+    row chose it; of more pairs than the buffer's ``cap`` rows, those past
+    it in (expert, row) order add nothing (the step then fails)."""
+    z = DISPATCH
+    hf = h.reshape(-1, z["d"])
+    logits = jnp.dot(hf, params["router"], precision=lax.Precision.HIGHEST)
+    scores = (jax.nn.sigmoid(logits) if scoring == "sigmoid"
+              else jax.nn.softmax(logits, axis=-1))
+    top_p, top_e = lax.top_k(scores, z["top_k"])
+    gate = scale * top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    y, taken = 0.0, 0
+    for e in range(z["held"]):
+        chose = np.asarray(top_e == z["first"] + e)             # (rows, k)
+        rank = taken + np.cumsum(chose.any(-1)) - 1
+        taken += int(chose.any(-1).sum())
+        weight = jnp.sum(jnp.where(chose, gate, 0.0), axis=-1)
+        weight = jnp.where(rank < cap, weight, 0.0)
+        up = hf @ params["w1"][e]
+        out = (jax.nn.silu(up[:, :z["f"]]) * up[:, z["f"]:]) @ params["w2"][e]
+        y = y + weight[:, None] * out
+    return y.reshape(h.shape), None
+
+
+@pytest.mark.parametrize("scoring,scale", [("softmax", 1.0), ("sigmoid", 2.5)])
+@pytest.mark.parametrize("fill", sorted(FILLS))
+def test_the_dispatch_runs_the_live_tiles_and_equals_the_plain_layer(
+        monkeypatch, fill, scoring, scale):
+    """``routed_experts`` against ``_plain_layer``: the result and the
+    gradients with respect to the rows, the router and both expert matrices,
+    at every fill of the buffer; again with NaN in every row no pass wrote
+    (what the products leave past the last pair, and the loops' carries past
+    the last live tile), bit for bit; and the counter is the tiles the
+    pairs reach into."""
+    z, pairs = DISPATCH, FILLS[fill]
+    monkeypatch.setattr(moe, "ROW_TILE", z["tile"])
+    cap = moe.routed_capacity(z["rows"], z["top_k"], z["held"], z["experts"],
+                              2.0)
+    assert cap == 24
+    h, params, probe = _dispatch_case(pairs)
+
+    def run(layer):
+        def loss(h, params):
+            y, aux = layer(h, params)
+            return jnp.sum(y * probe), (y, aux)
+        (_, (y, aux)), grads = jax.value_and_grad(
+            loss, (0, 1), has_aux=True)(h, params)
+        return y, aux, grads
+
+    ours = functools.partial(
+        moe.routed_experts, top_k=z["top_k"], first_expert=z["first"],
+        capacity_factor=2.0, scoring=scoring, scale=scale)
+    with jax.default_matmul_precision("highest"):
+        want_y, _, want = run(functools.partial(
+            _plain_layer, scoring=scoring, scale=scale, cap=cap))
+        y, aux, grads = run(ours)
+        monkeypatch.setattr(moe, "_ragged", _poisoned(moe._ragged, "result"))
+        monkeypatch.setattr(moe, "_ragged_grads",
+                            _poisoned(moe._ragged_grads, "gradients"))
+        monkeypatch.setattr(moe, "_unwritten", lambda shape, dtype:
+                            jnp.full(shape, jnp.nan, dtype))
+        p_y, p_aux, p_grads = run(ours)
+    assert float(aux["overflow_rows"]) == max(0, pairs - cap)
+    assert float(aux["buffer_fill"]) == pytest.approx(pairs / cap)
+    live_tiles = -(-min(pairs, cap) // z["tile"])
+    assert float(aux["dispatch_tiles_frac"]) == pytest.approx(
+        live_tiles * z["tile"] / cap)
+    assert (float(aux["dispatch_tiles_frac"]) == 1) == (fill == "an overflow")
+    np.testing.assert_allclose(y, want_y, rtol=2e-5, atol=2e-5)
+    for name in ("router", "w1", "w2"):
+        np.testing.assert_allclose(grads[1][name], want[1][name],
+                                   rtol=1e-5, atol=1e-5, err_msg=name)
+    np.testing.assert_allclose(grads[0], want[0], rtol=1e-5, atol=1e-5)
+    if pairs:
+        assert np.any(np.asarray(grads[1]["w1"])) and np.any(np.asarray(y))
+    else:
+        assert not np.any(np.asarray(y)) and not np.any(
+            np.asarray(grads[1]["w2"]))
+    for got, plain in zip(jax.tree.leaves((p_y, p_grads)),
+                          jax.tree.leaves((y, grads))):
+        assert np.all(np.isfinite(got))
+        np.testing.assert_array_equal(got, plain)
+    assert {k: float(v) for k, v in p_aux.items()} == \
+        {k: float(v) for k, v in aux.items()}
+
+
+def test_the_dispatch_lowers_to_loops_whose_bound_is_the_routings():
+    """The four passes over the buffer are ``while`` loops whose trip count
+    is carried into them (the live tiles of this call's routing), in the
+    lowered text, and the compiler finds no constant for it either."""
+    h, params, probe = _dispatch_case(FILLS["a part-full last tile"])
+
+    def loss(h, params):
+        y, aux = moe.routed_experts(h, params, top_k=2, first_expert=2,
+                                    capacity_factor=4.0)
+        return jnp.sum(y * probe) + aux["dispatch_tiles_frac"]
+
+    lowered = jax.jit(jax.value_and_grad(loss, (0, 1))).lower(h, params)
+    text = lowered.as_text()
+    # counter and bound are both carried: compare LT, %iterArg, %iterArg_n
+    carried = re.findall(
+        r"stablehlo\.compare\s+LT, %iterArg\w*, %iterArg\w*", text)
+    assert text.count("stablehlo.while") >= 4 and len(carried) >= 4
+    loops = [line for line in lowered.compile().as_text().splitlines()
+             if " while(" in line and "moe_router" in line]
+    assert len(loops) == 4 and not any(
+        "known_trip_count" in line for line in loops), loops
+
+
 # ---- the streamed head, the noise, the flags ---------------------------------
 
 def test_the_streamed_heads_row_weights_and_denominator():
@@ -709,7 +862,8 @@ def test_the_noise_comes_from_the_key_and_the_eval_agrees_with_itself():
     assert set(one) >= {"loss", "accuracy", "diffusion_masked_frac",
                         "moe_rows_per_expert_max", "moe_rows_per_expert_mean",
                         "moe_overflow_rows", "moe_unrouted_frac",
-                        "moe_buffer_fill_max", "moe_tiles_run_frac"}
+                        "moe_buffer_fill_max", "moe_tiles_run_frac",
+                        "moe_dispatch_tiles_frac"}
     # about (1 - 4/16)^4 = 0.32 of the rows choose none of the four held
     assert 0.2 < float(one["moe_unrouted_frac"]) < 0.45
 
@@ -818,6 +972,8 @@ def test_the_trainer_runs_the_configuration_from_flags_alone(tmp_path):
         assert r["moe_overflow_rows"] == 0 and 0 < r["moe_buffer_fill_max"] <= 1
         # the buffer is one row tile here, which each held expert visits
         assert r["moe_tiles_run_frac"] in (1, 2, 3, 4)
+        # the pairs fill a quarter of the buffer: one live tile of its two
+        assert r["moe_dispatch_tiles_frac"] == 0.5
         assert r["moe_rows_per_expert_max"] >= r["moe_rows_per_expert_mean"] > 0
         assert 0.2 < r["moe_unrouted_frac"] < 0.45
         assert 0.3 < r["diffusion_masked_frac"] < 0.7
