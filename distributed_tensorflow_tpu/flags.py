@@ -390,6 +390,8 @@ def define_reference_flags():
                    "experts alike)")
     DEFINE_boolean("biases", True, "Biases on the feed-forward and the "
                    "output head (the attention projections have none)")
+    DEFINE_integer("mlp_dim", 0, "Width of the LM's dense feed-forward; "
+                   "0 = 4 x --d_model")
     DEFINE_string("layer_plan", "", "Layers that DIFFER: one entry a "
                   "layer, <attention>:<query heads>:<feed-forward> joined "
                   "by commas, attention full (the model's causal mask) or "
@@ -425,6 +427,22 @@ def define_reference_flags():
                   "softmax over all experts, or sigmoid of each")
     DEFINE_float("moe_scale", 1.0, "Factor on --moe_top_k's renormalised "
                  "top-k weights")
+    DEFINE_integer("loop_passes", 1, "Times the LM's stack of "
+                   "--num_blocks layers is run over the SAME weights: "
+                   "every pass ends in the final norm, feeds the next, and "
+                   "is scored by the one head; an exit gate (one linear "
+                   "unit a token) after every pass gives the distribution "
+                   "over passes that weighs the passes' losses. 1 = a "
+                   "stack run once, no gate. Local and data-parallel "
+                   "training only: the steps that split a model over the "
+                   "mesh's model axis, and serving, refuse more")
+    DEFINE_float("loop_exit_beta", 0.0, "Weight of the exit "
+                 "distribution's entropy in --loop_passes' loss: mean over "
+                 "tokens of sum_t p(t) CE_t - beta H(p)")
+    DEFINE_boolean("sandwich_norm", False, "A second --norm on the OUTPUT "
+                   "of the attention half and of the feed-forward half, "
+                   "before each is added to the residual stream (two more "
+                   "gains a layer)")
     DEFINE_string("objective", "next_token", "The LM's training "
                   "objective: next_token (causal, shifted targets) or "
                   "masked_diffusion (diffusion over blocks of "
@@ -678,6 +696,7 @@ def define_reference_flags():
     FLAGS._register_validator(_validate_model_data_flags)
     FLAGS._register_validator(_validate_lm_arch_flags)
     FLAGS._register_validator(_validate_layer_plan_flags)
+    FLAGS._register_validator(_validate_loop_flags)
     FLAGS._register_validator(_validate_pairing_flags)
     FLAGS._register_validator(_validate_pipeline_flags)
     FLAGS._register_validator(_validate_elastic_flags)
@@ -1006,6 +1025,15 @@ def _validate_lm_arch_flags(values: dict):
              "must be a boolean")
     _require(values, "biases", lambda v: isinstance(v, bool),
              "must be a boolean")
+    _require(values, "mlp_dim", lambda v: int(v) >= 0,
+             "must be >= 0 (0 = 4 x d_model)")
+    if values.get("mlp_dim"):
+        for other in ("seq_parallel", "expert_parallel"):
+            if values.get(other):
+                raise ValueError(
+                    f"--{other} rebuilds the model with a feed-forward of "
+                    f"4 x --d_model: --mlp_dim would silently change "
+                    f"nothing there — drop one")
     _require(values, "objective",
              lambda v: v in ("next_token", "masked_diffusion"),
              "must be next_token or masked_diffusion")
@@ -1147,6 +1175,43 @@ def _validate_layer_plan_flags(values: dict):
         raise ValueError("--layer_plan's layers have head counts of their "
                          "own; --model_axis > 1 (tensor parallelism) "
                          "splits one head count — drop one")
+
+
+def _validate_loop_flags(values: dict):
+    """--loop_passes, --loop_exit_beta and --sandwich_norm: each one's own
+    range, the pair that would be inert, and the steps and layers that a
+    stack run several times does not reach yet."""
+    _require(values, "loop_passes", lambda v: int(v) >= 1,
+             "must be >= 1 (1 = the stack run once)")
+    _require(values, "loop_exit_beta", lambda v: float(v) >= 0,
+             "must be >= 0 (0 = no entropy term)")
+    _require(values, "sandwich_norm", lambda v: isinstance(v, bool),
+             "must be a boolean")
+    looped = int(values.get("loop_passes") or 1) > 1
+    if values.get("loop_exit_beta") and not looped:
+        raise ValueError("--loop_exit_beta weighs the entropy of the exit "
+                         "distribution over passes: without --loop_passes "
+                         "> 1 it would silently change nothing")
+    if (looped or values.get("sandwich_norm")) and values.get("moe_experts"):
+        raise ValueError("--loop_passes > 1 and --sandwich_norm are the "
+                         "dense feed-forward layers'; --moe_experts makes "
+                         "them mixtures — drop one")
+    if not looped:
+        return
+    if values.get("objective") not in (None, "next_token"):
+        raise ValueError("--loop_passes > 1 runs under --objective "
+                         "next_token")
+    for other in ("seq_parallel", "pipeline"):
+        if values.get(other):
+            raise ValueError(
+                f"--loop_passes > 1 runs in the local and data-parallel "
+                f"steps; --{other} builds a step that walks the layers "
+                f"once (a pipeline's last stage would feed its first) — "
+                f"drop one")
+    if int(values.get("model_axis") or 1) > 1:
+        raise ValueError("--loop_passes > 1 is not split over --model_axis "
+                         "yet (tensor parallelism walks the layers once) — "
+                         "drop one")
 
 
 def _validate_pairing_flags(values: dict):

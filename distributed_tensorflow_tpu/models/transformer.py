@@ -77,6 +77,7 @@ class BlockArch(NamedTuple):
     attn_gate: bool = False     # sigmoid gate a head and row on attention
     ffn: str = "dense"          # or "switch" / "routed" (ops/moe.py)
     shared_dim: int = 0         # routed: a gated expert every row takes
+    sandwich: bool = False      # a second norm on each half's OUTPUT
 
 
 LAYER_ATTENTIONS = ("full", "window")
@@ -146,6 +147,16 @@ def _norm_params(prefix, d, dtype, arch):
     return out
 
 
+def _residual(h, out, blk, prefix, arch):
+    """``h`` plus a half's output; with sandwich norms the output is
+    normalised first (leaves ``prefix + "post_g"`` ...), so that what a
+    layer adds to the stream has a scale of its own however often the
+    layer is run."""
+    if arch is not None and arch.sandwich:
+        out = _norm(out, blk, prefix + "post_", arch)
+    return h + out
+
+
 def yarn_frequencies(theta, dr: int, yarn):
     """(the Dr / 2 inverse frequencies, the factor on cos and sin) of YaRN
     (arXiv:2309.00071, as ``rope_type: yarn`` computes it): frequency i of
@@ -212,6 +223,8 @@ def _attn_half_params(w, d, h, dh, dtype, arch=None):
     out["proj"] = w((h * dh, d))
     if arch is not None and arch.attn_gate:
         out["gate"] = w((d, h))
+    if arch is not None and arch.sandwich:
+        out.update(_norm_params("ln1_post_", d, dtype, arch))
     out.update(_norm_params("ln2_", d, dtype, arch))
     return out
 
@@ -225,6 +238,8 @@ def _mlp_params(w, d, mlp_dim, dtype, arch=None):
         out[name] = {"w": w(shape)}
         if arch is None or arch.biases:
             out[name]["b"] = jnp.zeros((shape[1],), dtype)
+    if arch is not None and arch.sandwich:
+        out.update(_norm_params("ln2_post_", d, dtype, arch))
     return out
 
 
@@ -262,8 +277,9 @@ def _mlp_half(h, blk, cd, arch=None):
         y = jax.nn.silu(y[..., :m]) * y[..., m:]
     else:
         y = jax.nn.relu(y)
-    return h + nn.dense(y, blk["mlp_out"]["w"], blk["mlp_out"].get("b"),
-                        compute_dtype=cd)
+    return _residual(h, nn.dense(y, blk["mlp_out"]["w"],
+                                 blk["mlp_out"].get("b"), compute_dtype=cd),
+                     blk, "ln2_", arch)
 
 
 def _attn_half(h, blk, attn_fn, cd, arch=None, pos=None):
@@ -303,7 +319,8 @@ def _attn_half_kv(h, blk, attn_fn, cd, arch=None, pos=None):
         a = a * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
             a.dtype)[..., None]
     a = a.reshape(*a.shape[:2], -1)  # (B, S, H*Dh)
-    return h + nn.dense(a, blk["proj"], compute_dtype=cd), k, v
+    return _residual(h, nn.dense(a, blk["proj"], compute_dtype=cd), blk,
+                     "ln1_", arch), k, v
 
 
 def _routed_block_params(w, d, h, dh, ffn_dim, num_experts, held, dtype,
@@ -403,6 +420,20 @@ def _planned_block(h, blk, attn_fn, cd, arch, pos, moe):
     if arch.ffn == "switch":
         return _transformer_block_moe(h, blk, attn_fn, cd, *moe)
     return _transformer_block(h, blk, attn_fn, cd, arch, pos), None
+
+
+def exit_log_distribution(gate_logits):
+    """(T, ...) f32 logits of the exit gate after each of T passes -> (T,
+    ...) ``log p(t)`` of leaving after pass t: ``p(t) = s(g_t) prod_{j<t}
+    (1 - s(g_j))`` for t < T and the remainder ``prod_{j<T} (1 - s(g_j))``
+    for t = T (the last pass's own gate is not read), so that p sums to 1.
+    From log-sigmoids: a saturated gate gives a large negative number and
+    no infinity."""
+    g = gate_logits.astype(jnp.float32)
+    none = jnp.zeros_like(g[:1])
+    stayed = jnp.concatenate(
+        [none, jnp.cumsum(jax.nn.log_sigmoid(-g[:-1]), axis=0)])
+    return stayed + jnp.concatenate([jax.nn.log_sigmoid(g[:-1]), none])
 
 
 def _remat(fn, static_argnums, **says):
@@ -613,6 +644,20 @@ class TransformerLM:
     the layers' ``BlockArch`` values that ``init`` and the forward pass
     walk; without ``layer_plan`` its entries are one value.
 
+    ``loop_passes=T`` > 1 runs the stack T TIMES over the same weights
+    (``_run_passes``: one ``lax.scan``, so one set of layers in the program
+    and one accumulator of their gradients): ``ln_f`` closes every pass, its
+    output feeds the next pass, the one head and an exit gate (``exit_gate``:
+    one linear unit a token), and the loss is the mean over tokens of the
+    passes' cross-entropies weighted by the exit distribution the gates
+    give, less ``loop_exit_beta`` times that distribution's entropy
+    (``_looped_loss``); ``apply`` returns the last pass's logits.
+    ``sandwich_norm`` puts a second norm on the OUTPUT of each half of a
+    layer before the residual add; ``mlp_dim`` is the dense feed-forward's
+    width where it is not ``mlp_ratio * d_model``. With one pass, no
+    entropy term and no sandwich norms the tree and the program are what
+    they were.
+
     ``objective="masked_diffusion"`` trains by diffusion over blocks of
     ``diffusion_block`` tokens: ``noise_batch`` (called under the step's
     key, and under a key folded from ``noise_seed`` by the eval) masks
@@ -668,6 +713,10 @@ class TransformerLM:
         moe_shared_dim: int = 0,
         moe_scoring: str = "softmax",
         moe_scale: float = 1.0,
+        mlp_dim: int = 0,
+        sandwich_norm: bool = False,
+        loop_passes: int = 1,
+        loop_exit_beta: float = 0.0,
         **_unused,
     ):
         if d_model % num_heads and not head_dim:
@@ -701,7 +750,7 @@ class TransformerLM:
         self.d_model = d_model
         self.num_heads = num_heads
         self.num_blocks = num_blocks
-        self.mlp_dim = mlp_ratio * d_model
+        self.mlp_dim = int(mlp_dim) or mlp_ratio * d_model
         self.compute_dtype = compute_dtype
         self.seq_axis = seq_axis
         self.attn_block = attn_block
@@ -728,7 +777,7 @@ class TransformerLM:
                          rope_yarn=parse_rope_yarn(rope_yarn),
                          attn_gate=bool(attn_gate), ffn=ffn,
                          shared_dim=int(moe_shared_dim) if self.moe_top_k
-                         else 0)
+                         else 0, sandwich=bool(sandwich_norm))
         if (arch.rope_fraction != 1.0 or arch.rope_yarn) and not rope_theta:
             raise ValueError("rope_fraction and rope_yarn shape rotary "
                              "positions: they need rope_theta > 0")
@@ -753,6 +802,24 @@ class TransformerLM:
                 f"{self.moe_first_expert + self.moe_held_experts - 1} of "
                 f"{self.moe_experts} by its configuration, not by a mesh "
                 f"axis")
+        self.loop_passes = int(loop_passes)
+        self.loop_exit_beta = float(loop_exit_beta)
+        if self.loop_passes < 1 or self.loop_exit_beta < 0:
+            raise ValueError("loop_passes must be >= 1 and loop_exit_beta "
+                             ">= 0")
+        if self.loop_passes == 1 and self.loop_exit_beta:
+            raise ValueError("loop_exit_beta weighs the entropy of the exit "
+                             "distribution over passes: it needs "
+                             "loop_passes > 1")
+        if (self.loop_passes > 1 or arch.sandwich) and any(
+                x.ffn != "dense" for x in self.plan):
+            raise ValueError("loop_passes > 1 and sandwich_norm are the "
+                             "dense feed-forward layers': no moe_experts")
+        if self.loop_passes > 1 and (seq_axis is not None
+                                     or objective != "next_token"):
+            raise ValueError("loop_passes > 1 runs under the next-token "
+                             "objective on one device's whole sequence: no "
+                             "seq_axis")
         if objective == "masked_diffusion":
             # found by the steps and the eval (getattr): absent otherwise
             self.noise_batch = self._noise_batch
@@ -825,6 +892,9 @@ class TransformerLM:
         params["head"] = {"w": w((d, self.vocab_size))}
         if arch is None or arch.biases:
             params["head"]["b"] = jnp.zeros((self.vocab_size,), dtype)
+        if self.loop_passes > 1:
+            # one linear unit on a pass's normed output, a token
+            params["exit_gate"] = {"w": w((d, 1)), "b": jnp.zeros((1,), dtype)}
         for layer in self.plan:
             h = layer.heads or self.num_heads
             if layer.ffn == "routed":
@@ -861,8 +931,10 @@ class TransformerLM:
         hidden states (B, S, d) after ln_f + dropout. The streamed-CE
         path consumes this directly so the (B, S, V) logits never
         materialize; ``apply`` adds the head on top."""
-        return self._hidden_and_aux(params, x, keep_prob=keep_prob,
-                                    rng=rng, train=train)[0]
+        h = self._hidden_and_aux(params, x, keep_prob=keep_prob, rng=rng,
+                                 train=train)[0]
+        # of a stack run several times, the last pass's
+        return h[-1] if self.loop_passes > 1 else h
 
     def attention_fn(self, window: int = 0):
         """``(q, k, v) -> out``, all (B, S, H, Dh): this model's causal
@@ -920,7 +992,8 @@ class TransformerLM:
         loss scaled by ``moe_aux``. With the routed layer the second is
         the routing counters of the layers (a dict); under masked
         diffusion x is ``[noised ; clean]`` and the hidden states are the
-        noised half's."""
+        noised half's. With ``loop_passes`` > 1: (every pass's hidden
+        states (T, B, S, d), the exit gate's logits (T, B, S))."""
         cd = self.compute_dtype
         arch = self.arch
         diffusion = self.objective == "masked_diffusion"
@@ -947,6 +1020,9 @@ class TransformerLM:
                 ids = jnp.tile(jnp.arange(self.seq_len), 2)
 
         fns = self._layer_fns()
+        if self.loop_passes > 1:
+            return self._run_passes(params, fns, h, ids, keep_prob, rng,
+                                    train)
         lb_total = jnp.float32(0.0)
         routed = []
         for blk, layer in zip(params["blocks"], self.plan):
@@ -981,6 +1057,109 @@ class TransformerLM:
             return (nn.dropout(h, keep_prob, rng, deterministic=not train),
                     lb_total)
 
+    def _run_passes(self, params, fns, h, ids, keep_prob, rng, train):
+        """The stack run ``loop_passes`` times: pass t takes in what pass
+        t - 1 handed on, the final norm closes every pass, and its output
+        goes to the head, to the exit gate and into the next pass. Returns
+        ((T, B, S, d) the passes' outputs, (T, B, S) f32 the gate's
+        logits).
+
+        The passes are a ``lax.scan`` whose body closes over the ONE set of
+        blocks: the compiled program holds the layers once, and the
+        backward pass sums the T contributions to a shared weight's
+        gradient in one accumulator. Under ``remat`` a block keeps what it
+        keeps (its input and ``REMAT_KEPT``) once a pass, T times a step.
+        ``loop_exit`` holds the gate's unit alone here; the scan's own work
+        (the outputs stacked, the shared gradients' accumulation) is under
+        no scope of the catalog and reads as unscoped."""
+        t_passes, layers = self.loop_passes, len(self.plan)
+        blocks, gate = params["blocks"], params["exit_gate"]
+        if len(blocks) != layers:
+            raise ValueError(f"{len(blocks)} blocks are not the {layers} "
+                             f"that every pass runs")
+
+        def close_pass(h, ln_f, gate):
+            with scope("lm_head"):
+                h = _norm(h, ln_f, "", self.arch)
+            with scope("loop_exit"):
+                # one unit: a multiply and a row sum in f32, no MXU pass
+                logit = jnp.sum(h.astype(jnp.float32)
+                                * gate["w"][:, 0].astype(jnp.float32),
+                                axis=-1) + gate["b"].astype(jnp.float32)
+            return h, logit
+
+        kept = None
+        if self.remat:
+            # else the norm and the gate keep three f32 copies of the
+            # stream a pass (805 MB at 4 x 8,192 rows of 2,048)
+            close_pass = jax.checkpoint(close_pass)
+            # kept a pass: the blocks' inputs, and out + logsumexp
+            rows = h.shape[0] * h.shape[1]
+            kept = layers * rows * h.shape[2] * h.dtype.itemsize
+            if self.attn_block is not None:
+                kept += sum(rows * (x.heads or self.num_heads)
+                            * (self.head_dim * h.dtype.itemsize + 4)
+                            for x in self.plan)
+        telemetry.get_tracer().record_instant(
+            "loop_plan", passes=t_passes, layers=layers, lowered="scan",
+            kept_bytes_per_pass=kept)
+
+        def one_pass(h, _):
+            for blk, layer in zip(blocks, self.plan):
+                h = fns[layer](h, blk, ids)[0]
+            h, logit = close_pass(h, params["ln_f"], gate)
+            return h, (h, logit)
+
+        _, (hs, logits) = lax.scan(one_pass, h, None, length=t_passes)
+        with scope("lm_head"):
+            return (nn.dropout(hs, keep_prob, rng, deterministic=not train),
+                    logits)
+
+    def _looped_loss(self, params, hs, gate_logits, y, train):
+        """The loss of a stack run T times: a token's cross-entropy after
+        every pass (the one head, T x B x S rows), weighted by its exit
+        distribution p(t), less ``loop_exit_beta`` times that
+        distribution's entropy; the mean over tokens. The gradient reaches
+        the gate through p, so the rows' cross-entropies are values it is
+        taken AROUND as well as through. The eval's ``loss`` is the
+        expected cross-entropy without the entropy term; ``accuracy`` is
+        the last pass's. Counters under ``loop_``: the batch's mean p(t),
+        the expected number of passes, the entropy, each pass's mean
+        cross-entropy (a later pass that reads no lower than the first
+        says the loop is not helping)."""
+        labels = jnp.broadcast_to(y, (self.loop_passes,) + y.shape)
+        head = params["head"]
+        if self.ce_block:
+            ce, hit = nn.streamed_softmax_ce_rows(
+                hs, head["w"], head.get("b"), labels, block=self.ce_block,
+                compute_dtype=self.compute_dtype)
+        else:
+            with scope("lm_head"):
+                logits = nn.dense(hs, head["w"], head.get("b"),
+                                  compute_dtype=self.compute_dtype)
+                logp = jax.nn.log_softmax(logits.astype(jnp.float32), -1)
+                onehot = jax.nn.one_hot(labels, logp.shape[-1],
+                                        dtype=logp.dtype)
+                ce = -jnp.sum(jnp.where(onehot != 0, logp, 0.0), axis=-1)
+                hit = (jnp.argmax(logp, -1) == labels).astype(jnp.float32)
+        with scope("loop_exit"):
+            log_p = exit_log_distribution(gate_logits)
+            p = jnp.exp(log_p)
+            expected = jnp.mean(jnp.sum(p * ce, axis=0))
+            entropy = jnp.mean(-jnp.sum(p * log_p, axis=0))
+            loss = expected - self.loop_exit_beta * entropy
+            by_pass = p.reshape(self.loop_passes, -1).mean(axis=1)
+            ce_by_pass = ce.reshape(self.loop_passes, -1).mean(axis=1)
+            metrics = {"loss": loss if train else expected,
+                       "accuracy": jnp.mean(hit[-1]),
+                       "loop_expected_passes": jnp.sum(
+                           by_pass * jnp.arange(1, self.loop_passes + 1)),
+                       "loop_exit_entropy": entropy}
+            for t in range(self.loop_passes):
+                metrics[f"loop_exit_p{t + 1}"] = by_pass[t]
+                metrics[f"loop_ce_{t + 1}"] = ce_by_pass[t]
+        return loss, metrics
+
     def apply(self, params, x, *, keep_prob=1.0, rng=None, train: bool = False):
         h = self.apply_hidden(params, x, keep_prob=keep_prob, rng=rng,
                               train=train)
@@ -995,9 +1174,11 @@ class TransformerLM:
         """True when training/eval must route through
         ``loss_with_metrics`` (training.loss_and_metrics checks this):
         the streamed CE head, the MoE auxiliary loss or counters, the
-        masked-diffusion loss."""
+        masked-diffusion loss, the loss over the passes of a stack run
+        several times."""
         return bool(self.ce_block or self.moe_experts
-                    or self.objective == "masked_diffusion")
+                    or self.objective == "masked_diffusion"
+                    or self.loop_passes > 1)
 
     def loss_with_metrics(self, params, x, y, *, keep_prob=1.0, rng=None,
                           train: bool = False):
@@ -1006,9 +1187,12 @@ class TransformerLM:
         softmax_cross_entropy to fp tolerance, tests/test_lm.py); with
         ``moe_experts`` the TRAINING loss adds ``moe_aux`` times the
         Switch load-balance term (metrics report it either way; eval
-        loss stays the plain CE)."""
+        loss stays the plain CE); with ``loop_passes`` > 1 it is
+        ``_looped_loss``."""
         h, lb = self._hidden_and_aux(params, x, keep_prob=keep_prob,
                                      rng=rng, train=train)
+        if self.loop_passes > 1:
+            return self._looped_loss(params, h, lb, y, train)
         diffusion = self.objective == "masked_diffusion"
         weights = denominator = None
         if diffusion:

@@ -223,6 +223,16 @@ def _streamed_ce_fwd(h2, w, b, labels2, block, cd, n_valid, weights2=None,
     return out, (h2, w, b, labels2, lses, weights2)
 
 
+def _head_block_grads(g, h_blk, w, cd):
+    """One row-block's (dh, dw) from its dL/dlogits ``g`` (f32): both
+    products in ``cd`` where there is one, dh back in h's dtype, dw f32."""
+    if cd is not None:
+        gc = g.astype(cd)
+        return (jnp.dot(gc, w.astype(cd).T).astype(h_blk.dtype),
+                jnp.dot(h_blk.astype(cd).T, gc).astype(jnp.float32))
+    return jnp.dot(g, w.T).astype(h_blk.dtype), jnp.dot(h_blk.T, g)
+
+
 @scoped("lm_head")
 def _streamed_ce_bwd(block, cd, n_valid, denom, res, ct):
     """The streamed backward: recompute each block's logits from
@@ -249,13 +259,8 @@ def _streamed_ce_bwd(block, cd, n_valid, denom, res, ct):
         ok = ((lbl >= 0) & (lbl < logits.shape[-1])).astype(jnp.float32)
         vf = _row_weights(vmask, *wts or (None,))[0]
         g = (p - onehot) * (vf * ok * scale)[:, None]
-        if cd is not None:
-            gc = g.astype(cd)
-            dh_blk = jnp.dot(gc, w.astype(cd).T).astype(h2.dtype)
-            dw = dw + jnp.dot(h_blk.astype(cd).T, gc).astype(jnp.float32)
-        else:
-            dh_blk = jnp.dot(g, w.T).astype(h2.dtype)
-            dw = dw + jnp.dot(h_blk.T, g)
+        dh_blk, dw_blk = _head_block_grads(g, h_blk, w, cd)
+        dw = dw + dw_blk
         if b is not None:
             db = db + jnp.sum(g, axis=0)
         return (dw, db), dh_blk
@@ -276,6 +281,115 @@ def _streamed_ce_bwd(block, cd, n_valid, denom, res, ct):
 
 
 _streamed_ce.defvjp(_streamed_ce_fwd, _streamed_ce_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _streamed_ce_rows(h2, w, b, labels2, block, cd):
+    return _streamed_ce_rows_fwd(h2, w, b, labels2, block, cd)[0]
+
+
+def _streamed_ce_rows_fwd(h2, w, b, labels2, block, cd):
+    """Forward scan over row blocks: ((every row's cross-entropy, every
+    row's hit), residuals). An out-of-range id costs nought, as above."""
+    n, d = h2.shape
+    nb = n // block
+
+    def step(_, inp):
+        h_blk, lbl = inp
+        logits = _head_logits(h_blk, w, b, cd)
+        m = jnp.max(logits, axis=-1)
+        lse = m + jnp.log(jnp.sum(jnp.exp(logits - m[:, None]), axis=-1))
+        onehot = jax.nn.one_hot(lbl, logits.shape[-1], dtype=logits.dtype)
+        lab = jnp.sum(jnp.where(onehot != 0, logits, 0.0), axis=-1)
+        ok = ((lbl >= 0) & (lbl < logits.shape[-1])).astype(jnp.float32)
+        hit = (jnp.argmax(logits, axis=-1) == lbl).astype(jnp.float32)
+        return None, ((lse - lab) * ok, hit, lse)
+
+    _, (ce, hit, lses) = lax.scan(
+        step, None, (h2.reshape(nb, block, d), labels2.reshape(nb, block)))
+    return ((ce.reshape(n), hit.reshape(n)),
+            (h2, w, b, labels2, lses.reshape(n)))
+
+
+@scoped("lm_head")
+def _streamed_ce_rows_bwd(block, cd, res, ct):
+    """``_streamed_ce_bwd`` with a cotangent a ROW (``ct[0]``, what the
+    caller's weighting of the rows hands back) where that one has the
+    loss's one scalar; the hits' cotangent is ignored. Its own scan, so
+    that the scalar form's program stays what it was."""
+    h2, w, b, labels2, lses = res
+    n, d = h2.shape
+    nb = n // block
+
+    def step(carry, inp):
+        dw, db = carry
+        h_blk, lbl, lse_blk, g_rows = inp
+        logits = _head_logits(h_blk, w, b, cd)
+        p = jnp.exp(logits - lse_blk[:, None])
+        onehot = jax.nn.one_hot(lbl, logits.shape[-1], dtype=jnp.float32)
+        ok = ((lbl >= 0) & (lbl < logits.shape[-1])).astype(jnp.float32)
+        g = (p - onehot) * (ok * g_rows.astype(jnp.float32))[:, None]
+        dh_blk, dw_blk = _head_block_grads(g, h_blk, w, cd)
+        dw = dw + dw_blk
+        if b is not None:
+            db = db + jnp.sum(g, axis=0)
+        return (dw, db), dh_blk
+
+    dw0 = jnp.zeros(w.shape, jnp.float32)
+    db0 = None if b is None else jnp.zeros(b.shape, jnp.float32)
+    (dw, db), dhb = lax.scan(
+        step, (dw0, db0),
+        (h2.reshape(nb, block, d), labels2.reshape(nb, block),
+         lses.reshape(nb, block), ct[0].reshape(nb, block)))
+    import numpy as np
+
+    from jax.dtypes import float0
+
+    return (dhb.reshape(n, d), dw.astype(w.dtype),
+            None if b is None else db.astype(b.dtype),
+            np.zeros(labels2.shape, float0))
+
+
+_streamed_ce_rows.defvjp(_streamed_ce_rows_fwd, _streamed_ce_rows_bwd)
+
+
+def _padded_rows(h, labels, block):
+    """(h as (N + pad, d), labels as (N + pad,), N, pad): zero rows of
+    label 0 up to a whole number of blocks."""
+    d = h.shape[-1]
+    n_valid = 1
+    for s in h.shape[:-1]:
+        n_valid *= int(s)
+    if labels.shape != h.shape[:-1]:
+        raise ValueError(f"labels shape {labels.shape} != hidden leading "
+                         f"shape {h.shape[:-1]}")
+    h2 = h.reshape(n_valid, d)
+    labels2 = labels.reshape(n_valid)
+    pad = (-n_valid) % int(block)
+    if pad:
+        # zero rows, label 0, masked out by n_valid inside the op; the
+        # concat/slice transpose drops their gradient automatically
+        h2 = jnp.concatenate([h2, jnp.zeros((pad, d), h2.dtype)])
+        labels2 = jnp.concatenate(
+            [labels2, jnp.zeros((pad,), labels2.dtype)])
+    return h2, labels2, n_valid, pad
+
+
+@scoped("lm_head")
+def streamed_softmax_ce_rows(h, w, b, labels, block: int,
+                             compute_dtype=None):
+    """The streamed head of ``streamed_softmax_ce_head`` with nothing summed:
+    (every row's cross-entropy, every row's hit), both f32 of the labels'
+    shape, differentiable in h, w and b under ANY cotangent a row. For a
+    loss whose weight of a row is itself a function of the parameters (a
+    learned exit distribution over passes: the gradient reaches the
+    weights through the rows' cross-entropies, which the caller holds),
+    and for a caller that wants the rows' losses apart. The (block, V)
+    panel is the peak in both passes, as there."""
+    h2, labels2, n_valid, _ = _padded_rows(h, labels, block)
+    ce, hit = _streamed_ce_rows(h2, w, b, labels2, int(block), compute_dtype)
+    return (ce[:n_valid].reshape(labels.shape),
+            hit[:n_valid].reshape(labels.shape))
 
 
 @scoped("lm_head")
@@ -304,26 +418,14 @@ def streamed_softmax_ce_head(h, w, b, labels, block: int,
     ``denominator`` what the weighted sum is divided by (the masked-
     diffusion loss: a masked position weighs 1/t, the others nought, the
     denominator is the row count whatever the mask); a row of weight
-    nought is left out of the hit count too. The weights carry no
-    gradient. With neither it is the mean over every row.
+    nought is left out of the hit count too. With neither it is the mean
+    over every row. The weights carry no gradient (they are data: the
+    noise's). A caller whose weights depend on the parameters takes the
+    rows' cross-entropies from ``streamed_softmax_ce_rows`` and weights
+    them itself: a weight's cotangent is then its row's cross-entropy.
     Returns (mean loss f32, accuracy f32).
     """
-    d = h.shape[-1]
-    n_valid = 1
-    for s in h.shape[:-1]:
-        n_valid *= int(s)
-    if labels.shape != h.shape[:-1]:
-        raise ValueError(f"labels shape {labels.shape} != hidden leading "
-                         f"shape {h.shape[:-1]}")
-    h2 = h.reshape(n_valid, d)
-    labels2 = labels.reshape(n_valid)
-    pad = (-n_valid) % int(block)
-    if pad:
-        # zero rows, label 0, masked out by n_valid inside the op; the
-        # concat/slice transpose drops their gradient automatically
-        h2 = jnp.concatenate([h2, jnp.zeros((pad, d), h2.dtype)])
-        labels2 = jnp.concatenate(
-            [labels2, jnp.zeros((pad,), labels2.dtype)])
+    h2, labels2, n_valid, pad = _padded_rows(h, labels, block)
     if weights is None and denominator is None:
         return _streamed_ce(h2, w, b, labels2, int(block), compute_dtype,
                             n_valid)

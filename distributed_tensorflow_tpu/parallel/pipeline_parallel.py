@@ -296,6 +296,11 @@ def _pp_step_fn(model, optimizer, mesh, microbatches: int,
         raise ValueError("pipeline parallelism stacks layers of one kind "
                          "into a stage's scan; a model with a layer_plan "
                          "(layers that differ) is not staged yet")
+    if getattr(model, "loop_passes", 1) > 1:
+        raise ValueError("pipeline parallelism sends a microbatch through "
+                         "the stages once; a model of loop_passes > 1 runs "
+                         "its stack several times (the last stage would "
+                         "feed the first) and is not staged yet")
     if getattr(model, "moe_experts", 0):
         raise ValueError("pipeline parallelism is not wired for MoE "
                          "blocks (the stage scan runs the dense block "

@@ -222,6 +222,10 @@ def shard_attention(model, mesh: Mesh):
         raise ValueError("tensor parallelism splits one head count over "
                          "the model axis; a model with a layer_plan (head "
                          "counts and masks a layer) is not split yet")
+    if getattr(model, "loop_passes", 1) > 1:
+        raise ValueError("tensor parallelism's rules shard a stack that is "
+                         "walked once; a model of loop_passes > 1 (a scan "
+                         "over passes, an exit gate) is not split yet")
     if getattr(model, "attn_block", None) is None:
         return model
     spec = P(DATA_AXIS, None, MODEL_AXIS, None)

@@ -66,6 +66,11 @@ def check_decodable(model) -> None:
                          "seq_axis=None")
     if model.moe_experts:
         raise ValueError("KV-cache decode does not support MoE blocks yet")
+    if getattr(model, "loop_passes", 1) > 1:
+        raise ValueError("KV-cache decode holds one set of keys and values "
+                         "a layer; a layer run loop_passes times holds "
+                         "that many (and a token may leave after fewer "
+                         "passes): such a model is not served yet")
     if getattr(model, "layer_plan", ""):
         raise ValueError("KV-cache decode runs one kind of layer under one "
                          "cache layout; a model with a layer_plan (window "

@@ -146,7 +146,8 @@ def _lm_arch_kwargs(FLAGS) -> dict:
              "diffusion_block", "diffusion_t_min", "layer_plan",
              "attn_window", "window_rope_theta", "rope_fraction",
              "rope_yarn", "attn_gate", "moe_shared_dim", "moe_scoring",
-             "moe_scale")
+             "moe_scale", "mlp_dim", "sandwich_norm", "loop_passes",
+             "loop_exit_beta")
     out = {n: getattr(FLAGS, n) for n in names if hasattr(FLAGS, n)}
     out["noise_seed"] = int(FLAGS.seed)
     return out
@@ -265,7 +266,7 @@ def _display_log(step, display, logger, scalars, eff, snt,
             {k: v for k, v in display.items()
              if k.startswith(("moe_rows", "moe_overflow", "moe_unrouted",
                               "moe_buffer", "moe_tiles", "moe_dispatch",
-                              "diffusion_"))})
+                              "diffusion_", "loop_"))})
         logger.scalars(step, scalars())
         logger.flush()
         telemetry.get_tracer().flush()

@@ -394,3 +394,77 @@ def test_the_planned_cells_step_compiles_with_both_masks_fused(one_chip):
     assert 0.94 * recorded["temp"] < ma.temp_size_in_bytes <= recorded["temp"]
     assert cell.config["bytes"]["parameters"] == model.num_params() \
         == cell.family().total_params(cell.sizes)
+
+
+def test_the_looped_cells_step_holds_one_accumulator_of_the_shared_gradients(
+        one_chip):
+    """``ouro-2.6b.train-b2-s4096``, built from its configuration's own file
+    through its family's flags: the whole step (2 x 4,096 tokens, eight
+    layers at the published widths run four times, remat, adam on f32
+    masters) for the described chip. The passes are ONE loop of four trips
+    forward and one backward, each holding the eight layers once: the
+    backward loop carries one f32 accumulator a shared matrix (not four),
+    the attention kernels are in the loops' bodies once a layer and the
+    remat pass runs none; and ``memory_analysis()`` holds the arguments the
+    configuration's ``bytes`` records and no more temporaries than it
+    records."""
+    from benchmark.harness import manifest
+    from distributed_tensorflow_tpu.data.device_data import DeviceData
+    from distributed_tensorflow_tpu.utils import telemetry
+
+    cell = manifest.load_cell("ouro-2.6b.train-b2-s4096")
+    trainer = cell.config["trainer"]
+    seq, batch = cell.mix["seq_len"], cell.mix["batch_per_chip"]
+    model = TransformerLM(
+        seq_len=seq, compute_dtype=jnp.bfloat16,
+        attn_block=trainer["attn_block"], ce_block=trainer["ce_block"],
+        remat=trainer["remat"], loop_exit_beta=trainer["loop_exit_beta"],
+        **cell.family().trainer_flags(cell.config, cell.mix))
+    opt = adam(trainer["learning_rate"])
+    state = jax.eval_shape(lambda: create_train_state(model, opt, seed=0))
+    data = DeviceData(jax.ShapeDtypeStruct((4096, seq), jnp.uint16),
+                      jax.ShapeDtypeStruct((4096, seq), jnp.uint16))
+    step = make_device_train_step(model, opt, batch, keep_prob=1.0, chunk=1)
+    telemetry.get_tracer().clear()
+    compiled = step.lower(*_on(one_chip, (state, data))).compile()
+    notes = {r["name"]: r for r in telemetry.last_spans(200)}
+    plan = notes["loop_plan"]
+    assert (plan["passes"], plan["layers"], plan["lowered"]) == (4, 8, "scan")
+    # a pass keeps 8 x (the block's input + out + logsumexp): 541 MB
+    assert plan["kept_bytes_per_pass"] == 8 * (
+        2 * 4096 * 2048 * 2 + notes["remat_saved"]["bytes_per_block"]) \
+        == 541_065_216
+    assert (notes["attention_path"]["tiles_run"],
+            notes["attention_path"]["grid_steps"]) == (36, 64)
+    hlo = compiled.as_text()
+    kernels = {}
+    for p in re.findall(
+            r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', hlo):
+        assert "/attention" in p and "loop_exit" not in p
+        key = (re.search(r"flash_attention_\w+", p).group(0),
+               "rematted_computation" in p)
+        kernels[key] = kernels.get(key, 0) + 1
+    assert kernels == {("flash_attention_fwd", False): 8,
+                       ("flash_attention_bwd", False): 8}
+    entry = hlo[hlo.index("ENTRY"):]
+    loops = {re.search(r'op_name="([^"]*)"', line).group(1).rsplit(
+        "closed_call/", 1)[1]: re.match(
+            r"\s*%[\w.-]+ = (\(.*?\)) while\(", line).group(1)
+        for line in entry.splitlines()
+        if " while(" in line and "lm_head" not in line}
+    # the scan over passes is under no scope of the catalog
+    assert set(loops) == {"jvp()/while", "transpose(jvp())/while"}
+    forward, backward = loops["jvp()/while"], loops["transpose(jvp())/while"]
+    for shape in ("2048,11264", "5632,2048", "2048,2048", "2048,3,16,128"):
+        assert len(re.findall(rf"f32\[{shape}\]", backward)) == 8, shape
+        assert not re.findall(rf"f32\[{shape}\]", forward)
+    # the passes' outputs stacked for the head: one (T, B, S, d) of bf16
+    assert "bf16[4,2,4096,2048]" in forward
+    assert "f32[4,2,4096,2048]" not in forward
+    ma = compiled.memory_analysis()
+    recorded = cell.config["bytes"]["compiled_step_for_described_v5e"]
+    assert ma.argument_size_in_bytes == recorded["arguments"]
+    assert 0.97 * recorded["temp"] < ma.temp_size_in_bytes <= recorded["temp"]
+    assert _device_bytes(compiled) < 0.9 * V5E_HBM_BYTES
+    assert cell.config["bytes"]["parameters"] == model.num_params() \
+        == cell.family().total_params(cell.sizes)

@@ -5,7 +5,10 @@ blockwise attention, with the dense MLP (``mlp``) or the dropless routed
 layer in its place (``moe_router``, ``moe_experts``, under the
 masked-diffusion objective), or with a plan of layers that differ (a dense
 full-attention layer and a routed window layer with a shared expert:
-``attention_window`` and ``moe_shared`` beside all the others); a scope
+``attention_window`` and ``moe_shared`` beside all the others), or with the
+stack run three times over shared weights (``loop_exit``: the exit gate and
+the weighting of the passes' losses, never around a block: the scan over
+passes itself is under no scope); a scope
 changes metadata only, so the step's outputs
 are bit-equal with ``jax.named_scope`` patched to a no-op; and the program
 opens no scope that the catalog does not hold."""
@@ -35,9 +38,11 @@ from distributed_tensorflow_tpu.utils.telemetry import SCOPES
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROUTED = {"moe_router", "moe_experts"}
 PLANNED = {"attention_window", "moe_shared"}
+LOOPED = {"loop_exit"}
 FORMS = [(remat, block, False) for remat in (False, True)
          for block in (None, 16)] + [(False, 16, True), (True, 16, True),
-                                     (False, None, "plan"), (True, 16, "plan")]
+                                     (False, None, "plan"), (True, 16, "plan"),
+                                     (False, None, "loop"), (True, 16, "loop")]
 
 
 def scopes_of(routed) -> set:
@@ -46,13 +51,19 @@ def scopes_of(routed) -> set:
     each, which also has the window layers' attention and the shared
     expert."""
     if routed == "plan":
-        return set(SCOPES)
-    return set(SCOPES) - PLANNED - ({"mlp"} if routed else ROUTED)
+        return set(SCOPES) - LOOPED
+    if routed == "loop":
+        return set(SCOPES) - PLANNED - ROUTED
+    return set(SCOPES) - PLANNED - LOOPED - ({"mlp"} if routed else ROUTED)
 
 
 def build(remat, attn_block, routed=False):
     kw = {}
-    if routed:
+    if routed == "loop":
+        kw = dict(norm="rmsnorm", rope_theta=1e4, mlp_gated=True, mlp_dim=48,
+                  biases=False, sandwich_norm=True, loop_passes=3,
+                  loop_exit_beta=0.05)
+    elif routed:
         kw = dict(norm="rmsnorm", rope_theta=1e4, num_kv_heads=1, head_dim=16,
                   qk_norm=True, mlp_gated=True, biases=False, moe_experts=8,
                   moe_top_k=2, moe_ffn_dim=32, moe_held_experts=4,
@@ -94,7 +105,14 @@ def test_every_scope_of_the_catalog_is_in_the_compiled_step(remat,
     if routed == "plan":  # beside attention, never inside it
         assert not any({"attention", "attention_window"} <= scopes_in(p)
                        for p in paths)
-    if routed:  # the grouped products' backward carries its name too
+    if routed == "loop":
+        # the gate and the weighting forward and backward; the scan over
+        # passes and the blocks inside it are not under it
+        assert any("transpose(" in p and "loop_exit" in scopes_in(p)
+                   for p in paths)
+        assert all(scopes_in(p) == {"loop_exit"} for p in paths
+                   if "loop_exit" in scopes_in(p))
+    elif routed:  # the grouped products' backward carries its name too
         assert any("transpose(" in p and "moe_experts" in scopes_in(p)
                    for p in paths)
     # the backward pass carries the names too, through jvp and transpose
