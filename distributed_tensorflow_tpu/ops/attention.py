@@ -56,6 +56,8 @@ from distributed_tensorflow_tpu.utils.profiling import (
 # and no inf - inf can arise (the causal scan keeps -inf: its first block
 # shows every row key 0)
 MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
+# the flags of a step of ``Mask.live_tiles``
+MASKED, FIRST, LAST = 1, 2, 4
 
 
 @dataclasses.dataclass(frozen=True)
@@ -63,17 +65,16 @@ class Mask:
     """Which (query, key) pairs attend, as a small static description from
     which every form derives what it needs: the dense mask
     (``allowed`` on index arrays), the scan's per-block mask (the same),
-    and for the fused kernels the three-way test of a tile (``tile``: runs
-    unmasked, masked, or not at all) and the index maps that keep a
-    skipped tile from moving bytes (``next_key_tile`` / ``next_query_tile``).
+    and for the fused kernels the mask inside a tile (the same again) and
+    their schedule (``live_tiles``: the tiles that run, in the order they
+    fold, each marked masked or not; made on the host from ``tile``, the
+    three-way test of a tile: every pair attends, some pair, none). The
+    kernels' grids are as long as that list, whatever the kind.
 
     - ``Mask("causal")``: key j <= query i.
     - ``Mask("window", window=W)``: the causal window, query i sees the W
       keys ``i - W < j <= i``. A query tile reaches ``window // tk + 1`` key
-      tiles or so, whatever the sequence's length, so the kernels' grids
-      are BANDED for it (``key_steps`` / ``query_steps`` count the band,
-      ``key_tile`` / ``query_tile`` place a step of it) where the other
-      kinds walk every tile and skip.
+      tiles or so, whatever the sequence's length.
     - ``Mask("block_diffusion", half=S, block=L)``: the sequence is
       ``[noised ; clean]``, 2 S rows whose position is ``i mod S`` and whose
       diffusion block is ``position // L``. Noised rows see the noised rows
@@ -130,35 +131,32 @@ class Mask:
 
     def tile(self, q0, q1, k0, k1):
         """(visible, runs) of the tile of queries q0..q1 and keys k0..k1
-        (inclusive; scalars, traced or not): every pair attends / some pair
+        (inclusive; host integers or arrays): every pair attends / some pair
         does. A block-diffusion tile lies within one half on each side
-        (``tiles_fit``). Host integers and arrays are answered on the host
-        (``tiles_run`` asks while a pass is traced)."""
-        xp = np if all(isinstance(x, (int, np.integer, np.ndarray))
-                       for x in (q0, q1, k0, k1)) else jnp
+        (``tiles_fit``)."""
         if self.kind == "causal":
             return k1 <= q0, k0 <= q1
         if self.kind == "window":
             w = self.window
-            return (xp.logical_and(k1 <= q0, k0 > q1 - w),
-                    xp.logical_and(k0 <= q1, k1 > q0 - w))
+            return (np.logical_and(k1 <= q0, k0 > q1 - w),
+                    np.logical_and(k0 <= q1, k1 > q0 - w))
         s, lb = self.half, self.block
         q_clean, k_clean = q0 >= s, k0 >= s
-        bq0 = xp.where(q_clean, q0 - s, q0) // lb
-        bq1 = xp.where(q_clean, q1 - s, q1) // lb
-        bk0 = xp.where(k_clean, k0 - s, k0) // lb
-        bk1 = xp.where(k_clean, k1 - s, k1) // lb
-        nn = xp.logical_not(xp.logical_or(q_clean, k_clean))
+        bq0 = np.where(q_clean, q0 - s, q0) // lb
+        bq1 = np.where(q_clean, q1 - s, q1) // lb
+        bk0 = np.where(k_clean, k0 - s, k0) // lb
+        bk1 = np.where(k_clean, k1 - s, k1) // lb
+        nn = np.logical_not(np.logical_or(q_clean, k_clean))
         same = q_clean == k_clean
-        runs = xp.where(nn, xp.logical_and(bk0 <= bq1, bq0 <= bk1),
-                        xp.where(same, bk0 <= bq1, bk0 < bq1))
-        visible = xp.where(
-            nn, xp.logical_and(xp.logical_and(bq0 == bq1, bk0 == bk1),
+        runs = np.where(nn, np.logical_and(bk0 <= bq1, bq0 <= bk1),
+                        np.where(same, bk0 <= bq1, bk0 < bq1))
+        visible = np.where(
+            nn, np.logical_and(np.logical_and(bq0 == bq1, bk0 == bk1),
                                bq0 == bk0),
-            xp.where(same, bk1 <= bq0, bk1 < bq0))
-        never = xp.logical_and(q_clean, xp.logical_not(k_clean))
-        return (xp.logical_and(visible, xp.logical_not(never)),
-                xp.logical_and(runs, xp.logical_not(never)))
+            np.where(same, bk1 <= bq0, bk1 < bq0))
+        never = np.logical_and(q_clean, np.logical_not(k_clean))
+        return (np.logical_and(visible, np.logical_not(never)),
+                np.logical_and(runs, np.logical_not(never)))
 
     def tiles_fit(self, seq_len: int, tq: int, tk: int) -> bool:
         """Whether tiles of tq queries and tk keys suit the kernels."""
@@ -168,124 +166,46 @@ class Mask:
                 and self.half % tk == 0
                 and self.block & (self.block - 1) == 0)
 
-    def next_key_tile(self, i, j, tq, tk):
-        """The key tile to hold at grid step (query tile i, key tile j): j
-        where the tile runs, else the next one that does, else the last
-        that did, so that a skipped step fetches nothing of its own."""
-        if self.kind == "causal":
-            return jnp.minimum(j, ((i + 1) * tq - 1) // tk)
-        if self.kind == "window":
-            return jnp.clip(j, self._first_key_tile(i, tq, tk),
-                            ((i + 1) * tq - 1) // tk)
-        s, lb = self.half, self.block
-        nk = s // tk
-        q0, q1 = i * tq, (i + 1) * tq - 1
-        q_clean = q0 >= s
-        b0 = jnp.where(q_clean, q0 - s, q0) // lb
-        b1 = jnp.where(q_clean, q1 - s, q1) // lb
-        noised = (b0 * lb // tk, ((b1 + 1) * lb - 1) // tk,
-                  jnp.logical_not(q_clean))
-        last_block = jnp.where(q_clean, b1, b1 - 1)  # clean keys' last block
-        clean = (nk, nk + ((last_block + 1) * lb - 1) // tk, last_block >= 0)
-        return _next_in_ranges(j, (noised, clean))
-
-    def next_query_tile(self, j, i, tq, tk, n_tiles=None):
-        """The query tile to hold at grid step (key tile j, query tile i)
-        of the backward kernel: as ``next_key_tile`` (``n_tiles``: how many
-        query tiles there are, which only the window has to be told)."""
-        if self.kind == "causal":
-            return jnp.maximum(i, (j * tk) // tq)
-        if self.kind == "window":
-            return jnp.clip(i, (j * tk) // tq,
-                            jnp.minimum(self._last_query_tile(j, tq, tk),
-                                        n_tiles - 1))
-        s, lb = self.half, self.block
-        nq = s // tq
-        k0, k1 = j * tk, (j + 1) * tk - 1
-        k_clean = k0 >= s
-        b0 = jnp.where(k_clean, k0 - s, k0) // lb
-        b1 = jnp.where(k_clean, k1 - s, k1) // lb
-        # noised queries: of these blocks (noised keys), of later blocks
-        # (clean keys); clean queries: of these and later blocks
-        first = jnp.where(k_clean, (b0 + 1) * lb, b0 * lb)
-        noised = (first // tq,
-                  jnp.where(k_clean, nq - 1, ((b1 + 1) * lb - 1) // tq),
-                  first < s)
-        clean = (nq + b0 * lb // tq, 2 * nq - 1, k_clean)
-        return _next_in_ranges(i, (noised, clean))
-
-
-    # ---- the kernels' grids: every tile (the index maps skip), or a band
-
-    @property
-    def banded(self) -> bool:
-        """Whether the kernels' grids walk a band of tiles and not all."""
-        return self.kind == "window"
-
-    def _first_key_tile(self, i, tq, tk):
-        """Window: the tile of the first key query tile i sees."""
-        return jnp.maximum(i * tq - self.window + 1, 0) // tk
-
-    def _last_query_tile(self, j, tq, tk):
-        """Window: the tile of the last query that sees key tile j (it may
-        lie past the sequence's end)."""
-        return ((j + 1) * tk + self.window - 2) // tq
-
-    def key_steps(self, seq_len: int, tq: int, tk: int) -> int:
-        """Steps of the forward grid's key dimension: every key tile, of
-        which the index map skips those that do not run; under a window the
-        most key tiles one query tile reaches."""
-        if not self.banded:
-            return seq_len // tk
-        return max(((i + 1) * tq - 1) // tk
-                   - max(i * tq - self.window + 1, 0) // tk + 1
-                   for i in range(seq_len // tq))
-
-    def key_tile(self, i, step, steps: int, tq: int, tk: int):
-        """The key tile of grid step ``step`` of query tile i. Under a
-        window the band ENDS at the tile of the diagonal, so an early query
-        tile's first steps fall before key 0 (a negative tile: the kernel
-        runs nothing there, ``next_key_tile`` holds the first)."""
-        if not self.banded:
-            return step
-        return ((i + 1) * tq - 1) // tk - (steps - 1) + step
-
-    def query_steps(self, seq_len: int, tq: int, tk: int) -> int:
-        """As ``key_steps``, of the backward grid's query dimension."""
-        if not self.banded:
-            return seq_len // tq
-        nq = seq_len // tq
-        return max(min(self._last_query_tile(j, tq, tk), nq - 1)
-                   - (j * tk) // tq + 1 for j in range(seq_len // tk))
-
-    def query_tile(self, j, step, tq: int, tk: int):
-        """The query tile of grid step ``step`` of key tile j. Under a
-        window the band STARTS at the tile of the diagonal, so a late key
-        tile's last steps fall past the sequence's end."""
-        if not self.banded:
-            return step
-        return (j * tk) // tq + step
+    def live_tiles(self, seq_len: int, tq: int, tk: int,
+                   key_major: bool = False) -> np.ndarray:
+        """The fused kernels' schedule, made on the host: the (query tile,
+        key tile) pairs of one head that run, as an int32 (3, n) table of
+        query tile, key tile and flags, one column a grid step. Query tile
+        major with key tiles ascending (the forward's fold order), or
+        ``key_major`` with query tiles ascending (the backward's). The
+        flags: ``MASKED`` where only some pairs of the tile attend (it folds
+        under the mask inside the tile), ``FIRST`` / ``LAST`` on the first
+        and the last step of a row (of a column, ``key_major``), where the
+        kernels reset and write what they accumulate."""
+        visible, runs = self._tiles(seq_len, tq, tk)
+        if key_major:
+            visible, runs = visible.T, runs.T
+        outer, inner = np.nonzero(runs)  # row major: both ascending
+        if not np.array_equal(np.unique(outer), np.arange(runs.shape[0])):
+            # its result would never be written
+            raise ValueError(f"{self} leaves a tile row or column of "
+                             f"({seq_len}, {tq}, {tk}) with nothing to run")
+        edge = np.flatnonzero(np.diff(outer)) + 1
+        flags = np.where(visible[outer, inner], 0, MASKED)
+        flags[np.r_[0, edge]] |= FIRST
+        flags[np.r_[edge - 1, -1]] |= LAST
+        qi, kj = (inner, outer) if key_major else (outer, inner)
+        return np.stack([qi, kj, flags]).astype(np.int32)
 
     def tiles_run(self, seq_len: int, tq: int, tk: int) -> int:
         """How many (query tile, key tile) pairs run, of one head."""
+        return int(np.sum(self._tiles(seq_len, tq, tk)[1]))
+
+    def _tiles(self, seq_len, tq, tk):
+        """``tile`` of every (query tile, key tile) of one head: two
+        (S / tq, S / tk) boolean arrays."""
         q0 = np.arange(0, seq_len, tq)[:, None]
         k0 = np.arange(0, seq_len, tk)[None, :]
-        return int(np.sum(self.tile(q0, q0 + tq - 1, k0, k0 + tk - 1)[1]))
+        return tuple(np.broadcast_to(x, (q0.size, k0.size)) for x in
+                     self.tile(q0, q0 + tq - 1, k0, k0 + tk - 1))
 
 
 CAUSAL = Mask("causal")
-
-
-def _next_in_ranges(x, ranges):
-    """``x`` if it lies in one of the ascending inclusive ``(lo, hi,
-    nonempty)`` ranges, else the start of the next one, else the end of
-    the last."""
-    out = None
-    for lo, hi, ok in ranges:  # the end of the last range that is there
-        out = hi if out is None else jnp.where(ok, hi, out)
-    for lo, hi, ok in reversed(ranges):
-        out = jnp.where(jnp.logical_and(ok, x <= hi), jnp.maximum(x, lo), out)
-    return out
 
 
 def _as_mask(causal, mask):
@@ -498,14 +418,15 @@ def _pick(pass_name, scan, q, k, v, block_size, mask, *rest):
 
     def run_fused(q, *xs):
         tq = flash_attention.query_tile(s, mask)
-        steps = (s // tq) * mask.key_steps(s, tq, block_size) \
-            if pass_name == "forward" \
-            else (s // block_size) * mask.query_steps(s, tq, block_size)
+        flags = mask.live_tiles(s, tq, block_size, pass_name == "backward")[2]
         # the tiles that run over the steps of the lowered pass's grid, a
-        # head: what is left between them is skipped steps
+        # head (the grid walks the live tiles only), and how many of them
+        # pay for the mask inside the tile
         q = lowering_instant("attention_path", q, path="fused", q_tile=tq,
                              tiles_run=mask.tiles_run(s, tq, block_size),
-                             grid_steps=steps, **note)
+                             grid_steps=flags.size,
+                             masked_tiles=int(np.count_nonzero(flags & MASKED)),
+                             **note)
         if mask.kind == "causal":  # today's call, and so today's trace
             return fused(q, *xs, block_size)
         return fused(q, *xs, block_size, mask)

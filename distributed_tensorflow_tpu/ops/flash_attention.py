@@ -8,14 +8,16 @@ logsumexp a row and the three gradients cross HBM.
 
 The mask is a static description (``ops/attention.py:Mask``: causal, the
 causal window, or block diffusion over a doubled sequence) that gives the
-kernels the three-way test of a tile (it runs unmasked, masked, or not at
-all), the mask inside a tile, the index maps that make a skipped tile move
-no bytes, and the grid's inner dimension: every tile of the other side
-(causal and block diffusion: the maps skip), or under a window the BAND of
-tiles one tile reaches (``window // tile + 1`` or so: at S = 8,192 with
-512-wide tiles 2 steps a query tile and not 16, of which 31 of 32 run; a
-skipped step costs about 0.35 us, PERF.md section 6, and 225 of them a
-head would cost more than the 31 tiles that run). Key/value heads may be fewer than query heads: the k/v index map
+kernels the mask inside a tile and their SCHEDULE: ``Mask.live_tiles``, a
+small int32 table made on the host of the (query tile, key tile) pairs
+that run, in the order they fold, each flagged masked or not and first /
+last of its row (forward) or column (backward). The table goes in by scalar
+prefetch; the grid is (heads * batch, live tiles): the index maps read a
+step's tiles from it, the body its flags, and no step runs nothing, whatever
+the mask (causal at S = 8,192 with 512-wide tiles: 136 steps a head and
+not 256; block diffusion: 80; a window of 512: 31. A step that ran nothing
+cost 0.35 us in the forward, PERF.md section 6). Key/value heads may be
+fewer than query heads: the k/v index map
 sends a group's query heads to its one key/value head, and the backward
 writes dk, dv a query head, summed over the group outside the kernel.
 
@@ -31,19 +33,19 @@ writes dk, dv a query head, summed over the group outside the kernel.
   ``D = sum(do * o)`` — is one row of f32 a query tile that broadcasts
   along sublanes, its reductions run down the sublanes on the VPU, and no
   panel is ever transposed.
-- **Forward** (``flash_forward``): grid (heads * batch, query tiles, key
-  tiles), the key axis innermost. A query tile visits only the key tiles
-  at or under its diagonal (the index map of k and v stops at the last one
-  it needs, so a skipped step moves no bytes); QK^T and P.V run on the MXU
-  with bf16 operands and f32 accumulation; the running maximum,
-  denominator and numerator are f32 VMEM scratch; the mask is applied only
-  in tiles the diagonal crosses.
-- **Backward** (``flash_backward``): one kernel, grid (heads * batch, key
-  tiles, query tiles), the query axis innermost. ``p`` is recomputed from
-  the logsumexp; dk and dv accumulate in f32 scratch over a key tile's
-  query tiles; dq accumulates in an f32 scratch that holds the whole
-  sequence of one (batch, head) and is written once, so no partial sums go
-  to HBM and every matmul of the flash backward runs once.
+- **Forward** (``flash_forward``): the table query tile major, a row's key
+  tiles ascending. QK^T and P.V run on the MXU with bf16 operands and f32
+  accumulation; the running maximum, denominator and numerator are f32
+  VMEM scratch, reset at a row's first step and written out (``out``,
+  the logsumexp) at its last; the mask is applied only in the tiles
+  flagged masked (the diagonal crosses them).
+- **Backward** (``flash_backward``): one kernel, the table key tile major,
+  a column's query tiles ascending. ``p`` is recomputed from the
+  logsumexp; dk and dv accumulate in f32 scratch over a key tile's query
+  tiles (reset at the column's first step, written at its last); dq
+  accumulates in an f32 scratch that holds the whole sequence of one
+  (batch, head) and is written once, so no partial sums go to HBM and
+  every matmul of the flash backward runs once.
 """
 
 from __future__ import annotations
@@ -56,6 +58,13 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from distributed_tensorflow_tpu.ops.attention import (
+    CAUSAL,
+    FIRST,
+    LAST,
+    MASKED,
+)
 
 LANES = 128
 # largest query tile taken from the shapes: the chip sweep's choice
@@ -111,30 +120,20 @@ def _scores(kt, qt, scale, i, j, tq, tk, masked, mask):
     return st, qt, (None if exact else scale)
 
 
-def _when_tile_runs(i, j, tq, tk, mask, fold, inside=None):
-    """``fold(masked)`` for tile (query tile i, key tile j): unmasked
-    where every pair attends (causal: its last key <= its first query),
-    masked where only some do (the diagonal crosses it), not at all where
-    none does (its first key > its last query), nor where a banded grid's
-    step lies outside the sequence (``inside`` false)."""
-    visible, runs = mask.tile(i * tq, (i + 1) * tq - 1,
-                              j * tk, (j + 1) * tk - 1)
-    if inside is not None:
-        visible = jnp.logical_and(visible, inside)
-        runs = jnp.logical_and(runs, inside)
-    pl.when(visible)(lambda: fold(False))
-    pl.when(jnp.logical_and(runs, jnp.logical_not(visible)))(
-        lambda: fold(True))
+def _fold_live(flags, fold):
+    """``fold(masked)`` for the step's tile: every step of the grid is a
+    tile that runs, under the mask where only some of its pairs attend
+    (the diagonal crosses it), else unmasked."""
+    pl.when(flags & MASKED == 0)(lambda: fold(False))
+    pl.when(flags & MASKED != 0)(lambda: fold(True))
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
-                scale, tq, tk, mask, steps):
-    i, step = pl.program_id(1), pl.program_id(2)
-    # the key tile of this step: the step itself, or its place in the band
-    j = mask.key_tile(i, step, steps, tq, tk)
-    inside = j >= 0 if mask.banded else None
+def _fwd_kernel(tiles_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc,
+                acc_sc, *, scale, tq, tk, mask):
+    n = pl.program_id(1)
+    i, j, flags = tiles_ref[0, n], tiles_ref[1, n], tiles_ref[2, n]
 
-    @pl.when(step == 0)
+    @pl.when(flags & FIRST != 0)
     def _():
         m_sc[...] = jnp.full_like(m_sc, -jnp.inf)
         l_sc[...] = jnp.zeros_like(l_sc)
@@ -152,9 +151,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
         acc_sc[...] = alpha * acc_sc[...] + _dot(vt, pt.astype(vt.dtype))
         m_sc[...] = m_next
 
-    _when_tile_runs(i, j, tq, tk, mask, fold, inside)
+    _fold_live(flags, fold)
 
-    @pl.when(step == pl.num_programs(2) - 1)
+    @pl.when(flags & LAST != 0)
     def _():
         l = l_sc[...]
         o_ref[...] = (acc_sc[...] / l).astype(o_ref.dtype)
@@ -182,12 +181,6 @@ def _kv_head(g, batch: int, group: int):
     return (g // batch) // group * batch + g % batch
 
 
-def _causal():
-    from distributed_tensorflow_tpu.ops.attention import CAUSAL
-
-    return CAUSAL
-
-
 # jitted: a model's layers share one trace and one lowering of each kernel
 @functools.partial(jax.jit, static_argnames=("block_size", "mask"))
 def flash_forward(q, k, v, block_size: int, mask=None):
@@ -195,56 +188,52 @@ def flash_forward(q, k, v, block_size: int, mask=None):
     None). q: (B, S, H, Dh) bf16, k and v: (B, S, Hkv, Dh); out like q;
     lse (B, H, S) f32, the logsumexp of each row's scores."""
     b, s, h, dh = q.shape
-    mask = _causal() if mask is None else mask
+    mask = CAUSAL if mask is None else mask
     group = h // k.shape[2]
     tk = block_size
     tq = query_tile(s, mask)
+    tiles = mask.live_tiles(s, tq, tk)
 
-    steps = mask.key_steps(s, tq, tk)
+    def q_map(g, n, tiles):
+        return g, 0, tiles[0, n]
 
-    def kv_map(g, i, step):
-        # stop at the query tile's last key tile (causal), hold the next
-        # tile that runs: a repeated block index is not fetched again, so
-        # the skipped steps move nothing
-        j = mask.key_tile(i, step, steps, tq, tk)
-        return _kv_head(g, b, group), 0, mask.next_key_tile(i, j, tq, tk)
+    def kv_map(g, n, tiles):
+        return _kv_head(g, b, group), 0, tiles[1, n]
 
     row = pltpu.VMEM((1, tq), jnp.float32)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=dh ** -0.5, tq=tq, tk=tk,
-                          mask=mask, steps=steps),
-        grid=(h * b, s // tq, steps),
-        in_specs=[pl.BlockSpec((None, dh, tq), lambda g, i, j: (g, 0, i)),
-                  pl.BlockSpec((None, dh, tk), kv_map),
-                  pl.BlockSpec((None, dh, tk), kv_map)],
-        out_specs=[pl.BlockSpec((None, dh, tq), lambda g, i, j: (g, 0, i)),
-                   pl.BlockSpec((None, 1, tq), lambda g, i, j: (g, 0, i))],
+                          mask=mask),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(h * b, tiles.shape[1]),
+            in_specs=[pl.BlockSpec((None, dh, tq), q_map),
+                      pl.BlockSpec((None, dh, tk), kv_map),
+                      pl.BlockSpec((None, dh, tk), kv_map)],
+            out_specs=[pl.BlockSpec((None, dh, tq), q_map),
+                       pl.BlockSpec((None, 1, tq), q_map)],
+            scratch_shapes=[row, row, pltpu.VMEM((dh, tq), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct((h * b, dh, s), q.dtype),
                    jax.ShapeDtypeStruct((h * b, 1, s), jnp.float32)],
-        scratch_shapes=[row, row, pltpu.VMEM((dh, tq), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         name="flash_attention_fwd",
-    )(_heads_first(q), _heads_first(k), _heads_first(v))
+    )(tiles, _heads_first(q), _heads_first(k), _heads_first(v))
     return (_heads_last(out, q),
             jnp.einsum("hbs->bhs", lse.reshape(h, b, s)))
 
 
-def _bwd_kernel(q_ref, do_ref, lse_ref, d_ref, k_ref, v_ref,
+def _bwd_kernel(tiles_ref, q_ref, do_ref, lse_ref, d_ref, k_ref, v_ref,
                 dq_ref, dk_ref, dv_ref, dq_sc, dk_sc, dv_sc, *,
                 scale, tq, tk, mask):
-    j, step = pl.program_id(1), pl.program_id(2)
-    last_step = pl.num_programs(2) - 1
-    # the query tile of this step: the step itself, or its place in the band
-    i = mask.query_tile(j, step, tq, tk)
-    inside = i < dq_sc.shape[0] if mask.banded else None
+    n = pl.program_id(1)
+    i, j, flags = tiles_ref[0, n], tiles_ref[1, n], tiles_ref[2, n]
 
-    @pl.when(jnp.logical_and(j == 0, step == 0))
+    @pl.when(n == 0)
     def _():
         dq_sc[...] = jnp.zeros_like(dq_sc)
 
-    @pl.when(step == 0)
+    @pl.when(flags & FIRST != 0)
     def _():
         dk_sc[...] = jnp.zeros_like(dk_sc)
         dv_sc[...] = jnp.zeros_like(dv_sc)
@@ -261,18 +250,17 @@ def _bwd_kernel(q_ref, do_ref, lse_ref, d_ref, k_ref, v_ref,
         dk_sc[...] += dk if owed is None else dk * owed
         dq_sc[i] += _dot(kt, dst)
 
-    _when_tile_runs(i, j, tq, tk, mask, fold, inside)
+    _fold_live(flags, fold)
 
-    @pl.when(step == last_step)
+    @pl.when(flags & LAST != 0)
     def _():
         dk_ref[...] = dk_sc[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_sc[...].astype(dv_ref.dtype)
 
-    @pl.when(jnp.logical_and(j == pl.num_programs(1) - 1,
-                             step == last_step))
+    @pl.when(n == pl.num_programs(1) - 1)
     def _():
-        for n in range(dq_sc.shape[0]):
-            dq_ref[:, n * tq:(n + 1) * tq] = (dq_sc[n] * scale).astype(
+        for t in range(dq_sc.shape[0]):
+            dq_ref[:, t * tq:(t + 1) * tq] = (dq_sc[t] * scale).astype(
                 dq_ref.dtype)
 
 
@@ -282,7 +270,7 @@ def flash_backward(q, k, v, out, lse, g, block_size: int, mask=None):
     results and the cotangent ``g`` of ``out``; dq like q, dk and dv like
     k and v."""
     b, s, h, dh = q.shape
-    mask = _causal() if mask is None else mask
+    mask = CAUSAL if mask is None else mask
     group = h // k.shape[2]
     tk = block_size
     tq = query_tile(s, mask)
@@ -290,40 +278,42 @@ def flash_backward(q, k, v, out, lse, g, block_size: int, mask=None):
     dd = jnp.einsum("bshd,bshd->hbs", g.astype(jnp.float32),
                     out.astype(jnp.float32))
 
-    def q_map(g_, j, step):
-        # start at the key tile's first query tile (see kv_map above)
-        i = mask.query_tile(j, step, tq, tk)
-        return g_, 0, mask.next_query_tile(j, i, tq, tk, s // tq)
+    tiles = mask.live_tiles(s, tq, tk, key_major=True)
 
-    def kv_map(g_, j, i):
-        return g_, 0, j
+    def q_map(g_, n, tiles):
+        return g_, 0, tiles[0, n]
 
-    def kv_in_map(g_, j, i):
-        return _kv_head(g_, b, group), 0, j
+    def kv_map(g_, n, tiles):
+        return g_, 0, tiles[1, n]
+
+    def kv_in_map(g_, n, tiles):
+        return _kv_head(g_, b, group), 0, tiles[1, n]
 
     grads = jax.ShapeDtypeStruct((h * b, dh, s), q.dtype)
     dq, dk, dv = pl.pallas_call(
         functools.partial(_bwd_kernel, scale=dh ** -0.5, tq=tq, tk=tk,
                           mask=mask),
-        grid=(h * b, s // tk, mask.query_steps(s, tq, tk)),
-        in_specs=[pl.BlockSpec((None, dh, tq), q_map),
-                  pl.BlockSpec((None, dh, tq), q_map),
-                  pl.BlockSpec((None, 1, tq), q_map),
-                  pl.BlockSpec((None, 1, tq), q_map),
-                  pl.BlockSpec((None, dh, tk), kv_in_map),
-                  pl.BlockSpec((None, dh, tk), kv_in_map)],
-        out_specs=[pl.BlockSpec((None, dh, s), lambda g_, j, i: (g_, 0, 0)),
-                   pl.BlockSpec((None, dh, tk), kv_map),
-                   pl.BlockSpec((None, dh, tk), kv_map)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(h * b, tiles.shape[1]),
+            in_specs=[pl.BlockSpec((None, dh, tq), q_map),
+                      pl.BlockSpec((None, dh, tq), q_map),
+                      pl.BlockSpec((None, 1, tq), q_map),
+                      pl.BlockSpec((None, 1, tq), q_map),
+                      pl.BlockSpec((None, dh, tk), kv_in_map),
+                      pl.BlockSpec((None, dh, tk), kv_in_map)],
+            out_specs=[pl.BlockSpec((None, dh, s),
+                                    lambda g_, n, tiles: (g_, 0, 0)),
+                       pl.BlockSpec((None, dh, tk), kv_map),
+                       pl.BlockSpec((None, dh, tk), kv_map)],
+            scratch_shapes=[pltpu.VMEM((s // tq, dh, tq), jnp.float32),
+                            pltpu.VMEM((dh, tk), jnp.float32),
+                            pltpu.VMEM((dh, tk), jnp.float32)]),
         out_shape=[grads, grads, grads],
-        scratch_shapes=[pltpu.VMEM((s // tq, dh, tq), jnp.float32),
-                        pltpu.VMEM((dh, tk), jnp.float32),
-                        pltpu.VMEM((dh, tk), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         name="flash_attention_bwd",
-    )(_heads_first(q), _heads_first(g.astype(q.dtype)),
+    )(tiles, _heads_first(q), _heads_first(g.astype(q.dtype)),
       jnp.einsum("bhs->hbs", lse).reshape(h * b, 1, s),
       dd.reshape(h * b, 1, s), _heads_first(k), _heads_first(v))
     if group > 1:

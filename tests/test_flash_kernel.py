@@ -29,6 +29,7 @@ from distributed_tensorflow_tpu.ops.attention import (
 )
 from distributed_tensorflow_tpu.utils import telemetry
 from distributed_tensorflow_tpu.utils.profiling import lowering_instant
+from tests.mask_tables import assert_tables_follow
 
 DH = 64
 # bf16 keeps 8 bits: operands, p, ds and the results are each rounded once
@@ -121,10 +122,11 @@ def test_fused_matches_the_reference(s, tile, h, reference):
 def test_fused_window_matches_the_dense_window_and_the_scan(fused_on_cpu, s,
                                                             window, tile, h,
                                                             hkv):
-    """The causal window over the kernels' BANDED grids (a window that is
-    and is not a multiple of the tile, one wider than the sequence,
+    """The causal window over the kernels' grids of live tiles (a window
+    that is and is not a multiple of the tile, one wider than the sequence,
     grouped-query heads): out, dq, dk, dv against the dense f32 window and
-    the scan on the same bf16 operands; the instant says the band."""
+    the scan on the same bf16 operands; the instant says the grid is the
+    tiles that run."""
     mask = attention.Mask("window", window=window)
     q, _, _, g = _operands(s, h)
     _, k, v, _ = _operands(s, hkv, seed=5)
@@ -139,11 +141,10 @@ def test_fused_window_matches_the_dense_window_and_the_scan(fused_on_cpu, s,
     tq = flash_attention.query_tile(s, mask)
     assert notes["forward"]["mask"] == "window" \
         and notes["forward"]["window"] == window
-    assert notes["forward"]["tiles_run"] == mask.tiles_run(s, tq, tile)
-    assert notes["forward"]["grid_steps"] == (
-        s // tq) * mask.key_steps(s, tq, tile) <= (s // tq) * (s // tile)
-    assert notes["backward"]["grid_steps"] == (
-        s // tile) * mask.query_steps(s, tq, tile)
+    live, masked = assert_tables_follow(mask, s, tq, tile)
+    for n in notes.values():
+        assert (n["tiles_run"], n["grid_steps"], n["masked_tiles"]) == (
+            live, live, masked)
     f32 = [x.astype(jnp.float32) for x in (q, k, v)]
     dense = _value_and_grads(
         lambda q, k, v: multi_head_attention(q, k, v, mask=mask), *f32, g)
@@ -160,15 +161,73 @@ def test_fused_window_matches_the_dense_window_and_the_scan(fused_on_cpu, s,
         assert _rel(a, b) < TOL and _rel(a, c) < TOL, name
 
 
-def test_the_causal_kernels_grid_is_every_tile_as_it_was(fused_on_cpu):
-    """Causal and block-diffusion masks walk every tile and skip through
-    the index maps: the band is the window's alone."""
-    q, k, v, _ = _operands(1024, 2)  # two query tiles of 512, eight of keys
-    _, notes = _paths(
-        lambda q, k, v: blockwise_attention(q, k, v, 128, causal=True),
-        q, k, v)
-    assert [(n["tiles_run"], n["grid_steps"]) for n in notes] == [(12, 16)]
+def test_the_causal_kernels_grid_is_the_tiles_that_run(fused_on_cpu):
+    """No mask's grid has a step that runs nothing: 12 of the 16 tiles are
+    at or under the diagonal, and 12 steps walk them (16 until PR 37, four
+    of them skipped); the diagonal crosses 8."""
+    q, k, v, g = _operands(1024, 2)  # two query tiles of 512, eight of keys
+
+    def loss(q, k, v):
+        out = blockwise_attention(q, k, v, 128, causal=True)
+        return jnp.sum(out.astype(jnp.float32) * g.astype(jnp.float32))
+
+    _, notes = _paths(jax.grad(loss, (0, 1, 2)), q, k, v)
+    assert [(n["pass"], n["tiles_run"], n["grid_steps"], n["masked_tiles"])
+            for n in notes] == [("forward", 12, 12, 8),
+                                ("backward", 12, 12, 8)]
     assert "window" not in notes[0] and "mask" not in notes[0]
+
+
+# the cells' own (mask, S, query tile, key tile) and what runs of them
+CELLS = {
+    "opt-125m.train-s2048": (attention.CAUSAL, 2048, 512, 512, (10, 4)),
+    "opt-1.3b.train-s2048": (attention.CAUSAL, 2048, 512, 512, (10, 4)),
+    "ouro-2.6b.train-b2-s4096": (attention.CAUSAL, 4096, 512, 512, (36, 8)),
+    "laguna-xs2.train-s8192 full": (attention.CAUSAL, 8192, 512, 512,
+                                    (136, 16)),
+    "laguna-xs2.train-s8192 window": (attention.Mask("window", window=512),
+                                      8192, 512, 512, (31, 31)),
+    "sdar-30b-a3b.train-s4096": (attention.Mask("block_diffusion", 4096, 4),
+                                 8192, 512, 512, (80, 24)),
+}
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_the_cells_tables_list_the_tiles_that_run(cell):
+    """(tiles that run, of which masked) a head, at the cells' shapes."""
+    mask, s, tq, tk, want = CELLS[cell]
+    assert flash_attention.query_tile(s, mask) == tq
+    assert assert_tables_follow(mask, s, tq, tk) == want
+
+
+@pytest.mark.parametrize("s,tq,tk", [
+    (512, 128, 128), (512, 256, 128), (512, 128, 256), (1024, 256, 256),
+    (1024, 512, 128)])
+@pytest.mark.parametrize("kind", ["causal", "window", "block_diffusion"])
+def test_the_tables_follow_the_dense_mask_at_the_same_shapes(kind, s, tq, tk):
+    """One enumeration for every mask: the three kinds at the same
+    sequences and tiles (query tiles larger and smaller than key tiles; a
+    window off the tile grid; diffusion blocks far smaller than a tile, so
+    a row has two live ranges)."""
+    mask = {"causal": attention.CAUSAL,
+            "window": attention.Mask("window", window=200),
+            "block_diffusion": attention.Mask("block_diffusion", s // 2, 4)
+            }[kind]
+    assert mask.tiles_fit(s, tq, tk)
+    live, masked = assert_tables_follow(mask, s, tq, tk)
+    assert 0 < masked <= live <= (s // tq) * (s // tk)
+
+
+def test_a_row_with_no_live_tile_is_refused():
+    """Every row and column of every mask has its diagonal tile; a table
+    that left one out would leave its result unwritten."""
+    class Hollow(attention.Mask):
+        def tile(self, q0, q1, k0, k1):
+            visible, runs = super().tile(q0, q1, k0, k1)
+            return visible, np.logical_and(runs, q0 > 0)
+
+    with pytest.raises(ValueError, match="nothing to run"):
+        Hollow("causal").live_tiles(512, 128, 128)
 
 
 def test_a_query_tile_smaller_than_the_key_tile(fused_on_cpu, monkeypatch):

@@ -4,9 +4,8 @@ to the plain reference of the family that brought the mechanism
 (``benchmark/reference/laguna.py``), and each mechanism held to something
 written independently:
 
-- the causal window ``Mask``: ``tile``, the banded grid's steps
-  (``key_steps`` / ``key_tile``, ``query_steps`` / ``query_tile``) and the
-  index maps (``next_key_tile`` / ``next_query_tile``) against ``allowed`` on
+- the causal window ``Mask``: ``tile`` and the kernels' tables of live tiles
+  (``live_tiles``) against ``allowed`` on
   every tile, for windows that are and are not multiples of the tile; the
   dense and the scan form against a mask built from indices (the fused
   kernels: ``tests/test_flash_kernel.py``);
@@ -55,6 +54,7 @@ from distributed_tensorflow_tpu.training.device_step import (
     make_device_train_step,
 )
 from distributed_tensorflow_tpu.utils import telemetry
+from tests.mask_tables import assert_tables_follow
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FAMILY = manifest.load_family(
@@ -291,8 +291,7 @@ WINDOWS = [(512, 128, 128, 128), (512, 200, 128, 128), (1024, 512, 256, 128),
 
 
 @pytest.mark.parametrize("seq,window,tq,tk", WINDOWS)
-def test_tiles_bands_and_index_maps_follow_the_dense_window(seq, window, tq,
-                                                            tk):
+def test_tiles_and_tables_follow_the_dense_window(seq, window, tq, tk):
     mask = Mask("window", window=window)
     nq, nk = seq // tq, seq // tk
     i, j = np.arange(seq)[:, None], np.arange(seq)[None, :]
@@ -304,31 +303,22 @@ def test_tiles_bands_and_index_maps_follow_the_dense_window(seq, window, tq,
     visible, runs = mask.tile(q0, q0 + tq - 1, k0, k0 + tk - 1)
     assert np.array_equal(np.asarray(visible), every)
     assert np.array_equal(np.asarray(runs), some)
-    assert mask.tiles_run(seq, tq, tk) == some.sum()
-    ks, qs = mask.key_steps(seq, tq, tk), mask.query_steps(seq, tq, tk)
-    assert ks == some.sum(axis=1).max() and qs == some.sum(axis=0).max()
-    for a in range(nq):  # the forward grid: every tile that runs, once
-        tiles = [int(mask.key_tile(a, step, ks, tq, tk)) for step in range(ks)]
-        held = [int(mask.next_key_tile(a, t, tq, tk)) for t in tiles]
-        assert all(some[a, h] for h in held)
-        assert [t for t in tiles if 0 <= t < nk and some[a, t]] \
-            == list(np.flatnonzero(some[a]))
-        assert all(h == t for h, t in zip(held, tiles)
-                   if 0 <= t < nk and some[a, t])
-    for b in range(nk):  # the backward grid
-        tiles = [int(mask.query_tile(b, step, tq, tk)) for step in range(qs)]
-        held = [int(mask.next_query_tile(b, t, tq, tk, nq)) for t in tiles]
-        assert all(some[h, b] for h in held)
-        assert [t for t in tiles if t < nq and some[t, b]] \
-            == list(np.flatnonzero(some[:, b]))
+    # the kernels' grids: every tile that runs, once, and no other step
+    assert assert_tables_follow(mask, seq, tq, tk) == (
+        some.sum(), (some & ~every).sum())
+    # a row's steps are the band the window reaches, whatever S
+    assert np.bincount(mask.live_tiles(seq, tq, tk)[0]).max() \
+        == some.sum(axis=1).max() <= (tq + window - 2) // tk + 2
 
 
-def test_the_cells_window_layers_run_31_of_32_steps_a_head():
+def test_the_cells_window_layers_run_31_steps_a_head():
     mask = Mask("window", window=512)
-    assert mask.key_steps(8192, 512, 512) == 2
-    assert mask.query_steps(8192, 512, 512) == 2
+    for key_major in (False, True):  # 32 steps of a band of 2 until PR 37
+        qi, kj, _ = mask.live_tiles(8192, 512, 512, key_major)
+        assert qi.size == 31 and np.all((qi == kj) | (qi == kj + 1))
     assert mask.tiles_run(8192, 512, 512) == 31
     assert Mask("causal").tiles_run(8192, 512, 512) == 136
+    assert Mask("causal").live_tiles(8192, 512, 512).shape == (3, 136)
     with pytest.raises(ValueError, match="window"):
         Mask("window")
     with pytest.raises(ValueError, match="window"):

@@ -61,6 +61,8 @@ from distributed_tensorflow_tpu.training.device_step import (
     make_device_train_step,
 )
 from distributed_tensorflow_tpu.training.train_state import make_eval_step
+from distributed_tensorflow_tpu.utils import telemetry
+from tests.mask_tables import assert_tables_follow
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FAMILY = manifest.load_family(
@@ -271,33 +273,22 @@ def test_mask_description_is_equation_4(seq, lb):
 @pytest.mark.parametrize("seq,lb,tq,tk", [
     (256, 4, 128, 128), (256, 128, 128, 128), (512, 128, 256, 256),
     (512, 4, 256, 128), (512, 64, 128, 256)])
-def test_tiles_and_index_maps_follow_the_dense_mask(seq, lb, tq, tk):
-    """The three-way test of a tile and the two index maps, against the
-    dense mask cut into the same tiles."""
+def test_tiles_and_tables_follow_the_dense_mask(seq, lb, tq, tk):
+    """The three-way test of a tile and the kernels' two tables
+    (``live_tiles``), against the dense mask cut into the same tiles."""
     mask = Mask("block_diffusion", seq, lb)
     dense = np.asarray(FAMILY.dense_mask(seq, lb))
     nq, nk = 2 * seq // tq, 2 * seq // tk
-    runs = np.zeros((nq, nk), bool)
     for i in range(nq):
         for j in range(nk):
             tile = dense[i * tq:(i + 1) * tq, j * tk:(j + 1) * tk]
             visible, some = mask.tile(i * tq, (i + 1) * tq - 1,
                                       j * tk, (j + 1) * tk - 1)
             assert (bool(visible), bool(some)) == (tile.all(), tile.any())
-            runs[i, j] = tile.any()
-
-    def held(x, running):
-        later = [r for r in running if r >= x]
-        return later[0] if later else running[-1]
-
-    for i in range(nq):
-        running = list(np.flatnonzero(runs[i]))
-        assert [int(mask.next_key_tile(i, j, tq, tk)) for j in range(nk)] \
-            == [held(j, running) for j in range(nk)]
-    for j in range(nk):
-        running = list(np.flatnonzero(runs[:, j]))
-        assert [int(mask.next_query_tile(j, i, tq, tk)) for i in range(nq)] \
-            == [held(i, running) for i in range(nq)]
+    cut = dense.reshape(nq, tq, nk, tk)
+    some, every = cut.any(axis=(1, 3)), cut.all(axis=(1, 3))
+    assert assert_tables_follow(mask, 2 * seq, tq, tk) == (
+        some.sum(), (some & ~every).sum())
 
 
 def test_eighty_of_the_cells_256_tiles_run():
@@ -339,6 +330,37 @@ def test_kernel_form_matches_the_dense_mask(seq, lb, tile):
     assert got[2].shape == k.shape and got[3].shape == v.shape
     for a, b in zip(got, want):
         assert _rel(a, b) < 1e-2  # bf16 keeps 8 bits
+
+
+def test_kernel_form_with_several_live_ranges_a_row(monkeypatch):
+    """Tiles a quarter of the half on both sides: a noised query tile's
+    live key tiles are two ranges with a gap between them (its own noised
+    tile, then the clean tiles up to its own), a clean key tile's query
+    tiles likewise; values and gradients against the dense mask and the
+    scan, and the grid is the 24 of 64 tiles that run."""
+    monkeypatch.setattr(flash_attention, "MAX_QUERY_TILE", 128)
+    seq, lb, tile = 512, 4, 128
+    mask = Mask("block_diffusion", seq, lb)
+    qi, kj, _ = mask.live_tiles(2 * seq, tile, tile)
+    assert list(kj[qi == 2]) == [2, 4, 5, 6] and qi.size == 24
+    q, k, v, g = _qkvg(2 * seq, 4, 2, 64, jnp.bfloat16, seed=3)
+    assert attention.fusable(q, k, v, tile, mask)
+    want = _dense_by_equation_4(q, k, v, g, seq, lb)
+
+    def attend(q, k, v):
+        return blockwise_attention(q, k, v, tile, mask=mask)
+
+    scan = _value_and_grads(attend, q, k, v, g)
+    telemetry.get_tracer().clear()
+    with kernels_interpreted():
+        got = _value_and_grads(attend, q, k, v, g)
+    notes = [n for n in telemetry.last_spans(100)
+             if n["name"] == "attention_path"]
+    assert [(n["path"], n["q_tile"], n["tiles_run"], n["grid_steps"],
+             n["masked_tiles"]) for n in notes] == [
+        ("fused", 128, 24, 24, 12)] * 2
+    for name, a, b, c in zip(("out", "dq", "dk", "dv"), got, want, scan):
+        assert _rel(a, b) < 1e-2 and _rel(a, c) < 1e-2, name
 
 
 @pytest.mark.parametrize("why,seq,lb,tile,dtype", [
@@ -397,9 +419,12 @@ def test_the_causal_case_is_bit_equal_to_what_it_was(dtype):
     assert np.array_equal(np.asarray(as_flag[0]),
                           np.asarray(jax.jit(_scan_as_it_was, static_argnums=3)(
                               q, k, v, 32)))
-    # the kernels are handed the causal description with today's arguments
-    assert Mask("causal").next_key_tile(3, 9, 512, 512) == 3
-    assert Mask("causal").next_query_tile(5, 2, 512, 512) == 5
+    # the kernels are handed the causal description: query tile 3's keys
+    # stop at its diagonal, key tile 5's queries start there
+    qi, kj, _ = Mask("causal").live_tiles(8192, 512, 512)
+    assert list(kj[qi == 3]) == [0, 1, 2, 3]
+    qi, kj, _ = Mask("causal").live_tiles(8192, 512, 512, key_major=True)
+    assert list(qi[kj == 5]) == list(range(5, 16))
 
 
 # ---- dropless ----------------------------------------------------------------
