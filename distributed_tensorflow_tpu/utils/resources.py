@@ -33,17 +33,20 @@ telemetry spine:
   memory samples (already riding the ring), what the budget SAID the
   state should cost, and which buffers actually held the HBM.
 - **Recompilation sentry** — ``CompileSentry`` counts and times every
-  XLA compile (a ``jax.monitoring`` backend-compile listener — cache
-  hits don't fire) and keys dispatches by TRACED SIGNATURE
-  (``observe(site, signature)``): the first signature per site is the
-  expected first compile, every NEW signature after it is a recompile,
-  and the report names the exact shape/dtype delta (the dimension that
-  churned). ``--recompile_budget N`` arms a sentinel-ladder storm
-  warning: more than N recompiles inside a rolling window prints the
-  offending delta, drops a ``recompile_storm`` instant span, and dumps
-  the flight recorder — the shape-churn failure mode the serving
-  bucket system and schedules.py exist to prevent, now detectable when
-  it regresses.
+  program the backend is asked for, compiled or loaded from the
+  persistent cache (a ``jax.monitoring`` listener — jit's in-memory
+  cache hits don't fire), emits one span for each of jax's three
+  compile phases of every program (``compile_trace`` /
+  ``compile_lower`` / ``compile_backend``), and keys dispatches by
+  TRACED SIGNATURE (``observe(site, signature)``): the first signature
+  per site is the expected first compile, every NEW signature after it
+  is a recompile, and the report names the exact shape/dtype delta (the
+  dimension that churned). ``--recompile_budget N`` arms a
+  sentinel-ladder storm warning: more than N recompiles inside a
+  rolling window prints the offending delta, drops a
+  ``recompile_storm`` instant span, and dumps the flight recorder — the
+  shape-churn failure mode the serving bucket system and schedules.py
+  exist to prevent, now detectable when it regresses.
 - **Comm ledger** — ``comm_ledger`` composes a static per-step analytic
   of collective wire bytes from the parallel modules' OWN row builders
   (``zero_comm_rows`` / ``pp_comm_rows`` / ``tp_comm_rows`` /
@@ -76,6 +79,17 @@ TOP_LIVE_BUFFERS = 8       # largest live buffers in the postmortem
 MEM_SAMPLE_RING = 64       # samples MemoryMeter retains for dumps
 RECOMPILE_WINDOW_S = 60.0  # rolling window behind --recompile_budget
 MAX_SIGS_PER_SITE = 256    # signature-ledger cap (FIFO eviction)
+
+# jax's compile phases (jax/_src/dispatch.py), each reported as a time span
+# on the epoch clock with the program's name; the persistent cache's events
+# fire on the compiling thread inside the backend phase
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_ASKED_EVENT = "/jax/compilation_cache/compile_requests_use_cache"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+CACHE_WRITE_EVENT = "/jax/compilation_cache/cache_misses"  # fires on a write
+CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 F32_BYTES = 4
 
@@ -704,16 +718,27 @@ def _sig_delta(old, new) -> str:
 
 
 class CompileSentry:
-    """Counts and times every XLA compile, detects recompiles by traced
-    signature, and trips a storm warning past ``--recompile_budget``.
+    """Counts and times every XLA compile or cache load, detects
+    recompiles by traced signature, and trips a storm warning past
+    ``--recompile_budget``.
 
-    Two sources, one ledger: the ``jax.monitoring`` backend-compile
-    listener (installed once per process, forwarding to the ACTIVE
-    sentry) supplies ``compiles_total`` / ``compile_time_s`` — every
-    program the backend was asked for, jit's in-memory cache hits don't
+    Two sources, one ledger: the ``jax.monitoring`` compile listener
+    (installed once per process, forwarding to the ACTIVE sentry)
+    supplies ``compiles_total`` / ``compile_time_s`` — every program the
+    backend was asked for, compiled or loaded from the persistent cache
+    (jax's backend phase wraps both), jit's in-memory cache hits don't
     fire; ``compile_cache_hits`` counts those of them that were LOADED
     from the persistent compilation cache (utils/compile_cache.py), not
-    compiled. ``observe(site, signature)``
+    compiled. The same listener emits one completed span a compile
+    phase, with jax's own start and end and the program's name as
+    ``fun``: ``compile_trace`` (the jaxpr; a function traced inside
+    another trace or a lowering is part of that program and gets none),
+    ``compile_lower`` (the MLIR module) and
+    ``compile_backend`` (XLA's compile or the cache load), whose
+    ``cache`` says which: ``hit`` (with ``retrieval_s``), ``miss`` (not
+    in the persistent cache, so compiled; ``stored`` when it was then
+    written there, inside the span) or ``off`` (no persistent cache
+    asked). ``observe(site, signature)``
     — called by the loops at each dispatch and by the serving engine
     per bucket — supplies the recompile story: the first signature a
     site ever shows is its expected first compile; a NEW signature
@@ -744,19 +769,64 @@ class CompileSentry:
         self._recent: deque = deque(
             maxlen=(self.budget + 1) if self.budget else 1024)
         self.last_delta: str | None = None
+        # per thread: the traces and lowerings open (a function traced
+        # inside either is part of that program, no program of its own)
+        # and the cache's events of the backend phase open, attached to
+        # its span when it closes
+        self._thread = threading.local()
+
+    def _cache_events(self) -> dict:
+        seen = getattr(self._thread, "seen", None)
+        if seen is None:
+            seen = self._thread.seen = {}
+        return seen
+
+    def on_compile_start(self, event: str) -> None:
+        if event in (TRACE_EVENT, LOWER_EVENT):
+            self._thread.open = getattr(self._thread, "open", 0) + 1
 
     def on_compile_event(self, event: str, dur: float) -> None:
-        if not event.endswith("backend_compile_duration"):
+        if event == CACHE_RETRIEVAL_EVENT:
+            self._cache_events()["retrieval_s"] = float(dur)
+            return
+        if event != BACKEND_EVENT:
             return
         with self._lock:
             self.compiles_total += 1
             self.compile_time_s += float(dur)
 
     def on_cache_event(self, event: str) -> None:
-        if event != "/jax/compilation_cache/cache_hits":
+        if event in (CACHE_ASKED_EVENT, CACHE_HIT_EVENT, CACHE_WRITE_EVENT):
+            self._cache_events()[event] = True
+        if event != CACHE_HIT_EVENT:
             return
         with self._lock:
             self.compile_cache_hits += 1
+
+    def on_compile_span(self, event: str, start: float, end: float,
+                        fun: str) -> None:
+        """One compile phase of one program, as a completed span on the
+        compiling thread (a child of the span open there)."""
+        if event in (TRACE_EVENT, LOWER_EVENT):
+            self._thread.open = max(0, getattr(self._thread, "open", 0) - 1)
+        if event == TRACE_EVENT:
+            if not self._thread.open:
+                telemetry.record_span("compile_trace", ts=start,
+                                      dur_s=end - start, fun=fun)
+        elif event == LOWER_EVENT:
+            telemetry.record_span("compile_lower", ts=start,
+                                  dur_s=end - start, fun=fun)
+        elif event == BACKEND_EVENT:
+            seen, self._thread.seen = self._cache_events(), None
+            attrs = {"cache": "off"}
+            if seen.get(CACHE_HIT_EVENT):
+                attrs = {"cache": "hit",
+                         "retrieval_s": seen.get("retrieval_s", 0.0)}
+            elif seen.get(CACHE_ASKED_EVENT) and _cache_dir_set():
+                attrs = {"cache": "miss",
+                         "stored": bool(seen.get(CACHE_WRITE_EVENT))}
+            telemetry.record_span("compile_backend", ts=start,
+                                  dur_s=end - start, fun=fun, **attrs)
 
     def site_signatures(self, site: str) -> int:
         with self._lock:
@@ -832,6 +902,14 @@ _ACTIVE_LOCK = threading.Lock()
 _LISTENER = {"installed": False}
 
 
+def _cache_dir_set() -> bool:
+    """Whether the persistent cache has a directory: jax asks for a
+    program's key whenever the cache is enabled, with or without one."""
+    import jax
+
+    return bool(jax.config.jax_compilation_cache_dir)
+
+
 def _install_compile_listener() -> None:
     with _ACTIVE_LOCK:
         if _LISTENER["installed"]:
@@ -850,8 +928,20 @@ def _install_compile_listener() -> None:
             if s is not None:
                 s.on_cache_event(event)
 
+        def _on_start(event, value, **kw):  # a phase's start time
+            s = _ACTIVE.get("sentry")
+            if s is not None:
+                s.on_compile_start(event)
+
+        def _on_time_span(event, start_time, end_time, fun_name="", **kw):
+            s = _ACTIVE.get("sentry")
+            if s is not None:
+                s.on_compile_span(event, start_time, end_time, fun_name)
+
         jax.monitoring.register_event_duration_secs_listener(_on_duration)
         jax.monitoring.register_event_listener(_on_event)
+        jax.monitoring.register_scalar_listener(_on_start)
+        jax.monitoring.register_event_time_span_listener(_on_time_span)
     except Exception as e:  # noqa: BLE001 — no jax, no compile events
         print(f"resources: compile listener unavailable: {e}")
 
