@@ -15,6 +15,7 @@ loop's termination test reads it (``:173``).
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, NamedTuple
 
 import jax
@@ -409,25 +410,32 @@ _EVAL_NOISE_SALT = 0xE7A1  # the eval's noise key: fold_in(key(seed), this)
 
 
 def make_eval_step(model):
-    """(params, batch, model_state) -> metrics, dropout off — the
+    """(params, batch, model_state=()) -> metrics, dropout off — the
     reference's eval run (``MNISTDist.py:181-182``) but usable on the *test*
     set too (the reference never evaluates on test data; the build's
     targets require it). A model whose objective noises its batch
-    (``noise_batch``: masked diffusion) is evaluated under a key folded
-    from its ``noise_seed``, the same at every call, so that two evals of
-    one state on one batch agree."""
+    (``noise_batch``: masked diffusion) is evaluated under one key, made
+    here on the host as ``fold_in(PRNGKey(noise_seed), _EVAL_NOISE_SALT)``
+    and passed to every call: two evals of one state on one batch agree,
+    and the program does not depend on the seed, so a run on a new seed
+    loads it from the persistent compile cache. A model without
+    ``noise_batch`` gets the program without that argument."""
     noise_fn = getattr(model, "noise_batch", None)
 
     @jax.jit
-    def eval_fn(params, batch, model_state=()):
-        if noise_fn is not None:
-            batch = noise_fn(batch, jax.random.fold_in(
-                jax.random.PRNGKey(model.noise_seed), _EVAL_NOISE_SALT))
+    def eval_fn(params, batch, model_state=(), *, noise_key=None):
+        if noise_key is not None:
+            batch = noise_fn(batch, noise_key)
         _, aux = loss_and_metrics(model, params, batch, train=False,
                                   model_state=model_state)
         return aux["metrics"]
 
-    return eval_fn
+    if noise_fn is None:
+        return eval_fn
+    # a host array: every process of a multi-host mesh passes the same one
+    noise_key = jax.device_get(jax.random.fold_in(
+        jax.random.PRNGKey(model.noise_seed), _EVAL_NOISE_SALT))
+    return functools.partial(eval_fn, noise_key=noise_key)
 
 
 def evaluate(model, params, dataset, batch_size: int = 1000, eval_fn=None,

@@ -60,7 +60,10 @@ from distributed_tensorflow_tpu.training import (
 from distributed_tensorflow_tpu.training.device_step import (
     make_device_train_step,
 )
-from distributed_tensorflow_tpu.training.train_state import make_eval_step
+from distributed_tensorflow_tpu.training.train_state import (
+    loss_and_metrics,
+    make_eval_step,
+)
 from distributed_tensorflow_tpu.utils import telemetry
 from tests.mask_tables import assert_tables_follow
 
@@ -891,6 +894,141 @@ def test_the_noise_comes_from_the_key_and_the_eval_agrees_with_itself():
                         "moe_dispatch_tiles_frac"}
     # about (1 - 4/16)^4 = 0.32 of the rows choose none of the four held
     assert 0.2 < float(one["moe_unrouted_frac"]) < 0.45
+
+
+def lowered_eval(eval_step, *args):
+    """The text of ``make_eval_step``'s program: a model that noises its
+    batch gets the jitted eval with its key bound as a keyword."""
+    program = getattr(eval_step, "func", eval_step)
+    keywords = getattr(eval_step, "keywords", {})
+    return program.lower(*args, **keywords).as_text()
+
+
+def test_the_eval_program_is_the_same_at_every_seed():
+    ds, _ = small_data()
+    x = jnp.asarray(ds.images[:8])
+    batch = (x, jnp.asarray(ds.labels[:8]))
+    params = small_model().init(jax.random.key(0))
+    texts = [lowered_eval(make_eval_step(small_model(noise_seed=s)),
+                          params, batch) for s in (3, 4)]
+    assert texts[0] == texts[1]
+
+    # the control: the key folded inside the program bakes the seed in
+    def key_inside(seed):
+        model = small_model(noise_seed=seed)
+
+        @jax.jit
+        def eval_fn(params, batch, model_state=()):
+            noised = model.noise_batch(batch, jax.random.fold_in(
+                jax.random.PRNGKey(seed), 0xE7A1))
+            return loss_and_metrics(model, params, noised, train=False,
+                                    model_state=model_state)[1]["metrics"]
+
+        return eval_fn.lower(params, batch).as_text()
+
+    assert key_inside(3) != key_inside(4)
+
+    # a model that noises nothing: the program it had, with no key
+    lm = get_model("lm", vocab_size=50, seq_len=16, d_model=32,
+                   num_heads=2, num_blocks=1)
+    lm_params = lm.init(jax.random.key(0))
+    tokens = jnp.zeros((2, 16), jnp.int32)
+
+    @jax.jit
+    def eval_fn(params, batch, model_state=()):
+        _, aux = loss_and_metrics(lm, params, batch, train=False,
+                                  model_state=model_state)
+        return aux["metrics"]
+
+    step = make_eval_step(lm)
+    assert not hasattr(step, "keywords")
+    assert lowered_eval(step, lm_params, (tokens, tokens)) == \
+        eval_fn.lower(lm_params, (tokens, tokens)).as_text()
+
+
+@contextlib.contextmanager
+def prng_impl(name):
+    prev = jax.config.jax_default_prng_impl
+    jax.config.update("jax_default_prng_impl", name)
+    try:
+        yield
+    finally:
+        jax.config.update("jax_default_prng_impl", prev)
+
+
+@pytest.mark.parametrize("impl", ["threefry2x32", "rbg"])
+def test_the_eval_noises_under_the_key_it_always_had(impl):
+    ds, _ = small_data()
+    x = jnp.asarray(ds.images[:8])
+    batch = (x, jnp.asarray(ds.labels[:8]))
+    with prng_impl(impl):
+        model = small_model()
+        params = model.init(jax.random.key(0))
+        got = make_eval_step(model)(params, batch)
+        key = jax.random.fold_in(jax.random.PRNGKey(SEED), 0xE7A1)
+        want = jax.jit(lambda p, b: loss_and_metrics(
+            model, p, model.noise_batch(b, key),
+            train=False)[1]["metrics"])(params, batch)
+        other = make_eval_step(small_model(noise_seed=SEED + 1))(params,
+                                                                 batch)
+    assert key.shape == {"threefry2x32": (2,), "rbg": (4,)}[impl]
+    assert {k: float(v) for k, v in got.items()} == \
+        {k: float(v) for k, v in want.items()}
+    assert float(got["loss"]) != float(other["loss"])
+    assert float(got["diffusion_masked_frac"]) != \
+        float(other["diffusion_masked_frac"])
+
+
+CACHED_EVAL = """
+import json, sys
+import jax, jax.numpy as jnp
+jax.config.update("jax_compilation_cache_dir", sys.argv[1])
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+from distributed_tensorflow_tpu.models import get_model
+from distributed_tensorflow_tpu.training.train_state import make_eval_step
+from distributed_tensorflow_tpu.utils import resources, telemetry
+kw = json.loads(sys.argv[2])
+model = get_model("lm", noise_seed=int(sys.argv[3]), **kw)
+params = model.init(jax.random.key(0))
+x = jnp.arange(8 * 64, dtype=jnp.int32).reshape(8, 64) % 299
+eval_step = make_eval_step(model)
+sentry = resources.CompileSentry()
+resources.activate(sentry=sentry)
+resources._install_compile_listener()
+metrics = eval_step(params, (x, x))
+spans = [r for r in telemetry.last_spans(2048)
+         if r["name"] == "compile_backend"]
+print(json.dumps({"spans": spans, "loss": float(metrics["loss"])}))
+"""
+
+
+def test_a_new_seed_loads_the_eval_from_the_cache(tmp_path):
+    kw = dict(vocab_size=300, seq_len=64, d_model=64, num_heads=4,
+              num_blocks=2, norm="rmsnorm", norm_eps=1e-6, rope_theta=1e6,
+              num_kv_heads=2, head_dim=16, qk_norm=True, mlp_gated=True,
+              biases=False, moe_experts=16, moe_top_k=4, moe_ffn_dim=32,
+              moe_first_expert=4, moe_held_experts=4, moe_capacity=4.0,
+              objective="masked_diffusion", diffusion_block=4, attn_block=16,
+              ce_block=16, remat=True)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_ENABLE_COMPILATION_CACHE="true", PYTHONPATH=REPO)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    out = []
+    for seed in (1, 2):
+        p = subprocess.run(
+            [sys.executable, "-c", CACHED_EVAL, str(tmp_path / "cache"),
+             json.dumps(kw), str(seed)],
+            cwd=REPO, env=env, capture_output=True, text=True, timeout=600)
+        assert p.returncode == 0, p.stderr[-3000:]
+        out.append(json.loads(p.stdout.strip().splitlines()[-1]))
+    first, second = out
+    evals = [[r for r in run["spans"] if r["fun"] == "jit(eval_fn)"]
+             for run in out]
+    assert [r["cache"] for r in evals[0]] == ["miss"]
+    assert [r["cache"] for r in evals[1]] == ["hit"]
+    assert all(r["cache"] == "hit" for r in second["spans"])
+    assert first["loss"] != second["loss"]  # each seed its own noise
 
 
 def test_todays_flags_build_todays_tree():
