@@ -372,8 +372,10 @@ def define_reference_flags():
                    "expert-parallel deployment; the others add nothing")
     DEFINE_integer("moe_held_experts", 0, "How many experts are held, "
                    "from --moe_first_expert on (0 = all of them)")
-    DEFINE_string("norm", "layernorm", "The LM's normalisation: layernorm "
-                  "or rmsnorm (no bias leaf)")
+    DEFINE_string("norm", "layernorm", "The LM's normalisation: layernorm, "
+                  "rmsnorm (no bias leaf) or rmsnorm_zero_centred (RMSNorm "
+                  "whose leaf w gives the gain 1 + w, drawn as 0; the "
+                  "--qk_norm gains too)")
     DEFINE_float("norm_eps", 1e-5, "Epsilon of --norm (and of --qk_norm)")
     DEFINE_float("rope_theta", 0.0, "If > 0, rotary positions of this "
                  "base on q and k (rotate-half form) in place of the "
@@ -394,8 +396,10 @@ def define_reference_flags():
                    "0 = 4 x --d_model")
     DEFINE_string("layer_plan", "", "Layers that DIFFER: one entry a "
                   "layer, <attention>:<query heads>:<feed-forward> joined "
-                  "by commas, attention full (the model's causal mask) or "
-                  "window (--attn_window keys), feed-forward dense (the "
+                  "by commas, attention full (the model's causal mask), "
+                  "window (--attn_window keys) or linear (the gated delta "
+                  "rule of --linear_key_heads; its heads are value heads), "
+                  "feed-forward dense (the "
                   "--mlp_gated MLP of 4 x --d_model) or routed "
                   "(--moe_top_k); e.g. full:48:dense,window:64:routed. "
                   "As many entries as --num_blocks. Empty: every layer "
@@ -422,6 +426,22 @@ def define_reference_flags():
     DEFINE_integer("moe_shared_dim", 0, "If > 0, beside the routed "
                    "experts of --moe_top_k one gated expert of this width "
                    "that every row takes, whole on every chip")
+    DEFINE_boolean("moe_shared_gate", False, "A sigmoid gate a row on "
+                   "--moe_shared_dim's expert, from the layer's normalised "
+                   "input through a (d_model, 1) matrix")
+    DEFINE_boolean("attn_gate_elementwise", False, "A sigmoid gate an "
+                   "element on the attention's output: q's projection is "
+                   "twice as wide, [q ; gate] a head")
+    DEFINE_integer("linear_key_heads", 0, "Key (and query) heads of "
+                   "--layer_plan's linear layers (Gated DeltaNet: the gated "
+                   "delta rule, ops/linear_attention.py); must divide each "
+                   "linear layer's value heads")
+    DEFINE_integer("linear_key_dim", 0, "Width of a linear layer's key "
+                   "head")
+    DEFINE_integer("linear_value_dim", 0, "Width of a linear layer's value "
+                   "head")
+    DEFINE_integer("linear_conv", 0, "Taps of a linear layer's causal "
+                   "depthwise convolution over its q, k and v channels")
     DEFINE_string("moe_scoring", "softmax", "How --moe_top_k's router "
                   "turns logits into the scores it ranks and weights by: "
                   "softmax over all experts, or sigmoid of each")
@@ -1002,6 +1022,8 @@ def _validate_model_data_flags(values: dict):
 def _validate_lm_arch_flags(values: dict):
     """The LM's further choices: each flag's own range, then the pairs
     that would be silently inert or cannot be built."""
+    from distributed_tensorflow_tpu.models.transformer import NORMS
+
     _require(values, "moe_top_k", lambda v: int(v) >= 0,
              "must be >= 0 (0 = the top-1 Switch layer)")
     _require(values, "moe_ffn_dim", lambda v: int(v) >= 0,
@@ -1010,8 +1032,8 @@ def _validate_lm_arch_flags(values: dict):
              "must be >= 0 (an index among --moe_experts)")
     _require(values, "moe_held_experts", lambda v: int(v) >= 0,
              "must be >= 0 (0 = every expert)")
-    _require(values, "norm", lambda v: v in ("layernorm", "rmsnorm"),
-             "must be layernorm or rmsnorm")
+    _require(values, "norm", lambda v: v in NORMS,
+             f"must be one of {', '.join(NORMS)}")
     _require(values, "norm_eps", lambda v: float(v) > 0, "must be > 0")
     _require(values, "rope_theta", lambda v: float(v) >= 0,
              "must be >= 0 (0 = learned positions)")
@@ -1114,6 +1136,27 @@ def _validate_layer_plan_flags(values: dict):
     _require(values, "moe_scoring", lambda v: v in SCORINGS,
              f"must be one of {', '.join(SCORINGS)}")
     _require(values, "moe_scale", lambda v: float(v) > 0, "must be > 0")
+    _require(values, "moe_shared_gate", lambda v: isinstance(v, bool),
+             "must be a boolean")
+    _require(values, "attn_gate_elementwise", lambda v: isinstance(v, bool),
+             "must be a boolean")
+    _require(values, "linear_key_heads", lambda v: int(v) >= 0,
+             "must be >= 0 (0 = no linear layers)")
+    _require(values, "linear_key_dim", lambda v: int(v) >= 0,
+             "must be >= 0 (0 = no linear layers)")
+    _require(values, "linear_value_dim", lambda v: int(v) >= 0,
+             "must be >= 0 (0 = no linear layers)")
+    _require(values, "linear_conv", lambda v: int(v) >= 0,
+             "must be >= 0 (0 = no linear layers)")
+    linear = ("linear_key_heads", "linear_key_dim", "linear_value_dim",
+              "linear_conv")
+    if values.get("moe_shared_gate") and not values.get("moe_shared_dim"):
+        raise ValueError("--moe_shared_gate gates --moe_shared_dim's expert: "
+                         "without it it would silently change nothing")
+    if values.get("attn_gate") and values.get("attn_gate_elementwise"):
+        raise ValueError("--attn_gate (a gate a head) and "
+                         "--attn_gate_elementwise (a gate an element) are two "
+                         "forms of one gate — drop one")
     parse_rope_yarn(values.get("rope_yarn") or "")
     fraction, head_dim = values.get("rope_fraction"), values.get("head_dim")
     if fraction and head_dim and (float(fraction) * int(head_dim)) % 2:
@@ -1134,11 +1177,17 @@ def _validate_layer_plan_flags(values: dict):
                          "shape the routed layer: without --moe_top_k they "
                          "would silently change nothing")
     plan = values.get("layer_plan")
+    some_linear = any(values.get(n) for n in linear)
     if not plan:
         if values.get("attn_window") or values.get("window_rope_theta"):
             raise ValueError("--attn_window and --window_rope_theta are "
                              "the window layers' of --layer_plan: without "
                              "it they would silently change nothing")
+        if some_linear:
+            raise ValueError("--linear_key_heads, --linear_key_dim, "
+                             "--linear_value_dim and --linear_conv are the "
+                             "linear layers' of --layer_plan: without it "
+                             "they would silently change nothing")
         return
     entries = parse_layer_plan(plan)
     blocks = values.get("num_blocks")
@@ -1146,8 +1195,18 @@ def _validate_layer_plan_flags(values: dict):
         raise ValueError(f"--layer_plan names {len(entries)} layers, "
                          f"--num_blocks={blocks}")
     kv = int(values.get("num_kv_heads") or 0)
+    key_heads = int(values.get("linear_key_heads") or 0)
     for attention, heads, ffn in entries:
-        if kv and heads % kv:
+        if attention == "linear":
+            if not all(values.get(n) for n in linear):
+                raise ValueError("--layer_plan names a linear layer: add "
+                                 "--linear_key_heads, --linear_key_dim, "
+                                 "--linear_value_dim and --linear_conv")
+            if heads % key_heads:
+                raise ValueError(f"--layer_plan: a linear layer's {heads} "
+                                 f"value heads do not divide over "
+                                 f"--linear_key_heads={key_heads}")
+        elif kv and heads % kv:
             raise ValueError(f"--layer_plan: {heads} query heads do not "
                              f"divide over --num_kv_heads={kv}")
         if attention == "window" and not values.get("attn_window"):
@@ -1163,6 +1222,9 @@ def _validate_layer_plan_flags(values: dict):
             e[0] == "window" for e in entries):
         raise ValueError("--attn_window is set and --layer_plan names no "
                          "window layer")
+    if some_linear and not any(e[0] == "linear" for e in entries):
+        raise ValueError("--linear_key_heads and the other --linear_* flags "
+                         "are set and --layer_plan names no linear layer")
     if values.get("objective") not in (None, "next_token"):
         raise ValueError("--layer_plan runs under --objective next_token")
     for other in ("seq_parallel", "pipeline", "expert_parallel"):

@@ -78,9 +78,19 @@ class BlockArch(NamedTuple):
     ffn: str = "dense"          # or "switch" / "routed" (ops/moe.py)
     shared_dim: int = 0         # routed: a gated expert every row takes
     sandwich: bool = False      # a second norm on each half's OUTPUT
+    attn_gate_elementwise: bool = False  # a sigmoid gate an element of a
+    #                                      head, from q's projection's second half
+    shared_gate: bool = False   # routed: a sigmoid gate a row on the shared
+    #                             expert's output
+    linear: tuple = ()          # a linear layer's (key heads, key width,
+    #                             value width, conv taps); ``heads`` are its
+    #                             value heads (``_linear_half``)
 
 
-LAYER_ATTENTIONS = ("full", "window")
+LAYER_ATTENTIONS = ("full", "window", "linear")
+# RMSNorm whose leaf is w in x / rms(x) * (1 + w), w drawn as 0
+ZERO_CENTRED = "rmsnorm_zero_centred"
+NORMS = ("layernorm", "rmsnorm", ZERO_CENTRED)
 LAYER_FFNS = ("dense", "routed")
 
 
@@ -129,6 +139,12 @@ def _rmsnorm(x, gain, eps):
     return (y * gain).astype(x.dtype)
 
 
+def _gain(g, arch):
+    """An RMSNorm's gain from its leaf: the leaf, or one plus the leaf where
+    the norm is zero-centred."""
+    return 1.0 + g if arch.norm == ZERO_CENTRED else g
+
+
 def _norm(x, params, prefix, arch):
     """The normalisation whose leaves are ``params[prefix + "g"]`` (and
     ``"b"``), by ``arch``."""
@@ -137,11 +153,19 @@ def _norm(x, params, prefix, arch):
     if arch.norm == "layernorm":
         return _layernorm(x, params[prefix + "g"], params[prefix + "b"],
                           arch.norm_eps)
-    return _rmsnorm(x, params[prefix + "g"], arch.norm_eps)
+    return _rmsnorm(x, _gain(params[prefix + "g"], arch), arch.norm_eps)
+
+
+def _gain_leaf(shape, dtype, arch):
+    """A norm's gain as it is drawn: ones, or zeros where the norm is
+    zero-centred."""
+    if arch is not None and arch.norm == ZERO_CENTRED:
+        return jnp.zeros(shape, dtype)
+    return jnp.ones(shape, dtype)
 
 
 def _norm_params(prefix, d, dtype, arch):
-    out = {prefix + "g": jnp.ones((d,), dtype)}
+    out = {prefix + "g": _gain_leaf((d,), dtype, arch)}
     if arch is None or arch.norm == "layernorm":
         out[prefix + "b"] = jnp.zeros((d,), dtype)
     return out
@@ -209,17 +233,19 @@ def _attn_half_params(w, d, h, dh, dtype, arch=None):
     and MoE block forms (like _attn_half on the compute side), so the
     layouts cannot diverge. Fewer key/value heads than query heads split
     ``qkv`` into ``q`` and ``kv``; ``qk_norm`` adds a gain a head width;
-    ``attn_gate`` a (d, H) matrix, one gate a head."""
+    ``attn_gate`` a (d, H) matrix, one gate a head;
+    ``attn_gate_elementwise`` doubles ``q``'s width a head, [q ; gate]."""
     kv = h if arch is None or not arch.kv_heads else arch.kv_heads
+    elementwise = arch is not None and arch.attn_gate_elementwise
     out = _norm_params("ln1_", d, dtype, arch)
-    if kv == h:
+    if kv == h and not elementwise:
         out["qkv"] = w((d, 3, h, dh))
     else:
-        out["q"] = w((d, h, dh))
+        out["q"] = w((d, h, 2 * dh if elementwise else dh))
         out["kv"] = w((d, 2, kv, dh))
     if arch is not None and arch.qk_norm:
-        out["q_norm_g"] = jnp.ones((dh,), dtype)
-        out["k_norm_g"] = jnp.ones((dh,), dtype)
+        out["q_norm_g"] = _gain_leaf((dh,), dtype, arch)
+        out["k_norm_g"] = _gain_leaf((dh,), dtype, arch)
     out["proj"] = w((h * dh, d))
     if arch is not None and arch.attn_gate:
         out["gate"] = w((d, h))
@@ -247,7 +273,7 @@ def _block_params(w, d, h, dh, mlp_dim, dtype, arch=None):
     """One pre-LN block's parameter dict (shared by both transformer
     families so their checkpoints stay structurally interchangeable)."""
     return {
-        **_attn_half_params(w, d, h, dh, dtype, arch),
+        **_mixer_params(w, d, h, dh, dtype, arch),
         **_mlp_params(w, d, mlp_dim, dtype, arch),
     }
 
@@ -284,7 +310,10 @@ def _mlp_half(h, blk, cd, arch=None):
 
 def _attn_half(h, blk, attn_fn, cd, arch=None, pos=None):
     """LN -> attention -> residual (shared by the dense-MLP and MoE
-    block forms)."""
+    block forms); of a linear layer, LN -> the gated delta rule ->
+    residual."""
+    if arch is not None and arch.linear:
+        return _linear_half(h, blk, cd, arch)
     return _attn_half_kv(h, blk, attn_fn, cd, arch, pos)[0]
 
 
@@ -304,9 +333,11 @@ def _attn_half_kv(h, blk, attn_fn, cd, arch=None, pos=None):
         q = jnp.einsum("bsd,dhe->bshe", y, blk["q"].astype(y.dtype))
         kv = jnp.einsum("bsd,dthe->tbshe", y, blk["kv"].astype(y.dtype))
         k, v = kv[0], kv[1]
+    if arch is not None and arch.attn_gate_elementwise:
+        q, gate = jnp.split(q, 2, axis=-1)
     if arch is not None and arch.qk_norm:
-        q = _rmsnorm(q, blk["q_norm_g"], arch.norm_eps)
-        k = _rmsnorm(k, blk["k_norm_g"], arch.norm_eps)
+        q = _rmsnorm(q, _gain(blk["q_norm_g"], arch), arch.norm_eps)
+        k = _rmsnorm(k, _gain(blk["k_norm_g"], arch), arch.norm_eps)
     if arch is not None and arch.rope_theta:
         if pos is None:
             pos = jnp.arange(q.shape[1])
@@ -318,20 +349,100 @@ def _attn_half_kv(h, blk, attn_fn, cd, arch=None, pos=None):
         gate = jnp.einsum("bsd,dh->bsh", y, blk["gate"].astype(y.dtype))
         a = a * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
             a.dtype)[..., None]
+    elif arch is not None and arch.attn_gate_elementwise:
+        a = a * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(a.dtype)
     a = a.reshape(*a.shape[:2], -1)  # (B, S, H*Dh)
     return _residual(h, nn.dense(a, blk["proj"], compute_dtype=cd), blk,
                      "ln1_", arch), k, v
 
 
+def _linear_half_params(w, d, dtype, arch):
+    """The linear layer's half (``_linear_half``): ``qkvz`` (d, the q, k, v
+    and z channels, in that order), ``ba`` (d, 2 value heads: b, then a),
+    the conv's taps (kernel, the q, k and v channels) ~ U(-0.5, 0.5),
+    ``a_log`` = log A with A ~ U(1e-4, 16) and ``dt_bias`` ones a value
+    head, the gated norm's gain (ones), the output projection ``proj``."""
+    nk, dk, dv, taps = arch.linear
+    nv = arch.heads
+    out = _norm_params("ln1_", d, dtype, arch)
+    out["qkvz"] = w((d, 2 * nk * dk + 2 * nv * dv))
+    out["ba"] = w((d, 2 * nv))
+    out["conv"] = w((taps, 2 * nk * dk + nv * dv), uniform=(-0.5, 0.5))
+    out["a_log"] = jnp.log(w((nv,), uniform=(1e-4, 16.0)))
+    out["dt_bias"] = jnp.ones((nv,), dtype)
+    out["o_norm_g"] = jnp.ones((dv,), dtype)
+    out["proj"] = w((nv * dv, d))
+    out.update(_norm_params("ln2_", d, dtype, arch))
+    return out
+
+
+def _mixer_params(w, d, h, dh, dtype, arch):
+    """The first half of a layer: attention's, or the linear layer's."""
+    if arch is not None and arch.linear:
+        return _linear_half_params(w, d, dtype, arch)
+    return _attn_half_params(w, d, h, dh, dtype, arch)
+
+
+def _l2norm(x, eps=1e-6):
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+
+
+@scoped("attn_proj")
+def _linear_half(h, blk, cd, arch):
+    """LN -> the linear layer (Gated DeltaNet) -> residual: the projections
+    ``qkvz`` and ``ba`` and the output projection under ``attn_proj``; all
+    between them under ``linear_attention``: the causal depthwise conv of
+    the q, k and v channels and its SiLU, beta = sigmoid(b) and the log
+    decay g = -exp(a_log) softplus(a + dt_bias) a value head and token, the
+    l2 norms of q and k (q over sqrt(dk)), each key head repeated to the
+    value heads that read it (value head n reads key head n // (value heads
+    / key heads)), the gated delta rule (``ops/linear_attention.py``), and
+    RMSNorm(o) * gain * silu(z) a value head, all in float32."""
+    from distributed_tensorflow_tpu.ops.linear_attention import (
+        gated_delta_rule,
+    )
+
+    y = _norm(h, blk, "ln1_", arch)
+    qkvz = nn.dense(y, blk["qkvz"], compute_dtype=cd)
+    ba = nn.dense(y, blk["ba"], compute_dtype=cd)
+    nk, dk, dv, _ = arch.linear
+    nv = arch.heads
+    b, s, _ = qkvz.shape
+    with scope("linear_attention"):
+        f32 = jnp.float32
+        mixed = 2 * nk * dk + nv * dv
+        taps = blk["conv"]
+        # depthwise over the sequence, causal: c_t = sum_i w_i x_{t-K+1+i}
+        x = jax.nn.silu(lax.conv_general_dilated(
+            qkvz[..., :mixed].astype(f32), taps.astype(f32)[:, None, :],
+            window_strides=(1,), padding=((taps.shape[0] - 1, 0),),
+            dimension_numbers=("NWC", "WIO", "NWC"),
+            feature_group_count=mixed, precision=lax.Precision.HIGHEST))
+        q = _l2norm(x[..., :nk * dk].reshape(b, s, nk, dk)) * dk ** -0.5
+        k = _l2norm(x[..., nk * dk:2 * nk * dk].reshape(b, s, nk, dk))
+        q, k = (jnp.repeat(t, nv // nk, axis=2) for t in (q, k))
+        beta = jax.nn.sigmoid(ba[..., :nv].astype(f32))
+        g = -jnp.exp(blk["a_log"].astype(f32)) * jax.nn.softplus(
+            ba[..., nv:].astype(f32) + blk["dt_bias"].astype(f32))
+        o = gated_delta_rule(q, k, x[..., 2 * nk * dk:].reshape(b, s, nv, dv),
+                             g, beta)
+        z = qkvz[..., mixed:].astype(f32).reshape(b, s, nv, dv)
+        o = _rmsnorm(o, blk["o_norm_g"].astype(f32), arch.norm_eps) \
+            * jax.nn.silu(z)
+        o = o.reshape(b, s, nv * dv).astype(y.dtype)
+    return _residual(h, nn.dense(o, blk["proj"], compute_dtype=cd), blk,
+                     "ln1_", arch)
+
+
 def _routed_block_params(w, d, h, dh, ffn_dim, num_experts, held, dtype,
                          arch):
-    """Routed block: the attention half of ``_block_params``; the
+    """Routed block: the first half of ``_block_params``; the
     feed-forward is ``held`` gated experts of width ``ffn_dim`` behind a
     router over ``num_experts`` (``ops/moe.py:routed_experts``), and where
     ``arch.shared_dim`` one more gated expert, whole here, that every row
-    takes."""
+    takes (``arch.shared_gate``: behind a sigmoid gate, a (d, 1) matrix)."""
     out = {
-        **_attn_half_params(w, d, h, dh, dtype, arch),
+        **_mixer_params(w, d, h, dh, dtype, arch),
         "moe": {
             "router": w((d, num_experts)),
             "w1": w((held, d, 2 * ffn_dim)),
@@ -341,6 +452,8 @@ def _routed_block_params(w, d, h, dh, ffn_dim, num_experts, held, dtype,
     if arch.shared_dim:
         out["shared"] = {"w1": w((d, 2 * arch.shared_dim)),
                          "w2": w((arch.shared_dim, d))}
+        if arch.shared_gate:
+            out["shared"]["gate"] = w((d, 1))
     return out
 
 
@@ -356,11 +469,16 @@ class Routing(NamedTuple):
 
 def _shared_expert(y, shared, cd):
     """The expert every row takes: silu(gate) * up from one (d, 2 f)
-    matrix, the gate's columns first (as the routed experts')."""
+    matrix, the gate's columns first (as the routed experts'); where the
+    expert has a ``gate`` leaf (d, 1), times sigmoid(y gate) a row."""
     up = nn.dense(y, shared["w1"], compute_dtype=cd)
     f = up.shape[-1] // 2
-    return nn.dense(jax.nn.silu(up[..., :f]) * up[..., f:], shared["w2"],
-                    compute_dtype=cd)
+    out = nn.dense(jax.nn.silu(up[..., :f]) * up[..., f:], shared["w2"],
+                   compute_dtype=cd)
+    if "gate" in shared:
+        gate = nn.dense(y, shared["gate"], compute_dtype=cd)
+        out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(out.dtype)
+    return out
 
 
 def _transformer_block_routed(h, blk, attn_fn, cd, arch, pos, routing):
@@ -436,7 +554,7 @@ def exit_log_distribution(gate_logits):
     return stayed + jnp.concatenate([jax.nn.log_sigmoid(g[:-1]), none])
 
 
-def _remat(fn, static_argnums, **says):
+def _remat(fn, static_argnums, shows=REMAT_KEPT, **says):
     """``fn`` (a block) under ``--remat``: ``jax.checkpoint`` whose backward
     pass recomputes the block from its input, all but the values that
     ``ops/attention.py`` names (``REMAT_KEPT``: a blockwise attention's
@@ -446,17 +564,22 @@ def _remat(fn, static_argnums, **says):
     whose attention is dense or the ring names nothing and keeps nothing.
     The one wrapper of every site that rematerializes a block.
 
-    The first block that shows the policy every name records the
-    ``remat_saved`` instant: the names and the bytes a block they cost
-    (and ``says``: of a plan, which kind of layer this is)."""
+    The first block that shows the policy every name it ``shows`` records
+    the ``remat_saved`` instant: the names and the bytes a block they cost
+    (and ``says``: of a plan, which kind of layer this is). A block that
+    shows none (a linear layer: its scan is run again, and nothing of it
+    is kept) records the instant at once, with no name and no bytes."""
     named = jax.checkpoint_policies.save_only_these_names(*REMAT_KEPT)
     kept = {}
+    if not shows:
+        telemetry.get_tracer().record_instant(
+            "remat_saved", names=[], bytes_per_block=0, **says)
 
     def policy(prim, *avals, **params):
         keep = named(prim, *avals, **params)
         if keep and params["name"] not in kept:
             kept[params["name"]] = avals[0].size * avals[0].dtype.itemsize
-            if len(kept) == len(REMAT_KEPT):
+            if len(kept) == len(shows):
                 telemetry.get_tracer().record_instant(
                     "remat_saved", names=sorted(kept),
                     bytes_per_block=sum(kept.values()), **says)
@@ -642,7 +765,16 @@ class TransformerLM:
     expert beside every routed layer's, ``moe_scoring`` and ``moe_scale``
     say how the router's logits become weights. ``plan`` is the tuple of
     the layers' ``BlockArch`` values that ``init`` and the forward pass
-    walk; without ``layer_plan`` its entries are one value.
+    walk; without ``layer_plan`` its entries are one value. An entry
+    ``linear:<value heads>:<feed-forward>`` is a LINEAR layer (Gated
+    DeltaNet, ``_linear_half``: the gated delta rule of
+    ``ops/linear_attention.py`` over ``linear_key_heads`` key heads of
+    ``linear_key_dim`` and its value heads of ``linear_value_dim``, after a
+    causal conv of ``linear_conv`` taps); ``attn_gate_elementwise`` gates
+    the attention layers' output an element, from a second half of q's
+    projection; ``moe_shared_gate`` puts a sigmoid gate a row on the shared
+    expert; ``norm="rmsnorm_zero_centred"`` is RMSNorm whose leaves are the
+    gain less one (drawn as zeros).
 
     ``loop_passes=T`` > 1 runs the stack T TIMES over the same weights
     (``_run_passes``: one ``lax.scan``, so one set of layers in the program
@@ -717,12 +849,18 @@ class TransformerLM:
         sandwich_norm: bool = False,
         loop_passes: int = 1,
         loop_exit_beta: float = 0.0,
+        attn_gate_elementwise: bool = False,
+        moe_shared_gate: bool = False,
+        linear_key_heads: int = 0,
+        linear_key_dim: int = 0,
+        linear_value_dim: int = 0,
+        linear_conv: int = 0,
         **_unused,
     ):
         if d_model % num_heads and not head_dim:
             raise ValueError(f"d_model={d_model} % num_heads={num_heads} != 0")
-        if norm not in ("layernorm", "rmsnorm"):
-            raise ValueError(f"norm={norm!r} is neither layernorm nor rmsnorm")
+        if norm not in NORMS:
+            raise ValueError(f"norm={norm!r} is not one of {NORMS}")
         if objective not in ("next_token", "masked_diffusion"):
             raise ValueError(f"objective={objective!r} is neither next_token "
                              f"nor masked_diffusion")
@@ -777,7 +915,16 @@ class TransformerLM:
                          rope_yarn=parse_rope_yarn(rope_yarn),
                          attn_gate=bool(attn_gate), ffn=ffn,
                          shared_dim=int(moe_shared_dim) if self.moe_top_k
-                         else 0, sandwich=bool(sandwich_norm))
+                         else 0, sandwich=bool(sandwich_norm),
+                         attn_gate_elementwise=bool(attn_gate_elementwise),
+                         shared_gate=bool(moe_shared_gate))
+        if arch.attn_gate and arch.attn_gate_elementwise:
+            raise ValueError("attn_gate (one gate a head) and "
+                             "attn_gate_elementwise (one an element) are two "
+                             "forms of one gate: pick one")
+        if moe_shared_gate and not moe_shared_dim:
+            raise ValueError("moe_shared_gate gates the shared expert: it "
+                             "needs moe_shared_dim > 0")
         if (arch.rope_fraction != 1.0 or arch.rope_yarn) and not rope_theta:
             raise ValueError("rope_fraction and rope_yarn shape rotary "
                              "positions: they need rope_theta > 0")
@@ -793,6 +940,9 @@ class TransformerLM:
                                float(moe_scale))
         self.layer_plan = str(layer_plan or "")
         self.attn_window = int(attn_window)
+        # the linear layers' (key heads, key width, value width, conv taps)
+        self.linear = (int(linear_key_heads), int(linear_key_dim),
+                       int(linear_value_dim), int(linear_conv))
         self.plan = self._build_plan(arch, float(window_rope_theta))
         if self.moe_top_k and (moe_axis is not None or not (
                 0 <= self.moe_first_expert
@@ -832,6 +982,10 @@ class TransformerLM:
             if self.attn_window or window_rope_theta:
                 raise ValueError("attn_window and window_rope_theta are the "
                                  "window layers': name those in layer_plan")
+            if any(self.linear):
+                raise ValueError("linear_key_heads, linear_key_dim, "
+                                 "linear_value_dim and linear_conv are the "
+                                 "linear layers': name those in layer_plan")
             return (arch,) * self.num_blocks
         if self.seq_axis is not None or self.moe_axis is not None \
                 or self.objective != "next_token":
@@ -845,7 +999,15 @@ class TransformerLM:
         kv = arch.kv_heads
         plan = []
         for attention, heads, ffn in entries:
-            if kv and heads % kv:
+            if attention == "linear":
+                if min(self.linear) < 1 or heads % self.linear[0]:
+                    raise ValueError(
+                        "layer_plan names a linear layer: it needs "
+                        "linear_key_heads, linear_key_dim, linear_value_dim "
+                        "and linear_conv > 0, and its value heads "
+                        f"({heads}) divided over the key heads "
+                        f"({self.linear[0]})")
+            elif kv and heads % kv:
                 raise ValueError(f"layer_plan: {heads} query heads do not "
                                  f"divide over {kv} key/value heads")
             if ffn == "routed" and not self.moe_top_k:
@@ -861,10 +1023,16 @@ class TransformerLM:
                 if window_rope_theta:
                     layer = layer._replace(rope_theta=window_rope_theta,
                                            rope_fraction=1.0, rope_yarn=())
+            elif attention == "linear":
+                layer = layer._replace(linear=self.linear)
             plan.append(layer)
         if self.moe_top_k and not any(x.ffn == "routed" for x in plan):
             raise ValueError("moe_top_k > 0 and layer_plan names no routed "
                              "layer")
+        if any(self.linear) and not any(x.linear for x in plan):
+            raise ValueError("linear_key_heads, linear_key_dim, "
+                             "linear_value_dim and linear_conv are set and "
+                             "layer_plan names no linear layer")
         return tuple(plan)
 
     @property
@@ -877,11 +1045,14 @@ class TransformerLM:
         d, dh = self.d_model, self.head_dim
         arch = self.arch
         # 8 keys a layer; a plan's layers may hold more matrices (a gate, a
-        # shared expert): 12
+        # shared expert and its gate, the linear layer's conv and decays):
+        # 12
         keys = iter(jax.random.split(
             key, 4 + (12 if self.layer_plan else 8) * self.num_blocks))
 
-        def w(shape, stddev=0.02):
+        def w(shape, stddev=0.02, uniform=None):
+            if uniform is not None:  # U(low, high)
+                return jax.random.uniform(next(keys), shape, dtype, *uniform)
             return truncated_normal_init(next(keys), shape, stddev, dtype)
 
         params = {"tok": w((self.vocab_size, d))}
@@ -975,8 +1146,11 @@ class TransformerLM:
             if self.remat:
                 says = {}
                 if self.layer_plan:
-                    says = {"attention": "window" if layer.window else "full",
+                    says = {"attention": "linear" if layer.linear else
+                            "window" if layer.window else "full",
                             "heads": layer.heads, "ffn": layer.ffn}
+                if layer.linear:  # names nothing: its scan runs again
+                    says["shows"] = ()
                 block = _remat(_planned_block, (2, 3, 4, 6), **says)
 
             def run(h, blk, ids, block=block, attn=attn, layer=layer):

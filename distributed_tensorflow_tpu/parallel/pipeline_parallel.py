@@ -292,6 +292,10 @@ def _pp_step_fn(model, optimizer, mesh, microbatches: int,
         raise ValueError("pipeline parallelism stages BLOCKS; it does "
                          "not compose with seq_axis (ring attention) — "
                          "pick one model-axis strategy")
+    if any(getattr(x, "linear", False) for x in getattr(model, "plan", ())):
+        raise ValueError("tensor parallelism has no linear-attention layer: the "
+                         "gated delta rule's state and its conv are not "
+                         "split over the model axis yet")
     if getattr(model, "layer_plan", ""):
         raise ValueError("pipeline parallelism stacks layers of one kind "
                          "into a stage's scan; a model with a layer_plan "

@@ -218,6 +218,10 @@ def shard_attention(model, mesh: Mesh):
     those is the same mathematics with local shapes, and each shard
     chooses kernel or scan from ITS shapes as a single chip would. Models
     without ``attn_block`` (dense attention is plain XLA) pass through."""
+    if any(getattr(x, "linear", False) for x in getattr(model, "plan", ())):
+        raise ValueError("tensor parallelism has no linear-attention layer: the "
+                         "gated delta rule's state and its conv are not "
+                         "split over the model axis yet")
     if getattr(model, "layer_plan", ""):
         raise ValueError("tensor parallelism splits one head count over "
                          "the model axis; a model with a layer_plan (head "

@@ -147,7 +147,9 @@ def _lm_arch_kwargs(FLAGS) -> dict:
              "attn_window", "window_rope_theta", "rope_fraction",
              "rope_yarn", "attn_gate", "moe_shared_dim", "moe_scoring",
              "moe_scale", "mlp_dim", "sandwich_norm", "loop_passes",
-             "loop_exit_beta")
+             "loop_exit_beta", "attn_gate_elementwise", "moe_shared_gate",
+             "linear_key_heads", "linear_key_dim", "linear_value_dim",
+             "linear_conv")
     out = {n: getattr(FLAGS, n) for n in names if hasattr(FLAGS, n)}
     out["noise_seed"] = int(FLAGS.seed)
     return out

@@ -71,7 +71,7 @@ WATCHDOG_LAST_SPANS = 32
 # tuple, tests/test_scopes.py holds the compiled step to it).
 SCOPES = ("attention", "attn_proj", "mlp", "lm_head", "embed", "optimizer",
           "sample_batch", "moe_router", "moe_experts", "attention_window",
-          "moe_shared", "loop_exit")
+          "moe_shared", "loop_exit", "linear_attention")
 
 
 def _json_safe(v):

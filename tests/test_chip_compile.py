@@ -490,3 +490,67 @@ def test_the_looped_cells_step_holds_one_accumulator_of_the_shared_gradients(
     assert _device_bytes(compiled) < 0.9 * V5E_HBM_BYTES
     assert cell.config["bytes"]["parameters"] == model.num_params() \
         == cell.family().total_params(cell.sizes)
+
+
+def test_the_linear_cells_step_compiles_with_the_chunked_rule_and_dh_256_fused(
+        one_chip):
+    """``qwen3-next-80b-a3b.train-b1-s16384``, built from its configuration's
+    own file through its family's flags: the whole step (1 x 16,384 tokens,
+    three linear layers and a gated full-attention layer at the published
+    widths, remat, adam on f32 masters) for the described chip. The full
+    layer's attention lowers to the flash kernels at a head width of 256
+    under ``attention`` (528 of 1,024 tiles a head); each linear layer's
+    core is under ``linear_attention``, in chunks of 64 (256 a sequence), its
+    ``(I + A)^-1`` the compiler's triangular inverse, not a kernel of ours;
+    the experts' products are Mosaic kernels; and ``memory_analysis()``
+    holds the arguments the configuration's ``bytes`` records and no more
+    temporaries than it records."""
+    from benchmark.harness import manifest
+    from distributed_tensorflow_tpu.data.device_data import DeviceData
+    from distributed_tensorflow_tpu.utils import telemetry
+
+    cell = manifest.load_cell("qwen3-next-80b-a3b.train-b1-s16384")
+    trainer = cell.config["trainer"]
+    seq, batch = cell.mix["seq_len"], cell.mix["batch_per_chip"]
+    model = TransformerLM(
+        seq_len=seq, compute_dtype=jnp.bfloat16,
+        attn_block=trainer["attn_block"], ce_block=trainer["ce_block"],
+        remat=trainer["remat"], moe_capacity=trainer["moe_capacity"],
+        **cell.family().trainer_flags(cell.config, cell.mix))
+    opt = adam(trainer["learning_rate"])
+    state = jax.eval_shape(lambda: create_train_state(model, opt, seed=0))
+    data = DeviceData(jax.ShapeDtypeStruct((4096, seq), jnp.uint16),
+                      jax.ShapeDtypeStruct((4096, seq), jnp.uint16))
+    step = make_device_train_step(model, opt, batch, keep_prob=1.0, chunk=1)
+    telemetry.get_tracer().clear()
+    compiled = step.lower(*_on(one_chip, (state, data))).compile()
+    notes = telemetry.last_spans(200)
+    assert _attention_grids(notes) == [
+        ("backward", "causal", "fused", 528, 528, 32),
+        ("forward", "causal", "fused", 528, 528, 32)]
+    path = {r["name"]: r for r in notes}["linear_attention_path"]
+    assert (path["chunk"], path["chunks"], path["state_bytes_per_head"]) == (
+        64, 256, 128 * 128 * 4)
+    hlo = compiled.as_text()
+    kernels = {}
+    for p in re.findall(
+            r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', hlo):
+        scope = [e for e in re.findall(r"[A-Za-z_]\w*", p)
+                 if e in telemetry.SCOPES][-1]
+        name = re.search(r"flash_attention_\w+|tgmm|gmm", p).group(0)
+        kernels[scope, name] = kernels.get((scope, name), 0) + 1
+    assert kernels == {
+        ("attention", "flash_attention_fwd"): 1,
+        ("attention", "flash_attention_bwd"): 1,
+        ("moe_experts", "gmm"): 4 * 6, ("moe_experts", "tgmm"): 4 * 2}
+    solves = re.findall(
+        r'custom_call_target="InvertDiagBlocksLowerTriangular".*?'
+        r'op_name="([^"]*)"', hlo)
+    assert solves and all("linear_attention" in p for p in solves)
+    ma = compiled.memory_analysis()
+    recorded = cell.config["bytes"]["compiled_step_for_described_v5e"]
+    assert ma.argument_size_in_bytes == recorded["arguments"]
+    assert 0.97 * recorded["temp"] < ma.temp_size_in_bytes <= recorded["temp"]
+    assert _device_bytes(compiled) == recorded["total"] < 0.85 * 16.91e9
+    assert cell.config["bytes"]["parameters"] == model.num_params() \
+        == cell.family().total_params(cell.sizes)

@@ -391,7 +391,7 @@ ROUTED = ["--moe_experts=8", "--moe_top_k=2", "--mlp_gated"]
 
 @pytest.mark.parametrize("argv,needle", [
     (["--layer_plan=full:4"], "<attention>:<query heads>:<feed-forward>"),
-    (["--layer_plan=linear:4:dense"], "attention one of"),
+    (["--layer_plan=conv:4:dense"], "attention one of"),
     (["--layer_plan=full:0:dense"], "heads >= 1"),
     (["--layer_plan=full:4:switch"], "feed-forward one of"),
     (["--layer_plan=full:4:dense", "--num_blocks=2"], "names 1 layers"),

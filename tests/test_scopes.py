@@ -5,7 +5,9 @@ blockwise attention, with the dense MLP (``mlp``) or the dropless routed
 layer in its place (``moe_router``, ``moe_experts``, under the
 masked-diffusion objective), or with a plan of layers that differ (a dense
 full-attention layer and a routed window layer with a shared expert:
-``attention_window`` and ``moe_shared`` beside all the others), or with the
+``attention_window`` and ``moe_shared`` beside all the others; or a routed
+linear layer and a routed full one: ``linear_attention`` beside
+``attention``, both inside ``attn_proj``), or with the
 stack run three times over shared weights (``loop_exit``: the exit gate and
 the weighting of the passes' losses, never around a block: the scan over
 passes itself is under no scope); a scope
@@ -39,22 +41,29 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROUTED = {"moe_router", "moe_experts"}
 PLANNED = {"attention_window", "moe_shared"}
 LOOPED = {"loop_exit"}
+LINEAR = {"linear_attention"}
 FORMS = [(remat, block, False) for remat in (False, True)
          for block in (None, 16)] + [(False, 16, True), (True, 16, True),
                                      (False, None, "plan"), (True, 16, "plan"),
-                                     (False, None, "loop"), (True, 16, "loop")]
+                                     (False, None, "loop"), (True, 16, "loop"),
+                                     (False, None, "linear"),
+                                     (True, 16, "linear")]
 
 
 def scopes_of(routed) -> set:
     """The catalog's names a form opens: the feed-forward is the dense
     MLP or the routed layer, never both, unless a plan gives a layer of
     each, which also has the window layers' attention and the shared
-    expert."""
+    expert; a plan of a linear layer and a full one, routed both, has the
+    linear layer's core and the shared expert, and no dense MLP."""
     if routed == "plan":
-        return set(SCOPES) - LOOPED
+        return set(SCOPES) - LOOPED - LINEAR
     if routed == "loop":
-        return set(SCOPES) - PLANNED - ROUTED
-    return set(SCOPES) - PLANNED - LOOPED - ({"mlp"} if routed else ROUTED)
+        return set(SCOPES) - PLANNED - ROUTED - LINEAR
+    if routed == "linear":
+        return set(SCOPES) - LOOPED - {"mlp", "attention_window"}
+    return set(SCOPES) - PLANNED - LOOPED - LINEAR - (
+        {"mlp"} if routed else ROUTED)
 
 
 def build(remat, attn_block, routed=False):
@@ -68,7 +77,14 @@ def build(remat, attn_block, routed=False):
                   qk_norm=True, mlp_gated=True, biases=False, moe_experts=8,
                   moe_top_k=2, moe_ffn_dim=32, moe_held_experts=4,
                   moe_capacity=2.0)
-        if routed == "plan":
+        if routed == "linear":
+            kw.update(norm="rmsnorm_zero_centred",
+                      layer_plan="linear:4:routed,full:2:routed",
+                      linear_key_heads=2, linear_key_dim=8,
+                      linear_value_dim=8, linear_conv=4,
+                      attn_gate_elementwise=True, moe_shared_dim=32,
+                      moe_shared_gate=True)
+        elif routed == "plan":
             kw.update(layer_plan="full:2:dense,window:3:routed",
                       attn_window=8, window_rope_theta=1e2, rope_fraction=0.5,
                       rope_yarn="4,16,8,1,1.1", attn_gate=True,
@@ -105,6 +121,11 @@ def test_every_scope_of_the_catalog_is_in_the_compiled_step(remat,
     if routed == "plan":  # beside attention, never inside it
         assert not any({"attention", "attention_window"} <= scopes_in(p)
                        for p in paths)
+    if routed == "linear":  # beside attention, inside attn_proj, backward too
+        assert not any({"attention", "linear_attention"} <= scopes_in(p)
+                       for p in paths)
+        assert any(re.search(r"attn_proj\)?/linear_attention", p)
+                   and "transpose(" in p for p in paths)
     if routed == "loop":
         # the gate and the weighting forward and backward; the scan over
         # passes and the blocks inside it are not under it
@@ -122,7 +143,9 @@ def test_every_scope_of_the_catalog_is_in_the_compiled_step(remat,
                for p in paths)
     # attention is opened inside attn_proj and is the innermost there
     assert any(re.search(r"attn_proj\)?/attention", p) for p in paths)
-    assert any("rematted_computation" in p for p in paths) == remat
+    # the delta rule recomputes its chunks in its own backward, remat or not
+    assert any("rematted_computation" in p for p in paths) == (
+        remat or routed == "linear")
 
 
 @pytest.mark.parametrize("remat,attn_block,routed", FORMS)
