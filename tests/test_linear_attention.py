@@ -7,7 +7,11 @@ mechanism held to something written independently:
 
 - the chunked delta rule (``ops/linear_attention.py``) against the token
   recurrence, values and the gradients of all five inputs, in f32 to 1e-5,
-  for chunks of 64 and of 16, under strong and weak decays;
+  for chunks of 64 and of 16, under strong and weak decays; its Pallas
+  forward (``ops/linear_attention_kernel.py``, run by the Pallas
+  interpreter) against both, at the kernel's widths, the shapes it takes
+  and the chunks a grid step walks, and which of the two a program
+  lowered for the CPU runs;
 - loss, every leaf's gradient and the change after three Adam steps, in f32
   and in bf16 within a band that the float8 control fails;
 - the share ties to the whole: the parts of sixteen shares of a routed
@@ -24,6 +28,7 @@ gated shared expert of 16; three linear layers and a full one, S 128 (two
 chunks of 64).
 """
 
+import contextlib
 import functools
 import os
 import re
@@ -41,7 +46,11 @@ from distributed_tensorflow_tpu.data.device_data import DeviceData
 from distributed_tensorflow_tpu.data.lm import LMDataSet
 from distributed_tensorflow_tpu.models import get_model
 from distributed_tensorflow_tpu.models import transformer
-from distributed_tensorflow_tpu.ops import moe
+from distributed_tensorflow_tpu.ops import (
+    linear_attention,
+    linear_attention_kernel,
+    moe,
+)
 from distributed_tensorflow_tpu.ops.linear_attention import gated_delta_rule
 from distributed_tensorflow_tpu.training import (
     create_train_state,
@@ -129,14 +138,14 @@ def gradient_shares(other, ref):
 
 # ---- the chunked rule against the recurrence ---------------------------------
 
-def rule_inputs(decay, seed=0):
+def rule_inputs(decay, seed=0, shape=(2, 256, 3, 16, 8)):
     k = jax.random.split(jax.random.key(seed), 6)
-    b, s, h, dk, dv = 2, 256, 3, 16, 8
+    b, s, h, dk, dv = shape
 
     def l2(x):
         return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
 
-    q = l2(jax.random.normal(k[0], (b, s, h, dk))) / 4.0
+    q = l2(jax.random.normal(k[0], (b, s, h, dk))) * dk ** -0.5
     kk = l2(jax.random.normal(k[1], (b, s, h, dk)))
     v = jax.random.normal(k[2], (b, s, h, dv))
     g = -decay * jax.nn.softplus(jax.random.normal(k[3], (b, s, h)))
@@ -161,16 +170,113 @@ def test_the_chunked_rule_is_the_token_recurrence(chunk, decay):
         assert np.linalg.norm(a - b) <= 1e-5 * np.linalg.norm(b), name
 
 
+def path_notes():
+    """The ``linear_attention_path`` instants since the tracer was cleared,
+    picked by name: a compile sentry that an earlier test of the process
+    left active adds its compile spans beside them at every lowering."""
+    return [r for r in telemetry.last_spans(200)
+            if r["name"] == "linear_attention_path"]
+
+
 def test_the_sequence_must_divide_into_chunks():
     args, _ = rule_inputs(1.0)
     with pytest.raises(ValueError, match="does not divide"):
         gated_delta_rule(*(x[:, :100] for x in args))
     telemetry.get_tracer().clear()
-    jax.make_jaxpr(gated_delta_rule)(*args)
-    note = telemetry.last_spans(10)[-1]
+    # the instant is the lowering's, once a function
+    jax.jit(lambda *a: gated_delta_rule(*a)).lower(*args)
+    [note] = path_notes()
     assert (note["name"], note["implementation"], note["chunk"],
             note["chunks"], note["state_bytes_per_head"]) == (
         "linear_attention_path", "chunked_scan", 64, 4, 16 * 8 * 4)
+
+
+# ---- the kernel against both ------------------------------------------------
+
+KERNEL_SHAPE = (1, 512, 2, 128, 128)  # 8 chunks a head, the kernel's widths
+
+
+@contextlib.contextmanager
+def _kernel_interpreted():
+    """Steer the dispatch as a TPU lowering would, the kernel run by the
+    Pallas interpreter, four chunks a grid step (two steps a head at S =
+    512). The kernel's wrapper is jitted, so its traces are dropped on the
+    way in and out: none made under the patch is met outside it."""
+    lk = linear_attention_kernel
+    by_platform, pallas_call = linear_attention._by_platform, lk.pl.pallas_call
+    per_step = lk.CHUNKS_PER_STEP
+    lk.chunk_forward.clear_cache()
+    linear_attention._by_platform = lambda kernel, scan, *args: kernel(*args)
+    lk.pl.pallas_call = functools.partial(pallas_call, interpret=True)
+    lk.CHUNKS_PER_STEP = 4
+    try:
+        yield
+    finally:
+        linear_attention._by_platform = by_platform
+        lk.pl.pallas_call = pallas_call
+        lk.CHUNKS_PER_STEP = per_step
+        lk.chunk_forward.clear_cache()
+
+
+@pytest.mark.parametrize("decay", [3.0, 0.01])  # exp(g) ~ 0.1 and ~ 0.99
+def test_the_kernel_is_the_token_recurrence_and_the_scan(decay):
+    """The kernel's o, and the five gradients of the op that runs it (its
+    backward is the scan form's), against the token recurrence and against
+    the scan form, at dk = dv = 128 in chunks of 64, to 1e-5."""
+    args, cotangent = rule_inputs(decay, shape=KERNEL_SHAPE)
+
+    def both(fn):
+        out, vjp = jax.vjp(fn, *args)
+        return (out,) + vjp(cotangent)
+
+    with jax.default_matmul_precision("highest"):
+        recurrence = both(jax.vmap(FAMILY.delta_rule))
+        scan = both(lambda *a: linear_attention._chunked(*a, 64))
+        telemetry.get_tracer().clear()
+        with _kernel_interpreted():
+            # a function of its own: a program is lowered, and the
+            # instant recorded, once a function
+            kernel = both(jax.jit(lambda *a: gated_delta_rule(*a)))
+    assert [(r["implementation"], r["chunks"], r["grid_steps"],
+             r["chunks_per_step"]) for r in path_notes()] == [
+        ("chunk_kernel", 8, 2, 4)]
+    for want in (recurrence, scan):
+        for name, a, b in zip(("o", "dq", "dk", "dv", "dg", "dbeta"), kernel,
+                              want):
+            assert np.linalg.norm(a - b) <= 1e-5 * np.linalg.norm(b), name
+
+
+def test_a_program_lowered_for_the_cpu_runs_the_scan_at_the_kernels_widths():
+    """At widths the kernel takes, the lowering platform decides: the CPU
+    lowers the scan form (the described chip lowers the kernel:
+    ``tests/test_chip_compile.py``)."""
+    args, _ = rule_inputs(1.0, shape=KERNEL_SHAPE)
+    telemetry.get_tracer().clear()
+    text = jax.jit(lambda *a: gated_delta_rule(*a)).lower(*args).as_text()
+    assert [r["implementation"] for r in path_notes()] == ["chunked_scan"]
+    assert "tpu_custom_call" not in text
+
+
+@pytest.mark.parametrize("seq_len,dk,dv,chunk,fits", [
+    (512, 128, 128, 64, True),
+    (16384, 128, 256, 64, True),
+    (512, 64, 128, 64, False),    # dk off the 128 lanes
+    (512, 128, 96, 64, False),    # dv off the 128 lanes
+    (576, 128, 128, 64, False),   # nine chunks: not whole pairs
+    (480, 128, 128, 48, False),   # C not a power of two
+    (512, 128, 128, 4, False),    # C under a sublane tile
+])
+def test_the_kernel_takes_the_shapes_its_tiling_fits(seq_len, dk, dv, chunk,
+                                                     fits):
+    assert linear_attention.kernel_fits(seq_len, dk, dv, chunk) is fits
+
+
+@pytest.mark.parametrize("seq_len,per_step", [
+    (16384, 16), (512, 8), (384, 6), (128, 2), (64 * 34, 2)])
+def test_a_grid_step_walks_the_largest_even_divisor_of_the_chunks(seq_len,
+                                                                  per_step):
+    assert linear_attention_kernel.CHUNKS_PER_STEP == 16
+    assert linear_attention_kernel.chunks_per_step(seq_len, 64) == per_step
 
 
 # ---- the program against the reference --------------------------------------
@@ -264,8 +370,9 @@ def test_remat_keeps_nothing_of_a_linear_layer_and_says_so():
     params = model.init(jax.random.key(0))
     x = jnp.zeros((2, 128), jnp.int32)
     telemetry.get_tracer().clear()
-    jax.make_jaxpr(jax.grad(
-        lambda p: model.loss_with_metrics(p, x, x, train=True)[0]))(params)
+    jax.jit(jax.grad(
+        lambda p: model.loss_with_metrics(p, x, x, train=True)[0])).lower(
+            params)
     spans = telemetry.last_spans(200)
     kinds = {(r["attention"], r["heads"], r["ffn"]): (r["names"],
                                                       r["bytes_per_block"])

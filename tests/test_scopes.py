@@ -17,6 +17,7 @@ opens no scope that the catalog does not hold."""
 
 import ast
 import contextlib
+import functools
 import os
 import re
 
@@ -103,6 +104,14 @@ def build(remat, attn_block, routed=False):
     return step, state, data
 
 
+@functools.lru_cache(maxsize=None)
+def compiled(remat, attn_block, routed):
+    """A form's step as ``build`` makes it, compiled once for both tests
+    below: its text for the catalog, its outputs for the numbers."""
+    step, state, data = build(remat, attn_block, routed)
+    return step.lower(state, data).compile(), state, data
+
+
 def scopes_in(op_name: str) -> set:
     """Catalogued scopes among the elements of a path, seen through the
     transform wrappers (``transpose(jvp(mlp))`` holds ``mlp``)."""
@@ -113,8 +122,7 @@ def scopes_in(op_name: str) -> set:
 def test_every_scope_of_the_catalog_is_in_the_compiled_step(remat,
                                                             attn_block,
                                                             routed):
-    step, state, data = build(remat, attn_block, routed)
-    text = step.lower(state, data).compile().as_text()
+    text = compiled(remat, attn_block, routed)[0].as_text()
     paths = set(re.findall(r'op_name="([^"]*)"', text))
     found = set().union(*(scopes_in(p) for p in paths))
     assert found == scopes_of(routed)
@@ -124,7 +132,8 @@ def test_every_scope_of_the_catalog_is_in_the_compiled_step(remat,
     if routed == "linear":  # beside attention, inside attn_proj, backward too
         assert not any({"attention", "linear_attention"} <= scopes_in(p)
                        for p in paths)
-        assert any(re.search(r"attn_proj\)?/linear_attention", p)
+        # attn_proj as a backward names it: inside transpose(jvp(...))
+        assert any(re.search(r"attn_proj\)*/linear_attention", p)
                    and "transpose(" in p for p in paths)
     if routed == "loop":
         # the gate and the weighting forward and backward; the scan over
@@ -150,13 +159,12 @@ def test_every_scope_of_the_catalog_is_in_the_compiled_step(remat,
 
 @pytest.mark.parametrize("remat,attn_block,routed", FORMS)
 def test_a_scope_changes_no_number(remat, attn_block, routed, monkeypatch):
-    def outputs():
-        step, state, data = build(remat, attn_block, routed)
+    def outputs(step, state, data):
         new_state, metrics = step(state, data)
         return jax.device_get((new_state.params, new_state.opt_state,
                                metrics))
 
-    with_scopes = outputs()
+    with_scopes = outputs(*compiled(remat, attn_block, routed))
     opened = []
 
     @contextlib.contextmanager
@@ -165,7 +173,7 @@ def test_a_scope_changes_no_number(remat, attn_block, routed, monkeypatch):
         yield
 
     monkeypatch.setattr(jax, "named_scope", no_scope)
-    without = outputs()
+    without = outputs(*build(remat, attn_block, routed))
     assert set(opened) == scopes_of(routed)  # the patch was what the step opened
     for a, b in zip(jax.tree.leaves(with_scopes), jax.tree.leaves(without)):
         assert np.array_equal(np.asarray(a), np.asarray(b))
